@@ -190,7 +190,7 @@ def _block(blk, h, cfg, m_axis, s_axis):
     import jax
     import jax.numpy as jnp
 
-    from pio_tpu.parallel.compat import axis_size
+    from jax.lax import axis_size
     from pio_tpu.parallel.ring import ring_attention
     from pio_tpu.parallel.ulysses import ulysses_attention
 
@@ -273,7 +273,7 @@ def _trunk(params, seqs, cfg, m_axis, s_axis, p_axis):
     if p_axis is None:
         h = apply_stack(h, blocks)
     else:
-        from pio_tpu.parallel.compat import axis_size
+        from jax.lax import axis_size
         from pio_tpu.parallel.pipeline import pipeline_apply
 
         # Microbatch so the pipe stays busy: with one microbatch every
@@ -358,7 +358,7 @@ def train_seqrec(
     import jax
     import jax.numpy as jnp
     import optax
-    from pio_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     cfg = config

@@ -410,6 +410,8 @@ def run_record(*, run_id: str, engine_id: str, status: str,
                train_seconds: float, phases: Dict[str, float],
                params_hash: str, step_summary: Optional[dict] = None,
                num_devices: Optional[int] = None,
+               platform: Optional[str] = None,
+               device_kind: Optional[str] = None,
                shard_manifest: Optional[str] = None,
                timestamp: Optional[str] = None,
                error: Optional[str] = None) -> dict:
@@ -430,6 +432,8 @@ def run_record(*, run_id: str, engine_id: str, status: str,
         "params_hash": params_hash,
         "train_seconds": round(float(train_seconds), 3),
         "num_devices": num_devices,
+        "platform": platform,
+        "device_kind": device_kind,
         "shard_manifest": shard_manifest,
     }
     for name, dur in (phases or {}).items():

@@ -222,27 +222,14 @@ def _pallas_cutoff_bytes() -> float:
 def _use_pallas(table) -> bool:
     # Mosaic single-row DMA slices must be lane-aligned: D % 128. Smaller
     # tables are cheap XLA gathers anyway (they fit VMEM).
-    try:
-        if table.shape[1] % 128 != 0:
-            return False
+    if table.shape[1] % 128 != 0:
+        return False
+    if isinstance(table, jax.Array) and not isinstance(table, jax.core.Tracer):
         # a committed concrete array knows its platform — a CPU-resident
         # table under jax.default_device(cpu) must NOT take the Mosaic
-        # path even when the process default backend is TPU (the bench's
-        # own-CPU anchor runs exactly that way)
-        devs = getattr(table, "devices", None)
-        if callable(devs):
-            ds = devs()
-            if ds:
-                return next(iter(ds)).platform == "tpu"
-        return jax.default_backend() == "tpu"
-    except Exception:  # tracers under jit: fall back to the backend
-        try:
-            return (
-                jax.default_backend() == "tpu"
-                and table.shape[1] % 128 == 0
-            )
-        except Exception:  # pragma: no cover
-            return False
+        # path even when the process default backend is TPU
+        return next(iter(table.devices())).platform == "tpu"
+    return jax.default_backend() == "tpu"  # tracers, host arrays
 
 
 # ------------------------------------------------------------------- public

@@ -10,15 +10,15 @@ is one device dispatch of a ``[B, K] @ [K, N]`` MXU matmul and only integer
 codes + top-N results cross the host link.
 
 **Adaptive routing.** What dominates per-request cost is the host↔device
-round trip, not the math: on a TPU VM the link RTT is microseconds and the
-device path wins at every batch size, while on a tunneled/remote device a
-single transfer can cost ~100 ms. The scorer therefore probes BOTH costs
-once at deploy — one tiny transfer round trip, one host-scored row — and
-routes each call by batch size: ``B ≥ RTT / host_row_cost`` goes to the
+round trip, not the math. The scorer therefore probes BOTH costs once at
+deploy — one tiny transfer round trip, one host-scored row — and routes
+each call by batch size: ``B ≥ RTT / host_row_cost`` goes to the
 accelerator (the RTT amortizes across the batch), smaller batches use the
 host mirror of the factors (which exists anyway — it is the serialized
 model state). ``PIO_TPU_SERVE_DEVICE=1|0`` forces device/host for all
-calls.
+calls. Which route answered is never implicit: every public call bumps a
+per-route counter, and :meth:`DeviceTopNScorer.route_info` reports the
+counts with the probe's measurements (``/stats.json`` ``topnScorers``).
 
 Shape discipline: jit specializes per shape, so both the batch dimension
 and the top-k width are bucketed to powers of two (a handful of
@@ -31,7 +31,7 @@ from __future__ import annotations
 
 import ctypes
 import functools
-import os
+import threading
 from pio_tpu.utils import knobs
 from pio_tpu.obs import monotonic_s
 from typing import Optional, Tuple
@@ -138,8 +138,7 @@ def _env_mode() -> str:
 @functools.lru_cache(maxsize=1)
 def _probe_link_rtt_s() -> float:
     """One-time cost of a minimal host→device→host round trip (measures the
-    link, not the math — 4 bytes each way). Microseconds on a local
-    PCIe/ICI-attached device, ~100 ms over a tunneled remote device."""
+    link, not the math — 4 bytes each way)."""
     import jax
 
     x = np.ones(1, np.float32)
@@ -197,6 +196,14 @@ class DeviceTopNScorer:
             mesh = None  # a 1-chip mesh is the plain device path
         self._mesh = mesh
         self._ncols_pad = self.n_cols
+        #: calls answered per route — the serving path can answer every
+        #: query from the host mirror, so which one did is on the record
+        self._route_lock = threading.Lock()
+        self._route_counts = {"device": 0, "host": 0}
+        #: what the adaptive probe measured (None: forced mode, no probe)
+        self.link_rtt_s: Optional[float] = None
+        self.host_row_s: Optional[float] = None
+        self.mode = "host"
 
         if self.n_rows == 0 or self.n_cols == 0:
             # degenerate factor tables cannot be probed (the host-row
@@ -213,6 +220,7 @@ class DeviceTopNScorer:
             mode = "host"
         else:
             mode = _env_mode()
+        self.mode = mode
         if mode == "host":
             self.min_device_batch = float("inf")
             self.min_pair_batch = float("inf")
@@ -247,6 +255,7 @@ class DeviceTopNScorer:
                 rtt = link_rtt_s if link_rtt_s is not None \
                     else _probe_link_rtt_s()
                 host_row = self._probe_host_row_s()
+                self.link_rtt_s, self.host_row_s = rtt, host_row
                 host_pair = max(host_row / self.n_cols, 1e-9)
                 self.min_device_batch = max(1, int(np.ceil(rtt / host_row)))
                 self.min_pair_batch = max(1, int(np.ceil(rtt / host_pair)))
@@ -269,8 +278,7 @@ class DeviceTopNScorer:
         shard multiple; pad rows are zero and masked out of top-k)."""
         import jax
 
-        from pio_tpu.parallel.compat import NamedSharding
-        from pio_tpu.parallel.compat import PartitionSpec as P
+        from jax.sharding import NamedSharding, PartitionSpec as P
 
         mesh = self._mesh
         axis = "data" if "data" in mesh.axis_names else mesh.axis_names[0]
@@ -328,6 +336,30 @@ class DeviceTopNScorer:
 
     def _route_to_device(self, batch: int) -> bool:
         return self.on_device and batch >= self.min_device_batch
+
+    def _count_route(self, device: bool) -> None:
+        with self._route_lock:
+            self._route_counts["device" if device else "host"] += 1
+
+    # pio: endpoint=/stats.json
+    def route_info(self) -> dict:
+        """Routing mode, the probe's measurements and the calls answered
+        per route so far (``/stats.json`` ``topnScorers``)."""
+        with self._route_lock:
+            counts = dict(self._route_counts)
+
+        def finite(v):
+            return None if v == float("inf") else int(v)
+
+        return {
+            "mode": self.mode,
+            "onDevice": self.on_device,
+            "linkRttS": self.link_rtt_s,
+            "hostRowS": self.host_row_s,
+            "minDeviceBatch": finite(self.min_device_batch),
+            "minPairBatch": finite(self.min_pair_batch),
+            "routes": counts,
+        }
 
     # ----------------------------------------------------------- device path
     def _top_n_device(self, codes, n, exclude):
@@ -438,11 +470,11 @@ class DeviceTopNScorer:
         stride-1 FMA over a transposed [K, N] table in L1-sized blocks,
         heap selection while each block is cache-hot. None → caller uses
         the numpy path (library unavailable, or exclusions requested)."""
-        try:
-            from pio_tpu.native import topn_host_lib
+        from pio_tpu.native import NativeUnavailable, topn_host_lib
 
+        try:
             lib = topn_host_lib()
-        except Exception:  # no toolchain → numpy fallback
+        except (NativeUnavailable, OSError):  # no toolchain / unloadable
             self._top_n_host_native = lambda codes, n: None
             return None
         if self._cols_t is None:
@@ -489,7 +521,9 @@ class DeviceTopNScorer:
             b = codes.shape[0]
             n = 0 if self.n_cols == 0 else n
             return (np.empty((b, n), np.int64), np.empty((b, n), np.float32))
-        if self._route_to_device(codes.shape[0]):
+        device = self._route_to_device(codes.shape[0])
+        self._count_route(device)
+        if device:
             return self._top_n_device(codes, n, exclude)
         return self._top_n_host(codes, n, exclude)
 
@@ -497,15 +531,18 @@ class DeviceTopNScorer:
         """Full ``[B, n_cols]`` score matrix (host numpy out).
 
         Unlike top-N, the result is B × n_cols floats back over the link —
-        on a slow link that payload, not the matmul, dominates, so the
-        device route is taken only when the link probe found it effectively
-        free (min_device_batch == 1, i.e. a local device or forced mode).
+        that payload, not the matmul, can dominate, so the device route is
+        taken only when the link probe found a round trip no dearer than
+        one host row (min_device_batch == 1, or forced mode).
         """
         import jax
 
         codes = np.asarray(codes, np.int32)
         B = codes.shape[0]
-        if B == 0 or self.min_device_batch > 1 or not self.on_device:
+        device = B > 0 and self.on_device and self.min_device_batch <= 1
+        if B:
+            self._count_route(device)
+        if not device:
             return self._rows_np[codes] @ self._cols_np.T
         out = np.empty((B, self.n_cols), np.float32)
         for lo in range(0, B, _MAX_BATCH_BUCKET):
@@ -526,7 +563,10 @@ class DeviceTopNScorer:
         rc = np.asarray(row_codes, np.int32)
         cc = np.asarray(col_codes, np.int32)
         B = rc.shape[0]
-        if B == 0 or B < self.min_pair_batch or not self.on_device:
+        device = B > 0 and self.on_device and B >= self.min_pair_batch
+        if B:
+            self._count_route(device)
+        if not device:
             return np.einsum(
                 "bk,bk->b", self._rows_np[rc], self._cols_np[cc]
             )

@@ -12,9 +12,12 @@ Spark shuffles and treeAggregate did.
 from __future__ import annotations
 
 import dataclasses
+import logging
 from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
+
+log = logging.getLogger("pio_tpu.context")
 
 
 def default_mesh(axis_names: Tuple[str, ...] = ("data",), devices=None):
@@ -64,7 +67,15 @@ class ComputeContext:
 
     @staticmethod
     def create(seed: int = 0, axis_names: Tuple[str, ...] = ("data",)):
-        return ComputeContext(mesh=default_mesh(axis_names), seed=seed)
+        """Mesh over every local device. Logs where the process landed:
+        with ``JAX_PLATFORMS`` unset, a chip held by another process
+        makes JAX fall back to CPU with only a warning of its own."""
+        ctx = ComputeContext(mesh=default_mesh(axis_names), seed=seed)
+        log.info(
+            "compute context: platform=%s device_kind=%s devices=%d",
+            ctx.platform, ctx.device_kind, ctx.num_devices,
+        )
+        return ctx
 
     @staticmethod
     def local(seed: int = 0):
@@ -82,6 +93,22 @@ class ComputeContext:
         if self.mesh is None:
             return 1
         return int(np.prod(list(self.mesh.shape.values())))
+
+    def _first_device(self):
+        if self.mesh is not None:
+            return self.mesh.devices.flat[0]
+        import jax
+
+        return jax.devices()[0]
+
+    @property
+    def platform(self) -> str:
+        """Backend the context's programs run on (``tpu``, ``cpu``...)."""
+        return self._first_device().platform
+
+    @property
+    def device_kind(self) -> str:
+        return self._first_device().device_kind
 
     def batch_sharding(self):
         """NamedSharding that shards dim 0 over the batch axis."""

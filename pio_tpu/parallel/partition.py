@@ -74,7 +74,7 @@ def match_partition_rules(
     """
     import jax
 
-    from pio_tpu.parallel.compat import PartitionSpec as P
+    from jax.sharding import PartitionSpec as P
 
     rules = list(rules)
     leaves, treedef = jax.tree_util.tree_flatten_with_path(pytree)
@@ -99,7 +99,7 @@ def match_partition_rules(
 
 
 def is_partition_spec(x: Any) -> bool:
-    from pio_tpu.parallel.compat import PartitionSpec as P
+    from jax.sharding import PartitionSpec as P
 
     return isinstance(x, P)
 
@@ -112,7 +112,7 @@ def spec_for_mesh(mesh, spec):
     (``data×pipe×seq×model``) and a 1-D serving mesh (``("data",)``)
     without per-consumer rule forks.
     """
-    from pio_tpu.parallel.compat import PartitionSpec as P
+    from jax.sharding import PartitionSpec as P
 
     axes = set(mesh.axis_names)
 
@@ -137,7 +137,7 @@ def make_shard_and_gather_fns(mesh, specs):
     """
     import jax
 
-    from pio_tpu.parallel.compat import NamedSharding
+    from jax.sharding import NamedSharding
 
     def mk_shard(spec):
         sharding = NamedSharding(mesh, spec_for_mesh(mesh, spec))
@@ -191,7 +191,7 @@ def rules_for(template: str) -> List[Tuple[str, Any]]:
 
 
 def _als_rules():
-    from pio_tpu.parallel.compat import PartitionSpec as P
+    from jax.sharding import PartitionSpec as P
 
     # factor matrices row-sharded over the entity (data) axis; indexes and
     # everything else replicated
@@ -201,7 +201,7 @@ def _als_rules():
 
 
 def _two_tower_rules():
-    from pio_tpu.parallel.compat import PartitionSpec as P
+    from jax.sharding import PartitionSpec as P
 
     # vocab-parallel embedding (ep), Megatron column/row MLP splits (tp);
     # the trained serving vectors row-shard over entities like ALS factors
@@ -216,7 +216,7 @@ def _two_tower_rules():
 
 
 def _seqrec_rules():
-    from pio_tpu.parallel.compat import PartitionSpec as P
+    from jax.sharding import PartitionSpec as P
 
     # layer-stacked blocks ride pipe on the leading (layer) dim; heads and
     # ffn hidden are tp column/row splits; embedding is vocab-sharded

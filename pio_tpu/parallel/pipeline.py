@@ -18,7 +18,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from pio_tpu.parallel.compat import axis_size
 
 
 def pipeline_apply(params, x, stage_fn: Callable, *, axis: str = "pipe"):
@@ -36,7 +35,7 @@ def pipeline_apply(params, x, stage_fn: Callable, *, axis: str = "pipe"):
     identical on every device of the axis (psum-reconciled), so callers can
     use ``out_specs=P(...)`` with the pipe dim unsharded.
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     n_micro = x.shape[0]
     ticks = n_micro + n - 1

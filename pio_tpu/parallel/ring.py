@@ -36,7 +36,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from pio_tpu.parallel.compat import axis_size
 
 _NEG_BIG = -1e30
 
@@ -82,7 +81,7 @@ def ring_attention(
     """
     b, t_loc, h, d = q.shape
     scale = 1.0 / (d ** 0.5)
-    n = 1 if axis is None else axis_size(axis)
+    n = 1 if axis is None else jax.lax.axis_size(axis)
     idx = 0 if axis is None else jax.lax.axis_index(axis)
 
     q32 = q.astype(jnp.float32)
@@ -124,7 +123,7 @@ def ring_attention_sharded(mesh, q, k, v, *, causal: bool = True):
     Batch rides the ``data`` axis, sequence the ``seq`` axis; heads and
     head-dim stay unsharded (shard heads over ``model`` upstream if needed).
     """
-    from pio_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = P("data", "seq", None, None)

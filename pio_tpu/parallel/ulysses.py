@@ -32,7 +32,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from pio_tpu.parallel.compat import axis_size
 
 _NEG_BIG = -1e30
 
@@ -77,7 +76,7 @@ def ulysses_attention(
             q.astype(jnp.float32), k, v, causal, scale
         ).astype(q.dtype)
 
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if h % n != 0:
         raise ValueError(
             f"ulysses attention needs n_heads divisible by the '{axis}' "
@@ -105,7 +104,7 @@ def ulysses_attention_sharded(mesh, q, k, v, *, causal: bool = True):
     """``shard_map``-wrapped all-to-all attention: global [B, T, H, D]
     in/out, batch on ``data``, sequence on ``seq`` (same contract as
     :func:`pio_tpu.parallel.ring.ring_attention_sharded`)."""
-    from pio_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = P("data", "seq", None, None)

@@ -733,7 +733,14 @@ class QueryServerService:
             "device-resident params",
             ("engine_id",),
         )
+        self._resident_fallback_total = self.obs.counter(
+            "pio_tpu_resident_fallback_total",
+            "Models whose device-resident scorer failed to build, so "
+            "they serve from the host mirror instead",
+            ("engine_id",),
+        )
         self._h2d_bytes_total.labels(eng)
+        self._resident_fallback_total.labels(eng)
         for outcome in ("hit", "miss"):
             self._donation_total.labels(eng, outcome)
         self._resident_params_bytes.labels(eng)
@@ -1010,6 +1017,7 @@ class QueryServerService:
                     "resident_scorer failed for %s; model serves from "
                     "the host mirror", type(algo).__name__,
                 )
+                self._resident_fallback_total.inc(engine_id=eng)
                 continue
             if sc is None:
                 continue
@@ -2040,6 +2048,15 @@ class QueryServerService:
             "measuredBytes": measured,
             "scorers": [sc.to_dict() for sc in resident],
         }
+        # which route answered: per-model top-N scorer dispatch counts
+        # (device vs host mirror) with the routing probe's measurements
+        out["topnScorers"] = []
+        for _algo, m in self.pairs:
+            sc = getattr(m, "__dict__", {}).get("_scorer")
+            if sc is not None:
+                out["topnScorers"].append(
+                    {"model": type(m).__name__, **sc.route_info()}
+                )
         with self._swap_lock:
             sharding = self._sharding_info
         out["sharding"] = (

@@ -75,12 +75,9 @@ def enabled() -> bool:
         return False
     if flag in ("1", "on", "true"):
         return True
-    try:
-        import jax
+    import jax
 
-        return jax.default_backend() != "cpu"
-    except Exception:
-        return False
+    return jax.default_backend() != "cpu"
 
 
 def wire_mode(has_scales: bool) -> str:
@@ -233,8 +230,7 @@ class ResidentLinearScorer:
         self._x_sharding = None
         self.mesh_fallback = False
         if mesh is not None and int(np.prod(mesh.devices.shape)) > 1:
-            from pio_tpu.parallel.compat import NamedSharding
-            from pio_tpu.parallel.compat import PartitionSpec as P
+            from jax.sharding import NamedSharding, PartitionSpec as P
             from pio_tpu.parallel.partition import assert_device_budget
 
             axis = (

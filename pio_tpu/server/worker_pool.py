@@ -15,7 +15,9 @@ deserialized model state — the same adaptive scorer fallback path that
 ``ops/topn.py`` uses for small batches), with an opt-in for worker 0 to
 own the device scorer (``device_worker=True``) when the pool runs on the
 TPU VM itself. Non-owner workers pin JAX to CPU before anything imports
-it, so they can never grab the chip.
+it, so they can never grab the chip. A pool with a device owner is ready
+only when worker 0 is, and stops when worker 0 dies at start-up or is
+retired: the siblings cannot stand in for the chip.
 
 ``mesh_worker=True`` is the multi-chip variant of the same ownership
 model: worker 0 owns the WHOLE mesh and serves with mesh-sharded factor
@@ -89,6 +91,9 @@ def _worker_main(spec: dict, idx: int, gen, shutdown_evt,
                  health_ports=None, lane_doorbell=None,
                  lane_resp_events=None) -> None:
     """Entry point of one pool worker (spawned process)."""
+    from pio_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()  # before the first backend use in this process
     owns_device = (
         (spec["device_worker"] or spec.get("mesh_worker")) and idx == 0
     )
@@ -100,17 +105,15 @@ def _worker_main(spec: dict, idx: int, gen, shutdown_evt,
         # host-mirror scoring only; pin JAX to CPU before ANY import can
         # initialize the TPU runtime (single-owner constraint)
         os.environ["PIO_TPU_SERVE_DEVICE"] = "host"
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
-        try:
-            import jax
+        os.environ["JAX_PLATFORMS"] = "cpu"
 
-            jax.config.update("jax_platforms", "cpu")
-        except Exception:  # jax missing/unconfigurable → host numpy only
-            pass
-
+    from pio_tpu.faults import failpoint
     from pio_tpu.server.http import JsonHTTPServer
     from pio_tpu.server.query_server import create_query_server
 
+    # chaos hook: `worker.start=crash` kills this worker before its
+    # engine loads (a device owner that cannot come up)
+    failpoint("worker.start")
     if spec.get("http_front"):
         # uniform front across the pool (see ServingPool._spec): the
         # listener keeps SO_REUSEPORT either way, so evloop means one
@@ -178,8 +181,6 @@ def _worker_main(spec: dict, idx: int, gen, shutdown_evt,
         # microseconds, shrinking the corruption window to ~nothing.
         # Each iteration beats the heartbeat: a wedged loop ages it out
         # and the supervisor's /healthz poll turns 503.
-        from pio_tpu.faults import failpoint
-
         while not shutdown_evt.is_set():
             # chaos hook: `worker.serve=crash:once` kills this worker
             # mid-serve to exercise the supervisor's respawn/backoff path
@@ -262,6 +263,9 @@ class ServingPool:
             "http_front": http_front,
         }
         self.n_workers = n_workers
+        #: worker 0 owns the accelerator; its siblings can only score on
+        #: the host mirror, so the pool must not serve without it
+        self._owns_device = bool(device_worker or mesh_worker)
         self._procs: list = []
         #: per-reason respawn counts ({"crash": n, "unhealthy": m}) —
         #: each reason spends its own budget (_MAX_RESPAWNS_BY_REASON)
@@ -386,11 +390,14 @@ class ServingPool:
         return self
 
     def wait_ready(self, timeout: float = 60.0) -> None:
-        """Block until a worker reports READY (deploy readiness): a plain
+        """Block until the pool reports READY (deploy readiness): a plain
         TCP accept is not enough — a worker accepts connections before
         its engine finished loading — so this polls ``GET /readyz`` until
-        a 200 (falling back to TCP-accept only if /readyz keeps erroring
-        at the HTTP layer, which cannot happen with in-tree workers)."""
+        a 200. A plain pool is ready when ANY worker is (the shared
+        port). A device-owning pool is ready only when WORKER 0 is (its
+        loopback sidecar): a sibling answering first would have the pool
+        serve from the CPU while the chip's owner is still — or never —
+        coming up. Worker 0 exiting before it is ready stops the pool."""
         import urllib.error
         import urllib.request
 
@@ -403,10 +410,21 @@ class ServingPool:
         while monotonic_s() < deadline:
             if self._shutdown.is_set():
                 raise RuntimeError("pool shut down during startup")
+            if self._owns_device:
+                if not self._procs[0].is_alive():
+                    self.stop()
+                    raise RuntimeError(
+                        "device-owning worker 0 exited during startup "
+                        f"(code {self._procs[0].exitcode}); pool stopped"
+                    )
+                if self._health_ports[0] <= 0:  # sidecar not up yet
+                    time.sleep(0.1)
+                    continue
+                url = f"http://127.0.0.1:{self._health_ports[0]}/readyz"
+            else:
+                url = f"http://{probe_host}:{self.port}/readyz"
             try:
-                with urllib.request.urlopen(
-                    f"http://{probe_host}:{self.port}/readyz", timeout=2.0
-                ) as r:
+                with urllib.request.urlopen(url, timeout=2.0) as r:
                     if r.status == 200:
                         return
             except urllib.error.HTTPError as e:
@@ -493,6 +511,14 @@ class ServingPool:
                 i, self._respawns[i][reason], reason,
             )
             self._retired[i] = True
+            if i == 0 and self._owns_device:
+                # the siblings are pinned to the host mirror: without
+                # its device owner the pool would keep answering from
+                # the CPU, so it stops instead
+                log.error(
+                    "device-owning worker 0 retired; stopping the pool"
+                )
+                self._shutdown.set()
             if getattr(self, "_metrics_seg", None) is not None:
                 # freeze the stripe: negative generation marks "retired,
                 # totals retained" so pool/fleet scrapes keep the sums

@@ -763,16 +763,25 @@ def cmd_status(args) -> int:
     import jax
 
     from pio_tpu.storage import pio_home
+    from pio_tpu.utils.compile_cache import place_compile_cache
 
     _out(f"pio-tpu {pio_tpu.__version__}")
     _out(f"home: {pio_home()}")
+    _out(f"compile cache: {place_compile_cache()}")
     try:
         devices = jax.devices()
+    except RuntimeError as e:
+        # a backend that cannot initialise (chip held by another
+        # process, missing runtime) is as broken as a broken store
+        devices = []
+        _out(f"  FAIL devices ({e})")
+    else:
+        kinds = sorted({d.device_kind for d in devices})
+        _out(f"backend: {devices[0].platform}  kinds: {', '.join(kinds)}  "
+             f"count: {len(devices)}")
         _out(f"devices: {[str(d) for d in devices]}")
-    except Exception as e:
-        _out(f"devices: unavailable ({e})")
     checks = _storage().verify_all_data_objects()
-    ok = all(checks.values())
+    ok = bool(devices) and all(checks.values())
     for name, healthy in sorted(checks.items()):
         _out(f"  {'OK ' if healthy else 'FAIL'} {name}")
     _out("(sanity check " + ("passed)" if ok else "FAILED)"))
@@ -1607,8 +1616,11 @@ def _configure_logging(verbosity: int) -> None:
 
 
 def main(argv=None) -> int:
+    from pio_tpu.utils.compile_cache import place_compile_cache
+
     args = build_parser().parse_args(argv)
     _configure_logging(-1 if args.quiet else args.verbose)
+    place_compile_cache()  # before any verb can touch a backend
     return args.fn(args)
 
 

@@ -209,6 +209,8 @@ def run_train(
                 params_hash=params_hash,
                 step_summary=recorder.summary(),
                 num_devices=ctx.num_devices,
+                platform=ctx.platform,
+                device_kind=ctx.device_kind,
                 shard_manifest=shard_manifest,
                 error=error,
             )
@@ -328,6 +330,8 @@ def run_train(
                 env={
                     "train_seconds": f"{train_s:.3f}",
                     "num_devices": str(ctx.num_devices),
+                    "platform": ctx.platform,
+                    "device_kind": ctx.device_kind,
                     # per-phase wall seconds (read / prepare / train:<algo>)
                     **{f"phase_{k}": str(v) for k, v in timings.items()},
                 },

@@ -79,6 +79,25 @@ class TestStatusVersion:
         assert code == 0
         assert "sanity check passed" in out
         assert out.count("OK ") >= 7
+        # where the process runs and where its compiles are kept
+        assert "backend: cpu  kinds: cpu  count: 8" in out
+        assert "compile cache: " in out
+
+    def test_status_fails_when_backend_cannot_initialise(
+        self, capsys, monkeypatch
+    ):
+        """A backend that fails to initialise (chip held by another
+        process) is a FAIL line and exit 1, like a broken store."""
+        import jax
+
+        def no_backend():
+            raise RuntimeError("Unable to initialize backend 'tpu'")
+
+        monkeypatch.setattr(jax, "devices", no_backend)
+        code, out, _ = run(capsys, "status")
+        assert code == 1
+        assert "FAIL devices (Unable to initialize backend 'tpu')" in out
+        assert "sanity check FAILED" in out
 
 
 class TestTrainDeployFlow:

@@ -409,6 +409,9 @@ class _FakeProc:
     def join(self, timeout=None):
         pass
 
+    def terminate(self):
+        self.alive = False
+
 
 class TestSupervisorHealthSweep:
     @pytest.fixture()
@@ -470,6 +473,63 @@ class TestSupervisorHealthSweep:
         pool._health_sweep()
         assert pool._health_fails[0] == 0
         assert pool._procs[0].killed == 0
+
+
+class TestDeviceOwnerReadiness:
+    """A device-owning pool is ready only when WORKER 0 is: its siblings
+    are pinned to the host mirror, so one of them answering /readyz on
+    the shared port must not make the pool ready."""
+
+    @pytest.fixture()
+    def harness(self):
+        """A two-worker ServingPool shell whose shared port is served by
+        a READY "sibling" and whose worker-0 sidecar the test controls."""
+        import threading
+
+        from pio_tpu.server.http import JsonHTTPServer, Router
+        from pio_tpu.server.worker_pool import ServingPool
+
+        state = {"worker0": 503}
+        servers = []
+        for status in (lambda: 200, lambda: state["worker0"]):
+            r = Router()
+            r.add("GET", "/readyz", lambda req, st=status: (st(), {}))
+            servers.append(
+                JsonHTTPServer(r, "127.0.0.1", 0, name="fake").start()
+            )
+        sibling, sidecar = servers
+        pool = ServingPool.__new__(ServingPool)  # skip __init__: no spawn
+        pool.n_workers = 2
+        pool._host, pool.port = "127.0.0.1", sibling.port
+        pool._owns_device = True
+        pool._shutdown = threading.Event()
+        pool._procs = [_FakeProc(), _FakeProc()]
+        pool._health_ports = [0, 0]
+        pool._anchor = pool._metrics_seg = pool._lane_seg = None
+        yield pool, state, sidecar.port
+        for srv in servers:
+            srv.stop()
+
+    def test_sibling_ready_is_not_pool_ready(self, harness):
+        pool, state, sidecar_port = harness
+        with pytest.raises(TimeoutError):  # worker 0 has no sidecar yet
+            pool.wait_ready(timeout=0.5)
+        pool._health_ports[0] = sidecar_port
+        with pytest.raises(TimeoutError):  # worker 0 answers 503
+            pool.wait_ready(timeout=0.5)
+        state["worker0"] = 200
+        pool.wait_ready(timeout=5.0)
+        pool._owns_device = False  # a plain pool: any worker will do
+        state["worker0"] = 503
+        pool.wait_ready(timeout=5.0)
+
+    def test_worker0_death_at_startup_stops_pool(self, harness):
+        pool, _, _ = harness
+        pool._procs[0].alive = False
+        pool._procs[0].exitcode = 137
+        with pytest.raises(RuntimeError, match="worker 0 exited"):
+            pool.wait_ready(timeout=5.0)
+        assert pool._shutdown.is_set()  # stopped, not left serving
 
 
 # -------------------------------------------------- deprecation shim

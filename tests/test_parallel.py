@@ -209,7 +209,7 @@ def test_ulysses_sharded_grads_flow():
 # ------------------------------------------------------------------ pipeline
 def test_pipeline_apply_matches_sequential():
     """4-stage pipeline over the pipe axis ≡ applying the stages in order."""
-    from pio_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n_stages, n_micro, mb, f = 4, 6, 4, 8
@@ -244,7 +244,7 @@ def test_pipeline_apply_matches_sequential():
 
 
 def test_pipeline_apply_differentiable():
-    from pio_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = build_mesh(MeshSpec(data=2, pipe=4))

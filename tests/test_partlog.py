@@ -781,7 +781,9 @@ class TestBreakerShedsDeadPartition:
 
 # ------------------------------------------- worker pool per-reason budgets
 class TestRespawnBudgetSplit:
-    def _shell(self, n=1):
+    def _shell(self, n=1, owns_device=False):
+        import threading
+
         from pio_tpu.obs import REGISTRY
         from pio_tpu.server.worker_pool import (
             _MAX_RESPAWNS_BY_REASON, ServingPool,
@@ -789,6 +791,8 @@ class TestRespawnBudgetSplit:
 
         pool = ServingPool.__new__(ServingPool)  # no spawn
         pool.n_workers = n
+        pool._owns_device = owns_device
+        pool._shutdown = threading.Event()
         pool._respawns = [
             {r: 0 for r in _MAX_RESPAWNS_BY_REASON} for _ in range(n)
         ]
@@ -832,6 +836,24 @@ class TestRespawnBudgetSplit:
         pool._kill_reason[0] = "unhealthy"
         pool._account_death(0, -9, now=50.0)
         assert pool._respawn_due[0] == 0.0
+
+    def test_device_owner_retirement_stops_pool(self):
+        """The siblings of a device-owning worker 0 are pinned to the
+        host mirror: once it is retired the pool must stop, not keep
+        answering from the CPU. A sibling's retirement (or worker 0's in
+        a plain pool) leaves the pool up."""
+        from pio_tpu.server.worker_pool import _MAX_RESPAWNS_BY_REASON
+
+        spent = _MAX_RESPAWNS_BY_REASON["crash"] + 1
+        for owns, idx, stops in (
+            (True, 0, True), (True, 1, False), (False, 0, False),
+        ):
+            pool = self._shell(n=2, owns_device=owns)
+            for _ in range(spent):
+                pool._account_death(idx, 1, now=50.0)
+                pool._respawn_due[idx] = 0.0
+            assert pool._retired[idx]
+            assert pool._shutdown.is_set() is stops, (owns, idx)
 
     def test_long_uptime_resets_every_reason(self):
         pool = self._shell()

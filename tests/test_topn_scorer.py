@@ -94,7 +94,7 @@ def test_adaptive_routing_by_link_speed():
     """Auto mode routes by batch size: a slow link sends small batches to
     the host mirror; a fast link sends everything to the device."""
     rows, cols = _factors()
-    slow = DeviceTopNScorer(rows, cols, link_rtt_s=10.0)  # tunneled link
+    slow = DeviceTopNScorer(rows, cols, link_rtt_s=10.0)  # remote link
     assert slow.on_device
     assert slow.min_device_batch > 1_000  # B=1 stays on host
     assert not slow._route_to_device(1)
@@ -107,6 +107,35 @@ def test_adaptive_routing_by_link_speed():
     i2, v2 = fast.top_n_batch(codes, 3)
     np.testing.assert_array_equal(i1, i2)
     np.testing.assert_allclose(v1, v2, rtol=1e-5)
+    # which route answered is on the record, with what the probe measured
+    info = slow.route_info()
+    assert info["routes"] == {"device": 0, "host": 1}
+    assert info["mode"] == "auto" and info["linkRttS"] == 10.0
+    assert info["hostRowS"] > 0
+    assert info["minDeviceBatch"] == slow.min_device_batch
+    assert fast.route_info()["routes"] == {"device": 1, "host": 0}
+
+
+def test_route_counters_cover_every_public_call():
+    """scores_batch and score_pairs route on their own thresholds; each
+    call bumps the route it took, and forced modes skip the probe."""
+    rows, cols = _factors()
+    s = DeviceTopNScorer(rows, cols, link_rtt_s=1e-3)
+    small = s.min_pair_batch - 1
+    s.score_pairs(np.zeros(small, np.int32), np.zeros(small, np.int32))
+    s.scores_batch(np.array([1], np.int32))
+    assert s.route_info()["routes"] == {"device": 0, "host": 2}
+    s.score_pairs([], [])  # nothing scored, nothing counted
+    assert s.route_info()["routes"] == {"device": 0, "host": 2}
+    dev = DeviceTopNScorer(rows, cols, prefer_device=True)
+    dev.score_pairs([1], [2])
+    dev.scores_batch(np.array([1], np.int32))
+    dev.top_n_batch(np.array([1], np.int32), 3)
+    info = dev.route_info()
+    assert info["routes"] == {"device": 3, "host": 0}
+    assert info["mode"] == "device" and info["linkRttS"] is None
+    host = DeviceTopNScorer(rows, cols, prefer_device=False).route_info()
+    assert host["mode"] == "host" and host["minDeviceBatch"] is None
 
 
 def test_env_override_forces_host(monkeypatch):

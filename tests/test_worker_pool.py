@@ -437,3 +437,36 @@ class TestPoolTraceAttribution:
         assert all(slow[tid].get("slow") for tid in ids)
         # worker index rides the trace for the merged view
         assert all("worker" in slow[tid] for tid in ids)
+
+
+def test_device_owner_dying_at_startup_stops_pool(tmp_home, monkeypatch):
+    """A device_worker pool whose worker 0 dies before it is ready is
+    never ready — its siblings only score on the host mirror — and
+    stops, instead of answering from the CPU."""
+    from pio_tpu.faults.registry import ENV_VAR
+    from pio_tpu.server.worker_pool import ServingPool
+
+    Storage.reset()
+    variant = _seed_and_train()
+    pool = ServingPool(
+        variant, host="127.0.0.1", port=0, n_workers=2, device_worker=True,
+    )
+    spawn = pool._spawn
+
+    def spawn_arming_worker0(idx):
+        # workers arm from the environment they are spawned with
+        if idx == 0:
+            monkeypatch.setenv(ENV_VAR, "worker.start=crash")
+        else:
+            monkeypatch.delenv(ENV_VAR, raising=False)
+        return spawn(idx)
+
+    pool._spawn = spawn_arming_worker0
+    pool.start()
+    try:
+        with pytest.raises(RuntimeError, match="worker 0 exited"):
+            pool.wait_ready(timeout=120)
+        assert all(not p.is_alive() for p in pool._procs)
+    finally:
+        pool.stop()
+        Storage.reset()
