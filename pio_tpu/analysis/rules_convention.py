@@ -303,7 +303,7 @@ class WallclockDurationRule(Rule):
 
 _SPAN_NAME_RE = re.compile(r"^[a-z0-9_]+(\.[a-z0-9_]+)*$")
 #: span-recording entry points whose first positional arg is a name
-_SPAN_METHODS = ("span", "add_span", "add_active_span")
+_SPAN_METHODS = ("span", "add_span", "add_active_span", "active_span")
 
 
 @register
@@ -317,8 +317,8 @@ class SpanNameRule(Rule):
         "math treats undotted names as tiling top-level stages and "
         "dotted ones as nested substages, so a stray name silently "
         "corrupts the attribution sums. Checked at .span()/.add_span()/"
-        "add_active_span() literal call sites and *_STAGES/*_SUBSTAGES "
-        "tuple declarations."
+        "add_active_span()/active_span() literal call sites and "
+        "*_STAGES/*_SUBSTAGES tuple declarations."
     )
 
     def check(self, module: ModuleInfo, ctx: LintContext) -> Iterable[Finding]:

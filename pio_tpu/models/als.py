@@ -42,8 +42,10 @@ from __future__ import annotations
 import dataclasses
 import functools
 import itertools
+import logging
 from pio_tpu.utils import knobs
-from pio_tpu.obs import monotonic_s, trainwatch
+from pio_tpu.obs import active_span, devicewatch, monotonic_s, trainwatch
+from pio_tpu.obs.profile import ScopeCapture
 from typing import Optional, Tuple
 
 import numpy as np
@@ -54,6 +56,12 @@ from pio_tpu.utils.numutil import (
 )
 
 from pio_tpu.parallel.context import ComputeContext
+
+log = logging.getLogger("pio_tpu.als")
+
+#: the two half-steps' scopes: the outermost segment of a device scope
+#: path, dropped where a ``stats`` map sums the sides
+_SIDE_SCOPES = ("als.user", "als.item")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -222,6 +230,7 @@ def _make_math(reg: float, implicit: bool, alpha: float,
     alpha_f = jnp.float32(alpha)
     mm_dtype = jnp.dtype(matmul_dtype)
 
+    @jax.named_scope("als.normal_eq")
     def partial_normal_eq(block_ent, block_other, block_r, factors,
                           n_entities, chunk, varying_axis=None):
         """Blocked scan: Σ w·q qᵀ and Σ rhs·q per entity (one shard)."""
@@ -236,7 +245,8 @@ def _make_math(reg: float, implicit: bool, alpha: float,
             ent, other, r_c = ch
             # padded slots are other == -1; validity derives from the sign
             m_c = (other >= 0).astype(jnp.float32)
-            q = factors_mm[jnp.maximum(other, 0)]  # [chunk, W, K] gather
+            with jax.named_scope("gather"):
+                q = factors_mm[jnp.maximum(other, 0)]  # [chunk, W, K] gather
             if implicit:
                 # confidence c = 1 + α r; correction weight (c-1)·mask
                 w = alpha_f * r_c * m_c
@@ -245,20 +255,24 @@ def _make_math(reg: float, implicit: bool, alpha: float,
                 w = m_c
                 rhs = r_c * m_c
             # batched MXU matmul: [chunk, K, W] @ [chunk, W, K], f32 acc
-            A_blk = jnp.einsum(
-                "cwk,cwl->ckl", q * w[:, :, None].astype(mm_dtype), q,
-                preferred_element_type=jnp.float32,
-            )
-            b_blk = jnp.einsum(
-                "cwk,cw->ck", q, rhs.astype(mm_dtype),
-                preferred_element_type=jnp.float32,
-            )
-            A = A + jax.ops.segment_sum(
-                A_blk, ent, num_segments=n_entities, indices_are_sorted=True
-            )
-            b = b + jax.ops.segment_sum(
-                b_blk, ent, num_segments=n_entities, indices_are_sorted=True
-            )
+            with jax.named_scope("outer"):
+                A_blk = jnp.einsum(
+                    "cwk,cwl->ckl", q * w[:, :, None].astype(mm_dtype), q,
+                    preferred_element_type=jnp.float32,
+                )
+                b_blk = jnp.einsum(
+                    "cwk,cw->ck", q, rhs.astype(mm_dtype),
+                    preferred_element_type=jnp.float32,
+                )
+            with jax.named_scope("segment_sum"):
+                A = A + jax.ops.segment_sum(
+                    A_blk, ent, num_segments=n_entities,
+                    indices_are_sorted=True,
+                )
+                b = b + jax.ops.segment_sum(
+                    b_blk, ent, num_segments=n_entities,
+                    indices_are_sorted=True,
+                )
             return (A, b), None
 
         S = block_ent.shape[0]
@@ -279,6 +293,7 @@ def _make_math(reg: float, implicit: bool, alpha: float,
         (A, b), _ = jax.lax.scan(chunk_step, (A0, b0), chunks)
         return A, b
 
+    @jax.named_scope("cg")
     def _cg_solve(A, b):
         """Batched Jacobi-preconditioned CG — matmul-only, so it rides the
         MXU instead of XLA's serialized batched factorizations (measured
@@ -309,6 +324,7 @@ def _make_math(reg: float, implicit: bool, alpha: float,
         x, *_ = jax.lax.fori_loop(0, K + 8, body, (x, r, p, rz))
         return x
 
+    @jax.named_scope("als.solve")
     def solve_block(A, b, gram):
         """Regularized batched solve on a block of entities."""
         K = b.shape[1]
@@ -338,6 +354,7 @@ def _make_math(reg: float, implicit: bool, alpha: float,
             return x[:, :, 0]
         return jnp.linalg.solve(A, b[:, :, None])[:, :, 0]
 
+    @jax.named_scope("als.gram")
     def gram_of(factors):
         if implicit:
             return jnp.einsum("ik,il->kl", factors, factors)
@@ -484,8 +501,10 @@ def _build_trainer(mesh, axis: str, iterations: int, reg: float,
 
         def iteration(_, PQ):
             P_f, Q_f = PQ
-            P_f = half_step(*by_user, Q_f, U_pad, chunk_user)
-            Q_f = half_step(*by_item, P_f, I_pad, chunk_item)
+            with jax.named_scope("als.user"):
+                P_f = half_step(*by_user, Q_f, U_pad, chunk_user)
+            with jax.named_scope("als.item"):
+                Q_f = half_step(*by_item, P_f, I_pad, chunk_item)
             return (P_f, Q_f)
 
         return jax.lax.fori_loop(0, iterations, iteration, (P_init, Q_init))
@@ -540,12 +559,14 @@ def _build_trainer(mesh, axis: str, iterations: int, reg: float,
             i_hi = gather_cat(i_hi, lens_hi)
             r = gather_cat(r, lens_r)
         E = i_lo.shape[0]
-        i32 = math.decode_items(i_lo, i_hi, ovf_idx, ovf_val, counts_u)
-        r32 = math.decode_ratings(r, E)
-        u32 = jnp.repeat(
-            jnp.arange(U_pad, dtype=jnp.int32), counts_u,
-            total_repeat_length=E,
-        )
+        with jax.named_scope("als.decode"):
+            i32 = math.decode_items(i_lo, i_hi, ovf_idx, ovf_val, counts_u)
+            r32 = math.decode_ratings(r, E)
+        with jax.named_scope("als.pack"):
+            u32 = jnp.repeat(
+                jnp.arange(U_pad, dtype=jnp.int32), counts_u,
+                total_repeat_length=E,
+            )
         # both degree histograms ride the wire (0.9 MB total) — the
         # on-device bincount is a 25M-edge scatter-add, the host count is
         # a pass the native packer already made
@@ -617,17 +638,20 @@ def _build_stream_trainer(iterations: int, reg: float, implicit: bool,
             # local_counts arrives sliced to the chunk's present-user span
             # [u0_c, pad_c] (ships span·4 B instead of U_pad·4 B per
             # chunk); expand to full length on device
-            lc = _lc_full(local_counts, u0_c)
-            i32 = math.decode_items(i_lo, i_hi, ovf_idx, ovf_val, lc)
-            r32 = math.decode_ratings(r, E_c)
+            with jax.named_scope("als.decode"):
+                lc = _lc_full(local_counts, u0_c)
+                i32 = math.decode_items(i_lo, i_hi, ovf_idx, ovf_val, lc)
+                r32 = math.decode_ratings(r, E_c)
             blocks = device_pack(
                 None, i32, r32, U_pad, w_user, S_c,
                 assume_sorted=True, counts=lc, pad_entity=pad_c,
             )
-            dA, db = math.partial_normal_eq(
-                *blocks, Q0, U_pad, chunk_stream
-            )
-            return A + dA, b + db, blocks
+            with jax.named_scope("als.user"):
+                dA, db = math.partial_normal_eq(
+                    *blocks, Q0, U_pad, chunk_stream
+                )
+                A, b = A + dA, b + db
+            return A, b, blocks
 
         return accum
 
@@ -638,40 +662,47 @@ def _build_stream_trainer(iterations: int, reg: float, implicit: bool,
                  lc_slices):
         # full by-user layout = concat of the chunk-local packs (padding
         # aliases each chunk's last user, so ids stay ascending)
-        by_user = tuple(
-            jnp.concatenate([blk[k] for blk in user_blocks])
-            for k in range(3)
-        )
+        with jax.named_scope("als.pack"):
+            by_user = tuple(
+                jnp.concatenate([blk[k] for blk in user_blocks])
+                for k in range(3)
+            )
         # item side needs the full COO: re-decode the (device-resident)
         # wire chunks — elementwise, cheap; the delta item wire is
         # chunk-segmented, so each chunk decodes against its own
         # local-counts span
-        i32 = jnp.concatenate([
-            math.decode_items(
-                lo, hi, ovf_i, ovf_v, _lc_full(lc, chunk_spec[c][2])
+        with jax.named_scope("als.decode"):
+            i32 = jnp.concatenate([
+                math.decode_items(
+                    lo, hi, ovf_i, ovf_v, _lc_full(lc, chunk_spec[c][2])
+                )
+                for c, ((lo, hi, ovf_i, ovf_v, _r), lc)
+                in enumerate(zip(wire_chunks, lc_slices))
+            ])
+            r32 = jnp.concatenate(
+                [math.decode_ratings(r, lo.shape[0])
+                 for lo, hi, ovf_i, ovf_v, r in wire_chunks]
             )
-            for c, ((lo, hi, ovf_i, ovf_v, _r), lc)
-            in enumerate(zip(wire_chunks, lc_slices))
-        ])
-        r32 = jnp.concatenate(
-            [math.decode_ratings(r, lo.shape[0])
-             for lo, hi, ovf_i, ovf_v, r in wire_chunks]
-        )
         E = i32.shape[0]
-        u32 = jnp.repeat(
-            jnp.arange(U_pad, dtype=jnp.int32), counts_u,
-            total_repeat_length=E,
-        )
+        with jax.named_scope("als.pack"):
+            u32 = jnp.repeat(
+                jnp.arange(U_pad, dtype=jnp.int32), counts_u,
+                total_repeat_length=E,
+            )
         by_item = device_pack(i32, u32, r32, I_pad, w_item, S_item,
                               counts=counts_i)
         # iteration 1: user half is already accumulated (streamed)
-        P = math.solve_block(A, b, math.gram_of(Q0))
-        Q = math.half_local(by_item, P, I_pad, chunk_item)
+        with jax.named_scope("als.user"):
+            P = math.solve_block(A, b, math.gram_of(Q0))
+        with jax.named_scope("als.item"):
+            Q = math.half_local(by_item, P, I_pad, chunk_item)
 
         def iteration(_, PQ):
             P, Q = PQ
-            P = math.half_local(by_user, Q, U_pad, chunk_stream)
-            Q = math.half_local(by_item, P, I_pad, chunk_item)
+            with jax.named_scope("als.user"):
+                P = math.half_local(by_user, Q, U_pad, chunk_stream)
+            with jax.named_scope("als.item"):
+                Q = math.half_local(by_item, P, I_pad, chunk_item)
             return (P, Q)
 
         return jax.lax.fori_loop(0, iterations - 1, iteration, (P, Q))
@@ -705,33 +736,35 @@ def device_pack(ent, oth, rat, n_entities: int, width: int, S: int,
     no real block belongs to an entity beyond it. ``ent`` may be ``None``
     when ``counts`` is supplied with ``assume_sorted`` (it is unused).
     """
+    import jax
     import jax.numpy as jnp
 
-    if counts is None:
-        counts = jnp.bincount(ent, length=n_entities)  # order-free
-    else:
-        counts = counts.astype(jnp.int32)  # caller-supplied (wire input)
-    blocks = -(-counts // width)
-    zero = jnp.zeros(1, counts.dtype)
-    block_start = jnp.concatenate([zero, jnp.cumsum(blocks)])
-    edge_start = jnp.concatenate([zero, jnp.cumsum(counts)])
+    with jax.named_scope("als.pack"):
+        if counts is None:
+            counts = jnp.bincount(ent, length=n_entities)  # order-free
+        else:
+            counts = counts.astype(jnp.int32)  # caller-supplied (wire input)
+        blocks = -(-counts // width)
+        zero = jnp.zeros(1, counts.dtype)
+        block_start = jnp.concatenate([zero, jnp.cumsum(blocks)])
+        edge_start = jnp.concatenate([zero, jnp.cumsum(counts)])
 
-    # per block: owning entity (padding blocks → pad_entity, masked out)
-    pad_tgt = (n_entities - 1) if pad_entity is None else pad_entity
-    bids = jnp.searchsorted(block_start[1:], jnp.arange(S), side="right")
-    block_ent = jnp.minimum(bids, pad_tgt).astype(jnp.int32)
+        # per block: owning entity (padding blocks → pad_entity, masked out)
+        pad_tgt = (n_entities - 1) if pad_entity is None else pad_entity
+        bids = jnp.searchsorted(block_start[1:], jnp.arange(S), side="right")
+        block_ent = jnp.minimum(bids, pad_tgt).astype(jnp.int32)
 
-    # per slot: position within the entity's adjacency, then edge index
-    blk_in_ent = jnp.arange(S) - block_start[block_ent]  # [S]
-    pos = blk_in_ent[:, None] * width + jnp.arange(width)[None, :]
-    valid = pos < counts[block_ent][:, None]  # [S, W]
-    src = jnp.where(valid, edge_start[block_ent][:, None] + pos, 0)
-    if not assume_sorted:
-        # compose through the stable sort permutation: one fused gather
-        src = jnp.argsort(ent, stable=True)[src]
-    block_other = jnp.where(valid, oth[src], jnp.int32(-1))
-    block_rating = jnp.where(valid, rat[src], jnp.float32(0.0))
-    return block_ent, block_other, block_rating
+        # per slot: position within the entity's adjacency, then edge index
+        blk_in_ent = jnp.arange(S) - block_start[block_ent]  # [S]
+        pos = blk_in_ent[:, None] * width + jnp.arange(width)[None, :]
+        valid = pos < counts[block_ent][:, None]  # [S, W]
+        src = jnp.where(valid, edge_start[block_ent][:, None] + pos, 0)
+        if not assume_sorted:
+            # compose through the stable sort permutation: one fused gather
+            src = jnp.argsort(ent, stable=True)[src]
+        block_other = jnp.where(valid, oth[src], jnp.int32(-1))
+        block_rating = jnp.where(valid, rat[src], jnp.float32(0.0))
+        return block_ent, block_other, block_rating
 
 
 def _run_streamed(config: "ALSConfig", rank: int, U_pad: int, I_pad: int,
@@ -739,7 +772,8 @@ def _run_streamed(config: "ALSConfig", rank: int, U_pad: int, I_pad: int,
                   counts_u: np.ndarray, counts_i: np.ndarray,
                   i_sorted: np.ndarray, r_ship: np.ndarray,
                   rating_wire: str, item_wire: str,
-                  n_stream: int, seed, stats: Optional[dict]):
+                  n_stream: int, seed, stats: Optional[dict],
+                  capture: ScopeCapture):
     """Dispatch the double-buffered single-device training run.
 
     Slices the (user, item)-sorted edges into ``n_stream`` spans, encodes
@@ -850,7 +884,7 @@ def _run_streamed(config: "ALSConfig", rank: int, U_pad: int, I_pad: int,
         list(zip(spans, local_slices)),
         encode=encode, put=put, put_extra=put_extra,
         init_carry=init_carry, dispatch=dispatch, finalize=fin,
-        stats=stats, encode_stat_key="pack_s",
+        stats=stats, encode_stat_key="pack_s", device_phase=capture,
     )
 
 
@@ -1032,9 +1066,7 @@ def _sort_edges_by_user(user_idx, item_idx, rating, n_edges, U_pad,
             # but the delta wire then won't apply (negative gaps →
             # planes fallback) — say so instead of silently diverging
             # from the numpy lexsort path.
-            import logging
-
-            logging.getLogger("pio_tpu.als").warning(
+            log.warning(
                 "within-user item sort skipped (an entity exceeds "
                 "2^24 edges); item wire falls back to planes"
             )
@@ -1065,7 +1097,7 @@ def _choose_item_wire(i_sorted, counts_u, I_pad, n_edges):
 
 def _run_mesh_compact(config, mesh, axis, n_shards, user_idx, item_idx,
                       rating, n_edges, U_pad, I_pad, w_user, w_item,
-                      counts_layout, trainer, seed, stats):
+                      counts_layout, trainer, seed, stats, capture):
     """Multi-shard training over the COMPACT edge wire.
 
     The host link (PCIe on a TPU VM) is the slow hop and ICI the fast
@@ -1178,13 +1210,52 @@ def _run_mesh_compact(config, mesh, axis, n_shards, user_idx, item_idx,
         jax.block_until_ready(args)
         stats["h2d_s"] = monotonic_s() - t0
         stats["h2d_chunk_s"] = chunk_ts
-        t0 = monotonic_s()
-        P_f, Q_f = run(*args, seed)
-        jax.block_until_ready((P_f, Q_f))
-        stats["device_s"] = monotonic_s() - t0
+        P_f, Q_f = _profiled_run(run, (*args, seed), stats, capture)
     else:
         P_f, Q_f = run(*args, seed)
     return P_f, Q_f
+
+
+def _profiled_run(run, args, stats: dict, capture: ScopeCapture):
+    """The device phase of a ``stats`` call: dispatch, block, time it as
+    ``device_s``, all inside the scope capture."""
+    import jax
+
+    with capture:
+        t0 = monotonic_s()
+        out = run(*args)
+        jax.block_until_ready(out)
+        stats["device_s"] = monotonic_s() - t0
+    return out
+
+
+def _fill_device_stats(stats: dict, capture: ScopeCapture,
+                       xla_before: Optional[dict]) -> None:
+    """What the scope capture and the compile listener saw of one call,
+    as JSON-plain ``stats`` entries (see :func:`train_als`)."""
+    seen = capture.result
+    if seen is not None:
+        summed: dict = {}
+        for path, sec in seen["scope_s"].items():
+            head, _, rest = path.partition("/")
+            key = rest if head in _SIDE_SCOPES and rest else path
+            summed[key] = summed.get(key, 0.0) + sec
+        stats["device_scope_s"] = dict(seen["scope_s"])
+        stats["device_scope_summed_s"] = summed
+        stats["device_unscoped_s"] = seen["unscoped_s"]
+        stats["device_busy_s"] = seen["busy_s"]
+        stats["device_program_s"] = dict(seen["program_s"])
+        if seen["busy_s"] > 0 and not seen["scope_s"]:
+            log.warning(
+                "the device trace names no als.* scope: the executables "
+                "were probably loaded from a compile cache keyed without op "
+                "metadata (see place_compile_cache); retrain with a fresh "
+                "JAX_COMPILATION_CACHE_DIR"
+            )
+    xla = devicewatch.xla_totals()
+    if xla is not None and xla_before is not None:
+        stats["xla"] = dict(
+            xla, in_call={k: xla[k] - xla_before[k] for k in xla})
 
 
 def train_als(
@@ -1207,6 +1278,25 @@ def train_als(
     BLOCKING between the host-pack / host→device / device-compute phases.
     That serialization disables the streamed path's transfer/compute
     overlap, so pass ``stats`` only on profiling runs, not timed ones.
+
+    On a TPU, with no profiler session already running, the device phase
+    of a ``stats`` call is also traced and reduced to the program's named
+    scopes (:class:`pio_tpu.obs.profile.ScopeCapture`; elsewhere these
+    keys are absent): ``device_scope_s`` (``{scope path: device
+    self-seconds}``, per side: ``als.item/als.solve/cg``),
+    ``device_scope_summed_s`` (the same with ``als.user``/``als.item``
+    dropped and the sides summed: ``als.solve/cg``), ``device_unscoped_s``
+    (operations outside every scope), ``device_busy_s`` (union of the
+    device operations' intervals; the scopes and the unscoped seconds sum
+    to it) and ``device_program_s`` (``{jit name: seconds}``). ``xla`` is
+    the process's real compiles so far, ``{compiles, compile_s,
+    cache_loads, cache_load_s}`` from JAX's monitoring events
+    (:func:`pio_tpu.obs.devicewatch.xla_totals`), with ``in_call``, the
+    same four over this call: 0 compiles and 0 loads once warm.
+
+    Host work is marked by leaf spans (:func:`pio_tpu.obs.active_span`):
+    ``als.sort``, the feed's ``stream.*`` and ``als.readback``; they tile
+    and never nest, and no span encloses the call.
     """
     import jax
     import jax.numpy as jnp
@@ -1220,6 +1310,8 @@ def train_als(
     n_shards = mesh.shape[axis] if mesh is not None else 1
     K = config.rank
     n_edges = len(user_idx)
+    capture = ScopeCapture("als.")  # entered by a ``stats`` call only
+    xla_before = devicewatch.xla_totals()
 
     user_idx = np.asarray(user_idx, np.int32)
     item_idx = np.asarray(item_idx, np.int32)
@@ -1314,7 +1406,7 @@ def train_als(
             P_f, Q_f = _run_mesh_compact(
                 config, mesh, axis, n_shards, user_idx, item_idx, rating,
                 n_edges, U_pad, I_pad, w_user, w_item, _counts_layout,
-                _trainer, seed, stats,
+                _trainer, seed, stats, capture,
             )
         else:
             t0 = monotonic_s()
@@ -1356,10 +1448,8 @@ def train_als(
                 u_dev, i_dev = put_blocks(by_user), put_blocks(by_item)
                 jax.block_until_ready((u_dev, i_dev))
                 stats["h2d_s"] = monotonic_s() - t0
-                t0 = monotonic_s()
-                P_f, Q_f = run(u_dev, i_dev, seed)
-                jax.block_until_ready((P_f, Q_f))
-                stats["device_s"] = monotonic_s() - t0
+                P_f, Q_f = _profiled_run(
+                    run, (u_dev, i_dev, seed), stats, capture)
             else:
                 P_f, Q_f = run(
                     put_blocks(by_user), put_blocks(by_item), seed
@@ -1373,24 +1463,27 @@ def train_als(
         # shipment is STREAMED in chunks overlapped with the chunk packs +
         # iteration-1 accumulation (_build_stream_trainer).
         t0 = monotonic_s()
-        counts_u, chunk_user, S_u = _counts_layout(user_idx, w_user, U_pad)
-        counts_i, chunk_item, S_i = _counts_layout(item_idx, w_item, I_pad)
-        if S_u * w_user >= 2 ** 31 or S_i * w_item >= 2 ** 31:
-            raise ValueError(
-                "edge set too large for int32 block addressing; "
-                "use a multi-device mesh"
-            )
+        with active_span("als.sort"):
+            counts_u, chunk_user, S_u = _counts_layout(
+                user_idx, w_user, U_pad)
+            counts_i, chunk_item, S_i = _counts_layout(
+                item_idx, w_item, I_pad)
+            if S_u * w_user >= 2 ** 31 or S_i * w_item >= 2 ** 31:
+                raise ValueError(
+                    "edge set too large for int32 block addressing; "
+                    "use a multi-device mesh"
+                )
 
-        counts_u = np.ascontiguousarray(counts_u, np.int64)
-        i_sorted, r_sorted = _sort_edges_by_user(
-            user_idx, item_idx, rating, n_edges, U_pad, counts_u
-        )
-        r_ship, rating_wire = _encode_ratings(r_sorted)
-        # item wire sized by a count-only pass so nothing is materialized
-        # before the stream/monolithic split
-        item_wire, n_ovf, item_bytes = _choose_item_wire(
-            i_sorted, counts_u, I_pad, n_edges
-        )
+            counts_u = np.ascontiguousarray(counts_u, np.int64)
+            i_sorted, r_sorted = _sort_edges_by_user(
+                user_idx, item_idx, rating, n_edges, U_pad, counts_u
+            )
+            r_ship, rating_wire = _encode_ratings(r_sorted)
+            # item wire sized by a count-only pass so nothing is
+            # materialized before the stream/monolithic split
+            item_wire, n_ovf, item_bytes = _choose_item_wire(
+                i_sorted, counts_u, I_pad, n_edges
+            )
         use_delta = item_wire == "delta12"
         edge_bytes = item_bytes + r_ship.nbytes
         if stats is not None:
@@ -1417,7 +1510,7 @@ def train_als(
             P_f, Q_f = _run_streamed(
                 config, K, U_pad, I_pad, w_user, w_item, S_i, chunk_item,
                 counts_u, counts_i, i_sorted, r_ship, rating_wire,
-                item_wire, n_stream, seed, stats,
+                item_wire, n_stream, seed, stats, capture,
             )
         else:
             if use_delta:
@@ -1442,14 +1535,14 @@ def train_als(
                 args = tuple(jax.device_put(a) for a in args)
                 jax.block_until_ready(args)
                 stats["h2d_s"] = monotonic_s() - t0
-                t0 = monotonic_s()
-                P_f, Q_f = run(*args, seed)
-                jax.block_until_ready((P_f, Q_f))
-                stats["device_s"] = monotonic_s() - t0
+                P_f, Q_f = _profiled_run(run, (*args, seed), stats, capture)
             else:
                 P_f, Q_f = run(*args, seed)
 
-    P_f, Q_f = jax.device_get((P_f, Q_f))
+    with active_span("als.readback"):
+        P_f, Q_f = jax.device_get((P_f, Q_f))
+    if stats is not None:
+        _fill_device_stats(stats, capture, xla_before)
     trainwatch.record_steps(
         int(config.iterations),
         examples=0 if edges_recorded else n_edges,

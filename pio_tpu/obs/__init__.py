@@ -66,6 +66,7 @@ from pio_tpu.obs.tracing import (
     TRACE_HEADER,
     Trace,
     Tracer,
+    active_span,
     active_trace,
     add_active_span,
     format_trace_header,
@@ -87,6 +88,7 @@ __all__ = [
     "TRACE_HEADER",
     "Trace",
     "Tracer",
+    "active_span",
     "active_trace",
     "add_active_span",
     "devicewatch",

@@ -26,6 +26,13 @@ third telemetry plane, mirroring the fleet (ISSUE 11) and training
   trace+compile entry. A shape the global jit cache already holds —
   e.g. a hot-swap re-warm over an unchanged bucket ladder — is NOT
   recounted, matching what XLA actually does.)
+- **Real compiles** — :func:`watch_xla_compiles` listens to JAX's own
+  monitoring events, so what the backend compiled and what it loaded
+  from the persistent compilation cache are counted apart, process
+  wide, whichever site dispatched (:func:`xla_totals`;
+  ``pio_tpu_xla_backend_compile*`` / ``pio_tpu_xla_cache_load*``). The
+  site counters above infer a compile from a shape key new to the
+  site; these count the compiler's own calls.
 - **Endpoints** — ``payload()`` renders ``GET /device.json`` on the
   query server and the trainer status sidecar; the fleet aggregator
   federates it into ``/fleet.json`` as a per-member ``devices`` block
@@ -510,6 +517,7 @@ class DeviceWatch:
                 "total": sum(r["count"] for r in compiles.values()),
                 "sites": compiles,
             },
+            "xla": xla_totals(),
         }
 
     # -- sampler thread -----------------------------------------------------
@@ -594,6 +602,84 @@ def watching(watch: DeviceWatch, sample: bool = True):
         if sample:
             watch.stop()
         deactivate(watch)
+
+
+# ---------------------------------------------------------------------------
+# real compiles — JAX's own monitoring events, process totals
+# ---------------------------------------------------------------------------
+
+#: jax 0.9.0 ``jax/_src/dispatch.py`` BACKEND_COMPILE_EVENT: one duration
+#: event per ``compile_or_get_cached`` call, a persistent-cache hit included
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+#: jax 0.9.0 ``jax/_src/compiler.py``: fired inside that call, before it
+#: ends, only when the executable came from the persistent cache
+_CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+_XLA_LOCK = threading.Lock()
+_XLA_TOTALS = {"compiles": 0, "compile_s": 0.0,
+               "cache_loads": 0, "cache_load_s": 0.0}
+_XLA_WATCHING = False
+_XLA_THREAD = threading.local()  # .loaded: a retrieval event is pending
+
+#: loaded from the cache? -> (totals' count key, seconds key, the two
+#: process-global counter families beside the inferred per-site ones)
+_XLA_KINDS = {
+    False: ("compiles", "compile_s", REGISTRY.counter(
+        "pio_tpu_xla_backend_compiles_total",
+        "Programs the XLA backend compiled (JAX's backend-compile events "
+        "that were not persistent-cache loads)",
+    ), REGISTRY.counter(
+        "pio_tpu_xla_backend_compile_seconds_total",
+        "Wall seconds of those backend compiles",
+    )),
+    True: ("cache_loads", "cache_load_s", REGISTRY.counter(
+        "pio_tpu_xla_cache_loads_total",
+        "Executables loaded from JAX's persistent compilation cache",
+    ), REGISTRY.counter(
+        "pio_tpu_xla_cache_load_seconds_total",
+        "Wall seconds of those persistent-cache loads",
+    )),
+}
+
+
+def _on_xla_duration(event: str, duration: float, **_kw) -> None:
+    if event == _CACHE_RETRIEVAL_EVENT:
+        _XLA_THREAD.loaded = True
+        return
+    if event != _BACKEND_COMPILE_EVENT:
+        return
+    n_key, s_key, count, seconds = _XLA_KINDS[
+        getattr(_XLA_THREAD, "loaded", False)]
+    _XLA_THREAD.loaded = False
+    duration = max(0.0, float(duration))
+    with _XLA_LOCK:
+        _XLA_TOTALS[n_key] += 1
+        _XLA_TOTALS[s_key] += duration
+    count.inc()
+    seconds.inc(duration)
+
+
+def watch_xla_compiles() -> None:
+    """Start counting real compiles and cache loads, once per process.
+    Called where a :class:`~pio_tpu.parallel.context.ComputeContext` is
+    built, which every entry point does before its first program; JAX is
+    imported by then. Touches no dispatch path: JAX calls the listener
+    from inside its own compile call."""
+    global _XLA_WATCHING
+    with _XLA_LOCK:
+        if _XLA_WATCHING:
+            return
+        _XLA_WATCHING = True
+    from jax import monitoring
+
+    monitoring.register_event_duration_secs_listener(_on_xla_duration)
+
+
+def xla_totals() -> Optional[dict]:
+    """``{compiles, compile_s, cache_loads, cache_load_s}`` of this
+    process so far, or ``None`` when nothing is listening."""
+    with _XLA_LOCK:
+        return dict(_XLA_TOTALS) if _XLA_WATCHING else None
 
 
 # ---------------------------------------------------------------------------

@@ -34,7 +34,9 @@ through call stacks that never see the server layer (the device scorer,
 storage, armed debug locks). :func:`add_active_span` records a span on
 whatever trace is active — a no-op when none is — so deep layers
 instrument unconditionally without plumbing handles through every
-signature.
+signature. :func:`active_span` is its context-manager form, which also
+writes the span into a running JAX profiler trace (the trainer's leaf
+host spans: ``als.sort``, ``stream.put``, ``als.readback``…).
 
 Naming: span/stage names are dot-scoped ``stage`` or ``stage.substage``
 (lowercase ``[a-z0-9_]`` atoms). Top-level stages tile the request
@@ -53,6 +55,7 @@ from __future__ import annotations
 
 import contextvars
 import re
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -112,6 +115,31 @@ def add_active_span(stage: str, dur_s: float,
     handle = ACTIVE_TRACE.get()
     if handle is not None:
         handle.add_span(stage, dur_s, rel_start_s)
+
+
+@contextmanager
+def active_span(stage: str):
+    """A leaf span around the body, on two clocks at once: recorded on
+    the active trace (if any) like :func:`add_active_span`, and entered
+    as a ``jax.profiler.TraceAnnotation`` so that a profiler trace shows
+    it on the Python thread's line beside the device events — the
+    profiler's clock is not ``monotonic_s``, so only a span the
+    profiler wrote itself can name a gap between device ops. The
+    annotation costs a few hundred nanoseconds with no profiler session.
+    JAX is never imported from here: a process that has not loaded it
+    has no profiler to write to. Keep these spans leaves that tile
+    (a trace reducer gives a device gap to the span overlapping it most,
+    so an enclosing span would take every gap)."""
+    jax = sys.modules.get("jax")
+    t0 = monotonic_s()
+    try:
+        if jax is None:
+            yield
+        else:
+            with jax.profiler.TraceAnnotation(stage):
+                yield
+    finally:
+        add_active_span(stage, monotonic_s() - t0)
 
 
 class Trace:

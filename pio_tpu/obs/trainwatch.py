@@ -414,10 +414,14 @@ def run_record(*, run_id: str, engine_id: str, status: str,
                device_kind: Optional[str] = None,
                shard_manifest: Optional[str] = None,
                timestamp: Optional[str] = None,
-               error: Optional[str] = None) -> dict:
+               error: Optional[str] = None,
+               device_scopes: Optional[dict] = None) -> dict:
     """One runs.jsonl row. Flat where it matters: the step summary's
     numeric fields are lifted to the top level so :func:`delta_rows`
-    can diff two rows directly."""
+    can diff two rows directly. ``device_scopes`` is a profiled run's
+    :func:`pio_tpu.obs.profile.reduce_scopes` result (``pio train
+    --profile-dir`` on a TPU): lifted as ``scope_<path>_s``,
+    ``device_busy_s`` and ``device_idle_pct``."""
     if timestamp is None:
         import datetime as _dt
 
@@ -444,6 +448,13 @@ def run_record(*, run_id: str, engine_id: str, status: str,
                     "overlap_ratio", "steps", "examples"):
             if step_summary.get(key) is not None:
                 rec[key] = step_summary[key]
+    if device_scopes:
+        for path, sec in device_scopes["scope_s"].items():
+            rec[f"scope_{path}_s"] = round(float(sec), 6)
+        busy, window = device_scopes["busy_s"], device_scopes["window_s"]
+        rec["device_busy_s"] = round(float(busy), 6)
+        if window > 0:
+            rec["device_idle_pct"] = round(100.0 * (1.0 - busy / window), 3)
     if error:
         rec["error"] = error[-500:]
     return rec
@@ -491,23 +502,26 @@ def read_runs(engine_id: Optional[str] = None,
 def run_delta_table(prev: dict, cur: dict,
                     threshold: float = DEFAULT_RUN_THRESHOLD) -> Tuple[list, list]:
     """``(table_lines, regressed_fields)`` for two run-ledger rows —
-    the static :data:`RUN_FIELDS` plus every ``phase_*`` duration both
-    rows carry (direction "down": a slower phase is a regression)."""
+    the static :data:`RUN_FIELDS` plus every ``phase_*`` duration and
+    every profiled run's ``scope_*`` / ``device_*`` number both rows
+    carry (direction "down": a slower phase or scope is a regression)."""
     fields = list(RUN_FIELDS)
-    phase_keys = sorted(
+    lower_is_better = sorted(
         k for k in cur
-        if k.startswith("phase_") and k in prev
+        if k.startswith(("phase_", "scope_", "device_busy_s",
+                         "device_idle_pct")) and k in prev
     )
-    fields.extend((k, "down") for k in phase_keys)
+    fields.extend((k, "down") for k in lower_is_better)
     rows, regressed = delta_rows(prev, cur, fields, threshold)
+    width = max([24] + [len(row[0]) for row in rows])  # scope paths are long
     lines = [
         f"run delta vs {prev.get('run_id') or '?'} "
         f"({prev.get('timestamp') or '?'}), threshold "
         f"{threshold * 100:.1f}%:",
-        f"  {'field':<24} {'prev':>12} {'now':>12} {'delta':>9}",
+        f"  {'field':<{width}} {'prev':>12} {'now':>12} {'delta':>9}",
     ]
     for field, a, b, delta, tag in rows:
-        lines.append(f"  {field:<24} {a:>12} {b:>12} {delta:>9}{tag}")
+        lines.append(f"  {field:<{width}} {a:>12} {b:>12} {delta:>9}{tag}")
     if not rows:
         lines.append("  (no comparable numeric fields)")
     return lines, regressed

@@ -65,6 +65,13 @@ class ComputeContext:
     checkpoint_base: Optional[str] = None
     checkpoint_every: int = 0
 
+    def __post_init__(self):
+        # every entry point builds a context before its first program:
+        # the one place that sees all of a process's real compiles
+        from pio_tpu.obs import devicewatch
+
+        devicewatch.watch_xla_compiles()
+
     @staticmethod
     def create(seed: int = 0, axis_names: Tuple[str, ...] = ("data",)):
         """Mesh over every local device. Logs where the process landed:

@@ -41,11 +41,16 @@ just bench.
 
 Failpoints: ``stream.encode`` / ``stream.put`` / ``stream.dispatch``
 fire per chunk per phase (fault-injection surface for the feed loop).
+The same three names, and ``stream.finalize``, are leaf host spans
+(:func:`pio_tpu.obs.active_span`): they tile the loop, land on the
+active trace and, in a JAX profiler trace, on the Python thread's line,
+where they name the device's idle gaps.
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable, Optional, Sequence
+import contextlib
+from typing import Any, Callable, ContextManager, Optional, Sequence
 
 from pio_tpu.obs import REGISTRY
 from pio_tpu.utils import knobs
@@ -133,6 +138,7 @@ def stream_feed(
     lookahead: int = 0,
     stats: Optional[dict] = None,
     encode_stat_key: str = "encode_s",
+    device_phase: Optional[ContextManager] = None,
 ) -> Any:
     """Run the streamed feed over ``chunks``; returns the final carry
     (or ``finalize``'s result).
@@ -162,11 +168,14 @@ def stream_feed(
         stats: phase-serialized profiling (see module docstring) —
             overlap is OFF while measuring.
         encode_stat_key: stats key the encode time accumulates under.
+        device_phase: entered around the serialized device phase of a
+            ``stats`` run, timing included (ALS's scope capture traces
+            exactly what ``device_s`` times); unused without ``stats``.
     """
     import jax
 
     from pio_tpu.faults import failpoint
-    from pio_tpu.obs import devicewatch, monotonic_s, trainwatch
+    from pio_tpu.obs import active_span, devicewatch, monotonic_s, trainwatch
 
     if put is None:
         def put(host, _idx):
@@ -174,7 +183,8 @@ def stream_feed(
 
     def _encode(i):
         failpoint("stream.encode")
-        return encode(chunks[i])
+        with active_span("stream.encode"):
+            return encode(chunks[i])
 
     shipped = [0]  # bytes shipped this call (overlap-probe bookkeeping)
     chunk_bytes: dict = {}  # in-flight chunk footprint (device ledger)
@@ -189,14 +199,15 @@ def stream_feed(
         devicewatch.stream_carry(nbytes)
         if stats is not None:
             stats["h2d_bytes"] = stats.get("h2d_bytes", 0) + nbytes
-        return put(host, i)
+        with active_span("stream.put"):
+            return put(host, i)
 
     def _dispatch(carry, dev, i):
         failpoint("stream.dispatch")
         # compile attribution: a chunk whose leaf shapes are new to the
         # feed's program cache (typically the first chunk and a ragged
         # tail) pays the trace+compile inside this call
-        with devicewatch.compile_span(
+        with active_span("stream.dispatch"), devicewatch.compile_span(
             "stream_dispatch", key=devicewatch.shape_key(dev)
         ):
             out = dispatch(carry, dev, i)
@@ -204,6 +215,10 @@ def stream_feed(
             # chunk consumed, device buffers released with the refs
             devicewatch.stream_carry(-chunk_bytes.pop(i, 0))
         return out
+
+    def _finalize(carry, devs):
+        with active_span("stream.finalize"):
+            return finalize(carry, devs)
 
     n = len(chunks)
     retain = finalize is not None
@@ -221,17 +236,18 @@ def stream_feed(
         extra = put_extra() if put_extra is not None else None
         jax.block_until_ready((devs, extra))
         stats["h2d_s"] = stats.get("h2d_s", 0.0) + (monotonic_s() - t0)
-        t0 = monotonic_s()
-        carry = init_carry()
-        for i in range(n):
-            carry = _dispatch(carry, devs[i], i)
-            if not retain:
-                devs[i] = None
-        result = finalize(carry, tuple(devs)) if retain else carry
-        jax.block_until_ready(result)
-        stats["device_s"] = stats.get("device_s", 0.0) + (
-            monotonic_s() - t0
-        )
+        with device_phase or contextlib.nullcontext():
+            t0 = monotonic_s()
+            carry = init_carry()
+            for i in range(n):
+                carry = _dispatch(carry, devs[i], i)
+                if not retain:
+                    devs[i] = None
+            result = _finalize(carry, tuple(devs)) if retain else carry
+            jax.block_until_ready(result)
+            stats["device_s"] = stats.get("device_s", 0.0) + (
+                monotonic_s() - t0
+            )
         if chunk_bytes:  # retained chunks freed with finalize's result
             devicewatch.stream_carry(-sum(chunk_bytes.values()))
             chunk_bytes.clear()
@@ -304,7 +320,7 @@ def stream_feed(
                 h2d_s0 * scale, device_s0 * scale, wall_rest
             )
             rec.set_overlap(ratio)
-    result = finalize(carry, tuple(devs[i] for i in range(n))) if retain \
+    result = _finalize(carry, tuple(devs[i] for i in range(n))) if retain \
         else carry
     if chunk_bytes:  # retained chunks freed with finalize's result
         devicewatch.stream_carry(-sum(chunk_bytes.values()))

@@ -44,6 +44,7 @@ from pio_tpu.storage import (
     Storage,
 )
 from pio_tpu.obs import devicewatch, slog, trainwatch
+from pio_tpu.obs.profile import reduce_scopes
 from pio_tpu.workflow import shard_store
 from pio_tpu.workflow.engine_json import EngineVariant
 from pio_tpu.workflow.params import WorkflowParams
@@ -213,6 +214,7 @@ def run_train(
                 device_kind=ctx.device_kind,
                 shard_manifest=shard_manifest,
                 error=error,
+                device_scopes=device_scopes,
             )
             path = trainwatch.append_run(rec)
             log.info("run record appended to %s", path)
@@ -221,6 +223,7 @@ def run_train(
 
     t0 = monotonic_s()
     timings: dict = {}
+    device_scopes: Optional[dict] = None  # a --profile-dir run's, reduced
     try:
         # the device watch samples memory + attributes trainer compiles
         # for the run's duration; the status sidecar serves its payload
@@ -248,6 +251,14 @@ def run_train(
                     timings=timings,
                 )
             train_s = monotonic_s() - t0
+            if workflow_params.profile_dir:
+                # the trace has closed: reduce it to seconds per named
+                # device scope for the run record (the raw trace stays
+                # where the operator asked for it)
+                try:
+                    device_scopes = reduce_scopes(workflow_params.profile_dir)
+                except Exception as exc:  # a CPU trace has no device plane
+                    log.info("no device scopes from the profile: %s", exc)
             # the phases already ran inside LIVE tr.span()s (engine.train
             # opens one per phase since ISSUE 16), so the stage
             # histograms (pio_tpu_train_stage_seconds) and the trace ring
