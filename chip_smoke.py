@@ -9,7 +9,8 @@ recommendation template at the full width of the headline configuration
 (162,541 users × 59,047 items, ``examples/recommendation/engine.json`` as
 shipped); the events are synthetic, made from a seed. Two further phases
 run the device programs that lifecycle cannot reach: the streamed ALS
-trainer on 25M edges and the Pallas embedding-bag kernel under Mosaic.
+trainer on 25M edges and the two Pallas kernels under Mosaic (the
+embedding bag, and the resident CG solve against the XLA loop).
 
 This parent never imports jax. Each phase is one child process that owns
 the chip and has exited before the next starts. The run fails unless every
@@ -55,11 +56,13 @@ FULL = {
     # monolithic program instead of the streamed one
     "stream_edges": 25_000_000,
     "kernel": {"V": 50_000, "D": 256, "B": 4096, "L": 64},
+    "solve": {"n": 40_000, "K": 64},
 }
 REHEARSAL = {
     "n_users": 400, "n_items": 150, "n_events": 5_000,
     "stream_edges": 20_000,
     "kernel": {"V": 512, "D": 128, "B": 16, "L": 8},
+    "solve": {"n": 200, "K": 16},
 }
 QUERY_USERS = (0, 1, 7, 42, 137, 399)
 TOP_N = 10
@@ -79,6 +82,9 @@ FALLBACK_COUNTERS = ("pio_tpu_shard_gather_fallback_total",
                      "pio_tpu_resident_fallback_total")
 #: Pallas kernel vs the XLA lowering (both accumulate in float32)
 KERNEL_REL_TOL = 1e-5
+#: the resident CG kernel vs the XLA loop: the same K+8 float32 sweeps,
+#: another summation order inside each matvec
+SOLVE_REL_TOL = 1e-4
 
 RESULT_TAG = "CHIP_SMOKE_RESULT "
 
@@ -342,6 +348,8 @@ class Runner:
         self.require_tpu("als_stream", stream["device"]["platform"])
         kernel = self.phase("embedding_bag_kernel")
         self.require_tpu("embedding_bag_kernel", kernel["device"]["platform"])
+        solve = self.phase("als_solve_kernel")
+        self.require_tpu("als_solve_kernel", solve["device"]["platform"])
 
         summary = {"ok": True}
         if self.rehearse:
@@ -365,6 +373,7 @@ class Runner:
             "reference": reference,
             "als_stream": stream,
             "embedding_bag_kernel": kernel,
+            "als_solve_kernel": solve,
             "claim": None,
         })
         return summary
@@ -605,12 +614,64 @@ def phase_embedding_bag_kernel(size: dict, work: str, rehearse: bool) -> dict:
             "pallas_s_incl_compile": round(wall, 2)}
 
 
+def phase_als_solve_kernel(size: dict, work: str, rehearse: bool) -> dict:
+    """``solve_block`` on one batch of SPD systems through both CG
+    implementations: the VMEM-resident Pallas kernel (what the selection
+    rule picks on the chip at this size) and the XLA loop it replaces
+    there. Alone it is a 20-second check that the kernel still compiles
+    and agrees: ``python chip_smoke.py --phase als_solve_kernel``."""
+    from pio_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+
+    from pio_tpu.models import als
+
+    n, K = size["solve"]["n"], size["solve"]["K"]
+    platform = jax.default_backend()
+    picked = als._solve_impl("cg", n, K, platform)
+    assert picked == ("xla_cg" if rehearse else "resident_cg"), picked
+
+    @jax.jit
+    def systems(key):
+        kw, kb = jax.random.split(key)
+        W = jax.random.normal(kw, (n, K, 2 * K), jnp.float32)
+        A = jnp.einsum("nkw,nlw->nkl", W, W, precision="highest")
+        return A, jax.random.normal(kb, (n, K), jnp.float32)
+
+    A, b = systems(jax.random.PRNGKey(SEED))
+    gram = jnp.zeros((K, K), jnp.float32)
+    out, seconds = {}, {}
+    for impl in ("resident_cg", "xla_cg"):
+        # a fresh math per implementation: the rule is read at trace time
+        with mock.patch.object(als, "_solve_impl", return_value=impl):
+            solve = jax.jit(
+                als._make_math(0.1, False, 1.0, "float32", "cg").solve_block
+            )
+            jax.block_until_ready(solve(A, b, gram))  # compile
+            t = time.monotonic()
+            out[impl] = jax.block_until_ready(solve(A, b, gram))
+            seconds[impl] = round(time.monotonic() - t, 4)
+    assert bool(jnp.isfinite(out["resident_cg"]).all())
+    rel = float(jnp.abs(out["resident_cg"] - out["xla_cg"]).max()
+                / jnp.abs(out["xla_cg"]).max())
+    assert rel <= SOLVE_REL_TOL, rel
+    return {"device": device_summary(), "shape": [n, K, K],
+            "picked": picked, "interpret": platform != "tpu",
+            "max_rel_diff": rel, "rel_tol": SOLVE_REL_TOL,
+            "solve_s": seconds}
+
+
 PHASES = {
     "env": phase_env,
     "generate": phase_generate,
     "reference": phase_reference,
     "als_stream": phase_als_stream,
     "embedding_bag_kernel": phase_embedding_bag_kernel,
+    "als_solve_kernel": phase_als_solve_kernel,
 }
 
 

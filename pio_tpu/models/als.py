@@ -84,10 +84,12 @@ class ALSConfig:
     #: override; accumulation and the solves stay float32 either way.
     matmul_dtype: str = "auto"
     #: per-entity K×K solver: "auto" uses exact Cholesky for small entity
-    #: counts and switches to Jacobi-preconditioned CG (matmul-only, rides
-    #: the MXU) above ~32k entities, where XLA's batched factorizations
-    #: serialize badly on TPU (LU at MovieLens-25M user count: ~780 ms per
-    #: half-step; CG: ~90 ms). Explicit "cg" / "cholesky" / "lu" override.
+    #: counts and switches to Jacobi-preconditioned CG (no factorization;
+    #: per-entity matvecs on the vector unit) above ~32k entities, where
+    #: XLA's batched factorizations serialize badly on TPU. Explicit "cg" /
+    #: "cholesky" / "lu" override. On a TPU, at a rank that is a multiple
+    #: of 8 up to 128, CG runs as a VMEM-resident Pallas kernel
+    #: (_solve_impl).
     solver: str = "auto"
     seed: int = 0
 
@@ -213,6 +215,138 @@ def _resolve_matmul_dtype(matmul_dtype: str) -> str:
     return "float32" if jax.default_backend() == "cpu" else "bfloat16"
 
 
+#: entities per grid step of the resident CG kernel (its lane dimension)
+_CG_TILE = 128
+#: the largest rank whose five [K, K, tile] float32 blocks fit a v5e's
+#: 128 MiB of VMEM (rank 256 is refused by the chip's compiler)
+_CG_MAX_RANK = 128
+
+
+def _solve_impl(solver: str, n_entities: int, rank: int,
+                platform: str) -> str:
+    """Which implementation ``solve_block`` runs for one side, from what
+    is visible at trace time: ``cholesky`` / ``lu`` / ``xla_cg`` /
+    ``resident_cg``. ``auto`` is exact Cholesky while it is cheap and CG
+    at the batch sizes where XLA's TPU factorizations serialize; CG runs
+    as the Pallas kernel (:func:`_cg_solve_resident`) on a TPU at a rank
+    its blocks tile (a multiple of 8, at most ``_CG_MAX_RANK``), and as
+    the XLA loop elsewhere."""
+    if solver not in ("auto", "cg", "cholesky", "lu"):
+        raise ValueError(
+            f"unknown ALS solver {solver!r}; use auto/cg/cholesky/lu"
+        )
+    if solver == "auto":
+        solver = "cg" if n_entities > 32768 else "cholesky"
+    if solver != "cg":
+        return solver
+    tiles = rank % 8 == 0 and rank <= _CG_MAX_RANK
+    return "resident_cg" if platform == "tpu" and tiles else "xla_cg"
+
+
+def _cg_solve_resident(A, b, reg, interpret: bool = False):
+    """``_cg_solve`` of ``A + reg`` (``reg`` the K×K regulariser every
+    entity shares: λI, plus the gram when implicit) as one Pallas TPU
+    kernel: a tile of ``_CG_TILE`` entities stays in VMEM for the whole
+    solve, so ``A`` crosses HBM once per half-step, not once per sweep.
+
+    Entities ride the lanes: ``A`` is transposed to ``[l, k, entity]``
+    outside the kernel (in the trainers XLA writes that layout with the
+    copy it already makes of the scan's result), every vector is
+    ``[K, tile]``, and ``Ap[k] = Σ_l A[k, l]·p[l]`` is K multiply-adds of
+    ``[K, tile]`` slabs by a sublane-broadcast row of ``p`` on the vector
+    unit. Nothing crosses lanes, so what the last, partial tile reads
+    beyond the batch stays in lanes that are never written back. The
+    recurrence, the guards and the K+8 sweeps are ``_cg_solve``'s, in
+    float32; only the summation order inside a matvec differs, and it
+    is kept as shallow as a tree reduction's."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, K = b.shape
+    T = _CG_TILE
+    A_t = jnp.transpose(A, (2, 1, 0))  # A_t[l, k, e] = A[e, k, l]
+    reg_t = jnp.broadcast_to(reg.T[:, :, None], (K, K, T))
+
+    def kernel(A_ref, reg_ref, b_ref, x_ref, As_ref):
+        rows = jax.lax.broadcasted_iota(jnp.int32, (K, T), 0)
+        d = jnp.zeros((K, T), jnp.float32)
+        for l in range(K):
+            slab = A_ref[l] + reg_ref[l]
+            As_ref[l] = slab
+            d = jnp.where(rows == l, slab, d)  # row l of slab l: A[l, l]
+
+        def matvec(p):
+            # runs of 8 columns summed in order, the runs pairwise: 64
+            # terms added one after another lose a digit that CG on an
+            # ill-conditioned system multiplies (relative error 1.3e-3
+            # against 5.0e-4 for this order and for XLA's reduction)
+            sums = []  # (tree level, partial sum), levels falling
+            for l0 in range(0, K, 8):
+                acc = As_ref[l0] * p[l0:l0 + 1, :]
+                for l in range(l0 + 1, l0 + 8):
+                    acc = acc + As_ref[l] * p[l:l + 1, :]
+                level = 0
+                while sums and sums[-1][0] == level:
+                    acc = sums.pop()[1] + acc
+                    level += 1
+                sums.append((level, acc))
+            acc = sums.pop()[1]
+            while sums:  # K / 8 is no power of two
+                acc = sums.pop()[1] + acc
+            return acc
+
+        def dot(u, v):
+            return jnp.sum(u * v, axis=0, keepdims=True)
+
+        inv_d = 1.0 / d
+        b_ = b_ref[...]
+        x = b_ * inv_d
+        r = b_ - matvec(x)
+        z = r * inv_d
+        p = z
+        rz = dot(r, z)
+
+        def body(_, st):
+            x, r, p, rz = st
+            Ap = matvec(p)
+            denom = dot(p, Ap)
+            alpha_c = rz / jnp.where(denom != 0, denom, 1.0)
+            x = x + alpha_c * p
+            r = r - alpha_c * Ap
+            z = r * inv_d
+            rz2 = dot(r, z)
+            beta = rz2 / jnp.where(rz != 0, rz, 1.0)
+            p = z + beta * p
+            return (x, r, p, rz2)
+
+        x, *_ = jax.lax.fori_loop(0, K + 8, body, (x, r, p, rz))
+        x_ref[...] = x
+
+    vec = pl.BlockSpec((K, T), lambda i: (0, i))
+    x_t = pl.pallas_call(
+        kernel,
+        grid=(pl.cdiv(n, T),),
+        in_specs=[
+            pl.BlockSpec((K, K, T), lambda i: (0, 0, i)),
+            pl.BlockSpec((K, K, T), lambda i: (0, 0, 0)),
+            vec,
+        ],
+        out_specs=vec,
+        out_shape=jax.ShapeDtypeStruct((K, n), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((K, K, T), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            # A's block twice (the pipeline), reg's twice, the scratch
+            vmem_limit_bytes=max(32 << 20, 6 * K * K * T * 4),
+        ),
+        interpret=interpret,
+        name="als_cg_resident",
+    )(A_t, reg_t, b.T)
+    return x_t.T
+
+
 def _make_math(reg: float, implicit: bool, alpha: float,
                matmul_dtype: str, solver: str, rating_wire: str = "f32",
                item_wire: str = "planes"):
@@ -295,11 +429,15 @@ def _make_math(reg: float, implicit: bool, alpha: float,
 
     @jax.named_scope("cg")
     def _cg_solve(A, b):
-        """Batched Jacobi-preconditioned CG — matmul-only, so it rides the
-        MXU instead of XLA's serialized batched factorizations (measured
-        ~8× faster than LU at MovieLens-25M entity counts). A is SPD
-        (normal equations + λI); K+8 iterations ≥ the Krylov dimension
-        with margin for f32 rounding on ill-conditioned systems."""
+        """Batched Jacobi-preconditioned CG: no factorization, so it
+        avoids XLA's serialized batched Cholesky/LU on TPU (measured ~8x
+        faster than LU at MovieLens-25M entity counts). The matvec is per
+        entity, so it runs on the vector unit, not the MXU, and every
+        sweep streams the whole ``A`` from HBM: the path on CPU, for
+        ranks the resident kernel does not tile, and that kernel's
+        oracle. A is SPD (normal equations + λI); K+8 iterations ≥ the
+        Krylov dimension with margin for f32 rounding on ill-conditioned
+        systems."""
         K = b.shape[1]
         inv_d = 1.0 / jnp.diagonal(A, axis1=1, axis2=2)
         x = b * inv_d
@@ -328,22 +466,25 @@ def _make_math(reg: float, implicit: bool, alpha: float,
     def solve_block(A, b, gram):
         """Regularized batched solve on a block of entities."""
         K = b.shape[1]
-        A = A + lam * jnp.eye(K, dtype=jnp.float32)[None, :, :]
+        # A.shape[0] and the backend are static at trace time, so this is
+        # a compile-time branch (the rule: _solve_impl)
+        backend = jax.default_backend()
+        impl = _solve_impl(solver, A.shape[0], K, backend)
+        reg_kk = lam * jnp.eye(K, dtype=jnp.float32)
+        if impl == "resident_cg":
+            # the kernel adds the regulariser in VMEM: no second copy of A
+            if implicit:
+                reg_kk = reg_kk + gram
+            # off a TPU only a test's steering gets here: interpreted
+            with jax.named_scope("cg"), jax.named_scope("resident"):
+                return _cg_solve_resident(
+                    A, b, reg_kk, interpret=backend != "tpu")
+        A = A + reg_kk[None, :, :]
         if implicit:
             A = A + gram[None, :, :]
-        # "auto": exact Cholesky while it's cheap, CG at the batch sizes
-        # where XLA's TPU factorizations serialize (A.shape[0] is static
-        # at trace time, so this is a compile-time branch)
-        if solver not in ("auto", "cg", "cholesky", "lu"):
-            raise ValueError(
-                f"unknown ALS solver {solver!r}; use auto/cg/cholesky/lu"
-            )
-        eff = solver
-        if eff == "auto":
-            eff = "cg" if A.shape[0] > 32768 else "cholesky"
-        if eff == "cg":
+        if impl == "xla_cg":
             return _cg_solve(A, b)
-        if eff == "cholesky":
+        if impl == "cholesky":
             L = jnp.linalg.cholesky(A)
             y = jax.scipy.linalg.solve_triangular(
                 L, b[:, :, None], lower=True
@@ -1274,7 +1415,9 @@ def train_als(
     counts are dropped on the way out.
 
     ``stats``, when a dict, is filled with a per-phase breakdown —
-    ``{pack_s, wire_bytes, encoding, n_stream, h2d_s, device_s}`` — by
+    ``{pack_s, wire_bytes, encoding, n_stream, h2d_s, device_s}`` and
+    ``solve_impl`` (``{"user", "item"}``: which solver each side's batch
+    gets, :func:`_solve_impl`) — by
     BLOCKING between the host-pack / host→device / device-compute phases.
     That serialization disables the streamed path's transfer/compute
     overlap, so pass ``stats`` only on profiling runs, not timed ones.
@@ -1330,6 +1473,17 @@ def train_als(
 
     w_user = config.block_width or _auto_width(n_edges, n_users)
     w_item = config.block_width or _auto_width(n_edges, n_items)
+
+    # what solve_block will pick for each side's batch (one device's share
+    # of the entities on a mesh); also refuses an unknown solver up front
+    solve_impl = {
+        side: _solve_impl(str(config.solver), n_pad // n_shards, K,
+                          jax.default_backend())
+        for side, n_pad in (("user", U_pad), ("item", I_pad))
+    }
+    trainwatch.set_solve_impl(solve_impl)
+    if stats is not None:
+        stats["solve_impl"] = solve_impl
 
     def _counts_layout(ent, width, n_entities):
         """counts + (chunk, padded block count S) for one side."""

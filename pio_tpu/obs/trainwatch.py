@@ -101,6 +101,7 @@ class StepRecorder:
         self.n_batches = 0
         self.streamed = False
         self.n_stream = 0
+        self.solve_impl: Optional[Dict[str, str]] = None
         self.params_per_device_bytes = 0
         self.h2d_bytes = 0
         self.overlap_ratio: Optional[float] = None
@@ -137,6 +138,7 @@ class StepRecorder:
             self.n_batches = int(n_batches)
             self.streamed = bool(streamed)
             self.n_stream = int(n_stream)
+            self.solve_impl = None
             self.params_per_device_bytes = int(per_device_bytes)
             self.last_loss = None
             self.losses.clear()
@@ -177,6 +179,12 @@ class StepRecorder:
         with self._lock:
             self.streamed = bool(streamed)
             self.n_stream = int(n_stream)
+
+    def set_solve_impl(self, impl: Dict[str, str]) -> None:
+        """Which implementation solves each side's normal equations (ALS
+        decides from entity count, rank and platform: ``_solve_impl``)."""
+        with self._lock:
+            self.solve_impl = dict(impl)
 
     def set_overlap(self, ratio: float) -> None:
         with self._lock:
@@ -271,6 +279,7 @@ class StepRecorder:
                 "overlap_ratio": self.overlap_ratio,
                 "streamed": self.streamed,
                 "stream_chunks": self.n_stream,
+                "solve_impl": self.solve_impl,
             }
 
 
@@ -343,6 +352,12 @@ def set_stream(streamed: bool, n_stream: int = 0) -> None:
     rec = _ACTIVE
     if rec is not None:
         rec.set_stream(streamed, n_stream)
+
+
+def set_solve_impl(impl: Dict[str, str]) -> None:
+    rec = _ACTIVE
+    if rec is not None:
+        rec.set_solve_impl(impl)
 
 
 # ---------------------------------------------------------------------------
@@ -421,7 +436,9 @@ def run_record(*, run_id: str, engine_id: str, status: str,
     can diff two rows directly. ``device_scopes`` is a profiled run's
     :func:`pio_tpu.obs.profile.reduce_scopes` result (``pio train
     --profile-dir`` on a TPU): lifted as ``scope_<path>_s``,
-    ``device_busy_s`` and ``device_idle_pct``."""
+    ``device_busy_s`` and ``device_idle_pct``. An ALS run's
+    ``solve_impl`` (``{"user", "item"}``: ``resident_cg`` / ``xla_cg`` /
+    ``cholesky`` / ``lu``) is lifted beside them."""
     if timestamp is None:
         import datetime as _dt
 
@@ -445,7 +462,7 @@ def run_record(*, run_id: str, engine_id: str, status: str,
     if step_summary:
         rec["step_summary"] = dict(step_summary)
         for key in ("examples_per_sec", "final_loss", "loss_window_mean",
-                    "overlap_ratio", "steps", "examples"):
+                    "overlap_ratio", "steps", "examples", "solve_impl"):
             if step_summary.get(key) is not None:
                 rec[key] = step_summary[key]
     if device_scopes:

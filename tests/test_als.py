@@ -625,6 +625,192 @@ class TestALS:
         assert np.isfinite(f.user_factors).all()
 
 
+def _spd_batch(n, K, seed=0):
+    """Random SPD systems, well enough conditioned that K+8 CG sweeps
+    converge to float32 rounding whatever the summation order."""
+    rng = np.random.default_rng(seed)
+    W = rng.standard_normal((n, K, 2 * K)).astype(np.float32)
+    A = np.einsum("nkw,nlw->nkl", W, W).astype(np.float32)
+    b = rng.standard_normal((n, K)).astype(np.float32)
+    G = rng.standard_normal((K, 3 * K)).astype(np.float32)
+    return A, b, (G @ G.T).astype(np.float32)
+
+
+def _both_cg(A, b, gram, implicit, reg=0.1):
+    """(the XLA loop through ``solve_block`` as the CPU selects it, the
+    Pallas kernel interpreted) on one batch."""
+    import jax
+    import jax.numpy as jnp
+
+    from pio_tpu.models import als
+
+    math = als._make_math(reg, implicit, 1.0, "float32", "cg")
+    want = np.asarray(jax.jit(math.solve_block)(A, b, gram))
+    reg_kk = reg * jnp.eye(A.shape[1], dtype=jnp.float32)
+    if implicit:
+        reg_kk = reg_kk + gram
+    got = np.asarray(jax.jit(
+        lambda A, b, r: als._cg_solve_resident(A, b, r, interpret=True)
+    )(A, b, reg_kk))
+    return want, got
+
+
+@pytest.fixture
+def fresh_trainers():
+    """The trainers are cached per static config and read the selection
+    rule when traced; a test that steers the rule empties the caches
+    (``clear``) after it changes it, and leaves them empty."""
+    from pio_tpu.models import als
+
+    def clear():
+        als._build_trainer.cache_clear()
+        als._build_stream_trainer.cache_clear()
+
+    clear()
+    yield clear
+    clear()
+
+
+class TestResidentCG:
+    """The VMEM-resident CG kernel (``_cg_solve_resident``) against the
+    XLA loop it replaces on a TPU (``_cg_solve``, the oracle), and the
+    rule that chooses between them."""
+
+    @pytest.mark.parametrize("implicit", [False, True],
+                             ids=["explicit", "implicit"])
+    @pytest.mark.parametrize("K,n", [(16, 300), (64, 200)])
+    def test_kernel_matches_xla_loop(self, K, n, implicit):
+        # n is no multiple of the 128-entity tile: the last tile is partial
+        A, b, gram = _spd_batch(n, K)
+        want, got = _both_cg(A, b, gram, implicit)
+        assert got.shape == want.shape == (n, K)
+        scale = np.abs(want).max()
+        assert np.abs(got - want).max() <= 2e-6 * scale, (
+            np.abs(got - want).max(), scale)
+
+    def test_as_accurate_as_the_xla_loop_on_ill_conditioned_systems(self):
+        """ALS's own hard case: degree about the rank, correlated positive
+        bf16 rows, condition numbers in the thousands. There CG multiplies
+        the matvec's rounding, so the order of its 64 additions shows: one
+        after another they cost 2.6x the XLA reduction's error against a
+        float64 solve (and read +4% on the chip cell's ``item_factors.fro``);
+        the kernel's runs-of-8 tree must stay level with XLA."""
+        import jax.numpy as jnp
+
+        rng = np.random.default_rng(0)
+        K, n, deg = 64, 256, 96
+        Q = (np.abs(rng.standard_normal((4000, K))) / 8
+             + 0.3 * np.abs(rng.standard_normal((4000, 1))))
+        Q = np.asarray(jnp.asarray(Q, jnp.bfloat16).astype(jnp.float32))
+        q = Q[rng.integers(0, 4000, (n, deg))]  # [n, deg, K]
+        r = (rng.integers(1, 11, (n, deg)) * 0.5).astype(np.float32)
+        A = np.einsum("ndk,ndl->nkl", q, q).astype(np.float32)
+        b = np.einsum("ndk,nd->nk", q, r).astype(np.float32)
+        exact = np.linalg.solve(
+            A.astype(np.float64) + 0.1 * np.eye(K),
+            b.astype(np.float64)[:, :, None])[:, :, 0]
+        want, got = _both_cg(A, b, np.zeros((K, K), np.float32), False)
+        err_xla = np.linalg.norm(want - exact) / np.linalg.norm(exact)
+        err_res = np.linalg.norm(got - exact) / np.linalg.norm(exact)
+        assert 1e-5 < err_xla < 1e-2, err_xla  # hard, and still solved
+        assert err_res <= 1.25 * err_xla, (err_res, err_xla)
+
+    def test_zero_rhs_and_padding_row_are_finite(self):
+        """b = 0 makes every CG denominator 0; an entity with no
+        observation has A = 0, so its system is the regulariser alone.
+        Both must come out as finite zeros, and leave their neighbours'
+        solutions alone."""
+        A, b, gram = _spd_batch(130, 16, seed=1)
+        A0, b0 = A.copy(), b.copy()
+        b0[3] = 0.0
+        A0[5], b0[5] = 0.0, 0.0
+        want, got = _both_cg(A0, b0, gram, implicit=False)
+        assert np.isfinite(got).all()
+        assert (got[3] == 0).all() and (got[5] == 0).all()
+        _, clean = _both_cg(A, b, gram, implicit=False)
+        keep = np.ones(130, bool)
+        keep[[3, 5]] = False
+        assert np.array_equal(got[keep], clean[keep])
+        assert np.abs(got - want).max() <= 2e-6 * np.abs(want).max()
+
+    @pytest.mark.parametrize("solver,n,rank,platform,want", [
+        ("auto", 162541, 64, "tpu", "resident_cg"),
+        ("cg", 100, 16, "tpu", "resident_cg"),
+        ("auto", 162541, 64, "cpu", "xla_cg"),
+        ("cg", 162541, 64, "gpu", "xla_cg"),
+        ("auto", 162541, 10, "tpu", "xla_cg"),
+        ("auto", 40000, 128, "tpu", "resident_cg"),
+        ("auto", 40000, 256, "tpu", "xla_cg"),
+        ("auto", 32768, 64, "tpu", "cholesky"),
+        ("auto", 32769, 64, "tpu", "resident_cg"),
+        ("cholesky", 162541, 64, "tpu", "cholesky"),
+        ("lu", 162541, 64, "tpu", "lu"),
+    ])
+    def test_selection_rule(self, solver, n, rank, platform, want):
+        from pio_tpu.models.als import _solve_impl
+
+        assert _solve_impl(solver, n, rank, platform) == want
+
+    @pytest.mark.parametrize("solver,want", [
+        ("auto", "cholesky"), ("cg", "xla_cg"), ("lu", "lu")])
+    def test_stats_name_the_cpu_paths(self, synthetic, solver, want):
+        """On CPU the kernel is never picked; ``stats`` and the run
+        record say which solver ran, per side."""
+        from pio_tpu.obs import trainwatch
+
+        s = synthetic
+        st = {}
+        recorder = trainwatch.StepRecorder("solve-impl")
+        with trainwatch.recording(recorder):
+            train_als(
+                ComputeContext.local(), s["u"], s["i"], s["r"], s["U"],
+                s["I"], ALSConfig(rank=8, iterations=2, solver=solver,
+                                  blocks_per_chunk=64), stats=st)
+        assert st["solve_impl"] == {"user": want, "item": want}
+        record = trainwatch.run_record(
+            run_id="r", engine_id="e", status="COMPLETED",
+            train_seconds=1.0, phases={}, params_hash="h",
+            step_summary=recorder.summary())
+        assert record["solve_impl"] == {"user": want, "item": want}
+
+    @pytest.mark.parametrize("path", ["monolithic", "streamed", "mesh"])
+    def test_train_als_on_the_interpreted_kernel(self, synthetic, path,
+                                                 fresh_trainers,
+                                                 monkeypatch):
+        """End to end through each trainer that calls ``solve_block``:
+        the kernel (interpreted) trains the model the XLA loop trains."""
+        from pio_tpu.models import als
+
+        s = synthetic
+        cfg = ALSConfig(rank=8, iterations=6, reg=0.05, solver="cg",
+                        blocks_per_chunk=64)
+        if path == "streamed":
+            monkeypatch.setenv("PIO_TPU_ALS_STREAM_MB", "0.0005")
+        ctx = (ComputeContext.create() if path == "mesh"
+               else ComputeContext.local())
+
+        def train(want):
+            st = {}
+            f = train_als(ctx, s["u"], s["i"], s["r"], s["U"], s["I"], cfg,
+                          stats=st)
+            assert st["solve_impl"] == {"user": want, "item": want}
+            assert (st["n_stream"] > 1) == (path == "streamed")
+            return f.user_factors @ f.item_factors.T
+
+        want = train("xla_cg")  # the real rule: on CPU, the XLA loop
+        # the rule as a TPU would read it; off a TPU solve_block then
+        # interprets the kernel
+        rule = als._solve_impl
+        monkeypatch.setattr(
+            als, "_solve_impl",
+            lambda solver, n, rank, platform: rule(solver, n, rank, "tpu"))
+        fresh_trainers()
+        got = train("resident_cg")
+        # rank 8 on rank-4 data leaves the factors a rotation's freedom
+        # that rounding picks; the predictions are what is determined
+        assert np.abs(got - want).max() <= 1e-3 * np.abs(want).max()
+
+
 class TestTopN:
     def test_basic(self):
         scores = np.array([0.1, 5.0, 3.0, 4.0])
