@@ -45,7 +45,7 @@ import itertools
 import logging
 from pio_tpu.utils import knobs
 from pio_tpu.obs import active_span, devicewatch, monotonic_s, trainwatch
-from pio_tpu.obs.profile import ScopeCapture
+from pio_tpu.obs.profile import ScopeCapture, device_stats
 from typing import Optional, Tuple
 
 import numpy as np
@@ -1381,11 +1381,8 @@ def _fill_device_stats(stats: dict, capture: ScopeCapture,
             head, _, rest = path.partition("/")
             key = rest if head in _SIDE_SCOPES and rest else path
             summed[key] = summed.get(key, 0.0) + sec
-        stats["device_scope_s"] = dict(seen["scope_s"])
+        stats.update(device_stats(seen))
         stats["device_scope_summed_s"] = summed
-        stats["device_unscoped_s"] = seen["unscoped_s"]
-        stats["device_busy_s"] = seen["busy_s"]
-        stats["device_program_s"] = dict(seen["program_s"])
         if seen["busy_s"] > 0 and not seen["scope_s"]:
             log.warning(
                 "the device trace names no als.* scope: the executables "

@@ -1,0 +1,502 @@
+"""The sequence model's blocks, described by data.
+
+Two blocks share ``models/seqrec.py``'s trainer and server:
+
+- ``attention_kind="mha", ffn_kind="relu"`` — the SASRec block (pre-LN,
+  learned positions, tied head); its math lives in ``seqrec._block``.
+- ``attention_kind="mla", ffn_kind="moe"`` — the block of the
+  DeepSeek-V3 / ``glm4_moe_lite`` family: RMSNorm, multi-head latent
+  attention with RoPE on a shared rope key, ``dense_layers`` leading SwiGLU
+  layers, then expert layers (a sigmoid ``noaux_tc`` router over all
+  ``n_experts``, the ``experts_held`` experts from ``experts_first`` computed
+  here, one always-on shared expert), an untied head and ``mtp_depth``
+  multi-token-prediction modules. This module holds that block's layers.
+
+:func:`describe_params` is the one place a block's parameter shapes are
+written: ``init_params``, ``param_specs`` and the placement check of
+``train_seqrec`` all derive from it, for both blocks.
+
+Compute policy of the new block (``compute_dtype``, bfloat16 as published):
+master weights, Adam and the residual stream are float32; matmul operands
+are cast to ``compute_dtype`` and accumulate in float32; norms, softmax,
+the router's scores and every reduction are float32.
+"""
+
+from __future__ import annotations
+
+import zlib
+from typing import Dict, NamedTuple, Tuple
+
+BLOCK_KINDS = {("mha", "relu"), ("mla", "moe")}
+
+#: How the mla/moe block's parameters are drawn (a norm's gain is 1): every
+#: matrix and the head; the embedding rows; the router's selection bias.
+#: Unit embedding rows keep the residual stream the token's own: with rows of
+#: 0.02 the stream after the first layer is one vector common to every
+#: position and every token selects the same experts (PERF.md section 6).
+INIT_STD, EMBED_INIT_STD, BIAS_INIT_STD = 0.02, 1.0, 0.02
+#: Edge of the attention tiles, and tokens to a chunk of the cross-entropy and
+#: of the dense SwiGLU (each clamped to a divisor of what it cuts).
+ATTN_BLOCK, TOKEN_CHUNK = 512, 2048
+#: Share of all (token, expert) pairs one pass of the grouped matmuls takes;
+#: further passes run only while held pairs are left.
+MOE_PASS_SHARE = 0.25
+
+
+#: parameter groups the trainer reports gradient norms for, in this order
+GROUPS = ("embedding", "head", "mla", "router", "routed_experts",
+          "shared_expert", "dense_mlp", "mtp")
+
+
+def group_of(path: str) -> str:
+    """Which of ``GROUPS`` the parameter at ``group/name`` belongs to: the
+    MTP module whole; the two tables; an expert layer's router, routed
+    experts and shared expert; the dense layers' MLP; and attention with
+    every norm under ``mla``."""
+    group, _, name = path.rpartition("/")
+    if group == "mtp":
+        return "mtp"
+    by_name = {"emb": "embedding", "head": "head", "router_w": "router",
+               "router_b": "router"}
+    if name in by_name:
+        return by_name[name]
+    for prefix, kind in (("e_", "routed_experts"), ("s_", "shared_expert"),
+                         ("w_", "dense_mlp")):
+        if name.startswith(prefix):
+            return kind
+    return "mla"
+
+
+def group_norms(grads: dict):
+    """``[len(GROUPS)]`` Frobenius norms of a two-deep gradient tree."""
+    import jax.numpy as jnp
+
+    total = dict.fromkeys(GROUPS, jnp.float32(0.0))
+    for group, value in grads.items():
+        leaves = value.items() if isinstance(value, dict) else [(None, value)]
+        for name, g in leaves:
+            kind = group_of(f"{group}/{name}" if name else group)
+            total[kind] = total[kind] + jnp.sum(jnp.square(g))
+    return jnp.sqrt(jnp.stack([total[k] for k in GROUPS]))
+
+
+class Leaf(NamedTuple):
+    """One parameter: its shape and how it starts. ``init`` is ``"ones"``,
+    ``"zeros"``, ``("split", i, scale)`` (the SASRec block's historical
+    draw: key ``i`` of ``split(PRNGKey(seed), 8)``) or ``("named", std)``
+    (normal of that std under ``fold_in(PRNGKey(seed), crc32(path))``, the
+    rule a plain reference can follow by name)."""
+
+    shape: Tuple[int, ...]
+    init: object
+
+
+def is_latent(cfg) -> bool:
+    return cfg.attention_kind == "mla"
+
+
+def check_block(cfg) -> None:
+    if (cfg.attention_kind, cfg.ffn_kind) not in BLOCK_KINDS:
+        raise ValueError(
+            f"unsupported block: attention_kind={cfg.attention_kind!r} with "
+            f"ffn_kind={cfg.ffn_kind!r}; have {sorted(BLOCK_KINDS)}"
+        )
+    if not is_latent(cfg):
+        return
+    if not 0 <= cfg.dense_layers < cfg.n_layers:
+        raise ValueError("dense_layers must leave at least one expert layer")
+    if not (0 <= cfg.experts_first
+            and cfg.experts_first + cfg.experts_held <= cfg.n_experts
+            and cfg.experts_held >= 1):
+        raise ValueError(
+            f"held experts [{cfg.experts_first}, "
+            f"{cfg.experts_first + cfg.experts_held}) are not among the "
+            f"router's {cfg.n_experts}"
+        )
+    if cfg.mtp_depth not in (0, 1):
+        raise ValueError("mtp_depth is 0 or 1")
+    if cfg.qk_rope_dim % 2:
+        raise ValueError("qk_rope_dim must be even")
+
+
+def _mla_leaves(L: int, cfg) -> Dict[str, Leaf]:
+    D, H, std = cfg.d_model, cfg.n_heads, ("named", INIT_STD)
+    qk = cfg.qk_nope_dim + cfg.qk_rope_dim
+    return {
+        "attn_norm": Leaf((L, D), "ones"),
+        "q_a": Leaf((L, D, cfg.q_lora_rank), std),
+        "q_norm": Leaf((L, cfg.q_lora_rank), "ones"),
+        "q_b": Leaf((L, cfg.q_lora_rank, H * qk), std),
+        "kv_a": Leaf((L, D, cfg.kv_lora_rank + cfg.qk_rope_dim), std),
+        "kv_norm": Leaf((L, cfg.kv_lora_rank), "ones"),
+        "kv_b": Leaf((L, cfg.kv_lora_rank,
+                      H * (cfg.qk_nope_dim + cfg.v_head_dim)), std),
+        "o_proj": Leaf((L, H * cfg.v_head_dim, D), std),
+        "ffn_norm": Leaf((L, D), "ones"),
+    }
+
+
+def _expert_layer_leaves(L: int, cfg) -> Dict[str, Leaf]:
+    D, Fe, std = cfg.d_model, cfg.expert_ffn, ("named", INIT_STD)
+    Eh, Fs = cfg.experts_held, cfg.expert_ffn * cfg.shared_experts
+    return {
+        **_mla_leaves(L, cfg),
+        "router_w": Leaf((L, D, cfg.n_experts), std),
+        "router_b": Leaf((L, cfg.n_experts), ("named", BIAS_INIT_STD)),
+        "e_gate": Leaf((L, Eh, D, Fe), std),
+        "e_up": Leaf((L, Eh, D, Fe), std),
+        "e_down": Leaf((L, Eh, Fe, D), std),
+        "s_gate": Leaf((L, D, Fs), std),
+        "s_up": Leaf((L, D, Fs), std),
+        "s_down": Leaf((L, Fs, D), std),
+    }
+
+
+def describe_params(vocab: int, cfg) -> Dict[str, Leaf]:
+    """``{"group/name": Leaf}`` of every parameter of the configured
+    block, layer-stacked (leading dim = layers of that group)."""
+    D, F, L = cfg.d_model, cfg.ffn, cfg.n_layers
+    if not is_latent(cfg):
+        s = D ** -0.5
+        blocks = {
+            "ln1_g": Leaf((L, D), "ones"), "ln1_b": Leaf((L, D), "zeros"),
+            "wq": Leaf((L, D, D), ("split", 2, s)),
+            "wk": Leaf((L, D, D), ("split", 6, s)),
+            "wv": Leaf((L, D, D), ("split", 7, s)),
+            "wo": Leaf((L, D, D), ("split", 3, s)),
+            "ln2_g": Leaf((L, D), "ones"), "ln2_b": Leaf((L, D), "zeros"),
+            "w1": Leaf((L, D, F), ("split", 4, s)),
+            "b1": Leaf((L, F), "zeros"),
+            "w2": Leaf((L, F, D), ("split", 5, F ** -0.5)),
+            "b2": Leaf((L, D), "zeros"),
+        }
+        return {
+            "emb": Leaf((vocab, D), ("split", 0, s)),
+            "pos": Leaf((cfg.max_len, D), ("split", 1, s)),
+            **{f"blocks/{k}": v for k, v in blocks.items()},
+            "lnf_g": Leaf((D,), "ones"), "lnf_b": Leaf((D,), "zeros"),
+        }
+    std = ("named", INIT_STD)
+    Ld = cfg.dense_layers
+    out = {"emb": Leaf((vocab, D), ("named", EMBED_INIT_STD)),
+           "head": Leaf((vocab, D), std),
+           "lnf_g": Leaf((D,), "ones")}
+    if Ld:
+        dense = {**_mla_leaves(Ld, cfg),
+                 "w_gate": Leaf((Ld, D, F), std), "w_up": Leaf((Ld, D, F), std),
+                 "w_down": Leaf((Ld, F, D), std)}
+        out.update({f"dense/{k}": v for k, v in dense.items()})
+    out.update({f"blocks/{k}": v
+                for k, v in _expert_layer_leaves(L - Ld, cfg).items()})
+    if cfg.mtp_depth:
+        mtp = {"eh_proj": Leaf((2 * D, D), std), "h_norm": Leaf((D,), "ones"),
+               "e_norm": Leaf((D,), "ones"), "lnf_g": Leaf((D,), "ones"),
+               **_expert_layer_leaves(1, cfg)}
+        out.update({f"mtp/{k}": v for k, v in mtp.items()})
+    return out
+
+
+def unflatten(flat: dict) -> dict:
+    """``{"a/b": x}`` -> ``{"a": {"b": x}}`` (the trees are two deep)."""
+    out: dict = {}
+    for path, value in flat.items():
+        group, _, name = path.rpartition("/")
+        if group:
+            out.setdefault(group, {})[name] = value
+        else:
+            out[name] = value
+    return out
+
+
+def name_key(seed: int, path: str):
+    import jax
+
+    return jax.random.fold_in(
+        jax.random.PRNGKey(seed), zlib.crc32(path.encode()) & 0x7FFFFFFF
+    )
+
+
+def init_from(desc: Dict[str, Leaf], seed: int) -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    split = jax.random.split(jax.random.PRNGKey(seed), 8)
+    flat = {}
+    for path, leaf in desc.items():
+        if leaf.init == "ones":
+            flat[path] = jnp.ones(leaf.shape, jnp.float32)
+        elif leaf.init == "zeros":
+            flat[path] = jnp.zeros(leaf.shape, jnp.float32)
+        elif leaf.init[0] == "split":
+            _, i, scale = leaf.init
+            flat[path] = jax.random.normal(split[i], leaf.shape) * scale
+        else:
+            flat[path] = jax.random.normal(
+                name_key(seed, path), leaf.shape, jnp.float32
+            ) * jnp.float32(leaf.init[1])
+    return unflatten(flat)
+
+
+# ------------------------------------------------------------------ layers
+def _dtype(cfg):
+    import jax.numpy as jnp
+
+    return jnp.dtype(cfg.compute_dtype)
+
+
+def mm(x, w, cd):
+    """``x @ w`` with operands in the compute dtype, float32 out."""
+    import jax.numpy as jnp
+
+    return jnp.dot(x.astype(cd), w.astype(cd),
+                   preferred_element_type=jnp.float32)
+
+
+def rms_norm(x, g, eps):
+    import jax
+    import jax.numpy as jnp
+
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt((x * x).mean(axis=-1, keepdims=True) + eps) * g
+
+
+def rope(x, pos, theta: float):
+    """Rotary embedding of ``x [..., T, h, d]`` at positions ``pos [T]``,
+    pairing dim ``i`` with ``i + d/2`` (the rotate-half convention)."""
+    import jax.numpy as jnp
+
+    half = x.shape[-1] // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = pos.astype(jnp.float32)[:, None] * inv[None, :]  # [T, half]
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    a, b = x[..., :half], x[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+def swiglu(x, w_gate, w_up, w_down, cd, chunk: int = 0):
+    """``W_down(silu(W_gate x) * W_up x)`` of ``x [N, D]``; with ``chunk``,
+    that many tokens at a time under ``jax.checkpoint``, so the float32
+    ``[N, F]`` hidden of a wide layer never stands whole."""
+    import jax
+
+    from pio_tpu.parallel.ring import pick_block
+
+    w_gate, w_up, w_down = (w.astype(cd) for w in (w_gate, w_up, w_down))
+
+    def part(x):
+        hidden = jax.nn.silu(mm(x, w_gate, cd)) * mm(x, w_up, cd)
+        return mm(hidden, w_down, cd)
+
+    n = x.shape[0]
+    size = pick_block(n, chunk) if chunk else n
+    if size == n:
+        return part(x)
+    return jax.lax.map(
+        jax.checkpoint(part), x.reshape(n // size, size, -1)
+    ).reshape(n, -1)
+
+
+def mla(blk, h, cfg, s_axis):
+    """Multi-head latent attention on the local ``[B, T_loc, D]`` slice."""
+    import jax
+    import jax.numpy as jnp
+
+    from pio_tpu.parallel.ring import ring_attention
+
+    cd, eps = _dtype(cfg), cfg.norm_eps
+    B, T, _ = h.shape
+    H, dn, dr, dv = (cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim,
+                     cfg.v_head_dim)
+    t_off = 0 if s_axis is None else jax.lax.axis_index(s_axis) * T
+    pos = t_off + jnp.arange(T)
+    with jax.named_scope("seq.mla/proj"):
+        x = rms_norm(h, blk["attn_norm"], eps)
+        c_q = rms_norm(mm(x, blk["q_a"], cd), blk["q_norm"], eps)
+        q = mm(c_q, blk["q_b"], cd).reshape(B, T, H, dn + dr)
+        kv_a = mm(x, blk["kv_a"], cd)
+        c_kv = rms_norm(kv_a[..., :cfg.kv_lora_rank], blk["kv_norm"], eps)
+        k_r = kv_a[..., cfg.kv_lora_rank:].reshape(B, T, 1, dr)
+        kv = mm(c_kv, blk["kv_b"], cd).reshape(B, T, H, dn + dv)
+        q = jnp.concatenate(
+            [q[..., :dn], rope(q[..., dn:], pos, cfg.rope_theta)], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :dn],
+             jnp.broadcast_to(rope(k_r, pos, cfg.rope_theta), (B, T, H, dr))],
+            axis=-1)
+        v = kv[..., dn:]
+    with jax.named_scope("seq.mla/attn"):
+        attn = ring_attention(
+            q.astype(cd), k.astype(cd), v.astype(cd), axis=s_axis,
+            causal=True, block=ATTN_BLOCK, scale=(dn + dr) ** -0.5,
+        )
+    with jax.named_scope("seq.mla/proj"):
+        return mm(attn.reshape(B, T, H * dv), blk["o_proj"], cd)
+
+
+def route(x, router_w, router_b, cfg):
+    """``(idx [N, k], gate [N, k], load [E])``: the selected experts of
+    every token (top-k of ``sigmoid(x W_r) + b``; ``b`` takes no gradient),
+    their normalised, scaled weights and the count per expert."""
+    import jax
+    import jax.numpy as jnp
+
+    s = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    ))
+    _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(router_b),
+                           cfg.experts_per_token)
+    picked = jnp.take_along_axis(s, idx, axis=1)
+    gate = cfg.routed_scale * picked / (
+        picked.sum(axis=-1, keepdims=True) + 1e-20)
+    load = (idx.reshape(-1, 1) == jnp.arange(cfg.n_experts)[None, :]).sum(
+        axis=0).astype(jnp.float32)
+    return idx, gate, load
+
+
+def pass_plan(sizes, width: int, n_pass: int):
+    """``[n_pass, held]``: how many rows of each expert's group lie in pass
+    ``p``'s slice ``[p * width, (p + 1) * width)`` of the sorted pairs."""
+    import jax.numpy as jnp
+
+    ends = jnp.cumsum(sizes)[None, :]
+    lo = (jnp.arange(n_pass) * width)[:, None]
+    return (jnp.clip(ends - lo, 0, width)
+            - jnp.clip(ends - sizes[None, :] - lo, 0, width))
+
+
+def routed_experts(blk, x, idx, gate, cfg, first, held: int):
+    """The held experts' part of the layer's output, dropless.
+
+    ``x [N, D]``; ``first`` (may be traced) and ``held`` say which experts'
+    weights ``blk["e_*"]`` are. The (token, expert) pairs are sorted by
+    held expert (pairs of absent experts last) and the three matmuls run
+    grouped (``jax.lax.ragged_dot``) over the sorted pairs, ``MOE_PASS_SHARE``
+    of all pairs to a pass. A pass runs only while held pairs are left, so
+    the work follows the load and no pair is dropped whatever the imbalance.
+    Returns ``(y [N, D] float32, pairs, dropped)``: the pairs routed to held
+    experts, and those of them that no grouped matmul that ran was given."""
+    import jax
+    import jax.numpy as jnp
+
+    cd = _dtype(cfg)
+    N, D = x.shape
+    k = cfg.experts_per_token
+    M = N * k
+    C = min(M, max(8, -(-int(M * MOE_PASS_SHARE) // 8) * 8))
+    n_pass = -(-M // C)
+    with jax.named_scope("seq.moe/route"):
+        local = idx.reshape(-1) - first
+        is_held = (local >= 0) & (local < held)
+        key = jnp.where(is_held, local, held).astype(jnp.int32)
+        order = jnp.argsort(key, stable=True)
+        sizes = (key[:, None] == jnp.arange(held)[None, :]).sum(
+            axis=0).astype(jnp.int32)
+        pairs = is_held.sum().astype(jnp.int32)
+        plan = pass_plan(sizes, C, n_pass)
+        pad = n_pass * C - M
+        gate_sorted = jnp.pad(gate.reshape(-1)[order], (0, pad))
+        token_sorted = jnp.pad(order // k, (0, pad))
+    xc = x.astype(cd)
+    w_gate, w_up, w_down = (blk[n].astype(cd)
+                            for n in ("e_gate", "e_up", "e_down"))
+
+    @jax.checkpoint
+    def weighted(tok, g, sizes_here, valid):
+        """One pass's rows ``g_e E_e(x)``; nothing of it is kept for the
+        backward pass but its small arguments."""
+        rows = valid[:, None]
+
+        def grouped(a, w):
+            # the TPU's grouped matmul writes only the rows of a group:
+            # the rows past the last group (pairs of absent experts, the
+            # padding) hold whatever the buffer held, in its result and in
+            # its transpose's. Selecting on both sides keeps them out of
+            # the sum and out of every gradient.
+            a = jnp.where(rows, a, jnp.zeros((), a.dtype))
+            return jnp.where(rows, jax.lax.ragged_dot(
+                a, w, sizes_here, preferred_element_type=jnp.float32), 0.0)
+
+        with jax.named_scope("seq.moe/experts"):
+            xs = xc[tok]
+            hidden = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
+            ys = grouped(hidden.astype(cd), w_down)
+        with jax.named_scope("seq.moe/route"):
+            return ys * g[:, None]
+
+    # the passes are unrolled, not scanned: a scan would stack what each
+    # pass's cond keeps for the backward pass, the experts' weights among it
+    state = (jnp.zeros((N, D), jnp.float32), jnp.int32(0))
+    for p in range(n_pass):
+        lo = p * C
+        tok = token_sorted[lo:lo + C]
+        g = gate_sorted[lo:lo + C]
+        valid = lo + jnp.arange(C) < pairs
+
+        def run(state, tok=tok, g=g, sizes_here=plan[p], valid=valid):
+            y, given = state
+            rows = weighted(tok, g, sizes_here, valid)
+            with jax.named_scope("seq.moe/route"):
+                # counted where it is consumed: the rows this pass's grouped
+                # matmuls were told to compute
+                return y.at[tok].add(rows), given + sizes_here.sum()
+
+        state = jax.lax.cond(lo < pairs, run, lambda state: state, state)
+    y, given = state
+    return y, pairs, pairs - given
+
+
+def moe(blk, x, cfg, m_axis):
+    """Expert feed-forward of the normalised ``x [B, T, D]``: the held
+    routed experts' sum (closed over ``m_axis`` when experts shard there)
+    plus the shared expert. Returns ``(y, counters)``."""
+    import jax
+    import jax.numpy as jnp
+
+    B, T, D = x.shape
+    flat = x.reshape(B * T, D)
+    with jax.named_scope("seq.moe/route"):
+        idx, gate, load = route(flat, blk["router_w"], blk["router_b"], cfg)
+    held = blk["e_gate"].shape[0]
+    first = cfg.experts_first
+    if m_axis is not None:
+        first = first + jax.lax.axis_index(m_axis) * held
+    y, pairs, dropped = routed_experts(blk, flat, idx, gate, cfg, first, held)
+    if m_axis is not None:
+        y = jax.lax.psum(y, m_axis)
+        pairs = jax.lax.psum(pairs, m_axis)
+        dropped = jax.lax.psum(dropped, m_axis)
+    with jax.named_scope("seq.ffn"):
+        y = y + swiglu(flat, blk["s_gate"], blk["s_up"], blk["s_down"],
+                       _dtype(cfg))
+    counters = {"load": load, "pairs": pairs.astype(jnp.float32),
+                "dropped": dropped.astype(jnp.float32)}
+    return y.reshape(B, T, D), counters
+
+
+def dense_layer(blk, h, cfg, m_axis, s_axis):
+    import jax
+
+    h = h + mla(blk, h, cfg, s_axis)
+    with jax.named_scope("seq.ffn"):
+        B, T, D = h.shape
+        x = rms_norm(h, blk["ffn_norm"], cfg.norm_eps).reshape(B * T, D)
+        return h + swiglu(x, blk["w_gate"], blk["w_up"], blk["w_down"],
+                          _dtype(cfg), TOKEN_CHUNK).reshape(B, T, D)
+
+
+def expert_layer(blk, h, cfg, m_axis, s_axis):
+    """``(h, counters)`` of one expert layer (``blk`` has no layer dim)."""
+    h = h + mla(blk, h, cfg, s_axis)
+    y, counters = moe(blk, rms_norm(h, blk["ffn_norm"], cfg.norm_eps), cfg,
+                      m_axis)
+    return h + y, counters
+
+
+def update_router_bias(router_b, load, rate: float):
+    """DeepSeek-V3's auxiliary-loss-free balancing: after a step, every
+    expert's selection bias moves by ``rate`` towards the mean load."""
+    import jax.numpy as jnp
+
+    return router_b + rate * jnp.sign(
+        load.mean(axis=-1, keepdims=True) - load)

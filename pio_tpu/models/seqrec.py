@@ -28,10 +28,24 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional
 
 import numpy as np
 
+from pio_tpu.models.seq_layers import (
+    TOKEN_CHUNK,
+    check_block,
+    dense_layer,
+    describe_params,
+    expert_layer,
+    group_norms,
+    init_from,
+    is_latent,
+    mm,
+    rms_norm,
+    unflatten,
+    update_router_bias,
+)
 from pio_tpu.parallel.mesh import mesh_axis_size
 from pio_tpu.parallel.vocab import (
     vocab_parallel_lookup,
@@ -67,6 +81,40 @@ class SeqRecConfig:
     #: PIO_TPU_DEVICE_BUDGET_BYTES. Streamed and staged runs with the
     #: same seed/config produce identical params.
     stream: str = "auto"
+    # -- the block, by data (pio_tpu/models/seq_layers.py). The defaults
+    # -- are the SASRec block; the widths below are read only by the kinds
+    # -- that have them.
+    #: "mha" (learned positions, full heads of d_model / n_heads) or
+    #: "mla" (multi-head latent attention, RoPE on a shared rope key)
+    attention_kind: str = "mha"
+    #: "relu" (one biased two-matmul FFN of width ``ffn``) or "moe"
+    #: (``dense_layers`` SwiGLU layers of width ``ffn``, then expert layers)
+    ffn_kind: str = "relu"
+    dense_layers: int = 0
+    q_lora_rank: int = 768
+    kv_lora_rank: int = 512
+    qk_nope_dim: int = 192
+    qk_rope_dim: int = 64
+    v_head_dim: int = 256
+    rope_theta: float = 1e6
+    norm_eps: float = 1e-5
+    #: the router's width, which of its experts this program holds, and
+    #: how many a token selects
+    n_experts: int = 64
+    experts_first: int = 0
+    experts_held: int = 64
+    experts_per_token: int = 4
+    expert_ffn: int = 1536
+    shared_experts: int = 1
+    routed_scale: float = 1.8
+    #: step of the selection bias towards the mean load, after every step
+    bias_update_rate: float = 1e-3
+    #: multi-token-prediction modules (0 or 1) and their loss weight
+    mtp_depth: int = 0
+    mtp_weight: float = 0.3
+    #: matmul operand dtype of the mla/moe block (float32 accumulation,
+    #: float32 master weights and Adam)
+    compute_dtype: str = "bfloat16"
 
 
 @dataclasses.dataclass
@@ -76,6 +124,13 @@ class SeqRecModel:
     params: dict  # layer-stacked pytree (host numpy)
     n_items: int
     config: SeqRecConfig
+    #: what the training call saw, per optimizer step (mla/moe block only):
+    #: ``l_main``/``l_mtp`` [steps], ``grad_norm`` [steps, parameter groups
+    #: of ``seq_layers.GROUPS``], and per expert layer (the MTP module's
+    #: last) ``pairs`` (token, held expert) routed here, ``dropped`` (those
+    #: of them the grouped matmuls were not given: 0 for a dropless layer),
+    #: 0), ``load_max_over_mean`` over all experts, ``bias_max``
+    trace: Optional[dict] = None
     _serve_cache: Optional[tuple] = dataclasses.field(
         default=None, init=False, repr=False, compare=False
     )
@@ -97,16 +152,23 @@ class SeqRecModel:
         if self._serve_cache is None:
             params = jax.tree.map(jnp.asarray, self.params)
 
+            cfg = self.config
+
             @jax.jit
             def fwd(params, seqs):
-                h = _trunk(params, seqs, self.config, None, None, None)
                 # score from the last real position of each row
                 lengths = (seqs > 0).sum(axis=1)
-                last = jnp.take_along_axis(
-                    h,
-                    jnp.maximum(lengths - 1, 0)[:, None, None],
-                    axis=1,
-                )[:, 0]
+                at = jnp.maximum(lengths - 1, 0)[:, None, None]
+                if is_latent(cfg):
+                    # serving runs no MTP module and keeps no counters
+                    h, _ = _latent_trunk(params, seqs, cfg, None, None)
+                    last = rms_norm(
+                        jnp.take_along_axis(h, at, axis=1)[:, 0],
+                        params["lnf_g"], cfg.norm_eps)
+                    return mm(last, params["head"].T,
+                              jnp.dtype(cfg.compute_dtype))
+                h = _trunk(params, seqs, cfg, None, None, None)
+                last = jnp.take_along_axis(h, at, axis=1)[:, 0]
                 return jnp.dot(
                     last,
                     params["emb"].T,
@@ -119,55 +181,19 @@ class SeqRecModel:
 
 
 def init_params(vocab: int, cfg: SeqRecConfig):
-    """Layer-stacked parameter pytree (leading dim = n_layers)."""
-    import jax
-
-    k = jax.random.PRNGKey(cfg.seed)
-    keys = jax.random.split(k, 8)
-    D, F, L = cfg.d_model, cfg.ffn, cfg.n_layers
-    s = D ** -0.5
-
-    def nrm(key, shape, scale):
-        return jax.random.normal(key, shape) * scale
-
-    return {
-        "emb": nrm(keys[0], (vocab, D), s),
-        "pos": nrm(keys[1], (cfg.max_len, D), s),
-        "blocks": {
-            "ln1_g": np.ones((L, D), np.float32),
-            "ln1_b": np.zeros((L, D), np.float32),
-            "wq": nrm(keys[2], (L, D, D), s),
-            "wk": nrm(keys[6], (L, D, D), s),
-            "wv": nrm(keys[7], (L, D, D), s),
-            "wo": nrm(keys[3], (L, D, D), s),
-            "ln2_g": np.ones((L, D), np.float32),
-            "ln2_b": np.zeros((L, D), np.float32),
-            "w1": nrm(keys[4], (L, D, F), s),
-            "b1": np.zeros((L, F), np.float32),
-            "w2": nrm(keys[5], (L, F, D), F ** -0.5),
-            "b2": np.zeros((L, D), np.float32),
-        },
-        "lnf_g": np.ones((D,), np.float32),
-        "lnf_b": np.zeros((D,), np.float32),
-    }
+    """Layer-stacked parameter pytree (leading dim = layers of the group),
+    drawn as :func:`seq_layers.describe_params` says."""
+    return init_from(describe_params(vocab, cfg), cfg.seed)
 
 
 def param_specs(cfg: SeqRecConfig):
-    """PartitionSpecs: ep for emb, tp for heads/ffn, pp over the stack —
-    derived from the partition-rule registry (``rules_for("seqrec")``)."""
+    """PartitionSpecs: ep for emb/head and the experts, tp for the SASRec
+    block's heads/ffn, pp over its stack — derived from the partition-rule
+    registry (``rules_for("seqrec")``) over the described tree."""
     from pio_tpu.parallel.partition import match_partition_rules, rules_for
 
-    block_keys = (
-        "ln1_g", "ln1_b", "wq", "wk", "wv", "wo",
-        "ln2_g", "ln2_b", "w1", "b1", "w2", "b2",
-    )
-    skeleton = {
-        "emb": np.empty(0),
-        "pos": np.empty(0),
-        "blocks": {k: np.empty(0) for k in block_keys},
-        "lnf_g": np.empty(0),
-        "lnf_b": np.empty(0),
-    }
+    skeleton = unflatten(
+        {path: np.empty(0) for path in describe_params(1, cfg)})
     return match_partition_rules(
         rules_for("seqrec"), skeleton, on_unmatched="error"
     )
@@ -290,6 +316,128 @@ def _trunk(params, seqs, cfg, m_axis, s_axis, p_axis):
     return _ln(h, params["lnf_g"], params["lnf_b"])
 
 
+#: the MTP module's own leaves; its other leaves are one expert layer's
+_MTP_OWN = ("eh_proj", "h_norm", "e_norm", "lnf_g")
+
+
+def _latent_trunk(params, seqs, cfg, m_axis, s_axis):
+    """Embed + the dense layers + the expert layers of the mla/moe block,
+    one ``jax.checkpoint`` a layer -> ``(h [mb, T_loc, D] float32 before
+    the final norm, counters stacked over the expert layers)``."""
+    import jax
+
+    h = vocab_parallel_lookup(params["emb"], seqs, m_axis)
+
+    # the barrier keeps a layer's float32 -> compute-dtype weight casts
+    # inside the layer: hoisted out of the scan they stand for every layer
+    # at once (1.1 GB of the v5e's 16 at the published widths)
+    # (inside the checkpoint, so that the backward pass's recomputation is
+    # held the same way)
+    def dense_body(h, blk):
+        return jax.checkpoint(
+            lambda blk, h: dense_layer(
+                jax.lax.optimization_barrier(blk), h, cfg, m_axis, s_axis)
+        )(blk, h), None
+
+    def expert_body(h, blk):
+        return jax.checkpoint(
+            lambda blk, h: expert_layer(
+                jax.lax.optimization_barrier(blk), h, cfg, m_axis, s_axis)
+        )(blk, h)
+
+    if "dense" in params:
+        h, _ = jax.lax.scan(dense_body, h, params["dense"])
+    return jax.lax.scan(expert_body, h, params["blocks"])
+
+
+def _mtp_hidden(params, h, next_ids, cfg, m_axis, s_axis):
+    """The depth-1 MTP module (DeepSeek-V3 report, 2.2): ``W_eh [norm(h_t)
+    ; norm(Emb(x_{t+1}))]`` through one more expert layer. The embedding
+    and the head are the main model's."""
+    import jax
+    import jax.numpy as jnp
+
+    mtp = params["mtp"]
+    e = vocab_parallel_lookup(params["emb"], next_ids, m_axis)
+    both = jnp.concatenate(
+        [rms_norm(h, mtp["h_norm"], cfg.norm_eps),
+         rms_norm(e, mtp["e_norm"], cfg.norm_eps)], axis=-1)
+    h2 = mm(both, mtp["eh_proj"], jnp.dtype(cfg.compute_dtype))
+    blk = {k: v[0] for k, v in mtp.items() if k not in _MTP_OWN}
+    return jax.checkpoint(
+        lambda blk, h: expert_layer(blk, h, cfg, m_axis, s_axis)
+    )(blk, h2)
+
+
+def _chunked_ce(h, norm_g, head, targets, mask, cfg, m_axis):
+    """Final RMSNorm, the untied head and the cross-entropy, ``TOKEN_CHUNK``
+    tokens at a time under ``jax.checkpoint``: ``[B, T, V]`` in float32
+    never stands whole, forward or backward. Returns ``(sum_ce, sum_mask)``
+    like :func:`_vocab_parallel_ce`."""
+    import jax
+    import jax.numpy as jnp
+
+    from pio_tpu.parallel.ring import pick_block
+
+    with jax.named_scope("seq.head"):
+        cd = jnp.dtype(cfg.compute_dtype)
+        B, T, D = h.shape
+        chunk = pick_block(B * T, TOKEN_CHUNK)
+        head = head.astype(cd)
+
+        @jax.checkpoint
+        def one(hc, tc, mc):
+            x = rms_norm(hc, norm_g, cfg.norm_eps).astype(cd)
+            return _vocab_parallel_ce(x[None], head, tc[None], mc[None], m_axis)
+
+        def body(acc, xs):
+            ce, den = one(*xs)
+            return (acc[0] + ce, acc[1] + den), None
+
+        (ce, den), _ = jax.lax.scan(
+            body, (jnp.float32(0.0), jnp.float32(0.0)),
+            (h.reshape(-1, chunk, D), targets.reshape(-1, chunk),
+             mask.reshape(-1, chunk)),
+        )
+        return ce, den
+
+
+def _latent_loss_sums(params, batch, cfg, m_axis, s_axis):
+    """Local sums of one batch through the mla/moe model: ``{"ce", "den",
+    "ce2", "den2"}`` and the expert layers' counters (the MTP module's
+    layer last). Callers psum over data/seq and divide."""
+    import jax
+    import jax.numpy as jnp
+
+    seqs, targets, mask, targets2, mask2 = batch
+    h, counters = _latent_trunk(params, seqs, cfg, m_axis, s_axis)
+    ce, den = _chunked_ce(
+        h, params["lnf_g"], params["head"], targets, mask, cfg, m_axis)
+    sums = {"ce": ce, "den": den}
+    if cfg.mtp_depth:
+        with jax.named_scope("seq.mtp"):
+            h2, c2 = _mtp_hidden(params, h, targets, cfg, m_axis, s_axis)
+            sums["ce2"], sums["den2"] = _chunked_ce(
+                h2, params["mtp"]["lnf_g"], params["head"], targets2, mask2,
+                cfg, m_axis)
+        counters = jax.tree.map(
+            lambda a, b: jnp.concatenate([a, b[None]]), counters, c2)
+    return sums, counters
+
+
+def _latent_loss(sums, counters, cfg):
+    """``(loss, aux)`` from the global sums."""
+    import jax.numpy as jnp
+
+    l_main = sums["ce"] / jnp.maximum(sums["den"], 1.0)
+    aux = dict(counters, l_main=l_main)
+    loss = l_main
+    if cfg.mtp_depth:
+        aux["l_mtp"] = sums["ce2"] / jnp.maximum(sums["den2"], 1.0)
+        loss = loss + cfg.mtp_weight * aux["l_mtp"]
+    return loss, aux
+
+
 def _vocab_parallel_ce(h, emb, targets, mask, m_axis):
     """CE over the vocab-sharded logits; [mb, T_loc] masked mean parts.
 
@@ -326,6 +474,130 @@ def _vocab_parallel_ce(h, emb, targets, mask, m_axis):
     return ce.sum(), mask.sum()
 
 
+class _Programs(NamedTuple):
+    """The jitted programs of one (block, mesh, sizes)."""
+
+    init: object  # (seed) -> params, placed as ``param_specs`` says
+    opt_init: object  # (params) -> Adam's state
+    #: (state, epoch, n) / (state, epoch, n) / (state, span, n) ->
+    #: (state, losses [n], aux); ``n`` static; the first two donate ``state``
+    chunk_full: object
+    chunk_staged: object
+    chunk_span: object
+
+
+@functools.lru_cache(maxsize=8)
+def _programs(cfg: SeqRecConfig, mesh, vocab: int, B: int,
+              n_batches: int) -> _Programs:
+    """Built once and kept: ``train_seqrec`` defines no jitted function of
+    its own, so a second call with the same block, mesh and sizes traces
+    and compiles nothing. ``cfg`` comes with ``seed`` and ``steps`` zeroed:
+    the seed is ``init``'s argument and the step count the steppers'."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from jax import shard_map
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    latent = is_latent(cfg)
+    m_axis = "model" if mesh is not None else None
+    s_axis = "seq" if mesh is not None else None
+    p_axis = "pipe" if mesh_axis_size(mesh, "pipe") > 1 else None
+    tx = optax.adam(cfg.learning_rate)
+    specs = param_specs(cfg)
+
+    def local_loss(params, batch, m_axis, s_axis, p_axis, psum):
+        """``(loss, aux)`` from one device's slice; ``psum`` closes the
+        sums over data and seq."""
+        if latent:
+            sums, counters = _latent_loss_sums(
+                params, batch, cfg, m_axis, s_axis)
+            return _latent_loss(*psum((sums, counters)), cfg)
+        seqs, targets, mask = batch
+        h = _trunk(params, seqs, cfg, m_axis, s_axis, p_axis)
+        ce, denom = psum(_vocab_parallel_ce(
+            h, params["emb"], targets, mask, m_axis))
+        return ce / jnp.maximum(denom, 1.0), {}
+
+    def global_loss(params, batch):
+        if mesh is None:
+            return local_loss(params, batch, None, None, None, lambda x: x)
+        dspec = P("data", "seq")
+        return shard_map(
+            lambda params, batch: local_loss(
+                params, batch, m_axis, s_axis, p_axis,
+                lambda x: jax.lax.psum(x, ("data", "seq"))),
+            mesh=mesh,
+            in_specs=(specs, (dspec,) * len(batch)),
+            out_specs=P(),
+            check_vma=False,
+        )(params, batch)
+
+    def init_all(seed):
+        p = init_from(describe_params(vocab, cfg), seed)
+        return jax.tree.map(lambda a: jnp.asarray(a, jnp.float32), p)
+
+    if mesh is None:
+        init = jax.jit(init_all)
+    else:
+        init = jax.jit(init_all, out_shardings=jax.tree.map(
+            lambda spec: NamedSharding(mesh, spec), specs,
+            is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec),
+        ))
+
+    def scan_steps(state, n, batch_fn):
+        step0, params, opt_state = state
+
+        def step(carry, i):
+            params, opt_state = carry
+            (loss, aux), grads = jax.value_and_grad(
+                global_loss, has_aux=True)(params, batch_fn(i, step0))
+            with jax.named_scope("seq.opt"):
+                if latent:
+                    aux["grad_norm"] = group_norms(grads)
+                updates, opt_state = tx.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
+                if latent:
+                    params, aux = _after_step(params, aux, cfg)
+            return (params, opt_state), (loss, aux)
+
+        (params, opt_state), (losses, aux) = jax.lax.scan(
+            step, (params, opt_state), jnp.arange(n)
+        )
+        # per-step losses ride along for the telemetry plane; callers
+        # that don't want them drop the array undereferenced (no sync)
+        return (step0 + n, params, opt_state), losses, aux
+
+    # the state is donated: beside 16 B a parameter there is no room for a
+    # second copy of the weights and Adam's moments
+    @functools.partial(jax.jit, static_argnums=2, donate_argnums=0)
+    def chunk_full(state, epoch, n):
+        return scan_steps(state, n, lambda i, step0: epoch)
+
+    @functools.partial(jax.jit, static_argnums=2, donate_argnums=0)
+    def chunk_staged(state, epoch, n):
+        def batch_fn(i, step0):
+            start = ((step0 + i) % n_batches) * B
+            return tuple(
+                jax.lax.dynamic_slice_in_dim(a, start, B) for a in epoch
+            )
+
+        return scan_steps(state, n, batch_fn)
+
+    # not donated: the feed keeps each span's carry to wait on it
+    @functools.partial(jax.jit, static_argnums=2)
+    def chunk_span(state, span, n):
+        def batch_fn(i, step0):
+            return tuple(
+                jax.lax.dynamic_slice_in_dim(a, i * B, B) for a in span
+            )
+
+        return scan_steps(state, n, batch_fn)
+
+    return _Programs(init, jax.jit(tx.init), chunk_full, chunk_staged,
+                     chunk_span)
+
+
 def train_seqrec(
     mesh,
     sequences: np.ndarray,
@@ -339,7 +611,9 @@ def train_seqrec(
 
     Args:
         mesh: build_mesh() mesh — data/seq/model/pipe all honored; None →
-            single-device.
+            single-device. The mla/moe block takes data, seq (ring over
+            the same blocked attention) and model (experts and vocabulary
+            shard, tokens stay replicated); it has no pipe split yet.
         sequences: [n, T] int32, item ids ≥ 1, 0 = pad (right-padded).
         n_items: vocabulary size (ids are 1..n_items; row 0 = pad).
         checkpoint/checkpoint_every: optional
@@ -347,29 +621,39 @@ def train_seqrec(
             interval in steps; resumes from the newest snapshot on restart.
         stats: optional dict — streamed runs report the executor phases
             (h2d_s/device_s/h2d_bytes/encode_s) plus n_stream; all runs
-            report place_s/steps_s (profiling only: phases serialize).
+            report pack_s/place_s/steps_s/readback_s (profiling only:
+            phases serialize). On a TPU the steps of a ``stats`` call are
+            also traced and reduced to the program's ``seq.*`` scopes
+            (``device_scope_s``, ``device_unscoped_s``, ``device_busy_s``,
+            ``device_program_s``: pio_tpu/obs/profile.py), ``xla`` holds
+            the compile counts with ``in_call``, and ``counters`` the
+            mla/moe block's routed pairs, load ratio, dropped pairs and
+            largest selection bias.
 
     Raises:
         DeviceBudgetExceeded: the params can't fit (single-chip or even
             sharded), or the staged epoch can't fit next to them and
             ``batch_size`` is 0 so the feed cannot stream (full-batch
-            steps need the whole dataset resident).
+            steps need the whole dataset resident). The check counts the
+            parameters alone (4 B each): not Adam's two moments, the
+            gradients or the activations, which the compiler places.
     """
     import jax
     import jax.numpy as jnp
-    import optax
-    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from pio_tpu.obs import active_span, devicewatch, monotonic_s, trainwatch
+    from pio_tpu.obs.profile import ScopeCapture, device_stats
+
     cfg = config
+    latent = is_latent(cfg)
     n_data = mesh_axis_size(mesh, "data")
     n_seq = mesh_axis_size(mesh, "seq")
     n_model = mesh_axis_size(mesh, "model")
     n_pipe = mesh_axis_size(mesh, "pipe")
-    m_axis = "model" if mesh is not None else None
-    s_axis = "seq" if mesh is not None else None
     p_axis = "pipe" if (mesh is not None and n_pipe > 1) else None
 
+    check_block(cfg)
     if cfg.stream not in ("auto", "on", "off"):
         raise ValueError(
             f"stream must be auto/on/off, got {cfg.stream!r}"
@@ -379,14 +663,25 @@ def train_seqrec(
             "stream='on' needs batch_size > 0 (full-batch steps consume "
             "the whole dataset every step — nothing to stream)"
         )
-    if cfg.n_heads % n_model:
-        raise ValueError("n_heads must divide by the model axis")
-    if cfg.n_layers % max(n_pipe, 1):
-        raise ValueError("n_layers must divide by the pipe axis")
     if cfg.attention not in ("ring", "ulysses"):
         raise ValueError(
             f"unknown attention mode {cfg.attention!r}; use ring/ulysses"
         )
+    if latent:
+        if p_axis is not None:
+            raise ValueError(
+                "the mla/moe block has no pipe split: its stages differ "
+                "(dense, expert, MTP) and pipeline_apply takes like stages"
+            )
+        if cfg.attention != "ring":
+            raise ValueError("the mla/moe block rides ring attention")
+        if cfg.experts_held % n_model:
+            raise ValueError("experts_held must divide by the model axis")
+    else:
+        if cfg.n_heads % n_model:
+            raise ValueError("n_heads must divide by the model axis")
+        if cfg.n_layers % max(n_pipe, 1):
+            raise ValueError("n_layers must divide by the pipe axis")
     if cfg.attention == "ulysses" and (cfg.n_heads // max(n_model, 1)) % max(
         n_seq, 1
     ):
@@ -397,48 +692,61 @@ def train_seqrec(
             f"({n_seq}); use ring attention or adjust n_heads"
         )
 
-    seqs = np.asarray(sequences, np.int32)
-    n, t = seqs.shape
-    t_pad = _round_up(min(t, cfg.max_len), n_seq)
-    if t_pad > cfg.max_len:
-        raise ValueError(
-            f"max_len {cfg.max_len} not a multiple of seq axis {n_seq}"
-        )
-    buf = np.zeros((_round_up(n, n_data), t_pad), np.int32)
-    if t <= t_pad:
-        buf[:n, :t] = seqs
-    else:
-        # keep each row's NEWEST t_pad events: serving scores the tail of
-        # the history (next_item_scores on codes[-max_len:]), so training
-        # on the head would skew heavy users onto stale behavior
-        for r in range(n):
-            codes = seqs[r][seqs[r] > 0][-t_pad:]
-            buf[r, : len(codes)] = codes
-    seqs = buf
+    t_pack = monotonic_s()
+    with active_span("seq.pack"):
+        seqs = np.asarray(sequences, np.int32)
+        n, t = seqs.shape
+        t_pad = _round_up(min(t, cfg.max_len), n_seq)
+        if t_pad > cfg.max_len:
+            raise ValueError(
+                f"max_len {cfg.max_len} not a multiple of seq axis {n_seq}"
+            )
+        buf = np.zeros((_round_up(n, n_data), t_pad), np.int32)
+        if t <= t_pad:
+            buf[:n, :t] = seqs
+        else:
+            # keep each row's NEWEST t_pad events: serving scores the tail
+            # of the history (next_item_scores on codes[-max_len:]), so
+            # training on the head would skew heavy users onto stale
+            # behavior
+            for r in range(n):
+                codes = seqs[r][seqs[r] > 0][-t_pad:]
+                buf[r, : len(codes)] = codes
+        seqs = buf
 
-    if cfg.batch_size > 0:
-        # minibatch SGD: contiguous row blocks with wraparound so every
-        # scan step slices a full batch (the two_tower discipline)
-        B = _round_up(min(cfg.batch_size, max(n, 1)), n_data)
-        reps = _round_up(max(n, B), B)
-        seqs = np.resize(seqs[:max(n, 1)], (reps, t_pad))
-        n_batches = reps // B
-    else:
-        B, n_batches = seqs.shape[0], 1
+        if cfg.batch_size > 0:
+            # minibatch SGD: contiguous row blocks with wraparound so every
+            # scan step slices a full batch (the two_tower discipline)
+            B = _round_up(min(cfg.batch_size, max(n, 1)), n_data)
+            reps = _round_up(max(n, B), B)
+            seqs = np.resize(seqs[:max(n, 1)], (reps, t_pad))
+            n_batches = reps // B
+        else:
+            B, n_batches = seqs.shape[0], 1
 
-    # next-item targets: target[t] = seq[t+1]; last position unsupervised
-    targets = np.zeros_like(seqs)
-    targets[:, :-1] = seqs[:, 1:]
-    mask = (targets > 0) & (seqs > 0)
+        # next-item targets: target[t] = seq[t+1]; last position
+        # unsupervised
+        targets = np.zeros_like(seqs)
+        targets[:, :-1] = seqs[:, 1:]
+        mask = (targets > 0) & (seqs > 0)
+        epoch = [seqs, targets, mask.astype(np.float32)]
+        if latent:
+            # the MTP module's targets, one more event ahead
+            targets2 = np.zeros_like(seqs)
+            targets2[:, :-2] = seqs[:, 2:]
+            epoch += [targets2, (mask & (targets2 > 0)).astype(np.float32)]
+        epoch = tuple(epoch)
+    if stats is not None:
+        stats["pack_s"] = monotonic_s() - t_pack
 
     vocab = _round_up(n_items + 1, n_model)  # +1 for the pad row
-    tx = optax.adam(cfg.learning_rate)
     specs = param_specs(cfg)
 
     # placement accounting BEFORE anything lands on device (the
     # two_tower discipline): sharded params must fit the per-chip
     # budget, and the staged epoch must fit NEXT TO them or the feed
-    # streams row spans instead
+    # streams row spans instead. What is counted is the parameters, 4 B
+    # each, as they shard; Adam's moments (8 B more) are not.
     from pio_tpu.parallel.partition import (
         DeviceBudgetExceeded,
         assert_device_budget,
@@ -446,40 +754,27 @@ def train_seqrec(
         per_device_nbytes,
     )
 
-    def _skeleton():
-        D, F, L, T = cfg.d_model, cfg.ffn, cfg.n_layers, cfg.max_len
-        z = np.zeros((), np.float32)
-
-        def bt(*shape):
-            return np.broadcast_to(z, shape)
-
-        return {
-            "emb": bt(vocab, D),
-            "pos": bt(T, D),
-            "blocks": {
-                "ln1_g": bt(L, D), "ln1_b": bt(L, D),
-                "wq": bt(L, D, D), "wk": bt(L, D, D), "wv": bt(L, D, D),
-                "wo": bt(L, D, D), "ln2_g": bt(L, D), "ln2_b": bt(L, D),
-                "w1": bt(L, D, F), "b1": bt(L, F),
-                "w2": bt(L, F, D), "b2": bt(L, D),
-            },
-            "lnf_g": bt(D), "lnf_b": bt(D),
-        }
-
-    skeleton = _skeleton()
+    z = np.zeros((), np.float32)
+    skeleton = unflatten({
+        path: np.broadcast_to(z, leaf.shape)
+        for path, leaf in describe_params(vocab, cfg).items()
+    })
     params_nbytes = sum(
         leaf.nbytes for leaf in jax.tree_util.tree_leaves(skeleton)
     )
     if mesh is None:
         assert_device_budget(
-            params_nbytes, 1, "seqrec params (single-chip placement)"
+            params_nbytes, 1,
+            "seqrec params alone, no optimizer state (single-chip placement)"
         )
         params_pd = params_nbytes
     else:
         params_pd = per_device_nbytes(mesh, skeleton, specs)
-        assert_device_budget(params_pd, 1, "seqrec sharded params")
-    # seqs + targets (int32) + mask (float32), sharded over data × seq
-    staged_pd = -(-12 * seqs.shape[0] * t_pad // (n_data * n_seq))
+        assert_device_budget(
+            params_pd, 1, "seqrec sharded params alone, no optimizer state")
+    # seqs + targets (int32) + masks (float32), sharded over data × seq
+    row_bytes = 4 * len(epoch)
+    staged_pd = -(-row_bytes * seqs.shape[0] * t_pad // (n_data * n_seq))
     budget = device_budget_bytes()
     over = budget > 0 and params_pd + staged_pd > budget
     streamed = cfg.batch_size > 0 and (
@@ -498,7 +793,7 @@ def train_seqrec(
 
         n_stream = max(
             2,
-            n_stream_chunks(12 * seqs.shape[0] * t_pad,
+            n_stream_chunks(row_bytes * seqs.shape[0] * t_pad,
                             "PIO_TPU_TRAIN_STREAM_MB", cap=256),
         )
         if budget > params_pd:
@@ -507,115 +802,30 @@ def train_seqrec(
     if stats is not None:
         stats["n_stream"] = n_stream
 
-    def global_loss(params, seqs, targets, mask):
-        if mesh is None:
-            h = _trunk(params, seqs, cfg, None, None, None)
-            ce, denom = _vocab_parallel_ce(
-                h, params["emb"], targets, mask, None
-            )
-            return ce / jnp.maximum(denom, 1.0)
-
-        def inner(params, seqs, targets, mask):
-            h = _trunk(params, seqs, cfg, m_axis, s_axis, p_axis)
-            ce, denom = _vocab_parallel_ce(
-                h, params["emb"], targets, mask, m_axis
-            )
-            ce = jax.lax.psum(ce, ("data", "seq"))
-            denom = jax.lax.psum(denom, ("data", "seq"))
-            return ce / jnp.maximum(denom, 1.0)
-
-        dspec = P("data", "seq")
-        return shard_map(
-            inner,
-            mesh=mesh,
-            in_specs=(specs, dspec, dspec, dspec),
-            out_specs=P(),
-            check_vma=False,
-        )(params, seqs, targets, mask)
-
-    mask = mask.astype(np.float32)
-
-    def _init_all():
-        p = init_params(vocab, cfg)
-        return jax.tree.map(lambda a: jnp.asarray(a, jnp.float32), p)
-
-    from pio_tpu.obs import monotonic_s
-
+    # the programs are built once for these sizes and kept: a second call
+    # traces and compiles nothing
+    prog = _programs(dataclasses.replace(cfg, seed=0, steps=0), mesh, vocab,
+                     B, n_batches)
     t0 = monotonic_s()
     dsh = None
-    if mesh is not None:
-        psh = jax.tree.map(
-            lambda spec: NamedSharding(mesh, spec),
-            specs,
-            is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec),
-        )
-        # each device materializes only its shard — the vocab-sharded
-        # table never exists unsharded on any chip
-        params = jax.jit(_init_all, out_shardings=psh)()
-        dsh = NamedSharding(mesh, P("data", "seq"))
-    else:
-        params = jax.jit(_init_all)()
+    with active_span("seq.init"):
+        # with a mesh each device materializes only its shard — the
+        # vocab-sharded table never exists unsharded on any chip
+        params = prog.init(jnp.int32(cfg.seed))
+        if mesh is not None:
+            dsh = NamedSharding(mesh, P("data", "seq"))
 
-    def _put_epoch(s_np, t_np, m_np):
+    def _put_epoch(*arrays):
         if mesh is None:
-            return jnp.asarray(s_np), jnp.asarray(t_np), jnp.asarray(m_np)
-        return tuple(
-            jax.device_put(jnp.asarray(a), dsh) for a in (s_np, t_np, m_np)
-        )
+            return tuple(jnp.asarray(a) for a in arrays)
+        return tuple(jax.device_put(jnp.asarray(a), dsh) for a in arrays)
 
-    seqs_d = targets_d = mask_d = None
+    epoch_d = None
     if not streamed:
-        seqs_d, targets_d, mask_d = _put_epoch(seqs, targets, mask)
+        epoch_d = _put_epoch(*epoch)
     if stats is not None:
-        jax.block_until_ready((params, seqs_d, targets_d, mask_d))
+        jax.block_until_ready((params, epoch_d))
         stats["place_s"] = monotonic_s() - t0
-
-    def _scan_steps(state, n, batch_fn):
-        step0, params, opt_state = state
-
-        def step(carry, i):
-            params, opt_state = carry
-            loss, grads = jax.value_and_grad(global_loss)(
-                params, *batch_fn(i, step0)
-            )
-            updates, opt_state = tx.update(grads, opt_state, params)
-            return (optax.apply_updates(params, updates), opt_state), loss
-
-        (params, opt_state), losses = jax.lax.scan(
-            step, (params, opt_state), jnp.arange(n)
-        )
-        # per-step losses ride along for the telemetry plane; callers
-        # that don't want them drop the array undereferenced (no sync)
-        return (step0 + n, params, opt_state), losses
-
-    @functools.partial(jax.jit, static_argnums=1)
-    def chunk_full(state, n):
-        return _scan_steps(
-            state, n, lambda i, step0: (seqs_d, targets_d, mask_d)
-        )
-
-    @functools.partial(jax.jit, static_argnums=1)
-    def chunk_staged(state, n):
-        def batch_fn(i, step0):
-            start = ((step0 + i) % n_batches) * B
-            return tuple(
-                jax.lax.dynamic_slice_in_dim(a, start, B)
-                for a in (seqs_d, targets_d, mask_d)
-            )
-
-        return _scan_steps(state, n, batch_fn)
-
-    @functools.partial(jax.jit, static_argnums=4)
-    def chunk_span(state, s_span, t_span, m_span, n):
-        def batch_fn(i, step0):
-            return tuple(
-                jax.lax.dynamic_slice_in_dim(a, i * B, B)
-                for a in (s_span, t_span, m_span)
-            )
-
-        return _scan_steps(state, n, batch_fn)
-
-    from pio_tpu.obs import devicewatch, trainwatch
 
     trainwatch.begin_algo(
         "seqrec", total_steps=cfg.steps, n_batches=n_batches,
@@ -627,6 +837,7 @@ def train_seqrec(
     # dispatch frontier; no recorder → dropped undereferenced.
     _pending: list = []
     _last_drain = [monotonic_s()]
+    _aux: list = []  # the mla/moe block's per-step counters, on device
 
     def _drain(keep: int = 0):
         while len(_pending) > keep:
@@ -639,7 +850,9 @@ def train_seqrec(
             )
             _last_drain[0] = now
 
-    def _note_chunk(n_s, losses_dev, keep: int):
+    def _note_chunk(n_s, losses_dev, aux_dev, keep: int):
+        if latent:
+            _aux.append(aux_dev)
         if trainwatch.active_recorder() is None:
             return
         _pending.append((n_s, losses_dev))
@@ -662,14 +875,13 @@ def train_seqrec(
             def encode(span):
                 b0, b1 = span
                 return tuple(
-                    np.ascontiguousarray(a[b0 * B:b1 * B])
-                    for a in (seqs, targets, mask)
+                    np.ascontiguousarray(a[b0 * B:b1 * B]) for a in epoch
                 )
 
             def dispatch(st, dev, i):
                 b0, b1 = work[i]
-                st, losses = chunk_span(st, *dev, b1 - b0)
-                _note_chunk(b1 - b0, losses, keep=2)
+                st, losses, aux = prog.chunk_span(st, dev, b1 - b0)
+                _note_chunk(b1 - b0, losses, aux, keep=2)
                 return st
 
             return stream_feed(
@@ -690,8 +902,8 @@ def train_seqrec(
             with devicewatch.compile_span(
                 "train_step", key=("seqrec", "staged", B, int(n))
             ):
-                state, losses = chunk_staged(state, n)
-            _note_chunk(n, losses, keep=1)
+                state, losses, aux = prog.chunk_staged(state, epoch_d, n)
+            _note_chunk(n, losses, aux, keep=1)
             return state
     else:
         def chunk_fn(state, n):
@@ -699,8 +911,8 @@ def train_seqrec(
             with devicewatch.compile_span(
                 "train_step", key=("seqrec", "full", int(n))
             ):
-                state, losses = chunk_full(state, n)
-            _note_chunk(n, losses, keep=1)
+                state, losses, aux = prog.chunk_full(state, epoch_d, n)
+            _note_chunk(n, losses, aux, keep=1)
             return state
 
     from pio_tpu.workflow.checkpoint import (
@@ -715,17 +927,83 @@ def train_seqrec(
         "seqrec", dataclasses.replace(cfg, steps=0, stream="auto"),
         n_items, seqs.shape, int(seqs.sum()),
     )
-    state = (jnp.int32(0), params, jax.jit(tx.init)(params))
-    state = run_chunked_steps(
-        state, cfg.steps, chunk_fn,
-        checkpoint=checkpoint, checkpoint_every=checkpoint_every,
-        fingerprint=fingerprint,
-    )
+    state = (jnp.int32(0), params, prog.opt_init(params))
+    xla_before = devicewatch.xla_totals() if stats is not None else None
+    capture = ScopeCapture("seq.")  # entered by a ``stats`` call only
+    t0 = monotonic_s()
+    if stats is not None:
+        jax.block_until_ready(state)
+        with capture:
+            state = run_chunked_steps(
+                state, cfg.steps, chunk_fn,
+                checkpoint=checkpoint, checkpoint_every=checkpoint_every,
+                fingerprint=fingerprint,
+            )
+            jax.block_until_ready(state)
+        stats["steps_s"] = monotonic_s() - t0
+    else:
+        state = run_chunked_steps(
+            state, cfg.steps, chunk_fn,
+            checkpoint=checkpoint, checkpoint_every=checkpoint_every,
+            fingerprint=fingerprint,
+        )
     _drain()  # flush the telemetry tail (no-op without a recorder)
     fitted = state[1]
 
     # ONE fused pull (device_get returns host numpy): per-leaf
     # np.asarray paid a host link round trip per parameter tensor
-    host = jax.device_get(fitted)
-    host["emb"] = host["emb"][: n_items + 1]
-    return SeqRecModel(params=host, n_items=n_items, config=cfg)
+    t0 = monotonic_s()
+    with active_span("seq.readback"):
+        host, aux = jax.device_get((fitted, _aux))
+    for table in ("emb", "head"):
+        if table in host:
+            host[table] = host[table][: n_items + 1]
+    trace = None
+    if aux:
+        trace = {
+            k: np.concatenate([np.asarray(a[k]) for a in aux])
+            for k in aux[0] if k != "load"
+        }
+    if stats is not None:
+        stats["readback_s"] = monotonic_s() - t0
+        stats.update(device_stats(capture.result))
+        xla = devicewatch.xla_totals()
+        if xla is not None and xla_before is not None:
+            stats["xla"] = dict(
+                xla, in_call={k: xla[k] - xla_before[k] for k in xla})
+        if trace is not None:
+            stats["counters"] = {
+                "pairs_held": float(trace["pairs"].sum()),
+                "dropped_pairs": float(trace["dropped"].sum()),
+                "load_max_over_mean": float(
+                    trace["load_max_over_mean"].max()),
+                "bias_max": float(trace["bias_max"].max()),
+            }
+    return SeqRecModel(params=host, n_items=n_items, config=cfg, trace=trace)
+
+
+def _after_step(params, aux, cfg):
+    """What follows the optimizer in a step of the mla/moe block: every
+    expert layer's selection bias moves towards the mean load, and the
+    per-expert loads reduce to the step's counters."""
+    import jax.numpy as jnp
+
+    load = aux["load"]  # [expert layers (+ the MTP module's), n_experts]
+    n_main = params["blocks"]["router_b"].shape[0]
+    params = dict(params)
+    groups = [("blocks", load[:n_main])]
+    if cfg.mtp_depth:
+        groups.append(("mtp", load[n_main:]))
+    biases = []
+    for group, group_load in groups:
+        b = update_router_bias(
+            params[group]["router_b"], group_load, cfg.bias_update_rate)
+        params[group] = dict(params[group], router_b=b)
+        biases.append(jnp.abs(b).max())
+    aux = dict(
+        aux,
+        load_max_over_mean=load.max(axis=-1) / jnp.maximum(
+            load.mean(axis=-1), 1.0),
+        bias_max=jnp.stack(biases).max(),
+    )
+    return params, aux

@@ -179,10 +179,13 @@ _SCOPE_ATOM = re.compile(r"^[a-z][a-z0-9_]*$")
 #: atoms JAX's own control flow and transforms put on the name stack
 _JAX_ATOMS = frozenset((
     "while", "body", "cond", "closed_call", "scan", "shard_map", "pjit",
-    "checkpoint", "remat", "core_call", "custom_jvp_call",
+    "checkpoint", "remat", "rematted_computation", "core_call",
+    "custom_jvp_call",
     "custom_vjp_call", "custom_lin",
 ))
 _JAX_BRANCH = re.compile(r"^branch_\d+_fun$")
+#: a scope that a transform wrapped whole: ``transpose(jvp(seq.mtp))``
+_TRANSFORMED = re.compile(r"^(?:(?:transpose|jvp|vmap)\()+([^()]*)\)+$")
 
 
 def _varint(buf, i: int) -> Tuple[int, int]:
@@ -235,10 +238,12 @@ def _map_entry(entry) -> dict:
 def scope_path(op_name: str, prefix: str) -> Optional[str]:
     """``jit(f)/als.item/als.solve/cg/while/body/closed_call/mul:`` ->
     ``als.item/als.solve/cg``: from the first segment that starts with
-    ``prefix``, the segments a program named (those with the prefix, and
+    ``prefix`` (a segment a transform wrapped, ``transpose(jvp(seq.mtp))``,
+    counts as the scope inside), the segments a program named (those with the prefix, and
     bare lowercase atoms that are not JAX's own), less the last segment,
     which is the primitive. ``None`` when no segment has the prefix."""
-    segments = op_name.rsplit(":", 1)[0].split("/")
+    segments = [_TRANSFORMED.sub(r"\1", seg)
+                for seg in op_name.rsplit(":", 1)[0].split("/")]
     for first, seg in enumerate(segments):
         if seg.startswith(prefix):
             break
@@ -246,6 +251,8 @@ def scope_path(op_name: str, prefix: str) -> Optional[str]:
         return None
     kept = [segments[first]]
     for seg in segments[first + 1:-1]:
+        if seg == kept[-1]:
+            continue  # transpose(jvp(s))/jvp(s): one scope, named twice
         if seg.startswith(prefix) or (
                 _SCOPE_ATOM.match(seg) and seg not in _JAX_ATOMS
                 and not _JAX_BRANCH.match(seg)):
@@ -381,6 +388,20 @@ def reduce_scopes(trace_dir: str, prefix: str = "als.") -> dict:
         "scope_s": scope_s,
         "unscoped_s": unscoped,
         "program_s": program_s,
+    }
+
+
+def device_stats(seen: Optional[dict]) -> dict:
+    """A :class:`ScopeCapture` result as the JSON-plain ``stats`` entries
+    the trainers report (``train_als``, ``train_seqrec``); ``{}`` when
+    nothing was captured."""
+    if seen is None:
+        return {}
+    return {
+        "device_scope_s": dict(seen["scope_s"]),
+        "device_unscoped_s": seen["unscoped_s"],
+        "device_busy_s": seen["busy_s"],
+        "device_program_s": dict(seen["program_s"]),
     }
 
 

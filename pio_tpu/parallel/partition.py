@@ -224,8 +224,16 @@ def _seqrec_rules():
         (r"blocks/(wq|wk|wv|w1)$", P("pipe", None, "model")),
         (r"blocks/(wo|w2)$", P("pipe", "model", None)),
         (r"blocks/b1$", P("pipe", "model")),
+        # the mla/moe block (models/seq_layers.py): routed experts shard
+        # by expert over model ([layer, expert, in, out]); attention, the
+        # router, the shared expert, the dense layers and the MTP module's
+        # own leaves are held whole
+        (r"(blocks|mtp)/e_(gate|up|down)$", P(None, "model")),
+        (r"blocks/(attn_norm|q_a|q_norm|q_b|kv_a|kv_norm|kv_b|o_proj|ffn_norm|"
+         r"router_w|router_b|s_gate|s_up|s_down)$", P()),
+        (r"(dense|mtp)/", P()),
         (r"blocks/", P("pipe", None)),
-        (r"emb$", P("model", None)),
+        (r"(emb|head)$", P("model", None)),
         (r"(pos|lnf_g|lnf_b)$", P()),
     ]
 

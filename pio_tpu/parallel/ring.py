@@ -20,12 +20,14 @@ Design (blockwise / ring formulation):
 - Causality uses *global* positions: device ``i`` owns q-positions
   ``i·T/n + [0, T/n)``; after ``s`` rotations it is looking at the K/V block
   that started on device ``(i - s) mod n``. Blocks entirely in the future
-  still flow through the ring (uniform program on every device — XLA cannot
-  skip them) but contribute zero weight.
+  still flow through the ring, but their tiles are skipped: each ring step
+  is one :func:`attention_partial`, blocked over local key blocks, whose
+  loop runs only over the key blocks at or below the diagonal.
 
 Inside ``jit`` with a sharded mesh this function must be wrapped in
 ``shard_map`` over the ``seq`` axis (see :func:`ring_attention_sharded`);
-on a single device (``axis=None``) it degrades to plain blockwise attention.
+on a single device (``axis=None``) it is the same blocked attention over
+the whole row (memory linear in T, forward and backward).
 """
 
 from __future__ import annotations
@@ -38,30 +40,185 @@ import jax.numpy as jnp
 
 
 _NEG_BIG = -1e30
+#: key/query block edge of the blocked update; the score tile is
+#: ``[B, H, block, block]`` float32 whatever the row's length
+DEFAULT_BLOCK = 512
 
 
-def _block_attn_update(o, m, l, q, k, v, q_pos, k_pos, causal, scale):
-    """One online-softmax accumulation of a (q-block, kv-block) pair.
+def pick_block(t: int, block: int) -> int:
+    """The largest divisor of ``t`` that is at most ``block``."""
+    b = max(1, min(int(block), int(t)))
+    while t % b:
+        b -= 1
+    return b
 
-    Shapes: q [B, Tq, H, D], k/v [B, Tk, H, D]; o/m/l accumulators.
-    """
-    scores = jnp.einsum(
-        "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
+
+def needed_key_blocks(i, q_off, k_off, bq: int, bk: int, nk: int, causal: bool):
+    """How many leading key blocks query block ``i`` can see: a key block
+    whose first position lies past the query block's last is above the
+    diagonal and is never computed."""
+    if not causal:
+        return nk
+    last_q = q_off + (i + 1) * bq - 1
+    return jnp.clip((last_q - k_off) // bk + 1, 0, nk)
+
+
+def _scores(qi, kj, q_pos, k_pos, causal, scale):
+    s = jnp.einsum(
+        "bhqd,bhkd->bhqk", qi, kj, preferred_element_type=jnp.float32
     ) * scale
-    if causal:
-        mask = q_pos[:, None] >= k_pos[None, :]  # [Tq, Tk]
-        scores = jnp.where(mask[None, None], scores, _NEG_BIG)
-    m_new = jnp.maximum(m, scores.max(axis=-1))
-    p = jnp.exp(scores - m_new[..., None])
-    if causal:
-        p = jnp.where(mask[None, None], p, 0.0)
-    correction = jnp.exp(m - m_new)
-    l_new = l * correction + p.sum(axis=-1)
-    o_new = o * correction[..., None] + jnp.einsum(
-        "bhqk,bkhd->bhqd", p, v.astype(p.dtype),
-        preferred_element_type=jnp.float32,
+    if not causal:
+        return s, None
+    mask = (q_pos[:, None] >= k_pos[None, :])[None, None]
+    return jnp.where(mask, s, _NEG_BIG), mask
+
+
+def _partial_fwd(q, k, v, q_off, k_off, causal, scale, bq, bk):
+    """Blocked online softmax of ``[B, H, T, D]`` operands: ``(o, lse)``,
+    ``o`` float32 and normalised over the keys given here."""
+    b, h, tq, _ = q.shape
+    tk, dv = k.shape[2], v.shape[3]
+    nq, nk = tq // bq, tk // bk
+
+    def q_block(i):
+        qi = jax.lax.dynamic_slice_in_dim(q, i * bq, bq, axis=2)
+        q_pos = q_off + i * bq + jnp.arange(bq)
+
+        def body(j, carry):
+            o, m, l = carry
+            kj = jax.lax.dynamic_slice_in_dim(k, j * bk, bk, axis=2)
+            vj = jax.lax.dynamic_slice_in_dim(v, j * bk, bk, axis=2)
+            s, mask = _scores(
+                qi, kj, q_pos, k_off + j * bk + jnp.arange(bk), causal, scale
+            )
+            m_new = jnp.maximum(m, s.max(axis=-1))
+            p = jnp.exp(s - m_new[..., None])
+            if causal:
+                p = jnp.where(mask, p, 0.0)
+            corr = jnp.exp(m - m_new)
+            l = l * corr + p.sum(axis=-1)
+            o = o * corr[..., None] + jnp.einsum(
+                "bhqk,bhkd->bhqd", p.astype(v.dtype), vj,
+                preferred_element_type=jnp.float32,
+            )
+            return o, m_new, l
+
+        init = (
+            jnp.zeros((b, h, bq, dv), jnp.float32),
+            jnp.full((b, h, bq), _NEG_BIG, jnp.float32),
+            jnp.zeros((b, h, bq), jnp.float32),
+        )
+        n = needed_key_blocks(i, q_off, k_off, bq, bk, nk, causal)
+        o, m, l = jax.lax.fori_loop(0, n, body, init)
+        safe = jnp.maximum(l, 1e-30)
+        return o / safe[..., None], jnp.where(l > 0, m + jnp.log(safe), _NEG_BIG)
+
+    o, lse = jax.lax.map(q_block, jnp.arange(nq))  # [nq, B, H, bq, ...]
+    o = jnp.moveaxis(o, 0, 2).reshape(b, h, tq, dv)
+    lse = jnp.moveaxis(lse, 0, 2).reshape(b, h, tq)
+    return o, lse
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def attention_partial(q, k, v, q_off, k_off, causal, scale, bq, bk):
+    """Exact attention of ``q`` over the keys given, in ``bq x bk`` tiles.
+
+    ``q`` ``[B, H, Tq, Dk]``, ``k`` ``[B, H, Tk, Dk]``, ``v`` ``[B, H, Tk,
+    Dv]``; ``q_off``/``k_off`` are the global positions of the first query
+    and key (int32 scalars, traced on a ring). Returns ``o`` ``[B, H, Tq,
+    Dv]`` float32, normalised over these keys, and ``lse`` ``[B, H, Tq]``,
+    so partial results over disjoint key sets merge exactly
+    (:func:`merge_partials`). Matmul operands stay in the dtype given;
+    scores, softmax and accumulators are float32. Memory is linear in the
+    row's length forward and backward: the backward recomputes each score
+    tile from ``lse`` and keeps none. Key blocks above the diagonal are
+    skipped, not masked.
+    """
+    return _partial_fwd(q, k, v, q_off, k_off, causal, scale, bq, bk)
+
+
+def _attention_partial_fwd(q, k, v, q_off, k_off, causal, scale, bq, bk):
+    o, lse = _partial_fwd(q, k, v, q_off, k_off, causal, scale, bq, bk)
+    return (o, lse), (q, k, v, q_off, k_off, o, lse)
+
+
+def _attention_partial_bwd(causal, scale, bq, bk, res, cts):
+    q, k, v, q_off, k_off, o, lse = res
+    do, dlse = cts
+    b, h, tq, dk_ = q.shape
+    tk, dv_ = k.shape[2], v.shape[3]
+    nq, nk = tq // bq, tk // bk
+    # d s_ij = p_ij (dp_ij - delta_i + dlse_i), delta_i = do_i . o_i
+    g = dlse - (do * o).sum(axis=-1)
+    do = do.astype(q.dtype)
+
+    def q_block(carry, i):
+        dk, dv = carry
+        qi = jax.lax.dynamic_slice_in_dim(q, i * bq, bq, axis=2)
+        doi = jax.lax.dynamic_slice_in_dim(do, i * bq, bq, axis=2)
+        lsei = jax.lax.dynamic_slice_in_dim(lse, i * bq, bq, axis=2)
+        gi = jax.lax.dynamic_slice_in_dim(g, i * bq, bq, axis=2)
+        q_pos = q_off + i * bq + jnp.arange(bq)
+
+        def body(j, carry):
+            dqi, dk, dv = carry
+            kj = jax.lax.dynamic_slice_in_dim(k, j * bk, bk, axis=2)
+            vj = jax.lax.dynamic_slice_in_dim(v, j * bk, bk, axis=2)
+            s, mask = _scores(
+                qi, kj, q_pos, k_off + j * bk + jnp.arange(bk), causal, scale
+            )
+            p = jnp.exp(s - lsei[..., None])
+            if causal:
+                p = jnp.where(mask, p, 0.0)
+            dvj = jnp.einsum(
+                "bhqk,bhqd->bhkd", p.astype(do.dtype), doi,
+                preferred_element_type=jnp.float32,
+            )
+            dp = jnp.einsum(
+                "bhqd,bhkd->bhqk", doi, vj, preferred_element_type=jnp.float32
+            )
+            ds = (p * (dp + gi[..., None]) * scale).astype(q.dtype)
+            dqi = dqi + jnp.einsum(
+                "bhqk,bhkd->bhqd", ds, kj, preferred_element_type=jnp.float32
+            )
+            dkj = jnp.einsum(
+                "bhqk,bhqd->bhkd", ds, qi, preferred_element_type=jnp.float32
+            )
+
+            def add(acc, blk):
+                old = jax.lax.dynamic_slice_in_dim(acc, j * bk, bk, axis=2)
+                return jax.lax.dynamic_update_slice_in_dim(
+                    acc, old + blk, j * bk, axis=2
+                )
+
+            return dqi, add(dk, dkj), add(dv, dvj)
+
+        n = needed_key_blocks(i, q_off, k_off, bq, bk, nk, causal)
+        dqi, dk, dv = jax.lax.fori_loop(
+            0, n, body, (jnp.zeros((b, h, bq, dk_), jnp.float32), dk, dv)
+        )
+        return (dk, dv), dqi
+
+    (dk, dv), dq = jax.lax.scan(
+        q_block,
+        (jnp.zeros((b, h, tk, dk_), jnp.float32),
+         jnp.zeros((b, h, tk, dv_), jnp.float32)),
+        jnp.arange(nq),
     )
-    return o_new, m_new, l_new
+    dq = jnp.moveaxis(dq, 0, 2).reshape(b, h, tq, dk_)
+    return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
+            None, None)
+
+
+attention_partial.defvjp(_attention_partial_fwd, _attention_partial_bwd)
+
+
+def merge_partials(o_a, lse_a, o_b, lse_b):
+    """Two normalised partial attentions over disjoint key sets -> one."""
+    lse = jnp.logaddexp(lse_a, lse_b)
+    w_a = jnp.exp(lse_a - lse)[..., None]
+    w_b = jnp.exp(lse_b - lse)[..., None]
+    return o_a * w_a + o_b * w_b, lse
 
 
 def ring_attention(
@@ -71,50 +228,53 @@ def ring_attention(
     *,
     axis: Optional[str],
     causal: bool = True,
+    block: int = DEFAULT_BLOCK,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Exact attention over a sequence sharded on mesh axis ``axis``.
 
     Call from inside ``shard_map``; each device passes its local
-    ``[B, T_local, H, D]`` blocks. With ``axis=None`` computes plain
-    single-device attention (same code path, ring of size 1).
-    Returns the local ``[B, T_local, H, D]`` output block.
+    ``[B, T_local, H, D]`` blocks (``v`` may have another width than
+    ``q``/``k``). With ``axis=None`` it is plain single-device attention.
+    Every ring step, and the single step without a ring, is one
+    :func:`attention_partial` in ``block``-sized tiles, so no ``[T, T]``
+    score matrix exists on any path. Returns the local ``[B, T_local, H,
+    Dv]`` output block in ``q``'s dtype.
     """
     b, t_loc, h, d = q.shape
-    scale = 1.0 / (d ** 0.5)
+    scale = 1.0 / (d ** 0.5) if scale is None else float(scale)
     n = 1 if axis is None else jax.lax.axis_size(axis)
-    idx = 0 if axis is None else jax.lax.axis_index(axis)
+    idx = jnp.int32(0) if axis is None else jax.lax.axis_index(axis)
+    blk = pick_block(t_loc, block)
+    qh, kh, vh = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
+    q_off = idx * t_loc
 
-    q32 = q.astype(jnp.float32)
-    o = jnp.zeros((b, h, t_loc, d), jnp.float32)
-    m = jnp.full((b, h, t_loc), _NEG_BIG, jnp.float32)
-    l = jnp.zeros((b, h, t_loc), jnp.float32)
-    q_pos = idx * t_loc + jnp.arange(t_loc)
-
-    def update(o, m, l, k_blk, v_blk, s):
+    def partial(k_blk, v_blk, s):
         src = (idx - s) % n  # which device this K/V block started on
-        k_pos = src * t_loc + jnp.arange(t_loc)
-        return _block_attn_update(
-            o, m, l, q32, k_blk.astype(jnp.float32),
-            v_blk.astype(jnp.float32), q_pos, k_pos, causal, scale,
+        return attention_partial(
+            qh, k_blk, v_blk, q_off, src * t_loc, causal, scale, blk, blk
         )
 
     def step(carry, s):
-        o, m, l, k_blk, v_blk = carry
-        o, m, l = update(o, m, l, k_blk, v_blk, s)
+        o, lse, k_blk, v_blk = carry
+        o, lse = merge_partials(o, lse, *partial(k_blk, v_blk, s))
         perm = [(i, (i + 1) % n) for i in range(n)]
         k_blk = jax.lax.ppermute(k_blk, axis, perm)
         v_blk = jax.lax.ppermute(v_blk, axis, perm)
-        return (o, m, l, k_blk, v_blk), None
+        return (o, lse, k_blk, v_blk), None
 
     if n > 1:
         # n-1 rotating steps, then the last block's update with no final
         # ppermute (the rotated result would be discarded — wasted ICI).
-        (o, m, l, k, v), _ = jax.lax.scan(
-            step, (o, m, l, k, v), jnp.arange(n - 1)
+        o = jnp.zeros((b, h, t_loc, vh.shape[-1]), jnp.float32)
+        lse = jnp.full((b, h, t_loc), _NEG_BIG, jnp.float32)
+        (o, lse, kh, vh), _ = jax.lax.scan(
+            step, (o, lse, kh, vh), jnp.arange(n - 1)
         )
-    o, m, l = update(o, m, l, k, v, n - 1)
-    out = o / jnp.maximum(l, 1e-20)[..., None]
-    return out.transpose(0, 2, 1, 3).astype(q.dtype)
+        o, _ = merge_partials(o, lse, *partial(kh, vh, n - 1))
+    else:
+        o, _ = partial(kh, vh, 0)
+    return o.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
 def ring_attention_sharded(mesh, q, k, v, *, causal: bool = True):

@@ -186,23 +186,13 @@ class Query:
 
 
 @dataclasses.dataclass(frozen=True)
-class SeqRecParams(Params):
-    d_model: int = 64
-    n_heads: int = 4
-    n_layers: int = 2
-    ffn: int = 128
-    max_len: int = 64
-    learning_rate: float = 1e-3
+class SeqRecParams(SeqRecConfig, Params):
+    """engine.json's algorithm params: every field of
+    :class:`~pio_tpu.models.seqrec.SeqRecConfig` (the block is described
+    there, by data: ``attention_kind``, ``ffn_kind``, ``dense_layers``,
+    the expert and MTP counts) plus the mesh splits."""
+
     steps: int = 300
-    seed: int = 0
-    #: sequence-parallel attention mode: "ring" or "ulysses" (all-to-all)
-    attention: str = "ring"
-    #: rows per optimizer step; 0 = full-batch (historical path),
-    #: > 0 enables minibatch SGD and the streamed epoch feed
-    batch_size: int = 0
-    #: epoch feed: "off" stages on device, "on" streams row spans,
-    #: "auto" streams only past PIO_TPU_DEVICE_BUDGET_BYTES
-    stream: str = "auto"
     #: mesh splits; remaining devices ride the data axis
     seq_parallel: int = 1
     pipe_parallel: int = 1
@@ -273,19 +263,10 @@ class SeqRecAlgorithm(Algorithm):
             mesh,
             pd.sequences,
             n_items=len(pd.item_index),
-            config=SeqRecConfig(
-                d_model=p.d_model,
-                n_heads=p.n_heads,
-                n_layers=p.n_layers,
-                ffn=p.ffn,
-                max_len=p.max_len,
-                learning_rate=p.learning_rate,
-                steps=p.steps,
-                attention=p.attention,
-                seed=p.seed,
-                batch_size=p.batch_size,
-                stream=p.stream,
-            ),
+            config=SeqRecConfig(**{
+                f.name: getattr(p, f.name)
+                for f in dataclasses.fields(SeqRecConfig)
+            }),
             checkpoint=ctx.checkpoint,
             checkpoint_every=ctx.checkpoint_every,
         )

@@ -112,6 +112,27 @@ def test_scope_path(op_name, path):
     assert profile.scope_path(op_name, "als.") == path
 
 
+@pytest.mark.parametrize("op_name, path", [
+    # a backward pass: JAX wraps the outer scope whole and keeps the inner
+    ("jit(chunk)/while/body/closed_call/transpose(jvp(seq.mtp))/jvp(seq.mtp)/"
+     "checkpoint/seq.mla/proj/add_any", "seq.mtp/seq.mla/proj"),
+    ("jit(chunk)/while/body/closed_call/transpose(jvp())/while/body/"
+     "closed_call/checkpoint/rematted_computation/seq.ffn/dot_general",
+     "seq.ffn"),
+    ("jit(chunk)/while/body/closed_call/jvp()/while/body/closed_call/"
+     "seq.mla/attn/closed_call/broadcast_in_dim", "seq.mla/attn"),
+    ("jit(chunk)/while/body/closed_call/transpose(jvp())/while/body/"
+     "closed_call/checkpoint/rematted_computation/cond/branch_0_fun/"
+     "seq.moe/route/jit(_where)/broadcast_in_dim", "seq.moe/route"),
+    ("jit(chunk)/while/body/closed_call/seq.opt/mul", "seq.opt"),
+    ("jit(chunk)/while/body/closed_call/transpose(jvp())/add_any", None),
+    # a jit's own name is no scope, wrapped or not
+    ("jit(seq.thing)/mul", None),
+])
+def test_scope_path_through_a_backward_pass(op_name, path):
+    assert profile.scope_path(op_name, "seq.") == path
+
+
 # -- the reducer on a trace recorded on the v5e (PR 26) ------------------------
 
 def test_reduce_scopes_on_the_recorded_v5e_trace():

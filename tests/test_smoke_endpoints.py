@@ -25,7 +25,10 @@ def test_smoke_script_passes():
         ["bash", str(SCRIPT)],
         capture_output=True,
         text=True,
-        timeout=280,
+        # the script takes 190-200 s alone on the 8-core sandbox (the lint
+        # passes grow with the tree); under tier-1's six workers 280 s was
+        # passed by chance
+        timeout=900,
     )
     assert proc.returncode == 0, (
         f"smoke.sh failed (rc={proc.returncode})\n"
