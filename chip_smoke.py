@@ -7,10 +7,11 @@ HTTP: ``pio app new`` → ``import`` → ``train`` → (process exits) →
 ``deploy`` → ``POST /queries.json`` → ``undeploy``. The model is the
 recommendation template at the full width of the headline configuration
 (162,541 users × 59,047 items, ``examples/recommendation/engine.json`` as
-shipped); the events are synthetic, made from a seed. Two further phases
+shipped); the events are synthetic, made from a seed. Further phases
 run the device programs that lifecycle cannot reach: the streamed ALS
-trainer on 25M edges and the two Pallas kernels under Mosaic (the
-embedding bag, and the resident CG solve against the XLA loop).
+trainer on 25M edges, the two Pallas kernels under Mosaic (the
+embedding bag, and the resident CG solve against the XLA loop) and the
+factor-row gather from both table layouts.
 
 This parent never imports jax. Each phase is one child process that owns
 the chip and has exited before the next starts. The run fails unless every
@@ -57,12 +58,16 @@ FULL = {
     "stream_edges": 25_000_000,
     "kernel": {"V": 50_000, "D": 256, "B": 4096, "L": 64},
     "solve": {"n": 40_000, "K": 64},
+    # the benchmark cell's two factor tables and one chunk's rows
+    "gather": {"rows": [162_541, 59_047], "K": 64, "chunk": 4096, "W": 64,
+               "steps": 64},
 }
 REHEARSAL = {
     "n_users": 400, "n_items": 150, "n_events": 5_000,
     "stream_edges": 20_000,
     "kernel": {"V": 512, "D": 128, "B": 16, "L": 8},
     "solve": {"n": 200, "K": 16},
+    "gather": {"rows": [301, 150], "K": 16, "chunk": 8, "W": 4, "steps": 3},
 }
 QUERY_USERS = (0, 1, 7, 42, 137, 399)
 TOP_N = 10
@@ -350,6 +355,8 @@ class Runner:
         self.require_tpu("embedding_bag_kernel", kernel["device"]["platform"])
         solve = self.phase("als_solve_kernel")
         self.require_tpu("als_solve_kernel", solve["device"]["platform"])
+        gather = self.phase("als_gather")
+        self.require_tpu("als_gather", gather["device"]["platform"])
 
         summary = {"ok": True}
         if self.rehearse:
@@ -374,6 +381,7 @@ class Runner:
             "als_stream": stream,
             "embedding_bag_kernel": kernel,
             "als_solve_kernel": solve,
+            "als_gather": gather,
             "claim": None,
         })
         return summary
@@ -665,6 +673,70 @@ def phase_als_solve_kernel(size: dict, work: str, rehearse: bool) -> dict:
             "solve_s": seconds}
 
 
+def phase_als_gather(size: dict, work: str, rehearse: bool) -> dict:
+    """``partial_normal_eq``'s factor-row gather alone, inside a scan,
+    from both table layouts: the table as it is (``plain``) and the
+    lane-dense one (``packed``) that the selection rule picks on the chip
+    for the larger of the cell's two tables. Seconds and ns a row for
+    each table in each layout; the rows must agree to the bit. A small
+    program is no witness of where the compiler keeps a table inside the
+    trainer (the tier-1 compile test is). Alone:
+    ``python chip_smoke.py --phase als_gather``."""
+    from pio_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from pio_tpu.models import als
+
+    g = size["gather"]
+    K, chunk, W, steps = g["K"], g["chunk"], g["W"], g["steps"]
+    picked = {str(n): als._gather_impl(jax.default_backend(), n, K, 2)
+              for n in g["rows"]}
+    want = "plain" if rehearse else "packed"  # of the larger table alone
+    assert list(picked.values()) == [want, "plain"], picked
+    layouts = {"plain": lambda t: t, "packed": als._pack_table}
+
+    @jax.jit
+    def gathered(table, other):
+        """Elementwise maximum over every chunk's gathered rows: exact
+        whatever the order, and it reads all of them."""
+        def step(acc, oth):
+            q = als._gather_rows(table, jnp.maximum(oth, 0), K)
+            return jnp.maximum(acc, q.max(axis=0)), None
+
+        acc0 = jnp.full((W, K), -jnp.inf, table.dtype)
+        return jax.lax.scan(step, acc0, other)[0]
+
+    seconds, ns_a_row = {}, {}
+    for n in g["rows"]:
+        kt, ko = jax.random.split(jax.random.fold_in(
+            jax.random.PRNGKey(SEED), n))
+        table = jax.random.normal(kt, (n, K), jnp.float32).astype(
+            jnp.bfloat16)
+        # -1 is a padded slot, as in a packed block
+        other = jax.random.randint(ko, (steps, chunk, W), -1, n, jnp.int32)
+        out = {}
+        for form, layout in layouts.items():
+            laid = layout(table)
+            jax.block_until_ready(gathered(laid, other))  # compile
+            t = time.monotonic()
+            out[form] = jax.block_until_ready(gathered(laid, other))
+            s = time.monotonic() - t
+            seconds[f"{form}_{n}"] = round(s, 4)
+            ns_a_row[f"{form}_{n}"] = round(
+                1e9 * s / (steps * chunk * W), 3)
+        assert np.array_equal(
+            np.asarray(out["plain"].astype(jnp.float32)),
+            np.asarray(out["packed"].astype(jnp.float32))), n
+    return {"device": device_summary(), "picked": picked,
+            "shape": {"rows": g["rows"], "K": K,
+                      "rows_a_step": chunk * W, "steps": steps},
+            "gather_s": seconds, "ns_a_row": ns_a_row}
+
+
 PHASES = {
     "env": phase_env,
     "generate": phase_generate,
@@ -672,6 +744,7 @@ PHASES = {
     "als_stream": phase_als_stream,
     "embedding_bag_kernel": phase_embedding_bag_kernel,
     "als_solve_kernel": phase_als_solve_kernel,
+    "als_gather": phase_als_gather,
 }
 
 

@@ -243,6 +243,79 @@ def _solve_impl(solver: str, n_entities: int, rank: int,
     return "resident_cg" if platform == "tpu" and tiles else "xla_cg"
 
 
+#: lanes of a TPU vector row: every tiling pads an array's minor dimension
+#: to a multiple of it, in VMEM as in a gather's result
+_LANES = 128
+#: the largest factor table, in bytes as tiled, that the chip's compiler
+#: keeps in VMEM for the whole scan of a half-step beside the chunk's
+#: gathered rows. Fitted on ``finalize`` of the streamed trainer compiled
+#: for a v5e (128 MiB of VMEM) at MovieLens-25M's shapes, rank 64, bf16,
+#: with the user count varied: the plain table is the gather's operand in
+#: memory space 1 up to 110,000 rows (28.2 MB padded) and in HBM from
+#: 120,000 (30.7 MB); packed, 162,541 rows (20.8 MB) are in memory space
+#: 1 (tests/test_tpu_compile.py holds that end). A smaller program takes
+#: more (``partial_normal_eq`` alone: 41.6 MB), so this is the trainer's.
+_GATHER_VMEM_BYTES = 24 << 20
+
+
+def _gather_impl(platform: str, n_rows: int, rank: int,
+                 itemsize: int) -> str:
+    """Which layout ``partial_normal_eq`` gathers its factor rows from,
+    from what is visible at trace time: ``packed`` / ``plain``. On a TPU
+    a table whose rows are narrower than the ``_LANES`` lanes is padded
+    to them wherever it is tiled, so the compiler's memory-space
+    assignment refuses VMEM to a table it would take unpadded, and every
+    row gathered from HBM is then a short read of its own (9.7 ns a row
+    on a v5e against 1.7-1.9 from VMEM). ``packed`` lays ``_LANES // rank``
+    entity rows side by side in one lane row (:func:`_pack_table`); the
+    select behind its gather costs 0.2 ms a chunk of 262,144 rows, so it
+    is picked only where it buys the VMEM: on a TPU, at a rank that divides
+    the lanes, for a table that is over ``_GATHER_VMEM_BYTES`` padded and
+    within them packed. ``plain`` is the table as it is: everywhere else
+    (CPU, rank 10, rank 128 and up, a table that fits as it is or not at
+    all), and the tests' oracle. Both give the same rows to the bit."""
+    if platform != "tpu" or rank >= _LANES or _LANES % rank:
+        return "plain"
+    padded = n_rows * _LANES * itemsize
+    packed = -(-n_rows // (_LANES // rank)) * _LANES * itemsize
+    fits_only_packed = packed <= _GATHER_VMEM_BYTES < padded
+    return "packed" if fits_only_packed else "plain"
+
+
+def _pack_table(table):
+    """``[n, K]`` → ``[ceil(n / r), r * K]`` with ``r = _LANES // K``:
+    entity ``i`` is lanes ``(i % r) * K`` onward of row ``i // r``."""
+    import jax.numpy as jnp
+
+    n, K = table.shape
+    r = _LANES // K
+    rows = -(-n // r)
+    return jnp.pad(table, ((0, rows * r - n), (0, 0))).reshape(rows, r * K)
+
+
+def _gather_rows(table, idx, K: int):
+    """Rows ``idx`` of the ``[n, K]`` factor table that ``table`` is
+    (plain) or packs (:func:`_pack_table`): gather the lane row, then
+    select the entity's ``K``-wide group. Gathered and selected as one
+    flat list of rows, ``[idx.size, ...]``: on that shape XLA fuses the
+    select with the lane slices behind the gather and relayouts the
+    result once for the matmuls, as it does the plain gather's; selected
+    on ``idx``'s own shape, each lane slice was written to HBM and
+    relayouted on its own (+0.5 ms a chunk of 262,144 rows on a v5e)."""
+    import jax.numpy as jnp
+
+    r = table.shape[1] // K
+    if r == 1:
+        return table[idx]
+    flat = idx.reshape(-1)
+    wide = table[flat // r]
+    group = (flat % r)[:, None]
+    q = wide[:, :K]
+    for g in range(1, r):
+        q = jnp.where(group == g, wide[:, g * K:(g + 1) * K], q)
+    return q.reshape(*idx.shape, K)
+
+
 def _cg_solve_resident(A, b, reg, interpret: bool = False):
     """``_cg_solve`` of ``A + reg`` (``reg`` the K×K regulariser every
     entity shares: λI, plus the gram when implicit) as one Pallas TPU
@@ -373,6 +446,11 @@ def _make_math(reg: float, implicit: bool, alpha: float,
         # precision table (half the HBM traffic) and the einsums hit the
         # MXU at its native bf16 rate; accumulation stays f32 below
         factors_mm = factors.astype(mm_dtype)
+        # the layout is static at trace time too (the rule: _gather_impl)
+        if _gather_impl(jax.default_backend(), factors.shape[0], K,
+                        mm_dtype.itemsize) == "packed":
+            with jax.named_scope("gather"):
+                factors_mm = _pack_table(factors_mm)
 
         def chunk_step(carry, ch):
             A, b = carry
@@ -380,7 +458,8 @@ def _make_math(reg: float, implicit: bool, alpha: float,
             # padded slots are other == -1; validity derives from the sign
             m_c = (other >= 0).astype(jnp.float32)
             with jax.named_scope("gather"):
-                q = factors_mm[jnp.maximum(other, 0)]  # [chunk, W, K] gather
+                # [chunk, W, K] gather
+                q = _gather_rows(factors_mm, jnp.maximum(other, 0), K)
             if implicit:
                 # confidence c = 1 + α r; correction weight (c-1)·mask
                 w = alpha_f * r_c * m_c
@@ -1414,7 +1493,8 @@ def train_als(
     ``stats``, when a dict, is filled with a per-phase breakdown —
     ``{pack_s, wire_bytes, encoding, n_stream, h2d_s, device_s}`` and
     ``solve_impl`` (``{"user", "item"}``: which solver each side's batch
-    gets, :func:`_solve_impl`) — by
+    gets, :func:`_solve_impl`) and ``gather_impl`` (which table layout each
+    half-step gathers the other side's rows from, :func:`_gather_impl`) — by
     BLOCKING between the host-pack / host→device / device-compute phases.
     That serialization disables the streamed path's transfer/compute
     overlap, so pass ``stats`` only on profiling runs, not timed ones.
@@ -1479,8 +1559,18 @@ def train_als(
         for side, n_pad in (("user", U_pad), ("item", I_pad))
     }
     trainwatch.set_solve_impl(solve_impl)
+    # ...and the layout partial_normal_eq will gather the OTHER side's
+    # table from (whole on every device of a mesh)
+    mm_itemsize = jnp.dtype(
+        _resolve_matmul_dtype(str(config.matmul_dtype))).itemsize
+    gather_impl = {
+        side: _gather_impl(jax.default_backend(), n_table, K, mm_itemsize)
+        for side, n_table in (("user", I_pad), ("item", U_pad))
+    }
+    trainwatch.set_gather_impl(gather_impl)
     if stats is not None:
         stats["solve_impl"] = solve_impl
+        stats["gather_impl"] = gather_impl
 
     def _counts_layout(ent, width, n_entities):
         """counts + (chunk, padded block count S) for one side."""

@@ -102,6 +102,7 @@ class StepRecorder:
         self.streamed = False
         self.n_stream = 0
         self.solve_impl: Optional[Dict[str, str]] = None
+        self.gather_impl: Optional[Dict[str, str]] = None
         self.params_per_device_bytes = 0
         self.h2d_bytes = 0
         self.overlap_ratio: Optional[float] = None
@@ -139,6 +140,7 @@ class StepRecorder:
             self.streamed = bool(streamed)
             self.n_stream = int(n_stream)
             self.solve_impl = None
+            self.gather_impl = None
             self.params_per_device_bytes = int(per_device_bytes)
             self.last_loss = None
             self.losses.clear()
@@ -185,6 +187,13 @@ class StepRecorder:
         decides from entity count, rank and platform: ``_solve_impl``)."""
         with self._lock:
             self.solve_impl = dict(impl)
+
+    def set_gather_impl(self, impl: Dict[str, str]) -> None:
+        """Which table layout each half-step gathers its factor rows
+        from (ALS decides from platform, rank and the table's bytes:
+        ``_gather_impl``)."""
+        with self._lock:
+            self.gather_impl = dict(impl)
 
     def set_overlap(self, ratio: float) -> None:
         with self._lock:
@@ -280,6 +289,7 @@ class StepRecorder:
                 "streamed": self.streamed,
                 "stream_chunks": self.n_stream,
                 "solve_impl": self.solve_impl,
+                "gather_impl": self.gather_impl,
             }
 
 
@@ -360,6 +370,12 @@ def set_solve_impl(impl: Dict[str, str]) -> None:
         rec.set_solve_impl(impl)
 
 
+def set_gather_impl(impl: Dict[str, str]) -> None:
+    rec = _ACTIVE
+    if rec is not None:
+        rec.set_gather_impl(impl)
+
+
 # ---------------------------------------------------------------------------
 # direction-aware deltas — the regression core shared with bench's
 # history ledger (bench.py history_delta_table delegates here)
@@ -438,7 +454,8 @@ def run_record(*, run_id: str, engine_id: str, status: str,
     --profile-dir`` on a TPU): lifted as ``scope_<path>_s``,
     ``device_busy_s`` and ``device_idle_pct``. An ALS run's
     ``solve_impl`` (``{"user", "item"}``: ``resident_cg`` / ``xla_cg`` /
-    ``cholesky`` / ``lu``) is lifted beside them."""
+    ``cholesky`` / ``lu``) and ``gather_impl`` (``{"user", "item"}``:
+    ``packed`` / ``plain``) are lifted beside them."""
     if timestamp is None:
         import datetime as _dt
 
@@ -462,7 +479,8 @@ def run_record(*, run_id: str, engine_id: str, status: str,
     if step_summary:
         rec["step_summary"] = dict(step_summary)
         for key in ("examples_per_sec", "final_loss", "loss_window_mean",
-                    "overlap_ratio", "steps", "examples", "solve_impl"):
+                    "overlap_ratio", "steps", "examples", "solve_impl",
+                    "gather_impl"):
             if step_summary.get(key) is not None:
                 rec[key] = step_summary[key]
     if device_scopes:

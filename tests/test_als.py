@@ -811,6 +811,142 @@ class TestResidentCG:
         assert np.abs(got - want).max() <= 1e-3 * np.abs(want).max()
 
 
+class TestPackedGather:
+    """The lane-dense factor table (``_pack_table`` / ``_gather_rows``)
+    against the plain gather it replaces on a TPU (the oracle), and the
+    rule that chooses between them (``_gather_impl``)."""
+
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+    @pytest.mark.parametrize("K", [8, 16, 64])
+    def test_packed_rows_equal_plain_to_the_bit(self, K, dtype):
+        """1,001 rows are no multiple of 16, 8 or 2 entities a lane row;
+        a chunk's padded slots (``other == -1``) read row 0 in both."""
+        import jax
+        import jax.numpy as jnp
+
+        from pio_tpu.models.als import _LANES, _gather_rows, _pack_table
+
+        rng = np.random.default_rng(K)
+        n = 1001
+        table = jnp.asarray(rng.standard_normal((n, K)), jnp.dtype(dtype))
+        other = rng.integers(0, n, (32, 16)).astype(np.int32)
+        other[rng.random(other.shape) < 0.2] = -1
+        other[0, :3] = (n - 1, n - 2, 0)  # the padded last lane row
+        idx = jnp.maximum(other, 0)
+        packed = _pack_table(table)
+        assert packed.shape == (-(-n // (_LANES // K)), _LANES)
+        want = jax.jit(lambda t, i: _gather_rows(t, i, K))(table, idx)
+        got = jax.jit(lambda t, i: _gather_rows(t, i, K))(packed, idx)
+        assert got.shape == want.shape == (32, 16, K)
+        assert got.dtype == want.dtype
+        as_bits = lambda a: np.asarray(a.astype(jnp.float32)).view(np.uint32)
+        assert np.array_equal(as_bits(got), as_bits(table[idx]))
+        assert np.array_equal(as_bits(got), as_bits(want))
+
+    @pytest.mark.parametrize("platform,n_rows,rank,itemsize,want", [
+        # the benchmark cell: the user table's 41.6 MB as tiled do not
+        # fit beside a chunk's rows, its 20.8 MB packed do; the item
+        # table's 15.1 MB fit as they are, and packing costs a select
+        ("tpu", 162_541, 64, 2, "packed"),
+        ("tpu", 59_047, 64, 2, "plain"),
+        ("cpu", 162_541, 64, 2, "plain"),
+        ("gpu", 162_541, 64, 2, "plain"),
+        ("tpu", 162_541, 10, 2, "plain"),   # 128 % 10 != 0: implicit
+        ("tpu", 162_541, 48, 2, "plain"),
+        ("tpu", 162_541, 128, 2, "plain"),  # a row fills the lanes
+        ("tpu", 162_541, 256, 2, "plain"),
+        # at the threshold: 24 MiB as tiled is 98,304 bf16 rows
+        ("tpu", 98_304, 64, 2, "plain"),
+        ("tpu", 98_305, 64, 2, "packed"),
+        ("tpu", 196_608, 64, 2, "packed"),
+        ("tpu", 196_609, 64, 2, "plain"),   # packed does not fit either
+        ("tpu", 59_047, 64, 4, "packed"),   # float32 rows are twice as wide
+        ("tpu", 40_000, 16, 2, "plain"),    # the quickstart
+        ("tpu", 162_541, 16, 2, "packed"),  # eight entities a lane row
+        ("tpu", 800_000, 16, 2, "plain"),
+    ])
+    def test_selection_rule(self, platform, n_rows, rank, itemsize, want):
+        from pio_tpu.models.als import _gather_impl
+
+        assert _gather_impl(platform, n_rows, rank, itemsize) == want
+
+    def test_stats_and_run_record_name_the_layout(self, synthetic,
+                                                  fresh_trainers,
+                                                  monkeypatch):
+        """``plain`` on CPU by the rule, ``packed`` once steered; ``stats``
+        and the run record carry it per half-step."""
+        from pio_tpu.models import als
+        from pio_tpu.obs import trainwatch
+
+        s = synthetic
+
+        def train():
+            st = {}
+            recorder = trainwatch.StepRecorder("gather-impl")
+            with trainwatch.recording(recorder):
+                train_als(
+                    ComputeContext.local(), s["u"], s["i"], s["r"], s["U"],
+                    s["I"], ALSConfig(rank=8, iterations=2,
+                                      blocks_per_chunk=64), stats=st)
+            record = trainwatch.run_record(
+                run_id="r", engine_id="e", status="COMPLETED",
+                train_seconds=1.0, phases={}, params_hash="h",
+                step_summary=recorder.summary())
+            assert record["gather_impl"] == st["gather_impl"]
+            return st["gather_impl"]
+
+        assert train() == {"user": "plain", "item": "plain"}
+        _steer_packed(monkeypatch, als)
+        fresh_trainers()
+        assert train() == {"user": "packed", "item": "packed"}
+
+    @pytest.mark.parametrize("implicit", [False, True],
+                             ids=["explicit", "implicit"])
+    @pytest.mark.parametrize("path", ["monolithic", "streamed", "mesh"])
+    def test_train_als_on_the_packed_table(self, synthetic, path, implicit,
+                                           fresh_trainers, monkeypatch):
+        """End to end through each trainer that calls
+        ``partial_normal_eq``: the gather is exact, so the packed form
+        trains the plain form's factor tables to the bit."""
+        from pio_tpu.models import als
+
+        s = synthetic
+        r = np.abs(s["r"]) if implicit else s["r"]
+        cfg = ALSConfig(rank=8, iterations=4, reg=0.05, implicit=implicit,
+                        alpha=2.0, blocks_per_chunk=64)
+        if path == "streamed":
+            monkeypatch.setenv("PIO_TPU_ALS_STREAM_MB", "0.0005")
+        ctx = (ComputeContext.create() if path == "mesh"
+               else ComputeContext.local())
+
+        def train(want):
+            st = {}
+            f = train_als(ctx, s["u"], s["i"], r, s["U"], s["I"], cfg,
+                          stats=st)
+            assert st["gather_impl"] == {"user": want, "item": want}
+            assert (st["n_stream"] > 1) == (path == "streamed")
+            return f
+
+        plain = train("plain")  # the real rule: on CPU, the table as it is
+        _steer_packed(monkeypatch, als)
+        fresh_trainers()
+        packed = train("packed")
+        assert np.isfinite(plain.user_factors).all()
+        assert np.array_equal(packed.user_factors, plain.user_factors)
+        assert np.array_equal(packed.item_factors, plain.item_factors)
+
+
+def _steer_packed(monkeypatch, als):
+    """The rule as a TPU would read it of a table over the threshold
+    (the caller empties the trainers' caches: the rule is read when a
+    trainer is traced)."""
+    rule = als._gather_impl
+    monkeypatch.setattr(
+        als, "_gather_impl",
+        lambda platform, n_rows, rank, itemsize: rule(
+            "tpu", 162_541, rank, 2))
+
+
 class TestTopN:
     def test_basic(self):
         scores = np.array([0.1, 5.0, 3.0, 4.0])

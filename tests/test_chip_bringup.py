@@ -111,9 +111,13 @@ def test_chip_smoke_rehearsal_passes_and_labels_itself():
     assert summary["deploy_default"]["scorer"]["linkRttS"] is not None
     assert summary["als_stream"]["streamed"] is True
     assert summary["embedding_bag_kernel"]["interpret"] is True
+    # both table layouts gathered and held equal; on CPU the rule
+    # packs neither
+    assert set(summary["als_gather"]["picked"].values()) == {"plain"}
+    assert len(summary["als_gather"]["ns_a_row"]) == 4
     for phase in ("env", "import", "train", "deploy_device",
                   "deploy_default", "reference", "als_stream",
-                  "embedding_bag_kernel"):
+                  "embedding_bag_kernel", "als_solve_kernel", "als_gather"):
         assert summary["phase_s"][phase] > 0
 
 

@@ -47,3 +47,69 @@ def test_resident_cg_compiles_for_v5e(one_chip, n, K):
     # A, its transposed copy and the vectors: no third copy of A
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes <= 1.05 * n * K * K * 4
+
+
+def test_stream_finalize_gathers_from_vmem_on_v5e(one_chip, monkeypatch):
+    """``finalize`` of the streamed ALS trainer at the benchmark cell's
+    shapes (MovieLens-25M, rank 64: seven stream chunks of 17 x 4,096
+    user blocks and 3,571,442 edges, 107 x 4,096 item blocks): in both
+    half-steps the gather reads its factor table from VMEM (memory space
+    1). The plain ``bf16[162541,64]`` table, padded to 128 lanes, stays
+    in HBM there, so ``_gather_impl`` packs it; this guards both tables
+    against a later change to the scans' carries that would push one
+    back unseen."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from pio_tpu.models import als
+
+    U, I, K, W, chunk = 162_541, 59_047, 64, 64, 4096
+    S_c, S_item, E_c, n_stream = 17 * chunk, 107 * chunk, 3_571_442, 7
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f32, i32, u8 = jnp.float32, jnp.int32, jnp.uint8
+    span = U // n_stream
+    spec = tuple((S_c, min(U - 1, (c + 1) * span), c * span)
+                 for c in range(n_stream))
+    # the rules read the backend when the trainer is traced
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    als._build_stream_trainer.cache_clear()
+    try:
+        _, _, finalize = als._build_stream_trainer(
+            10, 0.1, False, 1.0, "bfloat16", "auto", K, U, I, W, W, S_item,
+            chunk, chunk, "u4", "delta12", spec)
+        blocks = tuple((sd((S_c,), i32), sd((S_c, W), i32),
+                        sd((S_c, W), f32)) for _ in spec)
+        half = (E_c + 1) // 2
+        wire = tuple((sd((E_c,), u8), sd((half,), u8), sd((1000,), i32),
+                      sd((1000,), u8), sd((half,), u8)) for _ in spec)
+        counts = tuple(sd((pad - u0 + 1,), i32) for _, pad, u0 in spec)
+        compiled = finalize.lower(
+            sd((U, K, K), f32), sd((U, K), f32), sd((I, K), f32),
+            sd((U,), i32), sd((I,), i32), blocks, wire, counts).compile()
+    finally:
+        als._build_stream_trainer.cache_clear()
+
+    text = compiled.as_text()
+    tables = {}  # half-step → the table operand of each gather fusion
+    for line in text.splitlines():
+        m = re.match(r"\s*%\S+ = \S+ fusion\((%[\w.\-]+),", line)
+        if not (m and "kind=kCustom" in line
+                and "als.normal_eq" in line and "gather/gather" in line):
+            continue
+        side = re.search(r"/als\.(user|item)/", line).group(1)
+        shape = re.search(
+            r"^\s*" + re.escape(m.group(1)) + r" = (\S+) ", text, re.M)
+        tables.setdefault(side, []).append(shape.group(1))
+    assert set(tables) == {"user", "item"}, tables
+    assert all(t.startswith("bf16[81271,128]") for t in tables["item"])
+    # the item table fits as it is, so the rule leaves it plain
+    assert all(t.startswith("bf16[59047,64]") for t in tables["user"])
+    assert all("S(1)" in t for ts in tables.values() for t in ts), tables
+    # the plain form's temporaries at these shapes: 11,835,239,424 B
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= 1.01 * 11_835_239_424
