@@ -116,7 +116,7 @@ class Runner:
             # one device, like one chip (tier-1 exports an 8-device mesh)
             env.pop("XLA_FLAGS", None)
             # the tiny edge set must still take the streamed trainer
-            env["PIO_TPU_ALS_STREAM_MB"] = "0.02"
+            env["PIO_TPU_ALS_STREAM_MB"] = "0.08"
         self.env = env
 
     # -- plumbing ----------------------------------------------------------
@@ -544,7 +544,7 @@ def phase_reference(size: dict, work: str, rehearse: bool) -> dict:
 def phase_als_stream(size: dict, work: str, rehearse: bool) -> dict:
     """``train_als`` under ``ComputeContext.create()`` on the headline
     edge count: one chip takes the streamed trainer (donated accumulators,
-    overlapped device_puts), a multi-chip host the sharded compact wire."""
+    overlapped device_puts), a multi-chip host the sharded edge spans."""
     from pio_tpu.utils.compile_cache import place_compile_cache
 
     place_compile_cache()

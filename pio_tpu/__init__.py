@@ -22,7 +22,7 @@ A from-scratch rebuild of the capabilities of Apache PredictionIO
                            similar-product, e-commerce, text classification,
                            two-tower, sequence) [ref: examples/scala-parallel-*]
 - ``pio_tpu.native``     — C++ runtime components (event-log storage engine,
-                           ALS data packer), built with g++ on first use
+                           ALS edge sort), built with g++ on first use
 - ``pio_tpu.tools``      — the ``pio`` CLI equivalent
 
 Where the reference dispatches work to Spark executors, this package runs

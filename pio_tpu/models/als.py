@@ -7,10 +7,12 @@ UNVERIFIED path; see SURVEY.md). MLlib's ALS block-partitions the rating
 matrix into in/out-link blocks and shuffles factor updates between executors
 every half-iteration. This module is the TPU-first re-design:
 
-- Host-side, the COO rating list is packed ONCE per orientation (by-user and
-  by-item) into **fixed-width dense blocks**: edges sorted by entity, each
-  entity's adjacency split into ``[block_width]`` slices, padded slots
-  carrying weight 0. Static shapes, no ragged gathers.
+- The host sorts the COO rating list by (user, item) and ships it as it
+  is (``int32`` item ids, ``float32`` ratings, the two degree histograms);
+  the device packs it ONCE per orientation (by-user and by-item) into
+  **fixed-width dense blocks**: each entity's adjacency split into
+  ``[block_width]`` slices, padded slots carrying weight 0. Static shapes,
+  no ragged gathers.
 - One half-iteration (e.g. the user update) is::
 
       A_u = Σ_{i ∈ R(u)} q_i q_iᵀ + λI        b_u = Σ_i r_ui q_i
@@ -41,7 +43,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import itertools
 import logging
 from pio_tpu.utils import knobs
 from pio_tpu.obs import active_span, devicewatch, monotonic_s, trainwatch
@@ -62,6 +63,9 @@ log = logging.getLogger("pio_tpu.als")
 #: the two half-steps' scopes: the outermost segment of a device scope
 #: path, dropped where a ``stats`` map sums the sides
 _SIDE_SCOPES = ("als.user", "als.item")
+#: what one edge costs on the host→device link: its ``int32`` item id and
+#: its ``float32`` rating (the user column is rebuilt from the counts)
+_EDGE_BYTES = 8
 
 
 @dataclasses.dataclass(frozen=True)
@@ -105,8 +109,8 @@ class ALSFactors:
 
 
 def _native_packer():
-    """The C++ packer (pio_tpu/native/als_pack.cpp), or None when no
-    toolchain is available (tests cover both paths)."""
+    """The C++ edge sorter (pio_tpu/native/als_pack.cpp), or None when
+    no toolchain is available (tests cover both paths)."""
     if knobs.knob_str("PIO_TPU_NO_NATIVE"):
         return None
     from pio_tpu.native import NativeUnavailable, als_pack_lib
@@ -146,9 +150,9 @@ def _f32p(a: np.ndarray):
 
 
 def _auto_width(n_edges: int, n_entities: int) -> int:
-    # Narrow blocks: padding waste (≈ width/2 per entity) costs real
-    # host→device bytes, traded against the extra scatter rows (optimum
-    # 16-64 at MovieLens scales on the link it was tuned on; ROADMAP C1).
+    # Narrow blocks: padding waste (≈ width/2 per entity) is gathered and
+    # multiplied like real edges, traded against the extra segment-sum
+    # rows (16-64 at MovieLens scales; not re-fitted on the chip).
     mean_deg = max(1.0, n_edges / max(1, n_entities))
     w = 1 << int(np.ceil(np.log2(max(8.0, mean_deg / 4))))
     return int(min(64, max(16, w)))
@@ -421,13 +425,12 @@ def _cg_solve_resident(A, b, reg, interpret: bool = False):
 
 
 def _make_math(reg: float, implicit: bool, alpha: float,
-               matmul_dtype: str, solver: str, rating_wire: str = "f32",
-               item_wire: str = "planes"):
-    """Shared jittable ALS math: blocked normal-equation accumulation, the
-    batched solvers, and the wire decode. Closed over the static config and
-    used by BOTH the monolithic trainer (:func:`_build_trainer`) and the
-    streamed trainer (:func:`_build_stream_trainer`) so the two paths
-    cannot drift apart numerically."""
+               matmul_dtype: str, solver: str):
+    """Shared jittable ALS math: blocked normal-equation accumulation and
+    the batched solvers. Closed over the static config and used by BOTH
+    the monolithic trainer (:func:`_build_trainer`) and the streamed
+    trainer (:func:`_build_stream_trainer`) so the two paths cannot drift
+    apart numerically."""
     import types
 
     import jax
@@ -585,61 +588,11 @@ def _make_math(reg: float, implicit: bool, alpha: float,
         A, b = partial_normal_eq(*blocks, factors, n_entities, chunk)
         return solve_block(A, b, gram_of(factors))
 
-    def decode_items(i_lo, i_hi, ovf_idx=None, ovf_val=None, counts=None):
-        """Wire → int32 item ids.
-
-        ``planes``: uint16 low plane + optional uint8 high plane.
-        ``delta12``: 12-bit gaps over the (user, item)-sorted adjacency —
-        ``i_lo`` u8 low byte, ``i_hi`` nibble-packed high 4 bits (2
-        edges/byte), plus a sparse overflow list (``delta >> 12`` in
-        ``ovf_val``). Ids reconstruct as a segmented cumsum: global
-        uint32 cumsum of deltas minus each user's prefix (gathered at
-        segment starts from ``counts``) — wraparound-exact because every
-        true id < 2^16.
-        """
-        if item_wire == "delta12":
-            E = i_lo.shape[0]
-            lo = i_lo.astype(jnp.uint32)
-            hi = jnp.stack(
-                [i_hi & 0xF, i_hi >> 4], axis=1
-            ).reshape(-1)[:E].astype(jnp.uint32)
-            delta = lo | (hi << 8)
-            delta = delta.at[ovf_idx].add(
-                ovf_val.astype(jnp.uint32) << 12
-            )
-            G = jnp.cumsum(delta, dtype=jnp.uint32)
-            cnt = counts.astype(jnp.int32)
-            es = jnp.concatenate(
-                [jnp.zeros(1, jnp.int32), jnp.cumsum(cnt)]
-            )[:-1]
-            g_prev = jnp.where(es > 0, G[jnp.maximum(es - 1, 0)], 0)
-            offs = jnp.repeat(g_prev, cnt, total_repeat_length=E)
-            return (G - offs).astype(jnp.int32)
-        i32 = i_lo.astype(jnp.int32)
-        if i_hi.shape[0]:
-            i32 = i32 | (i_hi.astype(jnp.int32) << 16)
-        return i32
-
-    def decode_ratings(r, n_edges):
-        """Wire → float32 ratings per the static ``rating_wire`` kind:
-        ``u4`` nibble-packed half-star codes (2 edges/byte), ``u8``
-        half-star codes, ``f16``/``f32`` raw floats."""
-        if rating_wire == "u4":
-            lo = (r & 0xF).astype(jnp.float32)
-            hi = (r >> 4).astype(jnp.float32)
-            pairs = jnp.stack([lo, hi], axis=1).reshape(-1)
-            return pairs[:n_edges] * jnp.float32(0.5)
-        if rating_wire == "u8":
-            return r.astype(jnp.float32) * jnp.float32(0.5)
-        return r.astype(jnp.float32)
-
     return types.SimpleNamespace(
         partial_normal_eq=partial_normal_eq,
         solve_block=solve_block,
         gram_of=gram_of,
         half_local=half_local,
-        decode_items=decode_items,
-        decode_ratings=decode_ratings,
     )
 
 
@@ -650,18 +603,25 @@ def _build_trainer(mesh, axis: str, iterations: int, reg: float,
                    matmul_dtype: str = "bfloat16", solver: str = "cg",
                    packed_shapes=None, rank: int = 0,
                    U_pad: int = 0, I_pad: int = 0,
-                   rating_wire: str = "f32", item_wire: str = "planes",
-                   mesh_wire_lens=None):
+                   mesh_span_lens=None):
     """Jitted ALS trainer for one (mesh, static-config) combination.
 
-    The returned function takes the two packed-block layouts + initial
-    factors; shapes specialize inside jax.jit's own cache.
+    With ``packed_shapes`` (``(S_user, w_user, S_item, w_item)``) the
+    returned function is ``run_packed(counts_u, counts_i, i, r, seed)``:
+    the (user, item)-sorted edges as plain ``int32`` item ids and
+    ``float32`` ratings with the two degree histograms, from which it
+    builds both blocked layouts on the device (:func:`device_pack`) before
+    it iterates. On a mesh ``i`` and ``r`` are tuples of spans sharded
+    over ``axis``, ``mesh_span_lens`` their true lengths. Without
+    ``packed_shapes`` it takes the two blocked layouts themselves
+    (:func:`_train_mesh_host_packed`, the tests' oracle). Nothing here
+    depends on the values of the data: shapes specialize inside
+    jax.jit's own cache.
     """
     import jax
     import jax.numpy as jnp
 
-    math = _make_math(reg, implicit, alpha, matmul_dtype, solver,
-                      rating_wire, item_wire)
+    math = _make_math(reg, implicit, alpha, matmul_dtype, solver)
     partial_normal_eq = math.partial_normal_eq
     solve_block = math.solve_block
     gram_of = math.gram_of
@@ -732,64 +692,44 @@ def _build_trainer(mesh, axis: str, iterations: int, reg: float,
     if packed_shapes is None:
         return jax.jit(run_body)
 
-    # COO variant (single-device): ship the edge list ONCE, pre-sorted by
-    # (user, item) on the host (native two-pass sort), and build BOTH
-    # blocked layouts on device inside the same jit dispatch. Sorting
-    # host-side means the per-edge USER ids never cross the wire at all —
-    # one per-user counts array replaces them and the device rebuilds the
-    # id column with a single repeat. Items ship as 12-bit adjacency gaps
-    # (delta12) or uint16 planes, ratings as 4-bit half-star codes —
-    # ~2 B/edge total vs 12 B raw COO (measured 175 MB → ~50 MB at
-    # MovieLens-25M); where the host↔device link is the training
-    # bottleneck, wire bytes are throughput.
+    # the edge list ships ONCE, sorted by (user, item) on the host, and
+    # BOTH blocked layouts are built here, inside the same dispatch. The
+    # per-edge user ids never cross the link: the sorted order makes them
+    # one repeat of the per-user counts.
     su, wu, si, wi = packed_shapes
 
     @jax.jit
-    def run_packed(counts_u, counts_i, i_lo, i_hi, ovf_idx, ovf_val, r,
-                   seed):
-        # wire decode (all static dispatch on the wire kinds):
-        #   items: uint16 plane (+uint8 high plane < 2^24), or 12-bit
-        #   deltas over the item-sorted adjacency + sparse overflow
-        #   ratings: u4 nibble-packed half-star codes (2 edges/byte) when
-        #   every code ≤ 15, u8 codes, else fp16/f32 raw
-        if mesh is not None and mesh_wire_lens is not None:
-            # mesh compact wire: edge arrays arrived SHARDED over the
-            # mesh axis (host link crossed once) as one or more CHUNKS
-            # per array (PIO_TPU_ALS_STREAM_MB — chunked puts pipeline
-            # the per-device transfers); re-replicate each chunk over
-            # ICI here, drop its shard-divisibility padding, and splice
-            # the stream back together — the decode's cumsum needs the
-            # whole stream on every device. Chunking never re-encodes:
-            # concat(trimmed chunks) is byte-identical to the
-            # monolithic array.
+    def run_packed(counts_u, counts_i, i32, r32, seed):
+        if mesh is not None and mesh_span_lens is not None:
+            # the edge arrays arrived SHARDED over the mesh axis (the
+            # host link crossed once) as one or more spans
+            # (PIO_TPU_ALS_STREAM_MB: chunked puts pipeline the
+            # per-device transfers); re-replicate each span over ICI,
+            # drop its shard-divisibility padding and splice the stream
+            # back together: device_pack gathers from the whole list
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             repl = NamedSharding(mesh, P())
-            lens_lo, lens_hi, lens_r = mesh_wire_lens
 
-            def gather_cat(chunks, lens):
+            def gather_cat(spans):
                 parts = [
                     jax.lax.with_sharding_constraint(c, repl)[:n]
-                    for c, n in zip(chunks, lens)
+                    for c, n in zip(spans, mesh_span_lens)
                 ]
                 return parts[0] if len(parts) == 1 \
                     else jnp.concatenate(parts)
 
-            i_lo = gather_cat(i_lo, lens_lo)
-            i_hi = gather_cat(i_hi, lens_hi)
-            r = gather_cat(r, lens_r)
-        E = i_lo.shape[0]
-        with jax.named_scope("als.decode"):
-            i32 = math.decode_items(i_lo, i_hi, ovf_idx, ovf_val, counts_u)
-            r32 = math.decode_ratings(r, E)
+            i32 = gather_cat(i32)
+            r32 = gather_cat(r32)
+        E = i32.shape[0]
         with jax.named_scope("als.pack"):
             u32 = jnp.repeat(
                 jnp.arange(U_pad, dtype=jnp.int32), counts_u,
                 total_repeat_length=E,
             )
-        # both degree histograms ride the wire (0.9 MB total) — the
+        # both degree histograms ride the link (0.9 MB at ml-25m): the
         # on-device bincount is a 25M-edge scatter-add, the host count is
-        # a pass the native packer already made
+        # a pass the sort already made
         by_user = device_pack(u32, i32, r32, U_pad, wu, su,
                               assume_sorted=True, counts=counts_u)
         by_item = device_pack(i32, u32, r32, I_pad, wi, si,
@@ -805,9 +745,9 @@ def _build_stream_trainer(iterations: int, reg: float, implicit: bool,
                           rank: int, U_pad: int, I_pad: int,
                           w_user: int, w_item: int, S_item: int,
                           chunk_stream: int, chunk_item: int,
-                          rating_wire: str, item_wire: str,
                           chunk_spec: tuple):
-    """Double-buffered single-device trainer: the wire arrays arrive in
+    """Double-buffered single-device trainer: the sorted edges (plain
+    ``int32`` item ids, ``float32`` ratings) arrive in
     ``len(chunk_spec)`` slices and each slice's by-user block pack + its
     contribution to iteration 1's user-side normal equations run WHILE the
     next slice is still crossing the host↔device link (the queued
@@ -821,14 +761,16 @@ def _build_stream_trainer(iterations: int, reg: float, implicit: bool,
 
     The finalize program concatenates the chunk-local block layouts into
     the full by-user layout (no repack), solves P1 from the streamed
-    normal equations, packs the item side, and runs the remaining
-    iterations. Numerically this differs from the monolithic path only in
-    iteration-1 accumulation grouping (float reduction order)."""
+    normal equations, packs the item side from the concatenated edge
+    slices (still on the device), and runs the remaining iterations.
+    Numerically this differs from the monolithic path only in iteration-1
+    accumulation grouping (float reduction order). The programs depend
+    on the shapes alone (the two degree sequences through ``chunk_spec``
+    and the slice lengths), never on the ids or the ratings."""
     import jax
     import jax.numpy as jnp
 
-    math = _make_math(reg, implicit, alpha, matmul_dtype, solver,
-                      rating_wire, item_wire)
+    math = _make_math(reg, implicit, alpha, matmul_dtype, solver)
 
     def _lc_full(local_counts, u0_c):
         """Expand a chunk's sliced local-counts span to full U_pad."""
@@ -853,15 +795,12 @@ def _build_stream_trainer(iterations: int, reg: float, implicit: bool,
 
     def _make_accum(S_c: int, pad_c: int, u0_c: int):
         @functools.partial(jax.jit, donate_argnums=(0, 1))
-        def accum(A, b, Q0, local_counts, i_lo, i_hi, ovf_idx, ovf_val, r):
-            E_c = i_lo.shape[0]
+        def accum(A, b, Q0, local_counts, i32, r32):
             # local_counts arrives sliced to the chunk's present-user span
             # [u0_c, pad_c] (ships span·4 B instead of U_pad·4 B per
             # chunk); expand to full length on device
-            with jax.named_scope("als.decode"):
+            with jax.named_scope("als.pack"):
                 lc = _lc_full(local_counts, u0_c)
-                i32 = math.decode_items(i_lo, i_hi, ovf_idx, ovf_val, lc)
-                r32 = math.decode_ratings(r, E_c)
             blocks = device_pack(
                 None, i32, r32, U_pad, w_user, S_c,
                 assume_sorted=True, counts=lc, pad_entity=pad_c,
@@ -878,8 +817,7 @@ def _build_stream_trainer(iterations: int, reg: float, implicit: bool,
     accums = tuple(_make_accum(*spec) for spec in chunk_spec)
 
     @jax.jit
-    def finalize(A, b, Q0, counts_u, counts_i, user_blocks, wire_chunks,
-                 lc_slices):
+    def finalize(A, b, Q0, counts_u, counts_i, user_blocks, edge_chunks):
         # full by-user layout = concat of the chunk-local packs (padding
         # aliases each chunk's last user, so ids stay ascending)
         with jax.named_scope("als.pack"):
@@ -887,27 +825,12 @@ def _build_stream_trainer(iterations: int, reg: float, implicit: bool,
                 jnp.concatenate([blk[k] for blk in user_blocks])
                 for k in range(3)
             )
-        # item side needs the full COO: re-decode the (device-resident)
-        # wire chunks — elementwise, cheap; the delta item wire is
-        # chunk-segmented, so each chunk decodes against its own
-        # local-counts span
-        with jax.named_scope("als.decode"):
-            i32 = jnp.concatenate([
-                math.decode_items(
-                    lo, hi, ovf_i, ovf_v, _lc_full(lc, chunk_spec[c][2])
-                )
-                for c, ((lo, hi, ovf_i, ovf_v, _r), lc)
-                in enumerate(zip(wire_chunks, lc_slices))
-            ])
-            r32 = jnp.concatenate(
-                [math.decode_ratings(r, lo.shape[0])
-                 for lo, hi, ovf_i, ovf_v, r in wire_chunks]
-            )
-        E = i32.shape[0]
-        with jax.named_scope("als.pack"):
+            # item side needs the full COO: the (device-resident) slices
+            i32 = jnp.concatenate([i_c for i_c, _ in edge_chunks])
+            r32 = jnp.concatenate([r_c for _, r_c in edge_chunks])
             u32 = jnp.repeat(
                 jnp.arange(U_pad, dtype=jnp.int32), counts_u,
-                total_repeat_length=E,
+                total_repeat_length=i32.shape[0],
             )
         by_item = device_pack(i32, u32, r32, I_pad, w_item, S_item,
                               counts=counts_i)
@@ -935,10 +858,9 @@ def device_pack(ent, oth, rat, n_entities: int, width: int, S: int,
                 pad_entity=None):
     """On-device COO→blocked-CSR packing (traceable; jnp throughout).
 
-    Layout is bit-identical to the host packers (_pack_blocks /
-    native als_pack_fill) — enforced by tests/test_als.py
-    ``test_device_pack_matches_host_packers``. ``S``, ``width``, and
-    ``n_entities`` are static. ``assume_sorted`` skips the stable argsort
+    Layout is bit-identical to the host packer (_pack_blocks), enforced
+    by tests/test_als.py ``test_device_pack_matches_host_packers``.
+    ``S``, ``width``, and ``n_entities`` are static. ``assume_sorted`` skips the stable argsort
     when the caller guarantees ``ent`` is already ascending (the
     counts-rebuilt user column is sorted by construction).
 
@@ -987,36 +909,39 @@ def device_pack(ent, oth, rat, n_entities: int, width: int, S: int,
         return block_ent, block_other, block_rating
 
 
+def _edge_spans(n_edges: int, n_stream: int) -> list:
+    """The ``(e0, e1)`` edge spans of a chunked shipment: ``n_stream``
+    near-even cuts on even edge numbers, empty spans dropped. In the
+    streamed trainer the cuts group iteration 1's sums, so moving one
+    moves the trained floats (on a mesh the spans are spliced back)."""
+    bounds = [min(n_edges, (n_edges * c // n_stream) // 2 * 2)
+              for c in range(n_stream)] + [n_edges]
+    return [(e0, e1) for e0, e1 in zip(bounds[:-1], bounds[1:]) if e1 > e0]
+
+
 def _run_streamed(config: "ALSConfig", rank: int, U_pad: int, I_pad: int,
                   w_user: int, w_item: int, S_item: int, chunk_item: int,
                   counts_u: np.ndarray, counts_i: np.ndarray,
-                  i_sorted: np.ndarray, r_ship: np.ndarray,
-                  rating_wire: str, item_wire: str,
+                  i_sorted: np.ndarray, r_sorted: np.ndarray,
                   n_stream: int, seed, stats: Optional[dict],
                   capture: ScopeCapture):
     """Dispatch the double-buffered single-device training run.
 
-    Slices the (user, item)-sorted edges into ``n_stream`` spans, encodes
-    each span's item wire CHUNK-LOCALLY (the delta wire restarts each
-    user's gap chain at the chunk boundary — a straddling user's first
-    in-chunk edge ships its absolute id, so chunks decode independently
-    against their local counts), queues every span's ``device_put`` up
-    front (async — they drain on the transfer stream in order), then
+    Slices the (user, item)-sorted edges into ``n_stream`` spans (views:
+    nothing is encoded or copied), queues every span's ``device_put`` up
+    front (async: they drain on the transfer stream in order), then
     chains the per-chunk accumulate programs: chunk k's pack +
     normal-equation accumulation executes while chunk k+1 is still
-    crossing the link. With ``stats`` the phases are serialized (block
-    between h2d and compute) to measure them — overlap off. Chunk
-    boundaries are even so nibble-packed planes split on byte boundaries.
+    crossing the link. A user whose adjacency straddles a cut is in both
+    chunks' local counts with its share of each. With ``stats`` the
+    phases are serialized (block between h2d and compute) to measure
+    them: overlap off.
     """
     import jax
 
-    E = i_sorted.shape[0]
     edge_start = np.zeros(U_pad + 1, np.int64)
     np.cumsum(counts_u, out=edge_start[1:])
-    bounds = [min(E, (E * c // n_stream) // 2 * 2)
-              for c in range(n_stream)] + [E]
-    spans = [(bounds[c], bounds[c + 1]) for c in range(n_stream)
-             if bounds[c + 1] > bounds[c]]
+    spans = _edge_spans(i_sorted.shape[0], n_stream)
 
     local_slices, n_blocks, chunk_spec = [], [], []
     for e0, e1 in spans:
@@ -1039,37 +964,20 @@ def _run_streamed(config: "ALSConfig", rank: int, U_pad: int, I_pad: int,
         config.iterations, float(config.reg), bool(config.implicit),
         float(config.alpha), _resolve_matmul_dtype(str(config.matmul_dtype)), str(config.solver),
         rank, U_pad, I_pad, w_user, w_item, S_item,
-        chunk_stream, chunk_item, rating_wire, item_wire,
+        chunk_stream, chunk_item,
         tuple(tuple(s) for s in chunk_spec),
     )
 
-    def _encode_chunk(e0, e1, lc):
-        if item_wire == "delta12":
-            d_lo, d_hi, ovf_idx, ovf_val, _ = _encode_items_delta(
-                i_sorted[e0:e1], lc
-            )
-        else:
-            d_lo, d_hi = _planes(i_sorted[e0:e1], I_pad)
-            ovf_idx = np.zeros(0, np.int32)
-            ovf_val = np.zeros(0, np.uint8)
-        r_c = (r_ship[e0 // 2:(e1 + 1) // 2] if rating_wire == "u4"
-               else r_ship[e0:e1])
-        return d_lo, d_hi, ovf_idx, ovf_val, r_c
-
     # the shared streamed-feed executor (parallel/stream.py) runs the
-    # encode → queued-put → chained-dispatch loop; ALS retains the wire
-    # chunks (finalize re-decodes them for the item side) so it rides
-    # the queue-ahead mode (lookahead=0), and maps the executor's
-    # encode phase onto its historical ``pack_s`` stats key
+    # slice → queued-put → chained-dispatch loop; ALS retains the edge
+    # chunks (finalize packs the item side from them) so it rides the
+    # queue-ahead mode (lookahead=0). The executor's encode phase is the
+    # slicing alone and lands under the ``pack_s`` stats key.
     from pio_tpu.parallel.stream import stream_feed
 
     def encode(chunk):
         (e0, e1), lc = chunk
-        return (*_encode_chunk(e0, e1, lc), lc)
-
-    def put(host, _idx):
-        *wire, lc = host
-        return tuple(jax.device_put(a) for a in wire), jax.device_put(lc)
+        return lc, i_sorted[e0:e1], r_sorted[e0:e1]
 
     extra = {}
 
@@ -1086,8 +994,7 @@ def _run_streamed(config: "ALSConfig", rank: int, U_pad: int, I_pad: int,
 
     def dispatch(carry, dev, c):
         Q0, A, b, user_blocks = carry
-        wire, lc = dev
-        A, b, blk = accums[c](A, b, Q0, lc, *wire)
+        A, b, blk = accums[c](A, b, Q0, *dev)
         # chunk progress for the telemetry plane: ALS has no per-step
         # loss (normal equations), so progress is edges accumulated
         e0, e1 = spans[c]
@@ -1097,176 +1004,22 @@ def _run_streamed(config: "ALSConfig", rank: int, U_pad: int, I_pad: int,
     def fin(carry, devs):
         Q0, A, b, user_blocks = carry
         return finalize(A, b, Q0, extra["cu"], extra["ci"], user_blocks,
-                        tuple(d[0] for d in devs),
-                        tuple(d[1] for d in devs))
+                        tuple((i_c, r_c) for _lc, i_c, r_c in devs))
 
     return stream_feed(
         list(zip(spans, local_slices)),
-        encode=encode, put=put, put_extra=put_extra,
+        encode=encode, put_extra=put_extra,
         init_carry=init_carry, dispatch=dispatch, finalize=fin,
         stats=stats, encode_stat_key="pack_s", device_phase=capture,
     )
-
-
-def _nibble_pack(codes: np.ndarray) -> np.ndarray:
-    """Pack uint8 codes ≤ 15 two-per-byte: byte k = edge 2k (low nibble)
-    | edge 2k+1 (high nibble). Mirrors ``decode_ratings('u4')``."""
-    n = len(codes)
-    if n % 2:
-        codes = np.concatenate([codes, np.zeros(1, np.uint8)])
-    pair = codes.reshape(-1, 2)
-    return (pair[:, 0] | (pair[:, 1] << 4)).astype(np.uint8)
-
-
-def _planes(idx: np.ndarray, n_pad: int):
-    """(low, high) item wire planes: uint16 alone below 2^16, uint16 +
-    uint8 high plane below 2^24 (3 B/id instead of 4), raw int32 beyond.
-    The empty high plane means "unused"."""
-    none = np.zeros(0, np.uint8)
-    if n_pad < 65536:
-        return idx.astype(np.uint16), none
-    if n_pad < (1 << 24):
-        return (
-            (idx & 0xFFFF).astype(np.uint16),
-            (idx >> 16).astype(np.uint8),
-        )
-    return idx, none
-
-
-def _u8p(a: np.ndarray):
-    import ctypes
-
-    return _ptr(a, np.uint8, ctypes.c_uint8)
-
-
-def _np_deltas(ids: np.ndarray, counts: np.ndarray) -> np.ndarray:
-    """Per-edge gap to the previous same-segment id (first edge of each
-    segment gaps from 0). Numpy reference for the native delta encoder."""
-    E = len(ids)
-    cnt = counts[counts > 0].astype(np.int64)
-    starts = np.zeros(len(cnt), np.int64)
-    np.cumsum(cnt[:-1], out=starts[1:])
-    prev = np.empty(E, np.int32)
-    prev[0] = 0
-    prev[1:] = ids[:-1]
-    prev[starts] = 0
-    return ids.astype(np.int32) - prev
-
-
-def _delta_wire_size(
-    ids: np.ndarray, counts: np.ndarray
-) -> Optional[Tuple[int, int]]:
-    """``(wire_bytes, n_ovf)`` for the delta12 encoding WITHOUT
-    materializing it (one count pass), or None when the encoding is
-    inapplicable (ids not segment-sorted, or a gap ≥ 2^16)."""
-    E = len(ids)
-    if E == 0:
-        return 0, 0
-    native = _native_packer()
-    if native is not None:
-        cnt64 = np.ascontiguousarray(counts, np.int64)
-        n_ovf = int(native.als_delta_count(
-            _i32p(ids), _i64p(cnt64), len(cnt64)
-        ))
-        if n_ovf < 0:
-            return None
-    else:
-        delta = _np_deltas(ids, counts)
-        if len(delta) and (
-            int(delta.min()) < 0 or int(delta.max()) >= 65536
-        ):
-            return None
-        n_ovf = int((delta > 0xFFF).sum())
-    return E + (E + 1) // 2 + 5 * n_ovf, n_ovf
-
-
-def _encode_items_delta(ids: np.ndarray, counts: np.ndarray,
-                        n_ovf: Optional[int] = None):
-    """12-bit delta item wire over a (user, item)-sorted edge slice.
-
-    ``counts`` segments ``ids`` into per-user runs (zero entries allowed;
-    nonzero entries must sum to ``len(ids)``). Each edge ships the gap to
-    the previous item of the same user (the first edge of a run ships its
-    absolute id) as u8 low byte + nibble-packed high 4 bits — 1.5 B/edge
-    — plus a sparse overflow list carrying ``delta >> 12`` for the rare
-    gaps ≥ 4096. Exact for any id space < 2^16 (see
-    ``_make_math.decode_items``). Native single-pass encoder when the
-    toolchain is available; the numpy path is the format's reference.
-    Returns ``(d_lo, d_hi, ovf_idx i32, ovf_val u8, wire_bytes)``.
-    """
-    E = len(ids)
-    if E == 0:
-        z8 = np.zeros(0, np.uint8)
-        return z8, z8, np.zeros(0, np.int32), z8, 0
-    native = _native_packer()
-    if native is not None:
-        cnt64 = np.ascontiguousarray(counts, np.int64)
-        if n_ovf is None:  # caller may pass _delta_wire_size's count
-            n_ovf = int(native.als_delta_count(
-                _i32p(ids), _i64p(cnt64), len(cnt64)
-            ))
-        if n_ovf >= 0:
-            d_lo = np.empty(E, np.uint8)
-            d_hi = np.zeros((E + 1) // 2, np.uint8)
-            ovf_idx = np.empty(n_ovf, np.int32)
-            ovf_val = np.empty(n_ovf, np.uint8)
-            native.als_delta_fill(
-                _i32p(ids), _i64p(cnt64), len(cnt64), E,
-                _u8p(d_lo), _u8p(d_hi), _i32p(ovf_idx), _u8p(ovf_val),
-            )
-            bytes_ = (d_lo.nbytes + d_hi.nbytes + ovf_idx.nbytes
-                      + ovf_val.nbytes)
-            return d_lo, d_hi, ovf_idx, ovf_val, bytes_
-    delta = _np_deltas(ids, counts)
-    ovf = np.nonzero(delta > 0xFFF)[0]
-    d_lo = (delta & 0xFF).astype(np.uint8)
-    d_hi = _nibble_pack(((delta >> 8) & 0xF).astype(np.uint8))
-    ovf_idx = ovf.astype(np.int32)
-    ovf_val = (delta[ovf] >> 12).astype(np.uint8)
-    bytes_ = d_lo.nbytes + d_hi.nbytes + ovf_idx.nbytes + ovf_val.nbytes
-    return d_lo, d_hi, ovf_idx, ovf_val, bytes_
-
-
-def _encode_ratings(r_sorted: np.ndarray) -> Tuple[np.ndarray, str]:
-    """Choose the densest lossless rating wire format.
-
-    Returns ``(wire array, kind)`` where kind ∈ {u4, u8, f16, f32}:
-    nibble-packed half-star codes (2 edges/byte — MovieLens's 0.5..5.0
-    grid and implicit r=1 both qualify), byte codes to 127.5 stars, fp16
-    when that cast is exact, else raw f32. The decode lives in
-    ``_make_math.decode_ratings``; every kind round-trips exactly. The
-    grid check + byte coding is one fused native pass when available
-    (the numpy pipeline was ~10% of the whole host pack)."""
-    native = _native_packer()
-    if native is not None and r_sorted.size:
-        codes = np.empty(r_sorted.size, np.uint8)
-        mx = native.als_rating_codes(
-            _f32p(r_sorted), r_sorted.size, _u8p(codes)
-        )
-        if mx >= 0:
-            if mx <= 15:
-                return _nibble_pack(codes), "u4"
-            return codes, "u8"
-    else:
-        r2 = r_sorted * np.float32(2.0)
-        if r2.size and np.all(r2 == np.round(r2)) \
-                and float(r2.min()) >= 0.0:
-            if float(r2.max()) <= 15.0:
-                return _nibble_pack(r2.astype(np.uint8)), "u4"
-            if float(r2.max()) <= 255.0:
-                return r2.astype(np.uint8), "u8"
-    r16 = r_sorted.astype(np.float16)
-    if np.array_equal(r16.astype(np.float32), r_sorted):
-        return r16, "f16"
-    return r_sorted, "f32"
 
 
 def _sort_edges_by_user(user_idx, item_idx, rating, n_edges, U_pad,
                         counts_u):
     """(user, item)-sorted item/rating columns: native two-pass sort
     (counting sort by user + per-adjacency stable item sort) with a numpy
-    lexsort fallback. Item-sorted adjacencies are what make the delta
-    item wire dense AND improve factor-gather locality on device; ALS
+    lexsort fallback. The order fixes every float sum downstream, and
+    item-sorted adjacencies improve factor-gather locality on device; ALS
     itself is order-invariant within a user."""
     native = _native_packer()
     if native is not None:
@@ -1282,13 +1035,12 @@ def _sort_edges_by_user(user_idx, item_idx, rating, n_edges, U_pad,
         )
         if rc != 0:  # a single entity with ≥2^32 edges: the radix
             # sorter's 32-bit cursors would wrap, so it refuses
-            # wholesale. Training is order-invariant so this is safe,
-            # but the delta wire then won't apply (negative gaps →
-            # planes fallback) — say so instead of silently diverging
-            # from the numpy lexsort path.
+            # wholesale. Training is order-invariant so this is safe;
+            # say so instead of silently diverging from the numpy
+            # lexsort path in the last float digits.
             log.warning(
                 "within-user item sort skipped (an entity exceeds "
-                "2^24 edges); item wire falls back to planes"
+                "2^24 edges); its edges stay in arrival order"
             )
     else:
         order = np.lexsort((item_idx, user_idx))
@@ -1297,46 +1049,52 @@ def _sort_edges_by_user(user_idx, item_idx, rating, n_edges, U_pad,
     return i_sorted, r_sorted
 
 
-def _choose_item_wire(i_sorted, counts_u, I_pad, n_edges):
-    """Pick the denser lossless item wire: uint16/24/32 planes vs 12-bit
-    deltas over the (user, item)-sorted adjacency, sized by a count-only
-    pass (PIO_TPU_ALS_ITEM_WIRE overrides: auto/delta12/planes).
-    Returns (item_wire, n_ovf, edge_item_bytes)."""
-    item_env = knobs.knob_str("PIO_TPU_ALS_ITEM_WIRE")
-    plane_width = 2 if I_pad < 65536 else (3 if I_pad < 2 ** 24 else 4)
-    n_ovf = None
-    delta_bytes = None
-    if I_pad < 65536 and item_env in ("auto", "delta12"):
-        sized = _delta_wire_size(i_sorted, counts_u)
-        if sized is not None:
-            delta_bytes, n_ovf = sized
-            if item_env == "delta12" or delta_bytes < 2 * n_edges:
-                return "delta12", n_ovf, delta_bytes
-    return "planes", n_ovf, plane_width * n_edges
+def _counts_layout(ent, width: int, n_entities: int, n_shards: int,
+                   blocks_per_chunk: int):
+    """counts + (chunk, padded block count S) for one side."""
+    native = _native_packer()
+    if native is not None:
+        counts = np.zeros(n_entities, np.int64)
+        n_blocks = int(native.als_pack_count(
+            _i32p(ent), len(ent), n_entities, width, _i64p(counts)
+        ))
+        if n_blocks < 0:
+            raise ValueError("entity index out of range")
+    else:
+        counts = np.bincount(ent, minlength=n_entities)
+        n_blocks = int((-(-counts // width)).sum())
+    per_shard = max(1, -(-n_blocks // n_shards))
+    chunk = min(blocks_per_chunk, _round_up(per_shard, 8))
+    pad_to = n_shards * chunk
+    # single home for the padded block count: the numpy packer is handed
+    # S directly so the oracle cannot drift from the device's layout
+    S = max(pad_to, _round_up(max(n_blocks, 1), pad_to))
+    return counts, chunk, S
 
 
 def _run_mesh_compact(config, mesh, axis, n_shards, user_idx, item_idx,
                       rating, n_edges, U_pad, I_pad, w_user, w_item,
-                      counts_layout, trainer, seed, stats, capture):
-    """Multi-shard training over the COMPACT edge wire.
+                      trainer, seed, stats, capture):
+    """Multi-shard training from the sorted plain edges.
 
     The host link (PCIe on a TPU VM) is the slow hop and ICI the fast
-    one, so the wire crosses the host link exactly once:
-    every edge-indexed array ships SHARDED over the mesh axis (each
-    device receives 1/n of ~2 B/edge), and the jitted trainer
-    re-replicates them with an all-gather that rides ICI before the
-    on-device dual blocked-layout construction (``device_pack``). The
-    constructed block arrays come out sharded by block index — the
-    layout the shard_map half-steps consume — so block CONTENT never
-    needed host-side shard routing at all (the round-3 design note in
-    docs/parallelism.md). Bit-identical to the host-packed blocked-f32
-    path by the device_pack parity guarantee."""
+    one, so the edges cross the host link exactly once: the two
+    edge-indexed arrays (``int32`` item ids, ``float32`` ratings) ship
+    SHARDED over the mesh axis (each device receives 1/n of 8 B/edge),
+    and the jitted trainer re-replicates them with an all-gather that
+    rides ICI before the on-device dual blocked-layout construction
+    (``device_pack``). The constructed block arrays come out sharded by
+    block index (the layout the shard_map half-steps consume), so block
+    CONTENT never needs host-side shard routing. Bit-identical to
+    :func:`_train_mesh_host_packed` by the device_pack parity guarantee."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     t0 = monotonic_s()
-    counts_u, chunk_user, S_u = counts_layout(user_idx, w_user, U_pad)
-    counts_i, chunk_item, S_i = counts_layout(item_idx, w_item, I_pad)
+    counts_u, chunk_user, S_u = _counts_layout(
+        user_idx, w_user, U_pad, n_shards, config.blocks_per_chunk)
+    counts_i, chunk_item, S_i = _counts_layout(
+        item_idx, w_item, I_pad, n_shards, config.blocks_per_chunk)
     if S_u * w_user >= 2 ** 31 or S_i * w_item >= 2 ** 31:
         raise ValueError(
             "edge set too large for int32 block addressing; raise "
@@ -1346,53 +1104,22 @@ def _run_mesh_compact(config, mesh, axis, n_shards, user_idx, item_idx,
     i_sorted, r_sorted = _sort_edges_by_user(
         user_idx, item_idx, rating, n_edges, U_pad, counts_u
     )
-    r_ship, rating_wire = _encode_ratings(r_sorted)
-    item_wire, n_ovf, item_bytes = _choose_item_wire(
-        i_sorted, counts_u, I_pad, n_edges
-    )
-    if item_wire == "delta12":
-        i_ship, i_hi, ovf_idx, ovf_val, _ = _encode_items_delta(
-            i_sorted, counts_u, n_ovf=n_ovf
-        )
-    else:
-        i_ship, i_hi = _planes(i_sorted, I_pad)
-        ovf_idx = np.zeros(0, np.int32)
-        ovf_val = np.zeros(0, np.uint8)
     # chunked shipment (the single-device stream discipline applied to
-    # the sharded puts): slice each ENCODED array into ≤8 spans so the
+    # the sharded puts): slice both arrays into ≤8 spans so the
     # per-device transfers of span k+1 pipeline behind span k instead of
-    # one monolithic put per array serializing the whole h2d. Slicing
-    # happens after encoding, so the wire BYTES are unchanged — the
-    # trainer splices the trimmed spans back together before decoding.
-    edge_bytes = item_bytes + r_ship.nbytes
-    n_stream = _n_stream_chunks(edge_bytes, "PIO_TPU_ALS_STREAM_MB")
-
-    def spans_of(a):
-        if n_stream == 1 or len(a) == 0:
-            return [a]
-        bounds = [len(a) * c // n_stream for c in range(n_stream + 1)]
-        return [a[s:e] for s, e in zip(bounds[:-1], bounds[1:]) if e > s]
-
-    lo_spans = spans_of(i_ship)
-    hi_spans = spans_of(i_hi)
-    r_spans = spans_of(r_ship)
+    # one monolithic put per array serializing the whole h2d. The
+    # trainer splices the trimmed spans back together before packing.
+    spans = _edge_spans(n_edges, _n_stream_chunks(
+        _EDGE_BYTES * n_edges, "PIO_TPU_ALS_STREAM_MB"))
 
     if stats is not None:
         stats["pack_s"] = monotonic_s() - t0
-        stats["wire_bytes"] = (
-            item_bytes + r_ship.nbytes + 4 * (U_pad + I_pad)
-        )
-        stats["encoding"] = f"{rating_wire}+{item_wire}"
-        stats["n_stream"] = max(len(lo_spans), len(r_spans))
+        stats["wire_bytes"] = _EDGE_BYTES * n_edges + 4 * (U_pad + I_pad)
+        stats["n_stream"] = len(spans)
 
     run = trainer(
         chunk_user, chunk_item, (S_u, w_user, S_i, w_item),
-        rating_wire, item_wire,
-        mesh_wire_lens=(
-            tuple(len(s) for s in lo_spans),
-            tuple(len(s) for s in hi_spans),
-            tuple(len(s) for s in r_spans),
-        ),
+        mesh_span_lens=tuple(e1 - e0 for e0, e1 in spans),
     )
     shard1 = NamedSharding(mesh, P(axis))
     repl = NamedSharding(mesh, P())
@@ -1402,30 +1129,21 @@ def _run_mesh_compact(config, mesh, axis, n_shards, user_idx, item_idx,
         return np.concatenate([a, np.zeros(p, a.dtype)]) if p else a
 
     t0 = monotonic_s()
-    small = (
+    counts_dev = (
         jax.device_put(counts_u.astype(np.int32), repl),
         jax.device_put(np.ascontiguousarray(counts_i, np.int32), repl),
-        jax.device_put(ovf_idx, repl),
-        jax.device_put(ovf_val, repl),
     )
-    # interleave the arrays' spans so early spans of every array are in
-    # flight together; per-span timings land in stats on profiled runs
-    lo_dev: list = []
-    hi_dev: list = []
-    r_dev: list = []
-    chunk_ts = []
-    for parts in itertools.zip_longest(lo_spans, hi_spans, r_spans):
+    # a span of both arrays goes out together so early spans of each are
+    # in flight at once; per-span timings land in stats on profiled runs
+    i_dev, r_dev, chunk_ts = [], [], []
+    for e0, e1 in spans:
         tc = monotonic_s()
-        group = []
-        for part, dev in zip(parts, (lo_dev, hi_dev, r_dev)):
-            if part is not None:
-                dev.append(jax.device_put(pad_to_shards(part), shard1))
-                group.append(dev[-1])
+        i_dev.append(jax.device_put(pad_to_shards(i_sorted[e0:e1]), shard1))
+        r_dev.append(jax.device_put(pad_to_shards(r_sorted[e0:e1]), shard1))
         if stats is not None:
-            jax.block_until_ready(group)
+            jax.block_until_ready((i_dev[-1], r_dev[-1]))
             chunk_ts.append(round(monotonic_s() - tc, 3))
-    args = (*small[:2], tuple(lo_dev), tuple(hi_dev), *small[2:],
-            tuple(r_dev))
+    args = (*counts_dev, tuple(i_dev), tuple(r_dev))
     if stats is not None:
         jax.block_until_ready(args)
         stats["h2d_s"] = monotonic_s() - t0
@@ -1434,6 +1152,57 @@ def _run_mesh_compact(config, mesh, axis, n_shards, user_idx, item_idx,
     else:
         P_f, Q_f = run(*args, seed)
     return P_f, Q_f
+
+
+def _train_mesh_host_packed(ctx: ComputeContext, user_idx, item_idx, rating,
+                            n_users: int, n_items: int,
+                            config: "ALSConfig") -> "ALSFactors":
+    """The mesh route's bit-exact oracle; ``train_als`` never takes it,
+    tests/test_als.py and the dry run of ``__graft_entry__.py`` do.
+
+    Both blocked layouts are packed on the host in numpy
+    (:func:`_pack_blocks` over the ``lexsort``ed edges) and shipped as
+    they are, 16 times the bytes an edge; the shard_map trainer is the one
+    ``train_als`` runs, less its ``device_pack``."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    mesh, axis = ctx.mesh, ctx.batch_axis
+    n_shards = mesh.shape[axis]
+    U_pad = _round_up(max(n_users, 1), n_shards)
+    I_pad = _round_up(max(n_items, 1), n_shards)
+    order = np.lexsort((item_idx, user_idx))
+    u = np.ascontiguousarray(np.asarray(user_idx, np.int32)[order])
+    i = np.ascontiguousarray(np.asarray(item_idx, np.int32)[order])
+    r = np.ascontiguousarray(np.asarray(rating, np.float32)[order])
+
+    def layout(ent, other, n_true, n_pad):
+        width = config.block_width or _auto_width(len(ent), n_true)
+        counts, chunk, S = _counts_layout(
+            ent, width, n_pad, n_shards, config.blocks_per_chunk)
+        blocks = _pack_blocks(ent, other, r, n_pad, width, S, counts=counts)
+        assert blocks[0].shape[0] == S
+        return blocks, chunk
+
+    by_user, chunk_user = layout(u, i, n_users, U_pad)
+    by_item, chunk_item = layout(i, u, n_items, I_pad)
+    run = _build_trainer(
+        mesh, axis, config.iterations, float(config.reg),
+        bool(config.implicit), float(config.alpha), chunk_user, chunk_item,
+        _resolve_matmul_dtype(str(config.matmul_dtype)), str(config.solver),
+        None, config.rank, U_pad, I_pad,
+    )
+    blk = NamedSharding(mesh, P(axis))
+    blk2 = NamedSharding(mesh, P(axis, None))
+
+    def put_blocks(t):
+        return (jax.device_put(t[0], blk), jax.device_put(t[1], blk2),
+                jax.device_put(t[2], blk2))
+
+    P_f, Q_f = jax.device_get(
+        run(put_blocks(by_user), put_blocks(by_item), np.uint32(config.seed)))
+    return ALSFactors(user_factors=np.asarray(P_f)[:n_users],
+                      item_factors=np.asarray(Q_f)[:n_items])
 
 
 def _profiled_run(run, args, stats: dict, capture: ScopeCapture):
@@ -1490,8 +1259,17 @@ def train_als(
     Entity counts are padded to mesh multiples; factor rows beyond the true
     counts are dropped on the way out.
 
+    The feed is one on every route: the host sorts the edges by (user,
+    item) and ships them as what they are, ``int32`` item ids and
+    ``float32`` ratings with the two degree histograms; the device builds
+    both blocked layouts (:func:`device_pack`). Whole in one program
+    (small inputs), in ``n_stream`` chunks overlapped with iteration 1's
+    user half-step (:func:`_run_streamed`), or sharded over a mesh
+    (:func:`_run_mesh_compact`). The compiled programs depend on the
+    shapes (counts, both degree sequences), never on ids or ratings.
+
     ``stats``, when a dict, is filled with a per-phase breakdown —
-    ``{pack_s, wire_bytes, encoding, n_stream, h2d_s, device_s}`` and
+    ``{pack_s, wire_bytes, n_stream, h2d_s, device_s}`` and
     ``solve_impl`` (``{"user", "item"}``: which solver each side's batch
     gets, :func:`_solve_impl`) and ``gather_impl`` (which table layout each
     half-step gathers the other side's rows from, :func:`_gather_impl`) — by
@@ -1520,7 +1298,6 @@ def train_als(
     """
     import jax
     import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
 
     if len(user_idx) == 0:
         raise ValueError("ALS needs at least one rating")
@@ -1572,57 +1349,9 @@ def train_als(
         stats["solve_impl"] = solve_impl
         stats["gather_impl"] = gather_impl
 
-    def _counts_layout(ent, width, n_entities):
-        """counts + (chunk, padded block count S) for one side."""
-        native = _native_packer()
-        if native is not None:
-            counts = np.zeros(n_entities, np.int64)
-            n_blocks = int(native.als_pack_count(
-                _i32p(ent), len(ent), n_entities, width, _i64p(counts)
-            ))
-            if n_blocks < 0:
-                raise ValueError("entity index out of range")
-        else:
-            counts = np.bincount(ent, minlength=n_entities)
-            n_blocks = int((-(-counts // width)).sum())
-        per_shard = max(1, -(-n_blocks // n_shards))
-        chunk = min(config.blocks_per_chunk, _round_up(per_shard, 8))
-        pad_to = n_shards * chunk
-        # single home for the padded block count — the numpy packer is
-        # handed S directly so both paths cannot drift apart
-        S = max(pad_to, _round_up(max(n_blocks, 1), pad_to))
-        return counts, chunk, S
-
-    def _layout(ent, other, rat, width, n_entities):
-        """Host-packed blocks (the multi-shard path; single-device packs
-        on device instead — see _build_trainer's COO variant)."""
-        native = _native_packer()
-        counts, chunk, S = _counts_layout(ent, width, n_entities)
-        if native is not None:
-            block_ent = np.empty(S, np.int32)
-            block_other = np.empty(S * width, np.int32)
-            block_rating = np.empty(S * width, np.float32)
-            native.als_pack_fill(
-                _i32p(ent), _i32p(other), _f32p(rat), len(ent),
-                n_entities, width, _i64p(counts), S,
-                _i32p(block_ent), _i32p(block_other), _f32p(block_rating),
-            )
-            blocks = (
-                block_ent,
-                block_other.reshape(S, width),
-                block_rating.reshape(S, width),
-            )
-        else:
-            blocks = _pack_blocks(
-                ent, other, rat, n_entities, width, S, counts=counts
-            )
-            assert blocks[0].shape[0] == S
-        return blocks, chunk
-
     seed = np.uint32(config.seed)
 
-    def _trainer(chunk_user, chunk_item, packed_shapes, rating_wire="f32",
-                 item_wire="planes", mesh_wire_lens=None):
+    def _trainer(chunk_user, chunk_item, packed_shapes, mesh_span_lens=None):
         # one call site for the long positional signature so the mesh and
         # single-device branches can never drift apart
         return _build_trainer(
@@ -1630,85 +1359,27 @@ def train_als(
             bool(config.implicit), float(config.alpha),
             chunk_user, chunk_item,
             _resolve_matmul_dtype(str(config.matmul_dtype)), str(config.solver),
-            packed_shapes, K, U_pad, I_pad, rating_wire, item_wire,
-            mesh_wire_lens,
+            packed_shapes, K, U_pad, I_pad, mesh_span_lens,
         )
 
     if n_shards > 1:
-        # wire policy: "compact" (default) ships the single-device delta/
-        # plane+code wire — each device receives 1/n of it over the host
-        # link (PCIe/DCN, the slow hop) and the jitted trainer re-
-        # replicates it over ICI (fast) before the on-device dual blocked-
-        # layout construction, whose sharded outputs feed the shard_map
-        # half-steps. "blocked" keeps the host-packed f32 block shipment
-        # (~16× the bytes/edge) — retained as the equality reference.
-        mesh_wire = knobs.knob_str("PIO_TPU_ALS_MESH_WIRE")
-        if mesh_wire in ("auto", "compact"):
-            P_f, Q_f = _run_mesh_compact(
-                config, mesh, axis, n_shards, user_idx, item_idx, rating,
-                n_edges, U_pad, I_pad, w_user, w_item, _counts_layout,
-                _trainer, seed, stats, capture,
-            )
-        else:
-            t0 = monotonic_s()
-            # canonical (user, item) edge order BEFORE packing: block
-            # content becomes input-order-invariant and bit-identical to
-            # the compact path's on-device construction (which composes
-            # through a stable sort of the same canonical stream)
-            cu0 = np.ascontiguousarray(
-                np.bincount(user_idx, minlength=U_pad), np.int64
-            )
-            i_srt, r_srt = _sort_edges_by_user(
-                user_idx, item_idx, rating, n_edges, U_pad, cu0
-            )
-            u_srt = np.repeat(
-                np.arange(U_pad, dtype=np.int32), cu0
-            )
-            by_user, chunk_user = _layout(
-                u_srt, i_srt, r_srt, w_user, U_pad
-            )
-            by_item, chunk_item = _layout(
-                i_srt, u_srt, r_srt, w_item, I_pad
-            )
-            run = _trainer(chunk_user, chunk_item, None)
-            blk = NamedSharding(mesh, P(axis))
-            blk2 = NamedSharding(mesh, P(axis, None))
-            put_blocks = lambda t: (
-                jax.device_put(t[0], blk),
-                jax.device_put(t[1], blk2),
-                jax.device_put(t[2], blk2),
-            )
-            if stats is not None:
-                stats["pack_s"] = monotonic_s() - t0
-                stats["wire_bytes"] = sum(
-                    a.nbytes for t in (by_user, by_item) for a in t
-                )
-                stats["encoding"] = "blocked-f32"
-                stats["n_stream"] = 1
-                t0 = monotonic_s()
-                u_dev, i_dev = put_blocks(by_user), put_blocks(by_item)
-                jax.block_until_ready((u_dev, i_dev))
-                stats["h2d_s"] = monotonic_s() - t0
-                P_f, Q_f = _profiled_run(
-                    run, (u_dev, i_dev, seed), stats, capture)
-            else:
-                P_f, Q_f = run(
-                    put_blocks(by_user), put_blocks(by_item), seed
-                )
+        P_f, Q_f = _run_mesh_compact(
+            config, mesh, axis, n_shards, user_idx, item_idx, rating,
+            n_edges, U_pad, I_pad, w_user, w_item, _trainer, seed, stats,
+            capture,
+        )
     else:
-        # Single-device path: ship the COO edges pre-sorted by user (see
-        # _build_trainer's COO variant for the wire format) and let the
-        # jitted trainer build both blocked layouts on device, which
-        # matters where the device link is slow or shares a core with
-        # the process. Above a wire-size threshold the
+        # Single-device path: ship the edges sorted by (user, item) and
+        # let the jitted trainer build both blocked layouts on device
+        # (_build_trainer's ``run_packed``). Above a size threshold the
         # shipment is STREAMED in chunks overlapped with the chunk packs +
         # iteration-1 accumulation (_build_stream_trainer).
         t0 = monotonic_s()
         with active_span("als.sort"):
             counts_u, chunk_user, S_u = _counts_layout(
-                user_idx, w_user, U_pad)
+                user_idx, w_user, U_pad, 1, config.blocks_per_chunk)
             counts_i, chunk_item, S_i = _counts_layout(
-                item_idx, w_item, I_pad)
+                item_idx, w_item, I_pad, 1, config.blocks_per_chunk)
             if S_u * w_user >= 2 ** 31 or S_i * w_item >= 2 ** 31:
                 raise ValueError(
                     "edge set too large for int32 block addressing; "
@@ -1719,24 +1390,17 @@ def train_als(
             i_sorted, r_sorted = _sort_edges_by_user(
                 user_idx, item_idx, rating, n_edges, U_pad, counts_u
             )
-            r_ship, rating_wire = _encode_ratings(r_sorted)
-            # item wire sized by a count-only pass so nothing is
-            # materialized before the stream/monolithic split
-            item_wire, n_ovf, item_bytes = _choose_item_wire(
-                i_sorted, counts_u, I_pad, n_edges
-            )
-        use_delta = item_wire == "delta12"
-        edge_bytes = item_bytes + r_ship.nbytes
+        edge_bytes = _EDGE_BYTES * n_edges
         if stats is not None:
             stats["pack_s"] = monotonic_s() - t0
             stats["wire_bytes"] = (
                 edge_bytes + 4 * (U_pad + I_pad)  # + the two count arrays
             )
-            stats["encoding"] = f"{rating_wire}+{item_wire}"
 
-        # stream threshold: chunked double-buffered shipment once the edge
-        # wire exceeds ~one chunk (default 8 MiB); tiny runs keep the
-        # single-dispatch path. <= 0 disables streaming entirely.
+        # stream threshold: chunked double-buffered shipment once the
+        # edges exceed one chunk (default 30 MiB: 7 chunks at ml-25m);
+        # tiny runs keep the single-dispatch path. <= 0 disables
+        # streaming entirely.
         n_stream = _n_stream_chunks(edge_bytes, "PIO_TPU_ALS_STREAM_MB")
         if config.iterations < 1:
             # the streamed trainer fuses iteration 1's user half-step into
@@ -1750,26 +1414,16 @@ def train_als(
             edges_recorded = True  # _run_streamed records per chunk
             P_f, Q_f = _run_streamed(
                 config, K, U_pad, I_pad, w_user, w_item, S_i, chunk_item,
-                counts_u, counts_i, i_sorted, r_ship, rating_wire,
-                item_wire, n_stream, seed, stats, capture,
+                counts_u, counts_i, i_sorted, r_sorted, n_stream, seed,
+                stats, capture,
             )
         else:
-            if use_delta:
-                i_ship, i_hi, ovf_idx, ovf_val, _ = _encode_items_delta(
-                    i_sorted, counts_u, n_ovf=n_ovf
-                )
-            else:
-                i_ship, i_hi = _planes(i_sorted, I_pad)
-                ovf_idx = np.zeros(0, np.int32)
-                ovf_val = np.zeros(0, np.uint8)
             run = _trainer(
-                chunk_user, chunk_item, (S_u, w_user, S_i, w_item),
-                rating_wire, item_wire,
-            )
+                chunk_user, chunk_item, (S_u, w_user, S_i, w_item))
             args = (
                 counts_u.astype(np.int32),
                 np.ascontiguousarray(counts_i, np.int32),
-                i_ship, i_hi, ovf_idx, ovf_val, r_ship,
+                i_sorted, r_sorted,
             )
             if stats is not None:
                 t0 = monotonic_s()

@@ -5,8 +5,8 @@ SURVEY.md §2); this package holds the rebuild's own native pieces:
 
 - ``event_log.cpp`` — append-only binary event log with C++ filtered scan
   (pio_tpu/storage/eventlog.py wraps it as a storage backend).
-- ``als_pack.cpp`` — parallel COO→blocked-CSR packer feeding the ALS
-  trainer's coalesced device transfer (pio_tpu/models/als.py).
+- ``als_pack.cpp`` — parallel (user, item) edge sort and degree count
+  feeding the ALS trainer's device transfer (pio_tpu/models/als.py).
 
 Build model: no wheels, no pybind11 — ``g++ -O3 -march=native`` at first
 import, cached under ``$PIO_TPU_HOME/native/<src+flags sha>-<isa>.so`` so
@@ -157,7 +157,7 @@ def event_log_lib():
 
 
 def als_pack_lib():
-    """Load (building if needed) the ALS packer library; cached."""
+    """Load (building if needed) the ALS edge-sort library; cached."""
     with _lock:
         if "als_pack" in _cache:
             return _cache["als_pack"]
@@ -172,11 +172,6 @@ def als_pack_lib():
             i32p, ctypes.c_int64, ctypes.c_int32, ctypes.c_int32, i64p
         ]
         lib.als_pack_count.restype = ctypes.c_int64
-        lib.als_pack_fill.argtypes = [
-            i32p, i32p, f32p, ctypes.c_int64, ctypes.c_int32,
-            ctypes.c_int32, i64p, ctypes.c_int64, i32p, i32p, f32p,
-        ]
-        lib.als_pack_fill.restype = ctypes.c_int
         lib.als_sort_by_entity.argtypes = [
             i32p, i32p, f32p, ctypes.c_int64, ctypes.c_int32, i64p,
             i32p, f32p,
@@ -186,16 +181,6 @@ def als_pack_lib():
             i32p, f32p, ctypes.c_int32, i64p,
         ]
         lib.als_sort_within_entity.restype = ctypes.c_int
-        u8p = ctypes.POINTER(ctypes.c_uint8)
-        lib.als_delta_count.argtypes = [i32p, i64p, ctypes.c_int32]
-        lib.als_delta_count.restype = ctypes.c_int64
-        lib.als_delta_fill.argtypes = [
-            i32p, i64p, ctypes.c_int32, ctypes.c_int64,
-            u8p, u8p, i32p, u8p,
-        ]
-        lib.als_delta_fill.restype = ctypes.c_int
-        lib.als_rating_codes.argtypes = [f32p, ctypes.c_int64, u8p]
-        lib.als_rating_codes.restype = ctypes.c_int64
         _cache["als_pack"] = lib
         return lib
 

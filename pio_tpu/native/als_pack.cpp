@@ -1,12 +1,12 @@
-// Native COO→blocked-CSR packer — the ALS data-loader hot path.
+// Native (user, item) edge sorter: the host half of the ALS feed.
 //
 // The reference's training reads ride Spark RDD shuffles; this framework
-// packs rating edges into dense [n_blocks, width] blocks on the host
-// before one coalesced transfer to the TPU (pio_tpu/models/als.py
-// _pack_blocks documents the layout: blocks sorted by entity id, padded
-// slots carry other = -1). The numpy implementation is a single-threaded
-// argsort + scatter (~1s per 2M edges); this one is a stable parallel
-// counting sort writing straight into the caller's transfer buffers.
+// sorts the rating edges by (user, item) on the host and ships them to
+// the TPU as they are (int32 item ids, float32 ratings, the per-entity
+// counts); the device builds the blocked layouts (pio_tpu/models/als.py
+// device_pack). What is here: the degree histogram with the block count,
+// a stable parallel counting sort by entity, and the in-place sort of
+// each adjacency. numpy's lexsort is the reference and the fallback.
 //
 // Exposed via a C ABI consumed with ctypes (pio_tpu/native/__init__.py
 // builds this file with g++ on first use).
@@ -93,85 +93,10 @@ int64_t als_pack_count(const int32_t* ent, int64_t n_edges,
   return n_blocks;
 }
 
-// Pass 2: stable scatter into the caller-allocated block arrays
-// (block_ent [S], block_other [S*width], block_rating [S*width] — the
-// caller may point these INTO its coalesced transfer buffers). counts is
-// pass 1's output; S is the padded block count (≥ n_blocks). Edge order
-// within an entity is preserved (stable, like the numpy argsort path).
-// Returns 0.
-int als_pack_fill(const int32_t* ent, const int32_t* other,
-                  const float* rating, int64_t n_edges, int32_t n_entities,
-                  int32_t width, const int64_t* counts, int64_t S,
-                  int32_t* block_ent, int32_t* block_other,
-                  float* block_rating) {
-  const int T = n_threads(n_edges, n_entities);
-
-  // entity → first flat slot of its first block
-  std::vector<int64_t> slot_start(n_entities + 1);
-  slot_start[0] = 0;
-  for (int32_t e = 0; e < n_entities; ++e) {
-    int64_t blocks = (counts[e] + width - 1) / width;
-    slot_start[e + 1] = slot_start[e] + blocks * width;
-  }
-
-  // per-(thread, entity) write cursors: thread t starts after all edges
-  // of the same entity owned by threads < t → stable by construction
-  std::vector<std::vector<int64_t>> cursor(
-      T, std::vector<int64_t>(n_entities, 0));
-  if (T > 1) {
-    parallel_ranges(n_edges, T, [&](int t, int64_t lo, int64_t hi) {
-      auto& h = cursor[t];
-      for (int64_t k = lo; k < hi; ++k) ++h[ent[k]];
-    });
-    // exclusive scan over threads per entity
-    for (int32_t e = 0; e < n_entities; ++e) {
-      int64_t acc = 0;
-      for (int t = 0; t < T; ++t) {
-        int64_t c = cursor[t][e];
-        cursor[t][e] = acc;
-        acc += c;
-      }
-    }
-  }
-
-  const int64_t total = S * static_cast<int64_t>(width);
-  parallel_ranges(total, T, [&](int, int64_t lo, int64_t hi) {
-    std::fill(block_other + lo, block_other + hi, int32_t{-1});
-    std::memset(block_rating + lo, 0, sizeof(float) * (hi - lo));
-  });
-
-  parallel_ranges(n_edges, T, [&](int t, int64_t lo, int64_t hi) {
-    auto& cur = cursor[t];
-    for (int64_t k = lo; k < hi; ++k) {
-      int32_t e = ent[k];
-      int64_t pos = cur[e]++;
-      // position → flat slot: whole blocks are width apart
-      int64_t flat = slot_start[e] + pos;
-      block_other[flat] = other[k];
-      block_rating[flat] = rating[k];
-    }
-  });
-
-  // block_ent: entity of each block, ascending; padding blocks point at
-  // the last entity (their slots are all masked)
-  std::vector<int64_t> block_start(n_entities + 1);
-  block_start[0] = 0;
-  for (int32_t e = 0; e < n_entities; ++e)
-    block_start[e + 1] = block_start[e] + (counts[e] + width - 1) / width;
-  parallel_ranges(n_entities, T, [&](int, int64_t lo, int64_t hi) {
-    for (int64_t e = lo; e < hi; ++e)
-      for (int64_t s = block_start[e]; s < block_start[e + 1]; ++s)
-        block_ent[s] = static_cast<int32_t>(e);
-  });
-  for (int64_t s = block_start[n_entities]; s < S; ++s)
-    block_ent[s] = n_entities - 1;
-  return 0;
-}
-
-// Stable counting sort of (other, rating) by entity id — the wire-format
-// reducer for the single-device path: once edges are entity-sorted, the
-// per-edge entity plane collapses to a per-entity COUNTS array (65k× fewer
-// bytes at MovieLens scale) and the device rebuilds ids with one repeat.
+// Stable counting sort of (other, rating) by entity id: once edges are
+// entity-sorted, the per-edge entity column collapses to a per-entity
+// COUNTS array (65k× fewer bytes at MovieLens scale) and the device
+// rebuilds ids with one repeat.
 // counts is als_pack_count's output. Returns 0.
 //
 // Two-level scatter: a direct counting-sort scatter is TLB-miss bound
@@ -255,48 +180,11 @@ int als_sort_by_entity(const int32_t* ent, const int32_t* other,
   return 0;
 }
 
-// Fused rating-wire classifier + encoder, one parallel pass: detects the
-// half-star grid (every rating*2 a nonneg integer) and emits u8 codes.
-// Returns the max code (0..510), or -1 if any rating is off-grid (caller
-// falls back to f16/f32 encoding in numpy). Replaces a ~4-pass numpy
-// pipeline on the pack hot path.
-int64_t als_rating_codes(const float* rating, int64_t n_edges,
-                         uint8_t* codes) {
-  const int T = n_threads(n_edges, 1);
-  std::vector<int64_t> maxes(T, 0);
-  std::atomic<bool> ok{true};
-  parallel_ranges(n_edges, T, [&](int t, int64_t lo, int64_t hi) {
-    int64_t mx = 0;
-    for (int64_t k = lo; k < hi; ++k) {
-      float r2 = rating[k] * 2.0f;
-      // range-guard BEFORE the int cast: float→int of NaN/inf/out-of-
-      // range is UB (the guard also rejects NaN via negated compares)
-      if (!(r2 >= 0.0f) || !(r2 <= 255.0f)) {
-        ok.store(false, std::memory_order_relaxed);
-        return;
-      }
-      int32_t v = static_cast<int32_t>(r2);
-      if (static_cast<float>(v) != r2) {
-        ok.store(false, std::memory_order_relaxed);
-        return;
-      }
-      codes[k] = static_cast<uint8_t>(v);
-      if (v > mx) mx = v;
-    }
-    maxes[t] = mx;
-  });
-  if (!ok.load()) return -1;
-  int64_t mx = 0;
-  for (int t = 0; t < T; ++t) mx = std::max(mx, maxes[t]);
-  return mx;
-}
-
 // In-place stable sort of each entity's adjacency segment by the OTHER id
 // (items ascending within a user). ALS is invariant to within-entity edge
-// order, and the sorted adjacency is what makes the delta item wire
-// (pio_tpu/models/als.py _encode_items_delta) dense: gaps between
-// consecutive items fit 12 bits almost everywhere. Matches numpy's
-// np.lexsort((other, ent)) order exactly: stable on duplicate ids.
+// order up to float rounding; one canonical order makes the trained bits
+// independent of the input's order and of which sorter ran. Matches
+// numpy's np.lexsort((other, ent)) order exactly: stable on duplicate ids.
 //
 // Implementation: per-segment LSD radix over the id bytes (digit count
 // from the global max id — 2 passes at MovieLens scale), with a stable
@@ -393,52 +281,6 @@ int als_sort_within_entity(int32_t* other_sorted, float* rating_sorted,
       }
     }
   });
-  return 0;
-}
-
-// 12-bit delta item wire over a (user, item)-sorted edge array — the
-// native fast path for pio_tpu/models/als.py _encode_items_delta (the
-// numpy fallback there defines the format). Pass 1 counts gaps ≥ 4096;
-// pass 2 fills d_lo (u8 low byte), d_hi (high 4 bits nibble-packed, two
-// edges per byte) and the sparse overflow (edge index, delta >> 12).
-// counts segments the edges (zero entries allowed). Returns n_ovf, or
-// -1 on a negative gap (input not item-sorted) or a gap ≥ 2^16.
-int64_t als_delta_count(const int32_t* ids, const int64_t* counts,
-                        int32_t n_segments) {
-  int64_t n_ovf = 0, p = 0;
-  for (int32_t s = 0; s < n_segments; ++s) {
-    int32_t prev = 0;
-    for (int64_t k = 0; k < counts[s]; ++k, ++p) {
-      int64_t d = static_cast<int64_t>(ids[p]) - prev;
-      if (d < 0 || d >= (1LL << 16)) return -1;
-      if (d > 0xFFF) ++n_ovf;
-      prev = ids[p];
-    }
-  }
-  return n_ovf;
-}
-
-int als_delta_fill(const int32_t* ids, const int64_t* counts,
-                   int32_t n_segments, int64_t n_edges,
-                   uint8_t* d_lo, uint8_t* d_hi,
-                   int32_t* ovf_idx, uint8_t* ovf_val) {
-  std::memset(d_hi, 0, static_cast<size_t>((n_edges + 1) / 2));
-  int64_t n_ovf = 0, p = 0;
-  for (int32_t s = 0; s < n_segments; ++s) {
-    int32_t prev = 0;
-    for (int64_t k = 0; k < counts[s]; ++k, ++p) {
-      int32_t d = ids[p] - prev;
-      d_lo[p] = static_cast<uint8_t>(d & 0xFF);
-      d_hi[p / 2] |= static_cast<uint8_t>(((d >> 8) & 0xF)
-                                          << ((p % 2) ? 4 : 0));
-      if (d > 0xFFF) {
-        ovf_idx[n_ovf] = static_cast<int32_t>(p);
-        ovf_val[n_ovf] = static_cast<uint8_t>(d >> 12);
-        ++n_ovf;
-      }
-      prev = ids[p];
-    }
-  }
   return 0;
 }
 

@@ -31,7 +31,7 @@ The ops plane on top (ISSUE 2):
 
 Plus :mod:`pio_tpu.obs.profile` (the opt-in ``PIO_TPU_PROFILE=dir`` JAX
 profiler hook), :mod:`pio_tpu.obs.promparse` (a small text-format
-parser shared by tests, bench.py and the dashboard) and
+parser shared by tests, the fleet aggregator and the dashboard) and
 :mod:`pio_tpu.obs.trainwatch` (the training telemetry plane — step
 stream, ``/train.json`` progress, run ledger) and
 :mod:`pio_tpu.obs.devicewatch` (the device telemetry plane — live HBM

@@ -1,7 +1,6 @@
 """Small Prometheus text-format parser (and re-renderer).
 
 Shared by the test suite (round-tripping every ``/metrics`` endpoint),
-``bench.py`` (server-side metric deltas embedded in the bench artifact),
 the dashboard's serving view, and the fleet aggregator (ISSUE 11), which
 parses every member's scrape, relabels it with ``pio_tpu_member``, merges
 and re-exposes the union. Parses the subset the exposition spec defines

@@ -377,8 +377,7 @@ def set_gather_impl(impl: Dict[str, str]) -> None:
 
 
 # ---------------------------------------------------------------------------
-# direction-aware deltas — the regression core shared with bench's
-# history ledger (bench.py history_delta_table delegates here)
+# direction-aware deltas — the regression core of ``pio runs --diff``
 # ---------------------------------------------------------------------------
 
 

@@ -1,7 +1,7 @@
 """Where this checkout keeps JAX's persistent compilation cache.
 
 One home for the rule every entry point follows (the CLI, spawned pool
-workers, ``bench.py``, ``__graft_entry__.py``, ``chip_smoke.py``'s
+workers, ``benchmarks/``, ``__graft_entry__.py``, ``chip_smoke.py``'s
 children): an operator who exports ``JAX_COMPILATION_CACHE_DIR`` owns the
 location and the program sets nothing — JAX reads the variable itself.
 Otherwise the cache lives at ``<checkout>/.jax_cache``, derived from this

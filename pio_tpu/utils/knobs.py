@@ -129,14 +129,10 @@ _DECLARATIONS: Tuple[Knob, ...] = (
     # -- training / models -----------------------------------------------
     Knob("PIO_TPU_TRAIN_STREAM_MB", "float", 64.0,
          "streamed training-batch chunk size, MB; <= 0 disables"),
-    Knob("PIO_TPU_ALS_STREAM_MB", "float", 8.0,
+    Knob("PIO_TPU_ALS_STREAM_MB", "float", 30.0,
          "streamed ALS edge-shipment chunk size, MB; <= 0 disables"),
     Knob("PIO_TPU_LOGREG_STREAM_MB", "float", 8.0,
          "streamed logreg feature chunk size, MB; <= 0 disables"),
-    Knob("PIO_TPU_ALS_ITEM_WIRE", "str", "auto",
-         "ALS sharded item-factor wire encoding override"),
-    Knob("PIO_TPU_ALS_MESH_WIRE", "str", "auto",
-         "ALS mesh edge wire encoding override"),
     Knob("PIO_TPU_EMBED_PALLAS_OVER_MB", "float", 2048.0,
          "embedding table size above which the Pallas kernel is used"),
     Knob("PIO_TPU_EVAL_APP", "str", "",

@@ -4,7 +4,9 @@ mode, edge cases. Runs on the simulated 8-device CPU mesh (conftest)."""
 import numpy as np
 import pytest
 
-from pio_tpu.models.als import ALSConfig, top_n, train_als
+from pio_tpu.models.als import (
+    ALSConfig, _train_mesh_host_packed, top_n, train_als,
+)
 from pio_tpu.parallel.context import ComputeContext
 
 
@@ -46,58 +48,52 @@ class TestALS:
         # same predictions up to reduction-order float noise
         assert np.abs(pl - pm).max() < 0.05
 
-    def test_mesh_compact_wire_matches_blocked(self, synthetic,
-                                               monkeypatch):
-        """The compact mesh wire (sharded h2d → ICI all-gather → device
+    @pytest.mark.parametrize("implicit", [False, True],
+                             ids=["explicit", "implicit"])
+    def test_mesh_compact_wire_matches_blocked(self, synthetic, implicit):
+        """The mesh route (sharded h2d → ICI all-gather → device
         dual-layout construction) must train BYTE-IDENTICAL factors to
-        the host-packed blocked-f32 shipment — the two paths feed the
-        same shard_map trainer and device_pack is bit-identical to the
-        host packers. Grid ratings make the u4 rating decode exact."""
+        its oracle, the host-packed blocks shipped as they are: the two
+        feed the same shard_map trainer and device_pack is bit-identical
+        to the host packer."""
         s = synthetic
         rng = np.random.default_rng(5)
         r_grid = (rng.integers(1, 11, len(s["u"])) * 0.5).astype(np.float32)
-
-        monkeypatch.setenv("PIO_TPU_ALS_MESH_WIRE", "blocked")
-        st_b = {}
-        f_blocked = train_als(
-            ComputeContext.create(), s["u"], s["i"], r_grid,
-            s["U"], s["I"], CFG, stats=st_b,
-        )
-        assert st_b["encoding"] == "blocked-f32"
-
-        monkeypatch.setenv("PIO_TPU_ALS_MESH_WIRE", "compact")
+        cfg = ALSConfig(rank=8, iterations=12, reg=0.01, implicit=implicit,
+                        alpha=2.0, blocks_per_chunk=64)
+        ctx = ComputeContext.create()
+        f_blocked = _train_mesh_host_packed(
+            ctx, s["u"], s["i"], r_grid, s["U"], s["I"], cfg)
         st_c = {}
         f_compact = train_als(
-            ComputeContext.create(), s["u"], s["i"], r_grid,
-            s["U"], s["I"], CFG, stats=st_c,
-        )
-        assert st_c["encoding"].startswith("u4"), st_c
+            ctx, s["u"], s["i"], r_grid, s["U"], s["I"], cfg, stats=st_c)
+        assert np.isfinite(f_blocked.user_factors).all()
         assert np.array_equal(
             f_blocked.user_factors, f_compact.user_factors
         )
         assert np.array_equal(
             f_blocked.item_factors, f_compact.item_factors
         )
-        # the whole point: the compact wire crosses the host link with a
-        # small fraction of the blocked-f32 bytes
-        assert st_c["wire_bytes"] < st_b["wire_bytes"] / 3, (st_c, st_b)
+        # the edges cross the host link once, 8 B each, beside the two
+        # degree histograms padded to the mesh
+        n_dev = ctx.mesh.shape[ctx.batch_axis]
+        pads = -(-s["U"] // n_dev) * n_dev + -(-s["I"] // n_dev) * n_dev
+        assert st_c["wire_bytes"] == 8 * len(s["u"]) + 4 * pads, st_c
 
     def test_mesh_compact_wire_chunked_stream(self, synthetic,
                                               monkeypatch):
         """PIO_TPU_ALS_STREAM_MB applies to the mesh path too: the
-        encoded wire ships as multiple sharded spans (pipelined puts)
-        and the trainer splices them back — factors stay byte-identical
-        to blocked-f32 and the stats record the per-chunk timings."""
+        edges ship as multiple sharded spans (pipelined puts) and the
+        trainer splices them back: factors stay byte-identical to the
+        oracle and the stats record the per-chunk timings."""
         s = synthetic
         rng = np.random.default_rng(7)
         r_grid = (rng.integers(1, 11, len(s["u"])) * 0.5).astype(np.float32)
 
-        monkeypatch.setenv("PIO_TPU_ALS_MESH_WIRE", "blocked")
-        f_blocked = train_als(
+        f_blocked = _train_mesh_host_packed(
             ComputeContext.create(), s["u"], s["i"], r_grid,
             s["U"], s["I"], CFG,
         )
-        monkeypatch.setenv("PIO_TPU_ALS_MESH_WIRE", "compact")
         monkeypatch.setenv("PIO_TPU_ALS_STREAM_MB", "0.001")  # force chunks
         st = {}
         f_chunked = train_als(
@@ -113,36 +109,31 @@ class TestALS:
             f_blocked.item_factors, f_chunked.item_factors
         )
 
-    def test_mesh_compact_planes_wire_with_high_plane(self, monkeypatch):
-        """Items ≥ 2^16 force the planes wire with a NON-EMPTY high
-        plane — that array rides the sharded put + slice path too and
-        must stay byte-identical to blocked."""
+    def test_mesh_compact_item_ids_over_2_16(self):
+        """Item ids at and over 2^16 ride the sharded put + slice path
+        as what they are and must stay byte-identical to the oracle."""
         rng = np.random.default_rng(11)
         n = 3000
         u = rng.integers(0, 40, n).astype(np.int32)
         i = rng.integers(0, 70_000, n).astype(np.int32)
+        i[:3] = (65_535, 65_536, 69_999)
         r = (rng.integers(1, 11, n) * 0.5).astype(np.float32)
         cfg = ALSConfig(rank=4, iterations=4, reg=0.05,
                         blocks_per_chunk=16)
-        monkeypatch.setenv("PIO_TPU_ALS_MESH_WIRE", "blocked")
-        f_b = train_als(ComputeContext.create(), u, i, r, 40, 70_000, cfg)
-        monkeypatch.setenv("PIO_TPU_ALS_MESH_WIRE", "compact")
-        st = {}
-        f_c = train_als(ComputeContext.create(), u, i, r, 40, 70_000,
-                        cfg, stats=st)
-        assert st["encoding"].endswith("planes"), st
+        f_b = _train_mesh_host_packed(
+            ComputeContext.create(), u, i, r, 40, 70_000, cfg)
+        f_c = train_als(ComputeContext.create(), u, i, r, 40, 70_000, cfg)
         assert np.array_equal(f_b.user_factors, f_c.user_factors)
         assert np.array_equal(f_b.item_factors, f_c.item_factors)
 
-    def test_mesh_compact_delta_overflow(self, monkeypatch):
-        """Within-user item gaps > 4095 exercise the sparse overflow
-        list on the mesh wire; factors must match blocked exactly."""
+    def test_mesh_compact_sparse_adjacencies(self):
+        """A handful of items a user, spread over the whole item range
+        (within-user gaps over 4095); factors must match the oracle
+        exactly."""
         rng = np.random.default_rng(12)
         n_users, n_items = 24, 60_000
         us, its = [], []
         for uu in range(n_users):
-            # a handful of items spread across the full range → most
-            # consecutive gaps exceed 4095
             for ii in range(0, n_items, 7013):
                 us.append(uu)
                 its.append((ii + uu * 311) % n_items)
@@ -151,15 +142,10 @@ class TestALS:
         r = (rng.integers(1, 11, len(u)) * 0.5).astype(np.float32)
         cfg = ALSConfig(rank=4, iterations=3, reg=0.05,
                         blocks_per_chunk=16)
-        monkeypatch.setenv("PIO_TPU_ALS_ITEM_WIRE", "delta12")
-        monkeypatch.setenv("PIO_TPU_ALS_MESH_WIRE", "blocked")
-        f_b = train_als(ComputeContext.create(), u, i, r,
-                        n_users, n_items, cfg)
-        monkeypatch.setenv("PIO_TPU_ALS_MESH_WIRE", "compact")
-        st = {}
+        f_b = _train_mesh_host_packed(
+            ComputeContext.create(), u, i, r, n_users, n_items, cfg)
         f_c = train_als(ComputeContext.create(), u, i, r,
-                        n_users, n_items, cfg, stats=st)
-        assert st["encoding"].endswith("delta12"), st
+                        n_users, n_items, cfg)
         assert np.array_equal(f_b.user_factors, f_c.user_factors)
         assert np.array_equal(f_b.item_factors, f_c.item_factors)
 
@@ -209,40 +195,9 @@ class TestALS:
                 np.array([], np.float32), 5, 5,
             )
 
-    def test_native_packer_matches_numpy(self):
-        """C++ packer (pio_tpu/native/als_pack.cpp) must be bit-identical
-        to the numpy reference layout."""
-        from pio_tpu.models.als import (
-            _f32p, _i32p, _i64p, _native_packer, _pack_blocks, _round_up,
-        )
-
-        native = _native_packer()
-        if native is None:
-            pytest.skip("no native toolchain")
-        rng = np.random.default_rng(11)
-        E, N, W = 50_000, 700, 16
-        ent = rng.integers(0, N, E).astype(np.int32)
-        other = rng.integers(0, 9999, E).astype(np.int32)
-        rat = rng.random(E).astype(np.float32)
-        ref = _pack_blocks(ent, other, rat, N, W, 64)
-        S = ref[0].shape[0]
-        counts = np.zeros(N, np.int64)
-        nb = int(native.als_pack_count(_i32p(ent), E, N, W, _i64p(counts)))
-        assert S == max(64, _round_up(nb, 64))
-        be = np.empty(S, np.int32)
-        bo = np.empty(S * W, np.int32)
-        br = np.empty(S * W, np.float32)
-        native.als_pack_fill(
-            _i32p(ent), _i32p(other), _f32p(rat), E, N, W,
-            _i64p(counts), S, _i32p(be), _i32p(bo), _f32p(br),
-        )
-        assert (be == ref[0]).all()
-        assert (bo.reshape(S, W) == ref[1]).all()
-        assert (br.reshape(S, W) == ref[2]).all()
-
     def test_native_sort_by_entity_matches_numpy(self):
-        """C++ counting sort (the counts wire-format producer) must match
-        numpy's stable argsort exactly."""
+        """C++ counting sort (what makes the user column one repeat of
+        the counts) must match numpy's stable argsort exactly."""
         from pio_tpu.models.als import (
             _f32p, _i32p, _i64p, _native_packer,
         )
@@ -269,8 +224,8 @@ class TestALS:
 
     def test_native_and_numpy_paths_agree_bitwise(self, synthetic,
                                                   monkeypatch):
-        """Single-device training must not depend on which host packer
-        produced the wire format (same stable edge order → same floats)."""
+        """Single-device training must not depend on which host sorter
+        ran (same stable edge order → same floats)."""
         s = synthetic
         f1 = train_als(
             ComputeContext.local(), s["u"], s["i"], s["r"], s["U"], s["I"],
@@ -285,7 +240,7 @@ class TestALS:
         assert (f1.item_factors == f2.item_factors).all()
 
     def test_non_grid_ratings_train(self):
-        """Ratings off the uint8/fp16 grids ride the f32 wire fallback."""
+        """Ratings off every grid (not fp16-exact) train as they are."""
         rng = np.random.default_rng(3)
         E = 400
         u = rng.integers(0, 30, E).astype(np.int32)
@@ -322,26 +277,38 @@ class TestALS:
             assert (np.asarray(got[1]) == ref[1]).all(), (E, N, W)
             assert (np.asarray(got[2]) == ref[2]).all(), (E, N, W)
 
-    def test_wide_id_space_plane_encoding(self):
-        """Entity ids in [2^16, 2^24) ship as uint16+uint8 planes; a
-        mis-widened id would train the wrong rows."""
+    @pytest.mark.parametrize("side", ["user", "item"])
+    @pytest.mark.parametrize("route", ["monolithic", "streamed", "mesh"])
+    def test_wide_id_space(self, route, side, monkeypatch):
+        """Entity ids at and over 2^16 on either side train the rows they
+        name on every route; a mis-widened id would train the wrong
+        rows."""
         rng = np.random.default_rng(5)
-        hi_users = [65_536, 70_000, 99_999]  # beyond the uint16 range
-        u = np.array(hi_users * 40, np.int32)
-        i = rng.integers(0, 8, len(u)).astype(np.int32)
+        wide = [65_536, 70_000, 99_999]  # beyond the uint16 range
+        a = np.array(wide * 40, np.int32)
+        b = rng.integers(0, 8, len(a)).astype(np.int32)
         R = rng.normal(size=(3, 8)).astype(np.float32)
         r = np.array(
-            [R[hi_users.index(uu), ii] for uu, ii in zip(u, i)], np.float32
+            [R[wide.index(aa), bb] for aa, bb in zip(a, b)], np.float32
         )
-        f = train_als(
-            ComputeContext.local(), u, i, r, 100_000, 8,
-            ALSConfig(rank=4, iterations=10, reg=0.05),
-        )
+        u, i, U, I = (a, b, 100_000, 8) if side == "user" \
+            else (b, a, 8, 100_000)
+        if route == "streamed":
+            monkeypatch.setenv("PIO_TPU_ALS_STREAM_MB", "0.0002")
+        ctx = (ComputeContext.create() if route == "mesh"
+               else ComputeContext.local())
+        st = {}
+        f = train_als(ctx, u, i, r, U, I,
+                      ALSConfig(rank=4, iterations=10, reg=0.05), stats=st)
+        assert (st["n_stream"] > 1) == (route == "streamed"), st
         pred = (f.user_factors[u] * f.item_factors[i]).sum(1)
         rmse = float(np.sqrt(np.mean((pred - r) ** 2)))
         assert rmse < 0.1, rmse
-        # untouched rows stay at their tiny init scale
-        assert np.abs(f.user_factors[500]).max() < 0.05
+        # untouched rows: zero on the side solved last, and never more
+        # than the tiny init scale
+        wide_table = f.user_factors if side == "user" else f.item_factors
+        assert np.abs(wide_table[500]).max() < 0.05
+        assert np.abs(wide_table[65_535]).max() < 0.05
 
     def test_numpy_fallback_trains(self, synthetic, monkeypatch):
         monkeypatch.setenv("PIO_TPU_NO_NATIVE", "1")
@@ -364,20 +331,29 @@ class TestALS:
         pred = float(f.user_factors[0] @ f.item_factors[0])
         assert abs(pred - 5.0) < 0.5
 
-    def test_streamed_matches_monolithic(self, synthetic, monkeypatch):
+    @pytest.mark.parametrize("ratings", ["real", "halfstar"])
+    def test_streamed_matches_monolithic(self, synthetic, monkeypatch,
+                                         ratings):
         """The double-buffered chunked shipment must train the same model
         as the single-dispatch path (it differs only in iteration-1
-        accumulation grouping — float reduction order)."""
+        accumulation grouping — float reduction order), on real-valued
+        ratings and on the half-star grid."""
         s = synthetic
+        r = s["r"]
+        if ratings == "halfstar":
+            rng = np.random.default_rng(9)
+            r = (rng.integers(1, 11, len(s["u"])) * 0.5).astype(np.float32)
+        stats = {}
         f_mono = train_als(
-            ComputeContext.local(), s["u"], s["i"], s["r"], s["U"], s["I"],
-            CFG,
+            ComputeContext.local(), s["u"], s["i"], r, s["U"], s["I"],
+            CFG, stats=stats,
         )
+        assert stats["n_stream"] == 1, stats
         # ~KB-scale threshold forces the max 8 stream chunks on this data
         monkeypatch.setenv("PIO_TPU_ALS_STREAM_MB", "0.0005")
         stats = {}
         f_str = train_als(
-            ComputeContext.local(), s["u"], s["i"], s["r"], s["U"], s["I"],
+            ComputeContext.local(), s["u"], s["i"], r, s["U"], s["I"],
             CFG, stats=stats,
         )
         assert stats["n_stream"] > 1, stats
@@ -398,161 +374,102 @@ class TestALS:
         )
         assert stats["n_stream"] == 1, stats
 
-    def test_streamed_u4_ratings(self, synthetic, monkeypatch):
-        """Half-star-grid ratings ride the nibble-packed u4 wire; the
-        decode is exact, so streamed-vs-monolithic differences reduce to
-        reduction-order float noise."""
-        s = synthetic
-        rng = np.random.default_rng(9)
-        r_grid = (rng.integers(1, 11, len(s["u"])) * 0.5).astype(np.float32)
-        stats = {}
-        f_mono = train_als(
-            ComputeContext.local(), s["u"], s["i"], r_grid, s["U"], s["I"],
-            CFG, stats=stats,
-        )
-        assert stats["encoding"].startswith("u4"), stats
-        monkeypatch.setenv("PIO_TPU_ALS_STREAM_MB", "0.0005")
-        stats2 = {}
-        f_str = train_als(
-            ComputeContext.local(), s["u"], s["i"], r_grid, s["U"], s["I"],
-            CFG, stats=stats2,
-        )
-        assert stats2["n_stream"] > 1
-        assert stats2["encoding"].startswith("u4")
-        # the two paths saw identical decoded floats (u4 is exact), so
-        # they may differ only by reduction-order noise
-        pm = f_mono.user_factors @ f_mono.item_factors.T
-        ps = f_str.user_factors @ f_str.item_factors.T
-        assert np.abs(pm - ps).max() < 0.05
-
-    def test_delta_item_wire_roundtrip(self):
-        """The 12-bit delta item wire must reproduce ids EXACTLY (numpy
-        reference of the device decode, overflow gaps included)."""
-        from pio_tpu.models.als import _encode_items_delta
-
-        rng = np.random.default_rng(3)
-        # segmented ids with deliberate >4095 gaps and duplicate items
-        counts = np.array([0, 5, 0, 3, 1, 7, 0], np.int64)
-        ids = []
-        for c in counts:
-            row = np.sort(rng.integers(0, 60000, c))
-            ids.extend(row.tolist())
-        ids = np.array(ids, np.int32)
-        d_lo, d_hi, ovf_idx, ovf_val, nbytes = _encode_items_delta(
-            ids, counts
-        )
-        assert nbytes == d_lo.nbytes + d_hi.nbytes + ovf_idx.nbytes \
-            + ovf_val.nbytes
-        # numpy mirror of _make_math.decode_items("delta12")
-        E = len(ids)
-        hi = np.stack([d_hi & 0xF, d_hi >> 4], 1).reshape(-1)[:E]
-        delta = d_lo.astype(np.uint32) | (hi.astype(np.uint32) << 8)
-        delta[ovf_idx] += ovf_val.astype(np.uint32) << 12
-        G = np.cumsum(delta, dtype=np.uint32)
-        cnt = counts[counts > 0]
-        starts = np.zeros(len(cnt), np.int64)
-        np.cumsum(cnt[:-1], out=starts[1:])
-        prev = np.zeros(E, np.uint32)
-        es = np.repeat(np.where(starts > 0, G[starts - 1], 0), cnt)
-        got = (G - es).astype(np.int32)
-        assert (got == ids).all()
-
-    def test_item_wire_formats_agree_bitwise(self, synthetic, monkeypatch):
-        """delta12 decode is integer-exact, so forcing planes vs delta12
-        must give BITWISE identical factors (same sorted edge order →
-        same floats through the same math)."""
-        s = synthetic
-        outs = {}
-        for wire in ("planes", "delta12"):
-            monkeypatch.setenv("PIO_TPU_ALS_ITEM_WIRE", wire)
-            outs[wire] = train_als(
-                ComputeContext.local(), s["u"], s["i"], s["r"],
-                s["U"], s["I"], CFG,
-            )
-        assert (outs["planes"].user_factors
-                == outs["delta12"].user_factors).all()
-        assert (outs["planes"].item_factors
-                == outs["delta12"].item_factors).all()
-
-    def test_item_wire_formats_agree_streamed(self, synthetic,
-                                              monkeypatch):
-        """Same bitwise equality through the chunked stream path (the
-        delta wire restarts gap chains at chunk boundaries)."""
-        s = synthetic
-        monkeypatch.setenv("PIO_TPU_ALS_STREAM_MB", "0.0005")
-        outs = {}
-        for wire in ("planes", "delta12"):
-            monkeypatch.setenv("PIO_TPU_ALS_ITEM_WIRE", wire)
-            st = {}
-            outs[wire] = train_als(
-                ComputeContext.local(), s["u"], s["i"], s["r"],
-                s["U"], s["I"], CFG, stats=st,
-            )
-            assert st["n_stream"] > 1
-        assert (outs["planes"].user_factors
-                == outs["delta12"].user_factors).all()
-        assert (outs["planes"].item_factors
-                == outs["delta12"].item_factors).all()
-
-    def test_streamed_delta_overflow_and_chunk_carry(self, monkeypatch):
-        """Sparse adjacencies over a wide item space: deltas overflow the
-        12-bit field (sparse overflow list) AND chunk boundaries split
-        users mid-adjacency (the first in-chunk edge ships its ABSOLUTE
-        id, itself often an overflow). Streamed delta12 must still match
-        planes bitwise."""
-        from pio_tpu.models.als import _delta_wire_size
+    def test_streamed_chunk_carry(self, monkeypatch):
+        """Sparse adjacencies over a wide item space, cut into chunks
+        whose bounds split users mid-adjacency (such a user is in both
+        chunks' local counts with its share of each): streamed must
+        still train what the monolithic path trains."""
+        from pio_tpu.models.als import _edge_spans
 
         rng = np.random.default_rng(17)
         U, I, E = 25, 50_000, 1_200
         u = np.sort(rng.integers(0, U, E)).astype(np.int32)
-        i = rng.integers(0, I, E).astype(np.int32)  # mean gap ~2k, tail >4095
+        i = rng.integers(0, I, E).astype(np.int32)
         r = (rng.integers(1, 11, E) * 0.5).astype(np.float32)
-        # sanity: this workload really produces overflow entries
-        order = np.lexsort((i, u))
-        counts = np.bincount(u, minlength=U).astype(np.int64)
-        _, n_ovf = _delta_wire_size(
-            np.ascontiguousarray(i[order]), counts
-        )
-        assert n_ovf > 0, "fixture must exercise the overflow list"
+        # sanity: the cuts really fall inside adjacencies
+        starts = np.concatenate([[0], np.cumsum(np.bincount(u, minlength=U))])
+        cuts = [e0 for e0, _ in _edge_spans(E, 8)][1:]
+        assert len(cuts) == 7 and not set(cuts) & set(starts.tolist()), cuts
 
         cfg = ALSConfig(rank=4, iterations=5, reg=0.1, blocks_per_chunk=16)
+        f_mono = train_als(ComputeContext.local(), u, i, r, U, I, cfg)
         monkeypatch.setenv("PIO_TPU_ALS_STREAM_MB", "0.0002")  # many chunks
-        outs = {}
-        for wire in ("planes", "delta12"):
-            monkeypatch.setenv("PIO_TPU_ALS_ITEM_WIRE", wire)
-            st = {}
-            outs[wire] = train_als(
-                ComputeContext.local(), u, i, r, U, I, cfg, stats=st
-            )
-            assert st["n_stream"] > 1, st
-        assert (outs["planes"].user_factors
-                == outs["delta12"].user_factors).all()
-        assert (outs["planes"].item_factors
-                == outs["delta12"].item_factors).all()
+        st = {}
+        f_str = train_als(ComputeContext.local(), u, i, r, U, I, cfg,
+                          stats=st)
+        assert st["n_stream"] == 8, st
+        # the same sums in another grouping of iteration 1: rounding alone
+        for got, want in ((f_str.user_factors, f_mono.user_factors),
+                          (f_str.item_factors, f_mono.item_factors)):
+            assert np.abs(got - want).max() <= 1e-4 * np.abs(want).max()
 
-    def test_native_delta_encoder_matches_numpy(self, monkeypatch):
-        """The C++ delta encoder must be bit-identical to the numpy
-        reference (wire format parity, overflow entries included)."""
-        from pio_tpu.models.als import (
-            _delta_wire_size, _encode_items_delta, _native_packer,
-        )
+    def test_chunk_rule(self):
+        """The streamed feed's chunk count and cuts as a function of the
+        edge count alone: MovieLens-25M's 25,000,095 edges go in 7 chunks
+        on the cuts the benchmark's programs were compiled for, and what
+        fits one chunk of 30 MiB is not streamed."""
+        from pio_tpu.models.als import _EDGE_BYTES, _edge_spans
+        from pio_tpu.parallel.stream import n_stream_chunks
 
-        if _native_packer() is None:
-            pytest.skip("no native toolchain")
-        rng = np.random.default_rng(12)
-        counts = rng.integers(0, 40, 300).astype(np.int64)
-        ids = np.concatenate([
-            np.sort(rng.integers(0, 60000, c)) for c in counts
-        ]).astype(np.int32)
-        got_native = _encode_items_delta(ids, counts)
-        nb_native, novf_native = _delta_wire_size(ids, counts)
-        monkeypatch.setenv("PIO_TPU_NO_NATIVE", "1")
-        got_numpy = _encode_items_delta(ids, counts)
-        nb_numpy, novf_numpy = _delta_wire_size(ids, counts)
-        assert nb_native == nb_numpy == got_native[4]
-        assert novf_native == novf_numpy == len(got_native[2])
-        for a, b in zip(got_native[:4], got_numpy[:4]):
-            assert a.dtype == b.dtype and (a == b).all()
+        def chunks(n_edges):
+            return n_stream_chunks(_EDGE_BYTES * n_edges,
+                                   "PIO_TPU_ALS_STREAM_MB")
+
+        E = 25_000_095
+        assert chunks(E) == 7
+        assert chunks(3_900_000) == 1
+        assert chunks(10 ** 9) == 8  # the cap
+        cuts = [0, 3_571_442, 7_142_884, 10_714_326, 14_285_768,
+                17_857_210, 21_428_652, E]
+        assert _edge_spans(E, 7) == list(zip(cuts[:-1], cuts[1:]))
+        # more chunks than edges: the empty spans are dropped
+        assert _edge_spans(3, 8) == [(0, 2), (2, 3)]
+
+    @pytest.mark.parametrize("route", ["monolithic", "streamed", "mesh"])
+    def test_rating_values_pick_no_program(self, synthetic, route,
+                                           fresh_trainers, monkeypatch):
+        """Half-star and then off-grid ratings of one graph run the same
+        compiled programs: the data's values are no static argument."""
+        s = synthetic
+        rng = np.random.default_rng(9)
+        halfstar = (rng.integers(1, 11, len(s["u"])) * 0.5).astype(np.float32)
+        offgrid = (rng.random(len(s["u"])) * 3.7 + 0.123).astype(np.float32)
+        seen = _watch_programs(monkeypatch)
+        if route == "streamed":
+            monkeypatch.setenv("PIO_TPU_ALS_STREAM_MB", "0.0005")
+        ctx = (ComputeContext.create() if route == "mesh"
+               else ComputeContext.local())
+        train_als(ctx, s["u"], s["i"], halfstar, s["U"], s["I"], CFG)
+        first = seen()
+        assert first["programs"] > 0, first
+        train_als(ctx, s["u"], s["i"], offgrid, s["U"], s["I"], CFG)
+        assert seen() == first
+
+    @pytest.mark.parametrize("route", ["monolithic", "streamed"])
+    def test_ids_pick_no_program(self, route, fresh_trainers, monkeypatch):
+        """Two graphs with the same two degree sequences and different
+        ids (pairs of edges exchange their items) share every compiled
+        program: no compiled shape depends on the ids."""
+        rng = np.random.default_rng(23)
+        U, I, E = 25, 50_000, 1_200
+        u = np.sort(rng.integers(0, U, E)).astype(np.int32)
+        i = rng.integers(0, I, E).astype(np.int32)
+        r = (rng.integers(1, 11, E) * 0.5).astype(np.float32)
+        i2 = i.copy()
+        pairs = rng.permutation(E).reshape(-1, 2)
+        i2[pairs[:, 0]], i2[pairs[:, 1]] = i[pairs[:, 1]], i[pairs[:, 0]]
+        assert (i2 != i).mean() > 0.9
+        assert (np.bincount(i2, minlength=I) == np.bincount(i, minlength=I)).all()
+
+        cfg = ALSConfig(rank=4, iterations=3, reg=0.1, blocks_per_chunk=16)
+        seen = _watch_programs(monkeypatch)
+        if route == "streamed":
+            monkeypatch.setenv("PIO_TPU_ALS_STREAM_MB", "0.0002")
+        train_als(ComputeContext.local(), u, i, r, U, I, cfg)
+        first = seen()
+        assert first["programs"] > 0, first
+        train_als(ComputeContext.local(), u, i2, r, U, I, cfg)
+        assert seen() == first
 
     def test_native_within_entity_sort_matches_lexsort(self):
         """The native (user, item) two-pass sort must equal numpy's
@@ -584,34 +501,22 @@ class TestALS:
         assert (i_s == i[order]).all()
         assert (r_s == r[order]).all()
 
-    def test_nibble_roundtrip(self):
-        from pio_tpu.models.als import _encode_ratings, _nibble_pack
-
-        codes = np.array([1, 10, 7, 15, 0, 3, 9], np.uint8)  # odd length
-        packed = _nibble_pack(codes)
-        assert packed.shape == (4,)
-        lo, hi = packed & 0xF, packed >> 4
-        inter = np.stack([lo, hi], 1).reshape(-1)[: len(codes)]
-        assert (inter == codes).all()
-        wire, kind = _encode_ratings(codes.astype(np.float32) * 0.5)
-        assert kind == "u4" and (wire == packed).all()
-        # beyond the nibble range → u8; off-grid → f16/f32
-        assert _encode_ratings(np.array([8.5], np.float32))[1] == "u8"
-        assert _encode_ratings(np.array([0.123], np.float32))[1] in (
-            "f16", "f32"
-        )
-
     def test_stats_phases(self, synthetic):
         """Profiling mode fills the per-phase breakdown on every path."""
         s = synthetic
-        for ctx in (ComputeContext.local(), ComputeContext.create()):
+        for ctx, n_dev in ((ComputeContext.local(), 1),
+                           (ComputeContext.create(), 8)):
             st = {}
             train_als(ctx, s["u"], s["i"], s["r"], s["U"], s["I"], CFG,
                       stats=st)
             for k in ("pack_s", "wire_bytes", "h2d_s", "device_s",
-                      "n_stream", "encoding"):
+                      "n_stream"):
                 assert k in st, (k, st)
-            assert st["wire_bytes"] > 0 and st["device_s"] > 0
+            assert "encoding" not in st
+            # 8 B an edge and the two degree histograms, whatever the data
+            pads = -(-s["U"] // n_dev) * n_dev + -(-s["I"] // n_dev) * n_dev
+            assert st["wire_bytes"] == 8 * len(s["u"]) + 4 * pads, st
+            assert st["device_s"] > 0
 
     def test_entity_counts_not_multiple_of_mesh(self, synthetic):
         # 7 users, 3 items on an 8-device mesh exercises entity padding
@@ -653,6 +558,36 @@ def _both_cg(A, b, gram, implicit, reg=0.1):
         lambda A, b, r: als._cg_solve_resident(A, b, r, interpret=True)
     )(A, b, reg_kk))
     return want, got
+
+
+def _watch_programs(monkeypatch):
+    """``seen()`` → how many trainers were built (the builders' cache
+    misses) and how many programs their jitted functions hold compiled,
+    over every trainer ``train_als`` is handed from now on."""
+    import jax
+
+    from pio_tpu.models import als
+
+    builders = [als._build_trainer, als._build_stream_trainer]
+    built = []
+    for builder in builders:
+        def watched(*args, _builder=builder, **kwargs):
+            out = _builder(*args, **kwargs)
+            if not any(out is b for b in built):
+                built.append(out)
+            return out
+
+        watched.cache_clear = builder.cache_clear
+        monkeypatch.setattr(als, builder.__name__, watched)
+
+    def seen():
+        return {
+            "trainers": sum(b.cache_info().misses for b in builders),
+            "programs": sum(
+                j._cache_size() for j in jax.tree_util.tree_leaves(built)),
+        }
+
+    return seen
 
 
 @pytest.fixture

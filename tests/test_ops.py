@@ -1,7 +1,7 @@
 """Pallas ops tests — run on CPU via interpret mode (conftest pins cpu).
 
-The TPU-compiled path is exercised by bench.py and the driver's real-chip
-runs; here the same kernel body runs under the Pallas interpreter and must
+The TPU-compiled path is exercised by chip_smoke.py and the driver's
+real-chip runs; here the same kernel body runs under the Pallas interpreter and must
 match the XLA fallback bit-for-bit-ish (f32 tolerances).
 """
 

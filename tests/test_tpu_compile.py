@@ -71,7 +71,7 @@ def test_stream_finalize_gathers_from_vmem_on_v5e(one_chip, monkeypatch):
     def sd(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    f32, i32, u8 = jnp.float32, jnp.int32, jnp.uint8
+    f32, i32 = jnp.float32, jnp.int32
     span = U // n_stream
     spec = tuple((S_c, min(U - 1, (c + 1) * span), c * span)
                  for c in range(n_stream))
@@ -81,16 +81,13 @@ def test_stream_finalize_gathers_from_vmem_on_v5e(one_chip, monkeypatch):
     try:
         _, _, finalize = als._build_stream_trainer(
             10, 0.1, False, 1.0, "bfloat16", "auto", K, U, I, W, W, S_item,
-            chunk, chunk, "u4", "delta12", spec)
+            chunk, chunk, spec)
         blocks = tuple((sd((S_c,), i32), sd((S_c, W), i32),
                         sd((S_c, W), f32)) for _ in spec)
-        half = (E_c + 1) // 2
-        wire = tuple((sd((E_c,), u8), sd((half,), u8), sd((1000,), i32),
-                      sd((1000,), u8), sd((half,), u8)) for _ in spec)
-        counts = tuple(sd((pad - u0 + 1,), i32) for _, pad, u0 in spec)
+        edges = tuple((sd((E_c,), i32), sd((E_c,), f32)) for _ in spec)
         compiled = finalize.lower(
             sd((U, K, K), f32), sd((U, K), f32), sd((I, K), f32),
-            sd((U,), i32), sd((I,), i32), blocks, wire, counts).compile()
+            sd((U,), i32), sd((I,), i32), blocks, edges).compile()
     finally:
         als._build_stream_trainer.cache_clear()
 

@@ -5,7 +5,7 @@ balance across workers, answers match the single-process server, /reload
 rolls every worker via the shared generation counter, and /undeploy
 brings the whole pool down. Perf (the pool's reason to exist) needs a
 multi-core host — this environment pins to ONE core, so QPS claims live
-in bench.py/BASELINE.md, not here.
+in BASELINE.md, not here.
 """
 
 import datetime as dt
