@@ -23,7 +23,9 @@ every half-iteration. This module is the TPU-first re-design:
   followed by a ``segment_sum`` of the ~E/width block partials onto entities
   with ``indices_are_sorted=True`` — the scatter is over blocks, not edges,
   so the VPU-hostile part shrinks by the block width while the FLOPs ride
-  the systolic array.
+  the systolic array. On a TPU, at rank 64 and width 64, both are one
+  Pallas kernel (``_accum_fused``): the block products never leave VMEM
+  and each entity's sum is written once.
 - Cross-device combine is ``psum_scatter`` (reduce-scatter) over the entity
   dimension: each device sums partial normal equations from its block shard,
   receives 1/D of the entities, solves its slice with a batched
@@ -78,7 +80,9 @@ class ALSConfig:
     #: edges per dense block; None → power of two near half the mean degree
     #: (bounds padding waste at ~width/2 per entity)
     block_width: Optional[int] = None
-    #: blocks per scan step — bounds the [chunk, width, K] HBM intermediate
+    #: blocks per scan step — bounds the gathered ``[chunk, width, K]`` rows
+    #: and, on XLA's path, the ``[chunk, K, K]`` block products behind them
+    #: (the fused kernel keeps those in VMEM: _accum_impl)
     blocks_per_chunk: int = 4096
     #: dtype for the factor gather + normal-equation matmuls. "auto"
     #: picks bfloat16 on accelerator backends — the MXU's native rate,
@@ -424,13 +428,269 @@ def _cg_solve_resident(A, b, reg, interpret: bool = False):
     return x_t.T
 
 
+#: blocks per grid step of the fused accumulation kernel: a
+#: ``[_ACCUM_TILE * width, K]`` operand tile in flight twice (the pipeline)
+#: and half as many ``[K + 16, 128]`` products waiting in VMEM
+_ACCUM_TILE = 64
+#: finished entity records on their way to HBM at once (the staging ring)
+_ACCUM_RING = 8
+#: the rank and block width :func:`_accum_fused` is written for: two
+#: blocks' ``K``-wide products fill the ``_LANES`` lanes side by side, and
+#: two blocks' slots are one lane row of weights
+_ACCUM_RANK = 64
+_ACCUM_WIDTH = 64
+
+
+def _accum_impl(platform: str, rank: int, width: int, itemsize: int) -> str:
+    """Which way ``partial_normal_eq`` turns a chunk's gathered rows into
+    its entities' normal equations, from what is visible at trace time:
+    ``fused`` / ``xla``. ``fused`` is the Pallas kernel
+    (:func:`_accum_fused`): on a TPU, for bfloat16 operands, at the rank
+    and block width it tiles (``_ACCUM_RANK``, ``_ACCUM_WIDTH``: what
+    MovieLens-25M at rank 64 has on both sides, and the one shape measured
+    on a v5e), alone on a device or under ``shard_map``. ``xla`` is the
+    batched einsum and the ``segment_sum`` through HBM: everywhere else
+    (CPU, ranks 10, 16 and 128, float32 operands), and the kernel's
+    oracle."""
+    tiles = (rank == _ACCUM_RANK and width == _ACCUM_WIDTH
+             and itemsize == 2)
+    return "fused" if platform == "tpu" and tiles else "xla"
+
+
+def _accum_fused(AB, acc, held, ent, q, w, rhs, interpret: bool = False):
+    """One chunk's contribution to the normal equations as one Pallas TPU
+    kernel: the per-block products ``(q·w)ᵀ q`` and ``rhsᵀ q`` and their
+    per-entity sums stay in VMEM; what crosses HBM is the gathered rows
+    in and one finished record per entity out (the XLA path writes every
+    block's ``K×K`` product, 64 MB a chunk at rank 64, and scatter-adds
+    them back row by row).
+
+    ``AB [n, K/2 + 8, 128]`` stays in HBM and is aliased in and out, so a
+    scan's carry is updated in place. An entity's record is lane-dense:
+    row ``r < K/2`` holds rows ``2r`` and ``2r + 1`` of its ``K×K`` sum
+    side by side (the row-major matrix, so that entity-minor it IS the
+    solve kernel's operand), row ``K/2`` its ``K`` vector
+    (:func:`_accum_unpack`; ``f32[n, 64, 64]`` pads every row to 128
+    lanes on a TPU, twice the bytes). ``ent [chunk]`` (ascending) rides
+    in SMEM; ``q [chunk, W, K]``, ``w`` and ``rhs [chunk, W]`` come in
+    tiles of ``_ACCUM_TILE`` blocks.
+
+    Blocks go in pairs: the pair's 128 gathered rows, transposed, are the
+    lanes of one ``[K, 128]`` operand; times the weights' lane row, over
+    a row of ``rhs``, against the block-diagonal of the same two blocks it
+    is ONE full-width MXU matmul (float32 accumulation) whose result is
+    the two blocks' ``[K + 1, K]`` products side by side. Then the pairs
+    are walked in order: while ``ent`` is the entity in hand the pair is
+    added to the running sum ``acc [K + 16, 128]`` (even blocks left, odd
+    blocks right: the halves are added when the entity is finished), when
+    it changes the finished record goes to ``AB[entity]`` by an
+    asynchronous copy from a ring of ``_ACCUM_RING`` staging slots. Every
+    entity is written once and never read: the sum still open when the
+    chunk ends is handed on (``acc``, ``held [1]``) and comes back with
+    the next chunk, so an entity whose blocks straddle chunks sums in one
+    piece, blocks in ascending order; the caller starts from
+    :func:`_accum_init` and adds the last one after its scan
+    (:func:`_accum_unpack`). Records that no block names are not
+    touched."""
+    import jax.numpy as jnp
+
+    C, W, K = q.shape
+    assert (K, W) == (_ACCUM_RANK, _ACCUM_WIDTH), (K, W)
+    if C % 16:
+        # a chunk the tiles do not divide (a small input, an odd
+        # ``blocks_per_chunk``): blocks of weight 0 on the last entity
+        more = (0, -C % 16)
+        ent = jnp.pad(ent, more, mode="edge")
+        q = jnp.pad(q, (more, (0, 0), (0, 0)))
+        w, rhs = jnp.pad(w, (more, (0, 0))), jnp.pad(rhs, (more, (0, 0)))
+        C = q.shape[0]
+    call = _accum_call(AB.shape[0], C, W, K, q.dtype.name, interpret)
+    return call(ent, held, q.reshape(C * W, K), w.reshape(C // 2, 2 * W),
+                rhs.reshape(C // 2, 2 * W), acc, AB)
+
+
+@functools.lru_cache(maxsize=16)
+def _accum_call(n: int, C: int, W: int, K: int, dtype: str, interpret: bool):
+    """:func:`_accum_fused`'s ``pallas_call`` for one set of shapes, built
+    once: ``pallas_call`` hands back a jitted function that traces its
+    kernel whenever it is new, and a trainer calls the kernel from ten
+    programs at two shapes (a trace of its unrolled pairs is 0.5 s of
+    set-up on the chip's host)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    R, L, H = K + 16, 2 * K, K // 2  # product rows, lanes, record rows of A
+    T = next(t for t in (_ACCUM_TILE, 32, 16) if C % t == 0)
+    ring = _ACCUM_RING
+    dt = jnp.dtype(dtype)
+    f32 = jnp.float32
+
+    def kernel(ent_ref, held_ref, q_ref, w_ref, rhs_ref, acc_in, _AB_in,
+               AB_ref, acc_out, held_out, P_ref, acc_ref, stage_ref, sem,
+               state):
+        i = pl.program_id(0)
+        # lanes of the even block of a pair (a sliced mask does not compile)
+        left, left_k, left_h = (
+            jax.lax.broadcasted_iota(jnp.int32, (rows, L), 1) < K
+            for rows in (R, K, H))
+
+        @pl.when(i == 0)
+        def _():
+            acc_ref[...] = acc_in[...]
+            state[0] = held_ref[0]
+            state[1] = 0
+
+        def copy(slot, e):
+            return pltpu.make_async_copy(
+                stage_ref.at[slot], AB_ref.at[e], sem.at[slot])
+
+        def flush():
+            """The finished sum → its record, on its way to ``AB``."""
+            done = state[1]
+            slot = done % ring
+
+            @pl.when(done >= ring)
+            def _():
+                copy(slot, 0).wait()
+
+            # even + odd blocks (the lane halves), two matrix rows a lane
+            # row: the row-major [K, K] as [K/2, 128]
+            both = lambda x: x + pltpu.roll(x, K, 1)
+            stage_ref[slot, :H] = jnp.where(
+                left_h, both(acc_ref[pl.ds(0, H, stride=2), :]),
+                both(acc_ref[pl.ds(1, H, stride=2), :]))
+            stage_ref[slot, H:] = both(acc_ref[K:K + 8, :])
+            copy(slot, state[0]).start()
+            state[1] = done + 1
+
+        # every pair's products, branch-free: [P_even | P_odd], rows :K
+        # the matrices, row K the vectors, the rest repeat it (the
+        # operand's sublane tile). Unrolled: the pairs are independent and
+        # the scheduler overlaps them (as a loop, 2.5 times the kernel)
+        for p in range(T // 2):
+            xt = q_ref[p * 2 * W:(p + 1) * 2 * W, :].T  # [K, 2W]
+            # the weight multiplied in the operands' dtype, as XLA's path
+            # does it: the product of two such numbers is exact in float32
+            w_row = w_ref[p:p + 1, :].astype(dt).astype(f32)
+            lhs = jnp.concatenate([
+                xt.astype(f32) * w_row,
+                jnp.broadcast_to(rhs_ref[p:p + 1, :], (R - K, L)),
+            ], axis=0).astype(dt)
+            zero = jnp.zeros_like(xt)
+            diag_t = jnp.concatenate([
+                jnp.where(left_k, xt, zero), jnp.where(left_k, zero, xt),
+            ], axis=0)  # the transposed block-diagonal of the pair's rows
+            P_ref[p] = jax.lax.dot_general(
+                lhs, diag_t, (((1,), (1,)), ((), ())),
+                preferred_element_type=f32)
+
+        def take(e, part):
+            """One block's product (its half of the lanes) into the sum."""
+            @pl.when(e == state[0])
+            def _():
+                acc_ref[...] += part
+
+            @pl.when(e != state[0])
+            def _():
+                flush()
+                acc_ref[...] = part
+                state[0] = e
+
+        def pair(p, _):
+            e0 = ent_ref[i * T + 2 * p]
+            e1 = ent_ref[i * T + 2 * p + 1]
+            same = jnp.logical_and(e0 == state[0], e1 == state[0])
+
+            @pl.when(same)
+            def _():
+                acc_ref[...] += P_ref[p]
+
+            @pl.when(jnp.logical_not(same))
+            def _():
+                both = P_ref[p]
+                take(e0, jnp.where(left, both, 0.0))
+                take(e1, jnp.where(left, 0.0, both))
+
+            return 0
+
+        jax.lax.fori_loop(0, T // 2, pair, 0)
+
+        @pl.when(i == pl.num_programs(0) - 1)
+        def _():
+            acc_out[...] = acc_ref[...]
+            held_out[0] = state[0]
+            for slot in range(ring):
+                @pl.when(slot < state[1])
+                def _():
+                    copy(slot, 0).wait()
+
+    tile = lambda rows, cols: pl.BlockSpec((rows, cols), lambda i, *_: (i, 0))
+    whole = pl.BlockSpec((R, L), lambda i, *_: (0, 0))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(C // T,),
+            in_specs=[tile(T * W, K), tile(T // 2, L), tile(T // 2, L),
+                      whole, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec(memory_space=pl.ANY), whole,
+                       pl.BlockSpec(memory_space=pltpu.SMEM)],
+            scratch_shapes=[
+                pltpu.VMEM((T // 2, R, L), f32),
+                pltpu.VMEM((R, L), f32),
+                pltpu.VMEM((ring, H + 8, L), f32),
+                pltpu.SemaphoreType.DMA((ring,)),
+                pltpu.SMEM((2,), jnp.int32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((n, H + 8, L), f32),
+            jax.ShapeDtypeStruct((R, L), f32),
+            jax.ShapeDtypeStruct((1,), jnp.int32),
+        ],
+        # operand numbers count the two scalar-prefetch arguments
+        input_output_aliases={6: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="als_accum_fused",
+    )
+
+
+def _accum_init(n_entities: int, K: int, block_ent):
+    """:func:`_accum_fused`'s carry before a scan's first chunk: empty
+    records, an empty sum, and the first block's entity to hold it."""
+    import jax.numpy as jnp
+
+    return (jnp.zeros((n_entities, K // 2 + 8, 2 * K), jnp.float32),
+            jnp.zeros((K + 16, 2 * K), jnp.float32), block_ent[:1])
+
+
+def _accum_unpack(AB, acc, held):
+    """``(A [n, K, K], b [n, K])`` of :func:`_accum_fused`'s records, the
+    sum it left open added in."""
+    n, _, L = AB.shape
+    K = L // 2
+    H = K // 2
+    A = AB[:, :H].reshape(n, K, K)
+    b = AB[:, H, :K]
+    last = acc[:, :K] + acc[:, K:]
+    return A.at[held[0]].add(last[:K]), b.at[held[0]].add(last[K])
+
+
 def _make_math(reg: float, implicit: bool, alpha: float,
                matmul_dtype: str, solver: str):
     """Shared jittable ALS math: blocked normal-equation accumulation and
     the batched solvers. Closed over the static config and used by BOTH
     the monolithic trainer (:func:`_build_trainer`) and the streamed
     trainer (:func:`_build_stream_trainer`) so the two paths cannot drift
-    apart numerically."""
+    apart numerically. Three rules, each read when a trainer is traced,
+    pick between an XLA form and a TPU form of one algorithm:
+    :func:`_gather_impl` (the factor table's layout), :func:`_accum_impl`
+    (the block products and their per-entity sums) and :func:`_solve_impl`
+    (the solver)."""
     import types
 
     import jax
@@ -443,7 +703,18 @@ def _make_math(reg: float, implicit: bool, alpha: float,
     @jax.named_scope("als.normal_eq")
     def partial_normal_eq(block_ent, block_other, block_r, factors,
                           n_entities, chunk, varying_axis=None):
-        """Blocked scan: Σ w·q qᵀ and Σ rhs·q per entity (one shard)."""
+        """Blocked scan: Σ w·q qᵀ and Σ rhs·q per entity (one shard).
+
+        A scan step gathers a chunk's factor rows ``[chunk, W, K]`` (the
+        one intermediate that crosses HBM whatever the path, unless the
+        compiler keeps it in VMEM) and adds the chunk to the carry: on
+        XLA's path a batched einsum writes every block's ``K×K`` product
+        and a ``segment_sum`` adds them into ``A [n, K, K]``; on the
+        fused path (:func:`_accum_impl`) one kernel multiplies and sums
+        in VMEM and writes each finished entity's lane-dense record, the
+        carry is ``[n, K/2 + 8, 128]`` and ``A``, ``b`` are cut from it
+        after the scan (:func:`_accum_unpack`). Same sums, the blocks of
+        an entity in ascending order on both."""
         K = factors.shape[1]
         # cast ONCE per half-step: the scan then gathers from the low-
         # precision table (half the HBM traffic) and the einsums hit the
@@ -455,8 +726,14 @@ def _make_math(reg: float, implicit: bool, alpha: float,
             with jax.named_scope("gather"):
                 factors_mm = _pack_table(factors_mm)
 
+        # ...and so is the way the rows become normal equations (the
+        # rule: _accum_impl); off a TPU only a test's steering picks the
+        # kernel, and it is interpreted
+        backend = jax.default_backend()
+        fused = _accum_impl(backend, K, block_other.shape[1],
+                            mm_dtype.itemsize) == "fused"
+
         def chunk_step(carry, ch):
-            A, b = carry
             ent, other, r_c = ch
             # padded slots are other == -1; validity derives from the sign
             m_c = (other >= 0).astype(jnp.float32)
@@ -470,6 +747,12 @@ def _make_math(reg: float, implicit: bool, alpha: float,
             else:
                 w = m_c
                 rhs = r_c * m_c
+            if fused:
+                # products and per-entity sums in VMEM: one kernel
+                with jax.named_scope("segment_sum"):
+                    return _accum_fused(*carry, ent, q, w, rhs,
+                                        interpret=backend != "tpu"), None
+            A, b = carry
             # batched MXU matmul: [chunk, K, W] @ [chunk, W, K], f32 acc
             with jax.named_scope("outer"):
                 A_blk = jnp.einsum(
@@ -497,17 +780,20 @@ def _make_math(reg: float, implicit: bool, alpha: float,
             x.reshape(n_chunks, chunk, *x.shape[1:])
             for x in (block_ent, block_other, block_r)
         )
-        A0 = jnp.zeros((n_entities, K, K), jnp.float32)
-        b0 = jnp.zeros((n_entities, K), jnp.float32)
+        if fused:
+            init = _accum_init(n_entities, K, block_ent)
+        else:
+            init = (jnp.zeros((n_entities, K, K), jnp.float32),
+                    jnp.zeros((n_entities, K), jnp.float32))
         if varying_axis is not None:
             # Inside shard_map the carry becomes device-varying after the
             # first chunk; mark the zeros accordingly so scan types match.
             from jax.lax import pcast
 
-            A0 = pcast(A0, (varying_axis,), to="varying")
-            b0 = pcast(b0, (varying_axis,), to="varying")
-        (A, b), _ = jax.lax.scan(chunk_step, (A0, b0), chunks)
-        return A, b
+            init = tuple(
+                pcast(x, (varying_axis,), to="varying") for x in init)
+        carry, _ = jax.lax.scan(chunk_step, init, chunks)
+        return _accum_unpack(*carry) if fused else carry
 
     @jax.named_scope("cg")
     def _cg_solve(A, b):
@@ -1271,8 +1557,10 @@ def train_als(
     ``stats``, when a dict, is filled with a per-phase breakdown —
     ``{pack_s, wire_bytes, n_stream, h2d_s, device_s}`` and
     ``solve_impl`` (``{"user", "item"}``: which solver each side's batch
-    gets, :func:`_solve_impl`) and ``gather_impl`` (which table layout each
-    half-step gathers the other side's rows from, :func:`_gather_impl`) — by
+    gets, :func:`_solve_impl`), ``gather_impl`` (which table layout each
+    half-step gathers the other side's rows from, :func:`_gather_impl`) and
+    ``accum_impl`` (``fused`` / ``xla``: whether the Pallas kernel sums each
+    half-step's normal equations, :func:`_accum_impl`) — by
     BLOCKING between the host-pack / host→device / device-compute phases.
     That serialization disables the streamed path's transfer/compute
     overlap, so pass ``stats`` only on profiling runs, not timed ones.
@@ -1345,9 +1633,17 @@ def train_als(
         for side, n_table in (("user", I_pad), ("item", U_pad))
     }
     trainwatch.set_gather_impl(gather_impl)
+    # ...and the way each half-step turns the gathered rows into normal
+    # equations
+    accum_impl = {
+        side: _accum_impl(jax.default_backend(), K, width, mm_itemsize)
+        for side, width in (("user", w_user), ("item", w_item))
+    }
+    trainwatch.set_accum_impl(accum_impl)
     if stats is not None:
         stats["solve_impl"] = solve_impl
         stats["gather_impl"] = gather_impl
+        stats["accum_impl"] = accum_impl
 
     seed = np.uint32(config.seed)
 

@@ -103,6 +103,7 @@ class StepRecorder:
         self.n_stream = 0
         self.solve_impl: Optional[Dict[str, str]] = None
         self.gather_impl: Optional[Dict[str, str]] = None
+        self.accum_impl: Optional[Dict[str, str]] = None
         self.params_per_device_bytes = 0
         self.h2d_bytes = 0
         self.overlap_ratio: Optional[float] = None
@@ -141,6 +142,7 @@ class StepRecorder:
             self.n_stream = int(n_stream)
             self.solve_impl = None
             self.gather_impl = None
+            self.accum_impl = None
             self.params_per_device_bytes = int(per_device_bytes)
             self.last_loss = None
             self.losses.clear()
@@ -194,6 +196,13 @@ class StepRecorder:
         ``_gather_impl``)."""
         with self._lock:
             self.gather_impl = dict(impl)
+
+    def set_accum_impl(self, impl: Dict[str, str]) -> None:
+        """Whether the fused kernel or XLA sums each half-step's normal
+        equations (ALS decides from platform, rank, block width and
+        operand size: ``_accum_impl``)."""
+        with self._lock:
+            self.accum_impl = dict(impl)
 
     def set_overlap(self, ratio: float) -> None:
         with self._lock:
@@ -290,6 +299,7 @@ class StepRecorder:
                 "stream_chunks": self.n_stream,
                 "solve_impl": self.solve_impl,
                 "gather_impl": self.gather_impl,
+                "accum_impl": self.accum_impl,
             }
 
 
@@ -376,6 +386,12 @@ def set_gather_impl(impl: Dict[str, str]) -> None:
         rec.set_gather_impl(impl)
 
 
+def set_accum_impl(impl: Dict[str, str]) -> None:
+    rec = _ACTIVE
+    if rec is not None:
+        rec.set_accum_impl(impl)
+
+
 # ---------------------------------------------------------------------------
 # direction-aware deltas — the regression core of ``pio runs --diff``
 # ---------------------------------------------------------------------------
@@ -453,8 +469,9 @@ def run_record(*, run_id: str, engine_id: str, status: str,
     --profile-dir`` on a TPU): lifted as ``scope_<path>_s``,
     ``device_busy_s`` and ``device_idle_pct``. An ALS run's
     ``solve_impl`` (``{"user", "item"}``: ``resident_cg`` / ``xla_cg`` /
-    ``cholesky`` / ``lu``) and ``gather_impl`` (``{"user", "item"}``:
-    ``packed`` / ``plain``) are lifted beside them."""
+    ``cholesky`` / ``lu``), ``gather_impl`` (``{"user", "item"}``:
+    ``packed`` / ``plain``) and ``accum_impl`` (``{"user", "item"}``:
+    ``fused`` / ``xla``) are lifted beside them."""
     if timestamp is None:
         import datetime as _dt
 
@@ -479,7 +496,7 @@ def run_record(*, run_id: str, engine_id: str, status: str,
         rec["step_summary"] = dict(step_summary)
         for key in ("examples_per_sec", "final_loss", "loss_window_mean",
                     "overlap_ratio", "steps", "examples", "solve_impl",
-                    "gather_impl"):
+                    "gather_impl", "accum_impl"):
             if step_summary.get(key) is not None:
                 rec[key] = step_summary[key]
     if device_scopes:

@@ -882,6 +882,190 @@ def _steer_packed(monkeypatch, als):
             "tpu", 162_541, rank, 2))
 
 
+def _steer_fused(monkeypatch, als):
+    """The rule as a TPU would read it of bfloat16 operands (the caller
+    empties the trainers' caches: the rule is read when a trainer is
+    traced); off a TPU ``partial_normal_eq`` then interprets the kernel."""
+    rule = als._accum_impl
+    monkeypatch.setattr(
+        als, "_accum_impl",
+        lambda platform, rank, width, itemsize: rule("tpu", rank, width, 2))
+
+
+def _block_lists(case):
+    """``(block_ent, n_entities, chunk)`` at the kernel's width: ascending
+    entity ids, in chunks of 128 blocks (two tiles of 64) but for one."""
+    if case == "straddles":
+        # entity 7 owns blocks 60..69 (across the tile boundary at 64),
+        # entity 11 blocks 120..139 (across the chunk boundary at 128),
+        # entity 30 the odd-length run 250..260 (pairs split in the middle)
+        runs = ([9, 8, 9, 8, 9, 8, 9, 10, 20, 15, 15, 20, 7, 7] + [6] * 16
+                + [11] + [1] * 123)
+        ent = np.repeat(np.arange(len(runs)), runs)
+        assert len(ent) == 384 and ent[60] == ent[69] == 7
+        assert ent[120] == ent[139] == 11 and ent[250] == ent[260] == 30
+        return ent, 210, 128
+    if case == "whole_chunk":
+        # entity 5 owns all of the second chunk and more (the cell's
+        # ``max_degree`` 32,768 is 512 blocks of 64)
+        ent = np.repeat(np.arange(90), [20] * 5 + [328] + [1] * 84)
+        assert len(ent) == 512 and ent[100] == ent[427] == 5
+        return ent, 90, 128
+    if case == "padded":
+        # ids with gaps (entities that no block names), padded entities
+        # beyond the last id, padding blocks that alias the last entity
+        ids = np.arange(0, 120, 3)
+        ent = np.concatenate([np.repeat(ids, 5), np.full(56, ids[-1])])
+        assert len(ent) == 256
+        return ent, 160, 128
+    if case == "ragged_chunk":
+        # a chunk that no tile of 16 blocks divides: padded in the wrapper
+        return np.repeat(np.arange(12), 6), 14, 24
+    assert case == "one_chunk"
+    return np.repeat(np.arange(16), 8), 16, 128
+
+
+class TestFusedAccum:
+    """The kernel that multiplies a chunk's blocks and sums them per
+    entity in VMEM (``_accum_fused``, interpreted here) against the
+    einsum and ``segment_sum`` it replaces on a TPU (the oracle), and the
+    rule that chooses between them (``_accum_impl``)."""
+
+    @pytest.mark.parametrize("case", [
+        "straddles", "whole_chunk", "padded", "one_chunk", "ragged_chunk"])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("implicit", [False, True],
+                             ids=["explicit", "implicit"])
+    def test_kernel_matches_xla_path(self, implicit, dtype, case,
+                                     monkeypatch):
+        import jax
+
+        from pio_tpu.models import als
+
+        block_ent, n, chunk = _block_lists(case)
+        S, W, K, n_other = len(block_ent), 64, 64, 300
+        rng = np.random.default_rng(S)
+        other = rng.integers(0, n_other, (S, W)).astype(np.int32)
+        other[rng.random((S, W)) < 0.3] = -1
+        if case == "padded":
+            other[-56:] = -1  # the padding blocks carry no edge
+        r = (rng.integers(1, 11, (S, W)) * 0.5).astype(np.float32)
+        table = (np.abs(rng.standard_normal((n_other, K))) / 8
+                 ).astype(np.float32)
+
+        def normal_eq():
+            math = als._make_math(0.1, implicit, 2.0, dtype, "cg")
+            A, b = jax.jit(
+                lambda e, o, r, t: math.partial_normal_eq(
+                    e, o, r, t, n, chunk)
+            )(block_ent.astype(np.int32), other, r, table)
+            return np.asarray(A), np.asarray(b)
+
+        want_A, want_b = normal_eq()  # the real rule: on CPU, XLA's path
+        _steer_fused(monkeypatch, als)
+        got_A, got_b = normal_eq()
+        assert got_A.shape == (n, K, K) and got_b.shape == (n, K)
+        # float32 sums in another order; on CPU XLA also keeps the
+        # weighted bfloat16 rows unrounded (the kernel rounds them, as the
+        # chip does), which shows where the weights are no 0/1 mask
+        tol = 4e-3 if implicit and dtype == "bfloat16" else 2e-6
+        for got, want in ((got_A, want_A), (got_b, want_b)):
+            assert np.abs(got - want).max() <= tol * np.abs(want).max()
+        named = np.zeros(n, bool)
+        named[block_ent] = True
+        assert not got_A[~named].any() and not got_b[~named].any()
+        assert got_A[named].any(axis=(1, 2)).all()
+
+    @pytest.mark.parametrize("platform,rank,width,itemsize,want", [
+        ("tpu", 64, 64, 2, "fused"),      # the benchmark cell
+        ("cpu", 64, 64, 2, "xla"),
+        ("gpu", 64, 64, 2, "xla"),
+        ("tpu", 64, 64, 4, "xla"),        # float32 operands
+        ("tpu", 10, 64, 2, "xla"),        # the template's default
+        ("tpu", 16, 64, 2, "xla"),        # not measured: PERF.md
+        ("tpu", 128, 64, 2, "xla"),
+        ("tpu", 64, 32, 2, "xla"),
+        ("tpu", 64, 16, 2, "xla"),
+    ])
+    def test_selection_rule(self, platform, rank, width, itemsize, want):
+        from pio_tpu.models.als import _accum_impl
+
+        assert _accum_impl(platform, rank, width, itemsize) == want
+
+    def test_stats_and_run_record_name_the_path(self, fresh_trainers,
+                                                monkeypatch):
+        """``xla`` on CPU by the rule, ``fused`` once steered; ``stats``
+        and the run record carry it per half-step."""
+        from pio_tpu.models import als
+        from pio_tpu.obs import trainwatch
+
+        u, i, r, U, I = _wide_ratings()
+
+        def train():
+            st = {}
+            recorder = trainwatch.StepRecorder("accum-impl")
+            with trainwatch.recording(recorder):
+                train_als(ComputeContext.local(), u, i, r, U, I,
+                          ALSConfig(rank=64, iterations=1, block_width=64),
+                          stats=st)
+            record = trainwatch.run_record(
+                run_id="r", engine_id="e", status="COMPLETED",
+                train_seconds=1.0, phases={}, params_hash="h",
+                step_summary=recorder.summary())
+            assert record["accum_impl"] == st["accum_impl"]
+            return st["accum_impl"]
+
+        assert train() == {"user": "xla", "item": "xla"}
+        _steer_fused(monkeypatch, als)
+        fresh_trainers()
+        assert train() == {"user": "fused", "item": "fused"}
+
+    @pytest.mark.parametrize("implicit", [False, True],
+                             ids=["explicit", "implicit"])
+    @pytest.mark.parametrize("path", ["monolithic", "streamed", "mesh"])
+    def test_train_als_on_the_interpreted_kernel(self, path, implicit,
+                                                 fresh_trainers,
+                                                 monkeypatch):
+        """End to end through each trainer that calls
+        ``partial_normal_eq`` (the mesh route under ``shard_map``): the
+        kernel (interpreted) trains the model XLA's path trains."""
+        from pio_tpu.models import als
+
+        u, i, r, U, I = _wide_ratings()
+        if implicit:
+            r = np.abs(r)
+        cfg = ALSConfig(rank=64, iterations=3, reg=0.05, implicit=implicit,
+                        alpha=2.0, block_width=64, blocks_per_chunk=16)
+        if path == "streamed":
+            monkeypatch.setenv("PIO_TPU_ALS_STREAM_MB", "0.005")
+        ctx = (ComputeContext.create() if path == "mesh"
+               else ComputeContext.local())
+
+        def train(want):
+            st = {}
+            f = train_als(ctx, u, i, r, U, I, cfg, stats=st)
+            assert st["accum_impl"] == {"user": want, "item": want}
+            assert (st["n_stream"] > 1) == (path == "streamed")
+            return f.user_factors @ f.item_factors.T
+
+        want = train("xla")  # the real rule: on CPU, XLA's path
+        _steer_fused(monkeypatch, als)
+        fresh_trainers()
+        got = train("fused")
+        assert np.isfinite(got).all()
+        assert np.abs(got - want).max() <= 1e-3 * np.abs(want).max()
+
+
+def _wide_ratings():
+    """24 users x 150 items, 80% observed: a user's adjacency is two
+    blocks of 64, and the by-user layout several chunks of 16 blocks."""
+    rng = np.random.default_rng(3)
+    U, I, K = 24, 150, 4
+    R = rng.normal(size=(U, K)) @ rng.normal(size=(I, K)).T
+    u, i = np.nonzero(rng.random((U, I)) < 0.8)
+    return u, i, R[u, i].astype(np.float32), U, I
+
+
 class TestTopN:
     def test_basic(self):
         scores = np.array([0.1, 5.0, 3.0, 4.0])

@@ -14,16 +14,21 @@ import pytest
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -49,17 +54,38 @@ def test_resident_cg_compiles_for_v5e(one_chip, n, K):
     assert mem.temp_size_in_bytes <= 1.05 * n * K * K * 4
 
 
-def test_stream_finalize_gathers_from_vmem_on_v5e(one_chip, monkeypatch):
-    """``finalize`` of the streamed ALS trainer at the benchmark cell's
-    shapes (MovieLens-25M, rank 64: seven stream chunks of 17 x 4,096
-    user blocks and 3,571,442 edges, 107 x 4,096 item blocks): in both
-    half-steps the gather reads its factor table from VMEM (memory space
-    1). The plain ``bf16[162541,64]`` table, padded to 128 lanes, stays
-    in HBM there, so ``_gather_impl`` packs it; this guards both tables
-    against a later change to the scans' carries that would push one
-    back unseen."""
-    import re
+@pytest.mark.parametrize("n", [162_541, 59_047])
+def test_fused_accum_compiles_for_v5e(one_chip, n):
+    """The benchmark cell's two sides: a chunk of 4,096 blocks of 64 slots
+    at rank 64 (the one shape ``_accum_impl`` hands the kernel). ``AB``
+    is updated in place, and nothing else of its size exists."""
+    import jax
+    import jax.numpy as jnp
 
+    from pio_tpu.models.als import _accum_fused
+
+    K, W, C = 64, 64, 4096
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f32, i32 = jnp.float32, jnp.int32
+    compiled = jax.jit(_accum_fused, donate_argnums=(0,)).lower(
+        sd((n, K // 2 + 8, 2 * K), f32), sd((K + 16, 2 * K), f32),
+        sd((1,), i32), sd((C,), i32), sd((C, W, K), jnp.bfloat16),
+        sd((C, W), f32), sd((C, W), f32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= n * (K // 2 + 8) * 2 * K * 4
+    assert mem.temp_size_in_bytes <= 4 << 20  # the weights' lane rows
+
+
+@pytest.fixture(scope="module")
+def stream_programs(one_chip):
+    """``accum`` (the first of seven) and ``finalize`` of the streamed ALS
+    trainer compiled at the benchmark cell's shapes (MovieLens-25M, rank
+    64: seven stream chunks of 17 x 4,096 user blocks and 3,571,442
+    edges, 107 x 4,096 item blocks), as ``{name: compiled}``."""
     import jax
     import jax.numpy as jnp
 
@@ -76,22 +102,60 @@ def test_stream_finalize_gathers_from_vmem_on_v5e(one_chip, monkeypatch):
     spec = tuple((S_c, min(U - 1, (c + 1) * span), c * span)
                  for c in range(n_stream))
     # the rules read the backend when the trainer is traced
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    real_backend = jax.default_backend
+    jax.default_backend = lambda: "tpu"
     als._build_stream_trainer.cache_clear()
     try:
-        _, _, finalize = als._build_stream_trainer(
+        _, accums, finalize = als._build_stream_trainer(
             10, 0.1, False, 1.0, "bfloat16", "auto", K, U, I, W, W, S_item,
             chunk, chunk, spec)
         blocks = tuple((sd((S_c,), i32), sd((S_c, W), i32),
                         sd((S_c, W), f32)) for _ in spec)
         edges = tuple((sd((E_c,), i32), sd((E_c,), f32)) for _ in spec)
-        compiled = finalize.lower(
-            sd((U, K, K), f32), sd((U, K), f32), sd((I, K), f32),
-            sd((U,), i32), sd((I,), i32), blocks, edges).compile()
+        A, b, Q0 = sd((U, K, K), f32), sd((U, K), f32), sd((I, K), f32)
+        return {
+            "accum": accums[0].lower(
+                A, b, Q0, sd((spec[0][1] - spec[0][2] + 1,), i32),
+                sd((E_c,), i32), sd((E_c,), f32)).compile(),
+            "finalize": finalize.lower(
+                A, b, Q0, sd((U,), i32), sd((I,), i32), blocks,
+                edges).compile(),
+        }
     finally:
+        jax.default_backend = real_backend
         als._build_stream_trainer.cache_clear()
 
+
+@pytest.mark.parametrize("program,kernels,temp_bytes", [
+    # as compiled with the kernel in; the XLA path's: 10,501,731,840 and
+    # 11,834,045,952 B
+    ("accum", 1, 7_239_892_992), ("finalize", 3, 8_783_849_984)])
+def test_stream_programs_sum_in_the_kernel_on_v5e(stream_programs, program,
+                                                  kernels, temp_bytes):
+    """Both programs of the streamed trainer hand every half-step to the
+    fused kernel (``finalize``: iteration 1's item side, then both sides
+    in the loop), no per-block ``f32[4096,64,64]`` product is written to
+    HBM, and the temporaries stay what the lane-dense carry needs."""
+    compiled = stream_programs[program]
     text = compiled.as_text()
+    calls = [ln for ln in text.splitlines()
+             if "custom-call(" in ln and "als_accum_fused" in ln]
+    assert len(calls) == kernels, len(calls)
+    assert "f32[4096,64,64]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1.01 * temp_bytes
+
+
+def test_stream_finalize_gathers_from_vmem_on_v5e(stream_programs):
+    """``finalize`` of the streamed ALS trainer at the benchmark cell's
+    shapes: in both half-steps the gather reads its factor table from
+    VMEM (memory space 1). The plain ``bf16[162541,64]`` table, padded to
+    128 lanes, stays in HBM there, so ``_gather_impl`` packs it; this
+    guards both tables against a later change to the scans' carries (or
+    a kernel in their bodies that asks for much VMEM) that would push one
+    back unseen."""
+    import re
+
+    text = stream_programs["finalize"].as_text()
     tables = {}  # half-step → the table operand of each gather fusion
     for line in text.splitlines():
         m = re.match(r"\s*%\S+ = \S+ fusion\((%[\w.\-]+),", line)
@@ -107,6 +171,45 @@ def test_stream_finalize_gathers_from_vmem_on_v5e(one_chip, monkeypatch):
     # the item table fits as it is, so the rule leaves it plain
     assert all(t.startswith("bf16[59047,64]") for t in tables["user"])
     assert all("S(1)" in t for ts in tables.values() for t in ts), tables
-    # the plain form's temporaries at these shapes: 11,835,239,424 B
-    assert compiled.memory_analysis().temp_size_in_bytes \
-        <= 1.01 * 11_835_239_424
+
+
+def test_mesh_trainer_sums_in_the_kernel_on_four_v5e(topo, monkeypatch):
+    """The mesh route's trainer (``shard_map`` over the block shards,
+    then ``psum_scatter``) compiled for the four described chips at rank
+    64, width 64: each half-step's sums are the fused kernel there too,
+    with its carry updated in place under ``shard_map``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from pio_tpu.models import als
+
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    U, I, K, W, chunk = 40_000, 20_000, 64, 64, 4096
+    # the rules read the backend when the trainer is traced
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    als._build_trainer.cache_clear()
+    try:
+        run = als._build_trainer(
+            mesh, "data", 3, 0.1, False, 1.0, chunk, chunk, "bfloat16",
+            "auto", None, K, U, I)
+
+        def side(S):
+            rows = NamedSharding(mesh, P("data"))
+            slots = NamedSharding(mesh, P("data", None))
+            return (jax.ShapeDtypeStruct((S,), jnp.int32, sharding=rows),
+                    jax.ShapeDtypeStruct((S, W), jnp.int32, sharding=slots),
+                    jax.ShapeDtypeStruct((S, W), jnp.float32,
+                                         sharding=slots))
+
+        seed = jax.ShapeDtypeStruct(
+            (), jnp.uint32, sharding=NamedSharding(mesh, P()))
+        text = run.lower(
+            side(4 * 8 * chunk), side(4 * 6 * chunk), seed).compile().as_text()
+    finally:
+        als._build_trainer.cache_clear()
+    calls = [ln for ln in text.splitlines()
+             if "custom-call(" in ln and "als_accum_fused" in ln]
+    assert len(calls) == 2, len(calls)
+    assert "f32[4096,64,64]" not in text
