@@ -1,6 +1,6 @@
 """The sequence model's blocks, described by data.
 
-Two blocks share ``models/seqrec.py``'s trainer and server:
+Three blocks share ``models/seqrec.py``'s trainer and server:
 
 - ``attention_kind="mha", ffn_kind="relu"`` — the SASRec block (pre-LN,
   learned positions, tied head); its math lives in ``seqrec._block``.
@@ -11,6 +11,17 @@ Two blocks share ``models/seqrec.py``'s trainer and server:
   ``n_experts``, the ``experts_held`` experts from ``experts_first`` computed
   here, one always-on shared expert), an untied head and ``mtp_depth``
   multi-token-prediction modules. This module holds that block's layers.
+- ``attention_kind="gqa", ffn_kind="moe"`` — grouped-query attention whose
+  layers come in kinds (``layer_pattern``: ``"full"`` causal layers and
+  ``"window"`` layers that see the last ``window`` keys), each kind with
+  its own count of query heads over the same ``kv_heads`` and its own RoPE
+  (a rotated slice of the head, YaRN's interpolated frequencies and factor
+  on a full layer), a sigmoid gate a query head on the attention's output,
+  and the same dense and expert layers behind a ``router_kind="softmax"``
+  router. Its stacks are ``dense/*``, ``window/*`` and ``full/*``: the
+  kinds' projections have unlike shapes. ``heads_full``, ``heads_window``
+  and ``kv_heads`` count the heads held here, as ``experts_held`` counts
+  the experts.
 
 :func:`describe_params` is the one place a block's parameter shapes are
 written: ``init_params``, ``param_specs`` and the placement check of
@@ -27,7 +38,9 @@ from __future__ import annotations
 import zlib
 from typing import Dict, NamedTuple, Tuple
 
-BLOCK_KINDS = {("mha", "relu"), ("mla", "moe")}
+BLOCK_KINDS = {("mha", "relu"), ("mla", "moe"), ("gqa", "moe")}
+LAYER_KINDS = ("full", "window")
+ROUTER_KINDS = ("sigmoid_bias", "softmax")
 
 #: How the mla/moe block's parameters are drawn (a norm's gain is 1): every
 #: matrix and the head; the embedding rows; the router's selection bias.
@@ -48,12 +61,33 @@ GROUPS = ("embedding", "head", "mla", "router", "routed_experts",
           "shared_expert", "dense_mlp", "mtp")
 
 
-def group_of(path: str) -> str:
-    """Which of ``GROUPS`` the parameter at ``group/name`` belongs to: the
-    MTP module whole; the two tables; an expert layer's router, routed
-    experts and shared expert; the dense layers' MLP; and attention with
-    every norm under ``mla``."""
+#: the gqa/moe block's: the attention of each kind of layer and the gate
+#: apart, every norm's gain together
+GQA_GROUPS = ("embedding", "head", "attn_window", "attn_full", "gate",
+              "router", "routed_experts", "shared_expert", "dense_mlp",
+              "norms")
+
+
+def groups_of(cfg) -> Tuple[str, ...]:
+    return GQA_GROUPS if cfg.attention_kind == "gqa" else GROUPS
+
+
+def group_of(path: str, cfg=None) -> str:
+    """Which of ``groups_of(cfg)`` the parameter at ``group/name`` belongs
+    to. The mla/moe block (and ``cfg=None``): the MTP module whole; the two
+    tables; an expert layer's router, routed experts and shared expert; the
+    dense layers' MLP; and attention with every norm under ``mla``. The
+    gqa/moe block: the four projections under the attention of their
+    layer's kind (a dense layer's kind is the pattern's), the gate's map,
+    and the norms' gains on their own."""
     group, _, name = path.rpartition("/")
+    if cfg is not None and cfg.attention_kind == "gqa":
+        if name.endswith("norm") or name == "lnf_g":
+            return "norms"
+        if name == "g_proj":
+            return "gate"
+        if name.endswith("_proj"):
+            return "attn_" + (layer_kind(cfg, 0) if group == "dense" else group)
     if group == "mtp":
         return "mtp"
     by_name = {"emb": "embedding", "head": "head", "router_w": "router",
@@ -67,17 +101,19 @@ def group_of(path: str) -> str:
     return "mla"
 
 
-def group_norms(grads: dict):
-    """``[len(GROUPS)]`` Frobenius norms of a two-deep gradient tree."""
+def group_norms(grads: dict, cfg=None):
+    """``[len(groups_of(cfg))]`` Frobenius norms of a two-deep gradient
+    tree."""
     import jax.numpy as jnp
 
-    total = dict.fromkeys(GROUPS, jnp.float32(0.0))
+    groups = GROUPS if cfg is None else groups_of(cfg)
+    total = dict.fromkeys(groups, jnp.float32(0.0))
     for group, value in grads.items():
         leaves = value.items() if isinstance(value, dict) else [(None, value)]
         for name, g in leaves:
-            kind = group_of(f"{group}/{name}" if name else group)
+            kind = group_of(f"{group}/{name}" if name else group, cfg)
             total[kind] = total[kind] + jnp.sum(jnp.square(g))
-    return jnp.sqrt(jnp.stack([total[k] for k in GROUPS]))
+    return jnp.sqrt(jnp.stack([total[k] for k in groups]))
 
 
 class Leaf(NamedTuple):
@@ -91,8 +127,31 @@ class Leaf(NamedTuple):
     init: object
 
 
-def is_latent(cfg) -> bool:
-    return cfg.attention_kind == "mla"
+def is_moe(cfg) -> bool:
+    """Whether the block is one of the two with dense and expert layers
+    (mla or gqa attention), which share the layer-stack trunk, the untied
+    head and the per-step trace."""
+    return cfg.ffn_kind == "moe"
+
+
+def layer_kind(cfg, layer: int) -> str:
+    """The gqa/moe block's kind of layer ``layer``: the pattern repeats."""
+    return cfg.layer_pattern[layer % len(cfg.layer_pattern)]
+
+
+def period_kinds(cfg) -> Tuple[str, ...]:
+    """The stacks that the expert layers of one period draw from, in the
+    layers' order: the gqa/moe block's kinds from the first layer behind
+    the dense ones; the mla/moe block's layers are alike, a period is one
+    layer of ``blocks``."""
+    if cfg.attention_kind != "gqa":
+        return ("blocks",)
+    n = len(cfg.layer_pattern)
+    return tuple(layer_kind(cfg, cfg.dense_layers + i) for i in range(n))
+
+
+def heads_of(cfg, kind: str) -> int:
+    return cfg.heads_window if kind == "window" else cfg.heads_full
 
 
 def check_block(cfg) -> None:
@@ -101,8 +160,12 @@ def check_block(cfg) -> None:
             f"unsupported block: attention_kind={cfg.attention_kind!r} with "
             f"ffn_kind={cfg.ffn_kind!r}; have {sorted(BLOCK_KINDS)}"
         )
-    if not is_latent(cfg):
+    if not is_moe(cfg):
         return
+    if cfg.router_kind not in ROUTER_KINDS:
+        raise ValueError(f"router_kind is one of {ROUTER_KINDS}")
+    if cfg.attention_kind == "gqa":
+        _check_gqa(cfg)
     if not 0 <= cfg.dense_layers < cfg.n_layers:
         raise ValueError("dense_layers must leave at least one expert layer")
     if not (0 <= cfg.experts_first
@@ -115,8 +178,33 @@ def check_block(cfg) -> None:
         )
     if cfg.mtp_depth not in (0, 1):
         raise ValueError("mtp_depth is 0 or 1")
-    if cfg.qk_rope_dim % 2:
+    if cfg.attention_kind == "mla" and cfg.qk_rope_dim % 2:
         raise ValueError("qk_rope_dim must be even")
+
+
+def _check_gqa(cfg) -> None:
+    pattern = cfg.layer_pattern
+    if not pattern or any(k not in LAYER_KINDS for k in pattern):
+        raise ValueError(f"layer_pattern holds kinds of {LAYER_KINDS}")
+    if (cfg.n_layers - cfg.dense_layers) % len(pattern):
+        raise ValueError(
+            "the expert layers must be whole periods of layer_pattern")
+    if len({layer_kind(cfg, i) for i in range(cfg.dense_layers)}) > 1:
+        raise ValueError("the dense layers must be of one kind: they stack")
+    for kind in set(pattern):
+        if heads_of(cfg, kind) % cfg.kv_heads or heads_of(cfg, kind) < 1:
+            raise ValueError(
+                f"the {kind} layers' query heads must be a multiple of "
+                f"kv_heads {cfg.kv_heads}")
+    rotary = cfg.rotary_dim or cfg.head_dim
+    if cfg.head_dim % 2 or rotary % 2 or rotary > cfg.head_dim:
+        raise ValueError("head_dim and rotary_dim are even, rotary_dim at "
+                         "most head_dim")
+    if "window" in pattern and cfg.window < 1:
+        raise ValueError("window layers need window >= 1")
+    if cfg.router_kind != "softmax" or cfg.mtp_depth:
+        raise ValueError("the gqa/moe block has a softmax router and no "
+                         "MTP module")
 
 
 def _mla_leaves(L: int, cfg) -> Dict[str, Leaf]:
@@ -136,13 +224,26 @@ def _mla_leaves(L: int, cfg) -> Dict[str, Leaf]:
     }
 
 
-def _expert_layer_leaves(L: int, cfg) -> Dict[str, Leaf]:
+def _gqa_leaves(L: int, cfg, kind: str) -> Dict[str, Leaf]:
+    D, H, std = cfg.d_model, heads_of(cfg, kind), ("named", INIT_STD)
+    d, Hkv = cfg.head_dim, cfg.kv_heads
+    return {
+        "attn_norm": Leaf((L, D), "ones"),
+        "q_proj": Leaf((L, D, H * d), std),
+        "k_proj": Leaf((L, D, Hkv * d), std),
+        "v_proj": Leaf((L, D, Hkv * d), std),
+        "g_proj": Leaf((L, D, H), std),
+        "o_proj": Leaf((L, H * d, D), std),
+        "ffn_norm": Leaf((L, D), "ones"),
+    }
+
+
+def _moe_leaves(L: int, cfg) -> Dict[str, Leaf]:
+    """An expert layer's feed-forward: router, held experts, shared expert."""
     D, Fe, std = cfg.d_model, cfg.expert_ffn, ("named", INIT_STD)
     Eh, Fs = cfg.experts_held, cfg.expert_ffn * cfg.shared_experts
     return {
-        **_mla_leaves(L, cfg),
         "router_w": Leaf((L, D, cfg.n_experts), std),
-        "router_b": Leaf((L, cfg.n_experts), ("named", BIAS_INIT_STD)),
         "e_gate": Leaf((L, Eh, D, Fe), std),
         "e_up": Leaf((L, Eh, D, Fe), std),
         "e_down": Leaf((L, Eh, Fe, D), std),
@@ -152,11 +253,40 @@ def _expert_layer_leaves(L: int, cfg) -> Dict[str, Leaf]:
     }
 
 
+def _describe_gqa(vocab: int, cfg) -> Dict[str, Leaf]:
+    D, F, std = cfg.d_model, cfg.ffn, ("named", INIT_STD)
+    Ld = cfg.dense_layers
+    out = {"emb": Leaf((vocab, D), ("named", EMBED_INIT_STD)),
+           "head": Leaf((vocab, D), std),
+           "lnf_g": Leaf((D,), "ones")}
+    if Ld:
+        dense = {**_gqa_leaves(Ld, cfg, layer_kind(cfg, 0)),
+                 "w_gate": Leaf((Ld, D, F), std), "w_up": Leaf((Ld, D, F), std),
+                 "w_down": Leaf((Ld, F, D), std)}
+        out.update({f"dense/{k}": v for k, v in dense.items()})
+    periods = (cfg.n_layers - Ld) // len(cfg.layer_pattern)
+    for kind in LAYER_KINDS:
+        L = periods * period_kinds(cfg).count(kind)
+        if L:
+            out.update({f"{kind}/{k}": v for k, v in {
+                **_gqa_leaves(L, cfg, kind), **_moe_leaves(L, cfg)}.items()})
+    return out
+
+
+def _expert_layer_leaves(L: int, cfg) -> Dict[str, Leaf]:
+    return {
+        **_mla_leaves(L, cfg), **_moe_leaves(L, cfg),
+        "router_b": Leaf((L, cfg.n_experts), ("named", BIAS_INIT_STD)),
+    }
+
+
 def describe_params(vocab: int, cfg) -> Dict[str, Leaf]:
     """``{"group/name": Leaf}`` of every parameter of the configured
     block, layer-stacked (leading dim = layers of that group)."""
     D, F, L = cfg.d_model, cfg.ffn, cfg.n_layers
-    if not is_latent(cfg):
+    if cfg.attention_kind == "gqa":
+        return _describe_gqa(vocab, cfg)
+    if not is_moe(cfg):
         s = D ** -0.5
         blocks = {
             "ln1_g": Leaf((L, D), "ones"), "ln1_b": Leaf((L, D), "zeros"),
@@ -260,17 +390,57 @@ def rms_norm(x, g, eps):
     return x * jax.lax.rsqrt((x * x).mean(axis=-1, keepdims=True) + eps) * g
 
 
-def rope(x, pos, theta: float):
+def rope(x, pos, theta: float, rotary_dim: int = 0, inv_freq=None,
+         factor: float = 1.0):
     """Rotary embedding of ``x [..., T, h, d]`` at positions ``pos [T]``,
-    pairing dim ``i`` with ``i + d/2`` (the rotate-half convention)."""
+    pairing dim ``i`` with ``i + r/2`` of the first ``r = rotary_dim`` dims
+    (the rotate-half convention; 0 = the whole head; the dims behind pass
+    through). ``inv_freq [r/2]`` replaces ``theta ** (-2i / r)``
+    (:func:`yarn_inv_freq`), and ``factor`` multiplies ``cos`` and ``sin``."""
     import jax.numpy as jnp
 
-    half = x.shape[-1] // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    d = rotary_dim or x.shape[-1]
+    half = d // 2
+    if inv_freq is None:
+        inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        inv = jnp.asarray(inv_freq, jnp.float32)
     ang = pos.astype(jnp.float32)[:, None] * inv[None, :]  # [T, half]
     cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    a, b = x[..., :half], x[..., half:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    if factor != 1.0:
+        cos, sin = cos * jnp.float32(factor), sin * jnp.float32(factor)
+    a, b = x[..., :half], x[..., half:d]
+    parts = [a * cos - b * sin, b * cos + a * sin]
+    if d < x.shape[-1]:
+        parts.append(x[..., d:])
+    return jnp.concatenate(parts, axis=-1)
+
+
+def yarn_inv_freq(theta: float, rotary_dim: int, factor: float,
+                  original_len: int, beta_fast: float, beta_slow: float):
+    """YaRN's frequency table ``[rotary_dim / 2]`` (float32 numpy): with
+    ``f_j = theta ** (-2j / r)`` and ``dim(n) = r ln(original_len / (2 pi
+    n)) / (2 ln theta)`` (the dim that turns ``n`` times over the original
+    length), ``low = floor(dim(beta_fast))``, ``high = ceil(dim(beta_slow))``
+    (clamped to the table), ``ramp_j = clip((j - low) / (high - low), 0,
+    1)``: a frequency below ``low`` is kept, one above ``high`` is divided
+    by ``factor``, those between are blended, ``f_j (1 - ramp_j) + f_j /
+    factor * ramp_j``."""
+    import math
+
+    import numpy as np
+
+    r, half = rotary_dim, rotary_dim // 2
+    f = float(theta) ** (-np.arange(half, dtype=np.float64) / half)
+
+    def dim(turns):
+        return r * math.log(original_len / (2 * math.pi * turns)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(dim(beta_fast)), 0)
+    high = min(math.ceil(dim(beta_slow)), r - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (f * (1.0 - ramp) + f / factor * ramp).astype(np.float32)
 
 
 def swiglu(x, w_gate, w_up, w_down, cd, chunk: int = 0):
@@ -333,19 +503,90 @@ def mla(blk, h, cfg, s_axis):
         return mm(attn.reshape(B, T, H * dv), blk["o_proj"], cd)
 
 
-def route(x, router_w, router_b, cfg):
-    """``(idx [N, k], gate [N, k], load [E])``: the selected experts of
-    every token (top-k of ``sigmoid(x W_r) + b``; ``b`` takes no gradient),
-    their normalised, scaled weights and the count per expert."""
+def gqa(blk, h, cfg, s_axis, kind: str):
+    """Grouped-query attention of a ``kind`` layer on the local ``[B,
+    T_loc, D]`` slice -> ``(attention's output before the residual, tile
+    counters)``: ``[2]``, the score tiles a window layer's loops ran (a KV
+    head and row) and those a causal layer of its length runs, zeros for a
+    full layer. The ``heads_of(kind) / kv_heads`` query heads of a KV head
+    share its keys and values inside one score tile; a window layer's tiles
+    outside the window are skipped. Every query head's output is scaled by
+    its own gate, ``sigmoid(x W_g)``, before ``W_o``."""
     import jax
     import jax.numpy as jnp
 
-    s = jax.nn.sigmoid(jnp.dot(
+    from pio_tpu.parallel.ring import ring_attention
+
+    cd, eps = _dtype(cfg), cfg.norm_eps
+    B, T, _ = h.shape
+    H, Hkv, d = heads_of(cfg, kind), cfg.kv_heads, cfg.head_dim
+    t_off = 0 if s_axis is None else jax.lax.axis_index(s_axis) * T
+    pos = t_off + jnp.arange(T)
+    if kind == "window":
+        window, turn = cfg.window, dict(theta=cfg.window_rope_theta)
+    else:
+        window = 0
+        turn = dict(theta=cfg.rope_theta, rotary_dim=cfg.rotary_dim)
+        if cfg.yarn_factor > 1.0:
+            turn.update(
+                inv_freq=yarn_inv_freq(
+                    cfg.rope_theta, cfg.rotary_dim or d, cfg.yarn_factor,
+                    cfg.yarn_original_len, cfg.yarn_beta_fast,
+                    cfg.yarn_beta_slow),
+                factor=cfg.yarn_attention_factor)
+    with jax.named_scope("seq.gqa/proj"):
+        x = rms_norm(h, blk["attn_norm"], eps)
+        q = rope(mm(x, blk["q_proj"], cd).reshape(B, T, H, d), pos, **turn)
+        k = rope(mm(x, blk["k_proj"], cd).reshape(B, T, Hkv, d), pos, **turn)
+        v = mm(x, blk["v_proj"], cd).reshape(B, T, Hkv, d)
+    with jax.named_scope("seq.gqa/gate"):
+        gate = jax.nn.sigmoid(jnp.dot(
+            x, blk["g_proj"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))  # [B, T, H] float32
+    with jax.named_scope(f"seq.gqa/attn/{kind}"):
+        attn, tiles = ring_attention(
+            q.astype(cd), k.astype(cd), v.astype(cd), axis=s_axis,
+            causal=True, block=ATTN_BLOCK, scale=d ** -0.5, window=window,
+            with_tiles=True,
+        )
+    # the window layers' tiles are what the counter is for
+    tiles = tiles.astype(jnp.float32) * (kind == "window")
+    with jax.named_scope("seq.gqa/gate"):
+        attn = attn.astype(jnp.float32) * gate[..., None]
+    with jax.named_scope("seq.gqa/proj"):
+        return mm(attn.reshape(B, T, H * d), blk["o_proj"], cd), tiles
+
+
+def attend(blk, h, cfg, s_axis, kind):
+    """``(attention's output, its counters)`` of the configured attention
+    in a layer of the stack ``kind``: the gqa/moe block counts ``tiles``,
+    the mla/moe block nothing."""
+    if cfg.attention_kind == "gqa":
+        out, tiles = gqa(blk, h, cfg, s_axis, kind)
+        return out, {"tiles": tiles}
+    return mla(blk, h, cfg, s_axis), {}
+
+
+def route(x, router_w, router_b, cfg):
+    """``(idx [N, k], gate [N, k], load [E])``: the selected experts of
+    every token, their normalised, scaled weights and the count per expert.
+    ``router_kind="sigmoid_bias"``: top-k of ``sigmoid(x W_r) + b`` (``b``
+    takes no gradient), weights from the sigmoids. ``"softmax"``: top-k of
+    ``softmax(x W_r)``, weights from the probabilities, no bias."""
+    import jax
+    import jax.numpy as jnp
+
+    logits = jnp.dot(
         x.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
-    ))
-    _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(router_b),
-                           cfg.experts_per_token)
+    )
+    if cfg.router_kind == "softmax":
+        s = jax.nn.softmax(logits, axis=-1)
+        _, idx = jax.lax.top_k(s, cfg.experts_per_token)
+    else:
+        s = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(router_b),
+                               cfg.experts_per_token)
     picked = jnp.take_along_axis(s, idx, axis=1)
     gate = cfg.routed_scale * picked / (
         picked.sum(axis=-1, keepdims=True) + 1e-20)
@@ -456,7 +697,7 @@ def moe(blk, x, cfg, m_axis):
     B, T, D = x.shape
     flat = x.reshape(B * T, D)
     with jax.named_scope("seq.moe/route"):
-        idx, gate, load = route(flat, blk["router_w"], blk["router_b"], cfg)
+        idx, gate, load = route(flat, blk["router_w"], blk.get("router_b"), cfg)
     held = blk["e_gate"].shape[0]
     first = cfg.experts_first
     if m_axis is not None:
@@ -474,23 +715,26 @@ def moe(blk, x, cfg, m_axis):
     return y.reshape(B, T, D), counters
 
 
-def dense_layer(blk, h, cfg, m_axis, s_axis):
+def dense_layer(blk, h, cfg, m_axis, s_axis, kind):
+    """``(h, the attention's counters)`` of one dense layer."""
     import jax
 
-    h = h + mla(blk, h, cfg, s_axis)
+    out, counters = attend(blk, h, cfg, s_axis, kind)
+    h = h + out
     with jax.named_scope("seq.ffn"):
         B, T, D = h.shape
         x = rms_norm(h, blk["ffn_norm"], cfg.norm_eps).reshape(B * T, D)
         return h + swiglu(x, blk["w_gate"], blk["w_up"], blk["w_down"],
-                          _dtype(cfg), TOKEN_CHUNK).reshape(B, T, D)
+                          _dtype(cfg), TOKEN_CHUNK).reshape(B, T, D), counters
 
 
-def expert_layer(blk, h, cfg, m_axis, s_axis):
+def expert_layer(blk, h, cfg, m_axis, s_axis, kind):
     """``(h, counters)`` of one expert layer (``blk`` has no layer dim)."""
-    h = h + mla(blk, h, cfg, s_axis)
+    out, attn_counters = attend(blk, h, cfg, s_axis, kind)
+    h = h + out
     y, counters = moe(blk, rms_norm(h, blk["ffn_norm"], cfg.norm_eps), cfg,
                       m_axis)
-    return h + y, counters
+    return h + y, {**counters, **attn_counters}
 
 
 def update_router_bias(router_b, load, rate: float):

@@ -28,7 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -40,8 +40,10 @@ from pio_tpu.models.seq_layers import (
     expert_layer,
     group_norms,
     init_from,
-    is_latent,
+    is_moe,
+    layer_kind,
     mm,
+    period_kinds,
     rms_norm,
     unflatten,
     update_router_bias,
@@ -84,8 +86,10 @@ class SeqRecConfig:
     # -- the block, by data (pio_tpu/models/seq_layers.py). The defaults
     # -- are the SASRec block; the widths below are read only by the kinds
     # -- that have them.
-    #: "mha" (learned positions, full heads of d_model / n_heads) or
-    #: "mla" (multi-head latent attention, RoPE on a shared rope key)
+    #: "mha" (learned positions, full heads of d_model / n_heads), "mla"
+    #: (multi-head latent attention, RoPE on a shared rope key) or "gqa"
+    #: (grouped queries over ``kv_heads``, layers of the kinds in
+    #: ``layer_pattern``, a sigmoid gate a query head)
     attention_kind: str = "mha"
     #: "relu" (one biased two-matmul FFN of width ``ffn``) or "moe"
     #: (``dense_layers`` SwiGLU layers of width ``ffn``, then expert layers)
@@ -112,9 +116,37 @@ class SeqRecConfig:
     #: multi-token-prediction modules (0 or 1) and their loss weight
     mtp_depth: int = 0
     mtp_weight: float = 0.3
-    #: matmul operand dtype of the mla/moe block (float32 accumulation,
-    #: float32 master weights and Adam)
+    #: matmul operand dtype of the mla/moe and gqa/moe blocks (float32
+    #: accumulation, float32 master weights and Adam)
     compute_dtype: str = "bfloat16"
+    #: how the router scores: "sigmoid_bias" (top-k of sigmoid + a moving
+    #: selection bias) or "softmax" (top-k of the probabilities, no bias)
+    router_kind: str = "sigmoid_bias"
+    # -- the gqa block. Layer ``i`` is of kind ``layer_pattern[i mod its
+    # -- length]``: "full" (causal; ``heads_full`` query heads; RoPE of
+    # -- ``rope_theta`` on the first ``rotary_dim`` dims of a head, 0 = all,
+    # -- with YaRN's frequencies and factor where ``yarn_factor`` > 1) or
+    # -- "window" (the last ``window`` keys, the query's own included;
+    # -- ``heads_window`` query heads; RoPE of ``window_rope_theta`` on the
+    # -- whole head). ``kv_heads`` and both head counts are the heads held
+    # -- here: with attention divided over chips by KV head, a chip's share.
+    layer_pattern: Tuple[str, ...] = ("full",)
+    head_dim: int = 128
+    kv_heads: int = 8
+    heads_full: int = 48
+    heads_window: int = 48
+    window: int = 512
+    window_rope_theta: float = 1e4
+    rotary_dim: int = 0
+    yarn_factor: float = 1.0
+    yarn_original_len: int = 8192
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 1.0
+
+    def __post_init__(self):
+        # engine.json gives a list; the config keys the kept programs
+        object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
 
 
 @dataclasses.dataclass
@@ -124,12 +156,15 @@ class SeqRecModel:
     params: dict  # layer-stacked pytree (host numpy)
     n_items: int
     config: SeqRecConfig
-    #: what the training call saw, per optimizer step (mla/moe block only):
+    #: what the training call saw, per optimizer step (the moe blocks only):
     #: ``l_main``/``l_mtp`` [steps], ``grad_norm`` [steps, parameter groups
-    #: of ``seq_layers.GROUPS``], and per expert layer (the MTP module's
+    #: of ``seq_layers.groups_of``], and per expert layer (the MTP module's
     #: last) ``pairs`` (token, held expert) routed here, ``dropped`` (those
     #: of them the grouped matmuls were not given: 0 for a dropless layer),
-    #: 0), ``load_max_over_mean`` over all experts, ``bias_max``
+    #: ``load_max_over_mean`` over all experts, ``bias_max`` (a router with
+    #: a selection bias), ``window_tiles``/``causal_tiles`` (the gqa block:
+    #: score tiles its window layers visited, and what causal layers of
+    #: their length visit)
     trace: Optional[dict] = None
     _serve_cache: Optional[tuple] = dataclasses.field(
         default=None, init=False, repr=False, compare=False
@@ -159,7 +194,7 @@ class SeqRecModel:
                 # score from the last real position of each row
                 lengths = (seqs > 0).sum(axis=1)
                 at = jnp.maximum(lengths - 1, 0)[:, None, None]
-                if is_latent(cfg):
+                if is_moe(cfg):
                     # serving runs no MTP module and keeps no counters
                     h, _ = _latent_trunk(params, seqs, cfg, None, None)
                     last = rms_norm(
@@ -321,10 +356,15 @@ _MTP_OWN = ("eh_proj", "h_norm", "e_norm", "lnf_g")
 
 
 def _latent_trunk(params, seqs, cfg, m_axis, s_axis):
-    """Embed + the dense layers + the expert layers of the mla/moe block,
-    one ``jax.checkpoint`` a layer -> ``(h [mb, T_loc, D] float32 before
-    the final norm, counters stacked over the expert layers)``."""
+    """Embed + the dense layers + the expert layers of a moe block, one
+    ``jax.checkpoint`` a layer -> ``(h [mb, T_loc, D] float32 before the
+    final norm, counters stacked over the expert layers)``. The expert
+    layers are scanned a period at a time; the period is data
+    (``period_kinds``): each of its layers takes the next slice of its
+    kind's stack (``window/*`` and ``full/*`` have unlike shapes; the
+    mla/moe block's period is one layer of ``blocks``)."""
     import jax
+    import jax.numpy as jnp
 
     h = vocab_parallel_lookup(params["emb"], seqs, m_axis)
 
@@ -333,21 +373,48 @@ def _latent_trunk(params, seqs, cfg, m_axis, s_axis):
     # at once (1.1 GB of the v5e's 16 at the published widths)
     # (inside the checkpoint, so that the backward pass's recomputation is
     # held the same way)
-    def dense_body(h, blk):
-        return jax.checkpoint(
-            lambda blk, h: dense_layer(
-                jax.lax.optimization_barrier(blk), h, cfg, m_axis, s_axis)
-        )(blk, h), None
+    def layer(fn, kind):
+        return jax.checkpoint(lambda blk, h: fn(
+            jax.lax.optimization_barrier(blk), h, cfg, m_axis, s_axis, kind))
 
-    def expert_body(h, blk):
-        return jax.checkpoint(
-            lambda blk, h: expert_layer(
-                jax.lax.optimization_barrier(blk), h, cfg, m_axis, s_axis)
-        )(blk, h)
-
+    dense = {}
     if "dense" in params:
-        h, _ = jax.lax.scan(dense_body, h, params["dense"])
-    return jax.lax.scan(expert_body, h, params["blocks"])
+        h, dense = jax.lax.scan(
+            lambda h, blk: layer(dense_layer, layer_kind(cfg, 0))(blk, h),
+            h, params["dense"])
+    kinds = period_kinds(cfg)
+
+    def period(h, blks):
+        taken, counters = dict.fromkeys(blks, 0), []
+        for kind in kinds:
+            blk = jax.tree.map(lambda a: a[taken[kind]], blks[kind])
+            taken[kind] += 1
+            h, c = layer(expert_layer, kind)(blk, h)
+            counters.append(c)
+        return h, jax.tree.map(lambda *a: jnp.stack(a), *counters)
+
+    if len(kinds) == 1:
+        # a period of one layer scans its stack as it stands: through the
+        # reshape below XLA converts the whole stack's weights before the
+        # scan, barrier or not (0.6 GB at the mla/moe cell, whose compiled
+        # step this keeps instruction for instruction)
+        h, counters = jax.lax.scan(
+            lambda h, blk: layer(expert_layer, kinds[0])(blk, h),
+            h, params[kinds[0]])
+    else:
+        stacks = {  # [layers of the kind, ..] -> [periods, its layers in one, ..]
+            kind: jax.tree.map(
+                lambda a: a.reshape(-1, kinds.count(kind), *a.shape[1:]),
+                params[kind])
+            for kind in set(kinds)
+        }
+        h, counters = jax.lax.scan(period, h, stacks)
+        counters = jax.tree.map(  # [periods, layers a period, ..] -> [layers, ..]
+            lambda a: a.reshape(-1, *a.shape[2:]), counters)
+    if "tiles" in counters:  # one sum over every layer, the dense ones too
+        counters["tiles"] = sum(
+            c["tiles"].sum(axis=0) for c in (dense, counters) if "tiles" in c)
+    return h, counters
 
 
 def _mtp_hidden(params, h, next_ids, cfg, m_axis, s_axis):
@@ -365,7 +432,7 @@ def _mtp_hidden(params, h, next_ids, cfg, m_axis, s_axis):
     h2 = mm(both, mtp["eh_proj"], jnp.dtype(cfg.compute_dtype))
     blk = {k: v[0] for k, v in mtp.items() if k not in _MTP_OWN}
     return jax.checkpoint(
-        lambda blk, h: expert_layer(blk, h, cfg, m_axis, s_axis)
+        lambda blk, h: expert_layer(blk, h, cfg, m_axis, s_axis, "mtp")
     )(blk, h2)
 
 
@@ -499,7 +566,7 @@ def _programs(cfg: SeqRecConfig, mesh, vocab: int, B: int,
     from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    latent = is_latent(cfg)
+    latent = is_moe(cfg)
     m_axis = "model" if mesh is not None else None
     s_axis = "seq" if mesh is not None else None
     p_axis = "pipe" if mesh_axis_size(mesh, "pipe") > 1 else None
@@ -554,7 +621,7 @@ def _programs(cfg: SeqRecConfig, mesh, vocab: int, B: int,
                 global_loss, has_aux=True)(params, batch_fn(i, step0))
             with jax.named_scope("seq.opt"):
                 if latent:
-                    aux["grad_norm"] = group_norms(grads)
+                    aux["grad_norm"] = group_norms(grads, cfg)
                 updates, opt_state = tx.update(grads, opt_state, params)
                 params = optax.apply_updates(params, updates)
                 if latent:
@@ -627,8 +694,9 @@ def train_seqrec(
             (``device_scope_s``, ``device_unscoped_s``, ``device_busy_s``,
             ``device_program_s``: pio_tpu/obs/profile.py), ``xla`` holds
             the compile counts with ``in_call``, and ``counters`` the
-            mla/moe block's routed pairs, load ratio, dropped pairs and
-            largest selection bias.
+            moe blocks' routed pairs, load ratio and dropped pairs, the
+            largest selection bias (a router that has one) and the gqa
+            block's ``window_tiles`` and ``causal_tiles``.
 
     Raises:
         DeviceBudgetExceeded: the params can't fit (single-chip or even
@@ -646,7 +714,7 @@ def train_seqrec(
     from pio_tpu.obs.profile import ScopeCapture, device_stats
 
     cfg = config
-    latent = is_latent(cfg)
+    latent = is_moe(cfg)
     n_data = mesh_axis_size(mesh, "data")
     n_seq = mesh_axis_size(mesh, "seq")
     n_model = mesh_axis_size(mesh, "model")
@@ -670,11 +738,11 @@ def train_seqrec(
     if latent:
         if p_axis is not None:
             raise ValueError(
-                "the mla/moe block has no pipe split: its stages differ "
+                "the moe blocks have no pipe split: their stages differ "
                 "(dense, expert, MTP) and pipeline_apply takes like stages"
             )
         if cfg.attention != "ring":
-            raise ValueError("the mla/moe block rides ring attention")
+            raise ValueError("the moe blocks ride ring attention")
         if cfg.experts_held % n_model:
             raise ValueError("experts_held must divide by the model axis")
     else:
@@ -977,33 +1045,39 @@ def train_seqrec(
                 "dropped_pairs": float(trace["dropped"].sum()),
                 "load_max_over_mean": float(
                     trace["load_max_over_mean"].max()),
-                "bias_max": float(trace["bias_max"].max()),
             }
+            if "bias_max" in trace:
+                stats["counters"]["bias_max"] = float(trace["bias_max"].max())
+            for name in ("window_tiles", "causal_tiles"):
+                if name in trace:
+                    stats["counters"][name] = float(trace[name].sum())
     return SeqRecModel(params=host, n_items=n_items, config=cfg, trace=trace)
 
 
 def _after_step(params, aux, cfg):
-    """What follows the optimizer in a step of the mla/moe block: every
-    expert layer's selection bias moves towards the mean load, and the
-    per-expert loads reduce to the step's counters."""
+    """What follows the optimizer in a step of a moe block: the per-expert
+    loads reduce to the step's counters, a router with a selection bias
+    moves every expert layer's bias towards the mean load, and the gqa
+    block's tile counters take their names."""
     import jax.numpy as jnp
 
     load = aux["load"]  # [expert layers (+ the MTP module's), n_experts]
-    n_main = params["blocks"]["router_b"].shape[0]
-    params = dict(params)
-    groups = [("blocks", load[:n_main])]
-    if cfg.mtp_depth:
-        groups.append(("mtp", load[n_main:]))
-    biases = []
-    for group, group_load in groups:
-        b = update_router_bias(
-            params[group]["router_b"], group_load, cfg.bias_update_rate)
-        params[group] = dict(params[group], router_b=b)
-        biases.append(jnp.abs(b).max())
-    aux = dict(
-        aux,
-        load_max_over_mean=load.max(axis=-1) / jnp.maximum(
-            load.mean(axis=-1), 1.0),
-        bias_max=jnp.stack(biases).max(),
-    )
+    aux = dict(aux)
+    if "tiles" in aux:
+        aux["window_tiles"], aux["causal_tiles"] = aux.pop("tiles")
+    if cfg.router_kind == "sigmoid_bias":
+        n_main = params["blocks"]["router_b"].shape[0]
+        params = dict(params)
+        groups = [("blocks", load[:n_main])]
+        if cfg.mtp_depth:
+            groups.append(("mtp", load[n_main:]))
+        biases = []
+        for group, group_load in groups:
+            b = update_router_bias(
+                params[group]["router_b"], group_load, cfg.bias_update_rate)
+            params[group] = dict(params[group], router_b=b)
+            biases.append(jnp.abs(b).max())
+        aux["bias_max"] = jnp.stack(biases).max()
+    aux["load_max_over_mean"] = load.max(axis=-1) / jnp.maximum(
+        load.mean(axis=-1), 1.0)
     return params, aux

@@ -22,7 +22,11 @@ Design (blockwise / ring formulation):
   that started on device ``(i - s) mod n``. Blocks entirely in the future
   still flow through the ring, but their tiles are skipped: each ring step
   is one :func:`attention_partial`, blocked over local key blocks, whose
-  loop runs only over the key blocks at or below the diagonal.
+  loop runs only over the key blocks at or below the diagonal, and, under
+  a ``window``, not before the window's first either.
+- Grouped queries: ``k``/``v`` may have fewer heads than ``q``; the query
+  heads of a KV head are folded into the query rows of one tile
+  (:func:`fold_groups`), so nothing is repeated.
 
 Inside ``jit`` with a sharded mesh this function must be wrapped in
 ``shard_map`` over the ``seq`` axis (see :func:`ring_attention_sharded`);
@@ -63,33 +67,58 @@ def needed_key_blocks(i, q_off, k_off, bq: int, bk: int, nk: int, causal: bool):
     return jnp.clip((last_q - k_off) // bk + 1, 0, nk)
 
 
-def _scores(qi, kj, q_pos, k_pos, causal, scale):
+def first_key_block(i, q_off, k_off, bq: int, bk: int, nk: int, window: int):
+    """The first key block query block ``i`` can see under a ``window``
+    (query ``t`` sees keys ``s`` with ``0 <= t - s < window``; 0 = no
+    window): the block that holds the earliest key of the block's first
+    query. The blocks before it lie outside the window and are never
+    computed."""
+    if not window:
+        return 0
+    earliest = q_off + i * bq - window + 1
+    return jnp.clip((earliest - k_off) // bk, 0, nk)
+
+
+def _scores(qi, kj, q_pos, k_pos, causal, scale, window=0):
     s = jnp.einsum(
         "bhqd,bhkd->bhqk", qi, kj, preferred_element_type=jnp.float32
     ) * scale
     if not causal:
         return s, None
-    mask = (q_pos[:, None] >= k_pos[None, :])[None, None]
+    mask = q_pos[:, None] >= k_pos[None, :]
+    if window:
+        mask = mask & (q_pos[:, None] - k_pos[None, :] < window)
+    mask = mask[None, None]
     return jnp.where(mask, s, _NEG_BIG), mask
 
 
-def _partial_fwd(q, k, v, q_off, k_off, causal, scale, bq, bk):
-    """Blocked online softmax of ``[B, H, T, D]`` operands: ``(o, lse)``,
-    ``o`` float32 and normalised over the keys given here."""
-    b, h, tq, _ = q.shape
+def _row_positions(bq: int, group: int):
+    """Positions, within a query block, of a tile's rows: ``group`` query
+    heads of one KV head lie one after the other, ``bq`` positions each."""
+    pos = jnp.arange(bq)
+    return pos if group == 1 else jnp.tile(pos, group)
+
+
+def _partial_fwd(q, k, v, q_off, k_off, causal, scale, bq, bk, window=0,
+                 group=1):
+    """Blocked online softmax of ``[B, H, T, D]`` operands: ``(o, lse,
+    tiles)``, ``o`` float32 and normalised over the keys given here."""
+    b, h, rows_q, _ = q.shape
     tk, dv = k.shape[2], v.shape[3]
-    nq, nk = tq // bq, tk // bk
+    rows = bq * group  # query rows of one tile
+    nq, nk = rows_q // rows, tk // bk
 
     def q_block(i):
-        qi = jax.lax.dynamic_slice_in_dim(q, i * bq, bq, axis=2)
-        q_pos = q_off + i * bq + jnp.arange(bq)
+        qi = jax.lax.dynamic_slice_in_dim(q, i * rows, rows, axis=2)
+        q_pos = q_off + i * bq + _row_positions(bq, group)
 
         def body(j, carry):
             o, m, l = carry
             kj = jax.lax.dynamic_slice_in_dim(k, j * bk, bk, axis=2)
             vj = jax.lax.dynamic_slice_in_dim(v, j * bk, bk, axis=2)
             s, mask = _scores(
-                qi, kj, q_pos, k_off + j * bk + jnp.arange(bk), causal, scale
+                qi, kj, q_pos, k_off + j * bk + jnp.arange(bk), causal, scale,
+                window,
             )
             m_new = jnp.maximum(m, s.max(axis=-1))
             p = jnp.exp(s - m_new[..., None])
@@ -104,68 +133,88 @@ def _partial_fwd(q, k, v, q_off, k_off, causal, scale, bq, bk):
             return o, m_new, l
 
         init = (
-            jnp.zeros((b, h, bq, dv), jnp.float32),
-            jnp.full((b, h, bq), _NEG_BIG, jnp.float32),
-            jnp.zeros((b, h, bq), jnp.float32),
+            jnp.zeros((b, h, rows, dv), jnp.float32),
+            jnp.full((b, h, rows), _NEG_BIG, jnp.float32),
+            jnp.zeros((b, h, rows), jnp.float32),
         )
         n = needed_key_blocks(i, q_off, k_off, bq, bk, nk, causal)
-        o, m, l = jax.lax.fori_loop(0, n, body, init)
+        first = first_key_block(i, q_off, k_off, bq, bk, nk, window)
+        o, m, l = jax.lax.fori_loop(first, n, body, init)
         safe = jnp.maximum(l, 1e-30)
-        return o / safe[..., None], jnp.where(l > 0, m + jnp.log(safe), _NEG_BIG)
+        # the loop's own bounds: the tiles it ran, and those from block 0
+        ran = jnp.stack([jnp.maximum(n - first, 0), n]).astype(jnp.int32)
+        return (o / safe[..., None],
+                jnp.where(l > 0, m + jnp.log(safe), _NEG_BIG), ran)
 
-    o, lse = jax.lax.map(q_block, jnp.arange(nq))  # [nq, B, H, bq, ...]
-    o = jnp.moveaxis(o, 0, 2).reshape(b, h, tq, dv)
-    lse = jnp.moveaxis(lse, 0, 2).reshape(b, h, tq)
-    return o, lse
+    o, lse, ran = jax.lax.map(q_block, jnp.arange(nq))  # [nq, B, H, rows, ..]
+    o = jnp.moveaxis(o, 0, 2).reshape(b, h, rows_q, dv)
+    lse = jnp.moveaxis(lse, 0, 2).reshape(b, h, rows_q)
+    return o, lse, ran.sum(axis=0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def attention_partial(q, k, v, q_off, k_off, causal, scale, bq, bk):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def attention_partial(q, k, v, q_off, k_off, causal, scale, bq, bk, window=0,
+                      group=1):
     """Exact attention of ``q`` over the keys given, in ``bq x bk`` tiles.
 
     ``q`` ``[B, H, Tq, Dk]``, ``k`` ``[B, H, Tk, Dk]``, ``v`` ``[B, H, Tk,
     Dv]``; ``q_off``/``k_off`` are the global positions of the first query
     and key (int32 scalars, traced on a ring). Returns ``o`` ``[B, H, Tq,
-    Dv]`` float32, normalised over these keys, and ``lse`` ``[B, H, Tq]``,
+    Dv]`` float32, normalised over these keys, ``lse`` ``[B, H, Tq]``,
     so partial results over disjoint key sets merge exactly
-    (:func:`merge_partials`). Matmul operands stay in the dtype given;
+    (:func:`merge_partials`), and ``tiles`` ``[2]`` int32 from the bounds
+    the forward's tile loops ran between: the score tiles computed (a head
+    and row), and those a loop from key block 0 computes. Matmul operands stay in the dtype given;
     scores, softmax and accumulators are float32. Memory is linear in the
     row's length forward and backward: the backward recomputes each score
     tile from ``lse`` and keeps none. Key blocks above the diagonal are
-    skipped, not masked.
+    skipped, not masked, and with a ``window`` (query ``t`` sees keys ``s``
+    with ``0 <= t - s < window``; causal only) so are the key blocks that
+    lie wholly before it, forward and backward.
+
+    ``group`` query heads may share one head of ``k`` and ``v``: ``q`` then
+    comes tile-major (:func:`fold_groups`), ``[B, H, nq * group * bq, Dk]``,
+    a tile's rows being the ``group`` heads' ``bq`` positions one after the
+    other, so that one tile's matmuls serve the whole group and ``k``/``v``
+    are read once a KV head; ``o`` and ``lse`` come back in the same order.
     """
-    return _partial_fwd(q, k, v, q_off, k_off, causal, scale, bq, bk)
+    return _partial_fwd(q, k, v, q_off, k_off, causal, scale, bq, bk, window,
+                        group)
 
 
-def _attention_partial_fwd(q, k, v, q_off, k_off, causal, scale, bq, bk):
-    o, lse = _partial_fwd(q, k, v, q_off, k_off, causal, scale, bq, bk)
-    return (o, lse), (q, k, v, q_off, k_off, o, lse)
+def _attention_partial_fwd(q, k, v, q_off, k_off, causal, scale, bq, bk,
+                           window=0, group=1):
+    o, lse, tiles = _partial_fwd(q, k, v, q_off, k_off, causal, scale, bq, bk,
+                                 window, group)
+    return (o, lse, tiles), (q, k, v, q_off, k_off, o, lse)
 
 
-def _attention_partial_bwd(causal, scale, bq, bk, res, cts):
+def _attention_partial_bwd(causal, scale, bq, bk, window, group, res, cts):
     q, k, v, q_off, k_off, o, lse = res
-    do, dlse = cts
-    b, h, tq, dk_ = q.shape
+    do, dlse, _ = cts  # the tile count is an integer: it has no cotangent
+    b, h, rows_q, dk_ = q.shape
     tk, dv_ = k.shape[2], v.shape[3]
-    nq, nk = tq // bq, tk // bk
+    rows = bq * group
+    nq, nk = rows_q // rows, tk // bk
     # d s_ij = p_ij (dp_ij - delta_i + dlse_i), delta_i = do_i . o_i
     g = dlse - (do * o).sum(axis=-1)
     do = do.astype(q.dtype)
 
     def q_block(carry, i):
         dk, dv = carry
-        qi = jax.lax.dynamic_slice_in_dim(q, i * bq, bq, axis=2)
-        doi = jax.lax.dynamic_slice_in_dim(do, i * bq, bq, axis=2)
-        lsei = jax.lax.dynamic_slice_in_dim(lse, i * bq, bq, axis=2)
-        gi = jax.lax.dynamic_slice_in_dim(g, i * bq, bq, axis=2)
-        q_pos = q_off + i * bq + jnp.arange(bq)
+        qi = jax.lax.dynamic_slice_in_dim(q, i * rows, rows, axis=2)
+        doi = jax.lax.dynamic_slice_in_dim(do, i * rows, rows, axis=2)
+        lsei = jax.lax.dynamic_slice_in_dim(lse, i * rows, rows, axis=2)
+        gi = jax.lax.dynamic_slice_in_dim(g, i * rows, rows, axis=2)
+        q_pos = q_off + i * bq + _row_positions(bq, group)
 
         def body(j, carry):
             dqi, dk, dv = carry
             kj = jax.lax.dynamic_slice_in_dim(k, j * bk, bk, axis=2)
             vj = jax.lax.dynamic_slice_in_dim(v, j * bk, bk, axis=2)
             s, mask = _scores(
-                qi, kj, q_pos, k_off + j * bk + jnp.arange(bk), causal, scale
+                qi, kj, q_pos, k_off + j * bk + jnp.arange(bk), causal, scale,
+                window,
             )
             p = jnp.exp(s - lsei[..., None])
             if causal:
@@ -194,8 +243,9 @@ def _attention_partial_bwd(causal, scale, bq, bk, res, cts):
             return dqi, add(dk, dkj), add(dv, dvj)
 
         n = needed_key_blocks(i, q_off, k_off, bq, bk, nk, causal)
+        first = first_key_block(i, q_off, k_off, bq, bk, nk, window)
         dqi, dk, dv = jax.lax.fori_loop(
-            0, n, body, (jnp.zeros((b, h, bq, dk_), jnp.float32), dk, dv)
+            first, n, body, (jnp.zeros((b, h, rows, dk_), jnp.float32), dk, dv)
         )
         return (dk, dv), dqi
 
@@ -205,7 +255,7 @@ def _attention_partial_bwd(causal, scale, bq, bk, res, cts):
          jnp.zeros((b, h, tk, dv_), jnp.float32)),
         jnp.arange(nq),
     )
-    dq = jnp.moveaxis(dq, 0, 2).reshape(b, h, tq, dk_)
+    dq = jnp.moveaxis(dq, 0, 2).reshape(b, h, rows_q, dk_)
     return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
             None, None)
 
@@ -221,6 +271,26 @@ def merge_partials(o_a, lse_a, o_b, lse_b):
     return o_a * w_a + o_b * w_b, lse
 
 
+def fold_groups(q, bq: int, group: int):
+    """``[B, T, H_kv * group, D]`` -> tile-major ``[B, H_kv, T * group, D]``:
+    query head ``j`` rides KV head ``j // group``, and the ``group`` heads'
+    rows of one ``bq``-block of positions lie together (what
+    :func:`attention_partial` takes with ``group`` > 1)."""
+    b, t, h, d = q.shape
+    q = q.reshape(b, t // bq, bq, h // group, group, d)
+    return q.transpose(0, 3, 1, 4, 2, 5).reshape(b, h // group, t * group, d)
+
+
+def unfold_groups(o, bq: int, group: int):
+    """The inverse of :func:`fold_groups`, for ``o [B, H_kv, T * group, D]``
+    (or ``lse`` without the last dim) -> ``[B, T, H, D]``."""
+    b, hk, rows = o.shape[:3]
+    t = rows // group
+    o = o.reshape(b, hk, t // bq, group, bq, *o.shape[3:])
+    o = jnp.moveaxis(o, (2, 4, 1, 3), (1, 2, 3, 4))
+    return o.reshape(b, t, hk * group, *o.shape[5:])
+
+
 def ring_attention(
     q: jax.Array,
     k: jax.Array,
@@ -230,51 +300,73 @@ def ring_attention(
     causal: bool = True,
     block: int = DEFAULT_BLOCK,
     scale: Optional[float] = None,
-) -> jax.Array:
+    window: int = 0,
+    with_tiles: bool = False,
+):
     """Exact attention over a sequence sharded on mesh axis ``axis``.
 
     Call from inside ``shard_map``; each device passes its local
     ``[B, T_local, H, D]`` blocks (``v`` may have another width than
-    ``q``/``k``). With ``axis=None`` it is plain single-device attention.
-    Every ring step, and the single step without a ring, is one
+    ``q``/``k``; ``k`` and ``v`` may have fewer heads, each shared by a
+    group of consecutive query heads, whose rows are folded into one tile:
+    nothing is repeated). ``window`` > 0 (with ``causal``) lets query ``t``
+    see keys ``s`` with ``0 <= t - s < window`` only; key blocks wholly
+    outside it are skipped. With ``axis=None`` it is plain single-device
+    attention. Every ring step, and the single step without a ring, is one
     :func:`attention_partial` in ``block``-sized tiles, so no ``[T, T]``
     score matrix exists on any path. Returns the local ``[B, T_local, H,
-    Dv]`` output block in ``q``'s dtype.
+    Dv]`` output block in ``q``'s dtype; ``with_tiles`` adds
+    :func:`attention_partial`'s tile counts, summed over the ring steps.
     """
     b, t_loc, h, d = q.shape
     scale = 1.0 / (d ** 0.5) if scale is None else float(scale)
     n = 1 if axis is None else jax.lax.axis_size(axis)
     idx = jnp.int32(0) if axis is None else jax.lax.axis_index(axis)
     blk = pick_block(t_loc, block)
-    qh, kh, vh = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
+    group = h // k.shape[2]
+    if group == 1:
+        qh, kh, vh = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
+    else:
+        qh = fold_groups(q, blk, group)
+        kh, vh = (a.transpose(0, 2, 1, 3) for a in (k, v))
+    h_kv, rows = kh.shape[1], t_loc * group
     q_off = idx * t_loc
 
     def partial(k_blk, v_blk, s):
         src = (idx - s) % n  # which device this K/V block started on
         return attention_partial(
-            qh, k_blk, v_blk, q_off, src * t_loc, causal, scale, blk, blk
+            qh, k_blk, v_blk, q_off, src * t_loc, causal, scale, blk, blk,
+            window, group,
         )
 
     def step(carry, s):
-        o, lse, k_blk, v_blk = carry
-        o, lse = merge_partials(o, lse, *partial(k_blk, v_blk, s))
+        o, lse, tiles, k_blk, v_blk = carry
+        o_s, lse_s, tiles_s = partial(k_blk, v_blk, s)
+        o, lse = merge_partials(o, lse, o_s, lse_s)
         perm = [(i, (i + 1) % n) for i in range(n)]
         k_blk = jax.lax.ppermute(k_blk, axis, perm)
         v_blk = jax.lax.ppermute(v_blk, axis, perm)
-        return (o, lse, k_blk, v_blk), None
+        return (o, lse, tiles + tiles_s, k_blk, v_blk), None
 
     if n > 1:
         # n-1 rotating steps, then the last block's update with no final
         # ppermute (the rotated result would be discarded — wasted ICI).
-        o = jnp.zeros((b, h, t_loc, vh.shape[-1]), jnp.float32)
-        lse = jnp.full((b, h, t_loc), _NEG_BIG, jnp.float32)
-        (o, lse, kh, vh), _ = jax.lax.scan(
-            step, (o, lse, kh, vh), jnp.arange(n - 1)
+        o = jnp.zeros((b, h_kv, rows, vh.shape[-1]), jnp.float32)
+        lse = jnp.full((b, h_kv, rows), _NEG_BIG, jnp.float32)
+        (o, lse, tiles, kh, vh), _ = jax.lax.scan(
+            step, (o, lse, jnp.zeros((2,), jnp.int32), kh, vh),
+            jnp.arange(n - 1)
         )
-        o, _ = merge_partials(o, lse, *partial(kh, vh, n - 1))
+        o_s, lse_s, tiles_s = partial(kh, vh, n - 1)
+        o, _ = merge_partials(o, lse, o_s, lse_s)
+        tiles = tiles + tiles_s
     else:
-        o, _ = partial(kh, vh, 0)
-    return o.transpose(0, 2, 1, 3).astype(q.dtype)
+        o, _, tiles = partial(kh, vh, 0)
+    if group == 1:
+        o = o.transpose(0, 2, 1, 3).astype(q.dtype)
+    else:
+        o = unfold_groups(o, blk, group).astype(q.dtype)
+    return (o, tiles) if with_tiles else o
 
 
 def ring_attention_sharded(mesh, q, k, v, *, causal: bool = True):

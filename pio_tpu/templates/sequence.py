@@ -190,7 +190,9 @@ class SeqRecParams(SeqRecConfig, Params):
     """engine.json's algorithm params: every field of
     :class:`~pio_tpu.models.seqrec.SeqRecConfig` (the block is described
     there, by data: ``attention_kind``, ``ffn_kind``, ``dense_layers``,
-    the expert and MTP counts) plus the mesh splits."""
+    the expert and MTP counts; for ``"gqa"`` the ``layer_pattern`` of full
+    and window layers, the heads held by kind, each kind's RoPE and the
+    ``router_kind``) plus the mesh splits."""
 
     steps: int = 300
     #: mesh splits; remaining devices ride the data axis

@@ -113,7 +113,7 @@ def test_partials_over_disjoint_keys_merge_exactly(qkvw):
                           scale, 16, 16)
     b = attention_partial(qh, kh[:, :, half:], vh[:, :, half:], 0, half,
                           False, scale, 16, 16)
-    merged = merge_partials(*a, *b)
+    merged = merge_partials(*a[:2], *b[:2])  # the third is the tile count
     np.testing.assert_allclose(merged[0], whole[0], atol=2e-6)
     np.testing.assert_allclose(merged[1], whole[1], atol=2e-6)
 
