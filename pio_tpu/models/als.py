@@ -992,7 +992,7 @@ def _build_trainer(mesh, axis: str, iterations: int, reg: float,
             # (PIO_TPU_ALS_STREAM_MB: chunked puts pipeline the
             # per-device transfers); re-replicate each span over ICI,
             # drop its shard-divisibility padding and splice the stream
-            # back together: device_pack gathers from the whole list
+            # back together: device_pack copies runs of the whole list
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             repl = NamedSharding(mesh, P())
@@ -1007,12 +1007,8 @@ def _build_trainer(mesh, axis: str, iterations: int, reg: float,
 
             i32 = gather_cat(i32)
             r32 = gather_cat(r32)
-        E = i32.shape[0]
         with jax.named_scope("als.pack"):
-            u32 = jnp.repeat(
-                jnp.arange(U_pad, dtype=jnp.int32), counts_u,
-                total_repeat_length=E,
-            )
+            u32 = _entity_column(counts_u, i32.shape[0])
         # both degree histograms ride the link (0.9 MB at ml-25m): the
         # on-device bincount is a 25M-edge scatter-add, the host count is
         # a pass the sort already made
@@ -1114,10 +1110,7 @@ def _build_stream_trainer(iterations: int, reg: float, implicit: bool,
             # item side needs the full COO: the (device-resident) slices
             i32 = jnp.concatenate([i_c for i_c, _ in edge_chunks])
             r32 = jnp.concatenate([r_c for _, r_c in edge_chunks])
-            u32 = jnp.repeat(
-                jnp.arange(U_pad, dtype=jnp.int32), counts_u,
-                total_repeat_length=i32.shape[0],
-            )
+            u32 = _entity_column(counts_u, i32.shape[0])
         by_item = device_pack(i32, u32, r32, I_pad, w_item, S_item,
                               counts=counts_i)
         # iteration 1: user half is already accumulated (streamed)
@@ -1146,17 +1139,22 @@ def device_pack(ent, oth, rat, n_entities: int, width: int, S: int,
 
     Layout is bit-identical to the host packer (_pack_blocks), enforced
     by tests/test_als.py ``test_device_pack_matches_host_packers``.
-    ``S``, ``width``, and ``n_entities`` are static. ``assume_sorted`` skips the stable argsort
-    when the caller guarantees ``ent`` is already ascending (the
-    counts-rebuilt user column is sorted by construction).
+    ``S``, ``width``, and ``n_entities`` are static. ``assume_sorted``
+    says that the edges arrive in ascending ``ent`` order (the streamed
+    chunks, the counts-rebuilt user column); otherwise one stable sort
+    by ``ent`` carries ``oth`` and ``rat`` along as payloads.
 
-    Formulated as pure GATHERS: every [S, W] slot computes which edge (if
-    any) it holds — block's entity via searchsorted over the block prefix
-    sum, position within the entity's adjacency from the block offset —
-    and gathers it, composing through the argsort permutation when the
-    input isn't pre-sorted. The scatter formulation (`.at[flat].set` over
-    the S·W slot space) measured ~3.2 s per 25M edges on v5e where the
-    gathers take ~0.3 s: scatters serialize on TPU, gathers tile.
+    Formulated as a COPY OF RUNS: in entity order a block's ``width``
+    slots hold consecutive edges, from ``edge_start[entity] +
+    block_in_entity * width``, masked by the entity's count. So every
+    block fetches its run as whole lane rows of the edge list
+    (:func:`_copy_runs`: a gather of ``S`` rows of 128, not of ``S *
+    width`` scalars; a TPU gather costs by the row, and a scalar is a
+    row). Forms to avoid: the scatter (``.at[flat].set`` over the slot
+    space serializes on a TPU), the slot-wise gather ``oth[src]`` (1.57 s
+    of a 5.09 s call at MovieLens-25M, rank 64: ledger, PR 33), and a
+    batched ``dynamic_slice`` of ``width`` elements out of the flat list,
+    which XLA expands into a ``while`` of ``S`` slices.
 
     ``pad_entity`` redirects the padding blocks' (masked) entity id —
     the streamed trainer points them at a chunk's LAST present entity so
@@ -1172,6 +1170,9 @@ def device_pack(ent, oth, rat, n_entities: int, width: int, S: int,
             counts = jnp.bincount(ent, length=n_entities)  # order-free
         else:
             counts = counts.astype(jnp.int32)  # caller-supplied (wire input)
+        if not assume_sorted:
+            _, oth, rat = jax.lax.sort(
+                (ent, oth, rat), num_keys=1, is_stable=True)
         blocks = -(-counts // width)
         zero = jnp.zeros(1, counts.dtype)
         block_start = jnp.concatenate([zero, jnp.cumsum(blocks)])
@@ -1179,20 +1180,67 @@ def device_pack(ent, oth, rat, n_entities: int, width: int, S: int,
 
         # per block: owning entity (padding blocks → pad_entity, masked out)
         pad_tgt = (n_entities - 1) if pad_entity is None else pad_entity
-        bids = jnp.searchsorted(block_start[1:], jnp.arange(S), side="right")
+        # (unrolled: the scan form is a ``while`` of log2(n) turns)
+        bids = jnp.searchsorted(block_start[1:], jnp.arange(S), side="right",
+                                method="scan_unrolled")
         block_ent = jnp.minimum(bids, pad_tgt).astype(jnp.int32)
 
-        # per slot: position within the entity's adjacency, then edge index
-        blk_in_ent = jnp.arange(S) - block_start[block_ent]  # [S]
-        pos = blk_in_ent[:, None] * width + jnp.arange(width)[None, :]
-        valid = pos < counts[block_ent][:, None]  # [S, W]
-        src = jnp.where(valid, edge_start[block_ent][:, None] + pos, 0)
-        if not assume_sorted:
-            # compose through the stable sort permutation: one fused gather
-            src = jnp.argsort(ent, stable=True)[src]
-        block_other = jnp.where(valid, oth[src], jnp.int32(-1))
-        block_rating = jnp.where(valid, rat[src], jnp.float32(0.0))
+        # per block: where its run starts within the entity's adjacency
+        # and how many edges are left there; per slot: one of them or not
+        first = (jnp.arange(S) - block_start[block_ent]) * width  # [S]
+        left = counts[block_ent] - first
+        valid = jnp.arange(width)[None, :] < left[:, None]  # [S, W]
+        # a padding block (nothing left) reads the list's head: its own
+        # start may lie past the end, and every slot of it is masked
+        start = jnp.where(left > 0, edge_start[block_ent] + first, 0)
+        block_other = jnp.where(
+            valid, _copy_runs(oth, start, width), jnp.int32(-1))
+        block_rating = jnp.where(
+            valid, _copy_runs(rat, start, width), jnp.float32(0.0))
         return block_ent, block_other, block_rating
+
+
+def _entity_column(counts, n_edges: int):
+    """The entity of every edge of a list in entity order, from the degree
+    counts: ``repeat(arange(n), counts)``. A one at each entity's first
+    edge (an empty entity's falls on its successor's, a trailing one's
+    off the end), prefix-summed: ``jnp.repeat`` computes the same and
+    then gathers one scalar an edge out of the ``arange``, which is the
+    identity and cost 0.17 s of a call at 25 M edges (my chip run, PR
+    34)."""
+    import jax.numpy as jnp
+
+    counts = counts.astype(jnp.int32)
+    first_edge = jnp.cumsum(counts) - counts
+    ones = jnp.zeros(n_edges, jnp.int32).at[first_edge].add(
+        1, indices_are_sorted=True, mode="drop")
+    return jnp.cumsum(ones) - 1
+
+
+def _copy_runs(flat, start, width: int):
+    """``out[s, :] = flat[start[s] : start[s] + width]`` as ``[S, width]``,
+    for starts inside ``flat``; what lies past its end reads 0.
+
+    The list is viewed as rows of ``_LANES``; a run is fetched as the
+    whole rows it touches (two, for a width up to 128 at any offset: one
+    native row gather each, no ``while``), joined, and shifted left by
+    the start's offset within its row: one select between two static
+    slices per bit of the offset, the highest first, each pass keeping
+    only the columns a remaining shift can still reach."""
+    import jax.numpy as jnp
+
+    n = flat.shape[0]
+    k = (width + _LANES - 2) // _LANES + 1  # rows a run can touch
+    n_rows = -(-n // _LANES) + k - 1  # so the last run's rows exist
+    rows = jnp.pad(flat, (0, n_rows * _LANES - n)).reshape(n_rows, _LANES)
+    row, off = start // _LANES, start % _LANES
+    x = jnp.concatenate([rows[row + j] for j in range(k)], axis=1)
+    for bit in reversed(range(_LANES.bit_length() - 1)):
+        step = 1 << bit
+        keep = width + step - 1
+        x = jnp.where((off & step != 0)[:, None],
+                      x[:, step:step + keep], x[:, :keep])
+    return x
 
 
 def _edge_spans(n_edges: int, n_stream: int) -> list:

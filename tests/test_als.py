@@ -25,6 +25,42 @@ def synthetic():
 CFG = ALSConfig(rank=8, iterations=12, reg=0.01, blocks_per_chunk=64)
 
 
+#: how the trainers call ``device_pack``
+_PACK_FORMS = ["shuffled", "shuffled_counts", "sorted", "chunk"]
+
+#: (degree sequence from a generator, block width): what a copy of runs
+#: can get wrong. A run is fetched as whole rows of 128 edges.
+_PACK_LAYOUTS = [
+    pytest.param(lambda g: np.bincount(g.integers(0, 80, 5000),
+                                       minlength=80), 16, id="random-w16"),
+    pytest.param(lambda g: [0, 0, 1, 0], 8, id="one-edge"),
+    pytest.param(lambda g: np.bincount(g.integers(0, 4, 64), minlength=4),
+                 8, id="dense-w8"),
+    pytest.param(lambda g: np.bincount(g.integers(0, 200, 97),
+                                       minlength=200), 8, id="sparse-w8"),
+    # starts at every offset of a row, at every width the rule picks
+    *[pytest.param(lambda g: g.integers(0, 150, 60), w, id=f"skewed-w{w}")
+      for w in (8, 16, 32, 64)],
+    pytest.param(lambda g: np.arange(1, 140), 64, id="every-offset-w64"),
+    # a run over a whole row, and one that touches three
+    pytest.param(lambda g: g.integers(0, 400, 30), 128, id="skewed-w128"),
+    pytest.param(lambda g: g.integers(0, 700, 20), 200, id="skewed-w200"),
+    # 384 edges: the last run ends where a row of 128 ends, so the row
+    # after it is padding alone
+    pytest.param(lambda g: [100, 156, 128], 64, id="ends-on-a-row"),
+    # 385 edges: the last run is the list's last edge, alone in its row
+    pytest.param(lambda g: [129, 250, 6], 8, id="ends-on-the-last-edge"),
+    # the last run starts on a row's last lane
+    pytest.param(lambda g: [255, 40], 32, id="starts-on-lane-127"),
+    pytest.param(lambda g: [16, 48, 0, 32, 16], 16,
+                 id="counts-are-multiples-of-the-width"),
+    pytest.param(lambda g: [0, 0, 5, 0, 0, 0, 17, 3, 0, 0], 8,
+                 id="empty-front-middle-end"),
+    pytest.param(lambda g: [0, 0, 300, 0], 16, id="one-entity-holds-all"),
+    pytest.param(lambda g: [1000], 64, id="one-entity-alone"),
+]
+
+
 class TestALS:
     def test_reconstructs_observed_local(self, synthetic):
         s = synthetic
@@ -252,30 +288,67 @@ class TestALS:
         pred = (f.user_factors[u] * f.item_factors[i]).sum(1)
         assert np.sqrt(np.mean((pred - r) ** 2)) < 1.0
 
-    def test_device_pack_matches_host_packers(self):
+    @pytest.mark.parametrize("form", _PACK_FORMS)
+    @pytest.mark.parametrize("counts_of,width", _PACK_LAYOUTS)
+    def test_device_pack_matches_host_packers(self, counts_of, width, form):
         """The on-device packer must be bit-identical to the host layout
         (the trainer's correctness rides on ascending block_ent for
-        indices_are_sorted segment sums and -1 padding sentinels)."""
+        indices_are_sorted segment sums and -1 padding sentinels), for
+        every way the trainers call it: ``shuffled`` (edges in any order
+        with ties, the stable order decides the layout; counted on the
+        device), ``shuffled_counts`` (``finalize``'s item side),
+        ``sorted`` (``run_packed``'s user side) and ``chunk`` (a streamed
+        chunk: no ``ent``, the padding blocks at the last present
+        entity)."""
         import jax
         import jax.numpy as jnp
 
-        from pio_tpu.models.als import (
-            _pack_blocks, _round_up, device_pack,
-        )
+        from pio_tpu.models.als import _pack_blocks, device_pack
 
         rng = np.random.default_rng(21)
-        for E, N, W in [(5000, 80, 16), (1, 4, 8), (64, 4, 8), (97, 200, 8)]:
-            ent = rng.integers(0, N, E).astype(np.int32)
-            oth = rng.integers(0, 999, E).astype(np.int32)
-            rat = rng.random(E).astype(np.float32)
-            ref = _pack_blocks(ent, oth, rat, N, W, 8)
-            S = ref[0].shape[0]
-            got = jax.jit(
-                device_pack, static_argnums=(3, 4, 5)
-            )(jnp.asarray(ent), jnp.asarray(oth), jnp.asarray(rat), N, W, S)
-            assert (np.asarray(got[0]) == ref[0]).all(), (E, N, W)
-            assert (np.asarray(got[1]) == ref[1]).all(), (E, N, W)
-            assert (np.asarray(got[2]) == ref[2]).all(), (E, N, W)
+        counts = np.asarray(counts_of(rng), np.int64)
+        N, E = len(counts), int(counts.sum())
+        ent = np.repeat(np.arange(N, dtype=np.int32), counts)
+        if form.startswith("shuffled"):
+            ent = rng.permutation(ent)
+        # every edge its own id and rating: a slot names the edge it holds
+        oth = rng.permutation(E).astype(np.int32)
+        rat = (rng.random(E) + 0.5).astype(np.float32)
+        ref_ent, ref_oth, ref_rat = _pack_blocks(ent, oth, rat, N, width, 8)
+        S = ref_ent.shape[0]
+        kw = {"assume_sorted": form in ("sorted", "chunk")}
+        if form == "chunk":
+            kw["pad_entity"] = last = int(np.flatnonzero(counts)[-1])
+            ref_ent = ref_ent.copy()
+            ref_ent[int((-(-counts // width)).sum()):] = last
+        got = jax.jit(
+            lambda e, o, r, c: device_pack(e, o, r, N, width, S, counts=c,
+                                           **kw)
+        )(None if form == "chunk" else jnp.asarray(ent),
+          jnp.asarray(oth), jnp.asarray(rat),
+          None if form == "shuffled" else jnp.asarray(counts, jnp.int32))
+        assert (np.asarray(got[0]) == ref_ent).all()
+        assert (np.asarray(got[1]) == ref_oth).all()
+        assert (np.asarray(got[2]) == ref_rat).all()
+
+    @pytest.mark.parametrize("counts", [
+        [3, 1, 4], [0, 0, 3, 0, 2, 0, 0], [5], [0, 1], [1, 0],
+        list(range(40)),
+    ], ids=["dense", "empty-front-middle-end", "one-entity", "empty-first",
+            "empty-last", "ramp"])
+    def test_entity_column_is_repeat_of_the_counts(self, counts):
+        """The per-edge entity ids the item side's pack is handed are
+        ``repeat(arange(n), counts)``, empty entities wherever they
+        stand."""
+        import jax
+        import jax.numpy as jnp
+
+        from pio_tpu.models.als import _entity_column
+
+        got = jax.jit(_entity_column, static_argnums=1)(
+            jnp.asarray(counts, jnp.int32), sum(counts))
+        assert (np.asarray(got)
+                == np.repeat(np.arange(len(counts)), counts)).all()
 
     @pytest.mark.parametrize("side", ["user", "item"])
     @pytest.mark.parametrize("route", ["monolithic", "streamed", "mesh"])

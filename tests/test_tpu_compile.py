@@ -129,7 +129,9 @@ def stream_programs(one_chip):
 @pytest.mark.parametrize("program,kernels,temp_bytes", [
     # as compiled with the kernel in; the XLA path's: 10,501,731,840 and
     # 11,834,045,952 B
-    ("accum", 1, 7_239_892_992), ("finalize", 3, 8_783_849_984)])
+    # (``accum`` held 7,239,892,992 B until PR 34: the pack's lane rows,
+    # ``[69632, 128]`` words each, are live beside the first chunk step)
+    ("accum", 1, 7_397_432_832), ("finalize", 3, 8_784_753_152)])
 def test_stream_programs_sum_in_the_kernel_on_v5e(stream_programs, program,
                                                   kernels, temp_bytes):
     """Both programs of the streamed trainer hand every half-step to the
@@ -143,6 +145,34 @@ def test_stream_programs_sum_in_the_kernel_on_v5e(stream_programs, program,
     assert len(calls) == kernels, len(calls)
     assert "f32[4096,64,64]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes <= 1.01 * temp_bytes
+
+
+@pytest.mark.parametrize("program,S", [
+    ("accum", 17 * 4096), ("finalize", 107 * 4096)])
+def test_stream_programs_pack_by_the_row_on_v5e(stream_programs, program, S):
+    """``device_pack`` in both programs of the streamed trainer copies a
+    block's run of edges as lane rows: under ``als.pack`` stand the four
+    native gathers of ``S`` rows of 128 (ids and ratings, a run's two
+    rows), no gather of one scalar for each of the ``S x 64`` slots (the
+    form that cost 1.57 s of a 5.09 s call: PERF.md section 6, PR 34),
+    and no ``while`` (what XLA makes of a batched ``dynamic_slice`` out
+    of the flat list, and of ``searchsorted``'s scan)."""
+    import math
+    import re
+
+    W = 64
+    pack = [ln for ln in stream_programs[program].as_text().splitlines()
+            if re.search(r'op_name="[^"]*als\.pack', ln)]
+    assert not [ln for ln in pack if " while(" in ln]
+    gathers = {}  # (elements gathered, slice sizes) -> how many
+    for ln in pack:
+        m = re.search(r"= \w+\[([\d,]+)\]\S* gather\(.*slice_sizes=\{([\d,]+)\}",
+                      ln)
+        if m:
+            key = (math.prod(map(int, m.group(1).split(","))), m.group(2))
+            gathers[key] = gathers.get(key, 0) + 1
+    assert gathers.get((S * 128, "1,128")) == 4, gathers
+    assert not [k for k in gathers if k[0] >= S * W and k[1] == "1"], gathers
 
 
 def test_stream_finalize_gathers_from_vmem_on_v5e(stream_programs):
