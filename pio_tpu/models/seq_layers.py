@@ -1,6 +1,9 @@
 """The sequence model's blocks, described by data.
 
-Three blocks share ``models/seqrec.py``'s trainer and server:
+Four blocks share ``models/seqrec.py``'s trainer and server. A layer is
+built in one of three ways: the SASRec block's attention and biased FFN, the
+moe blocks' "attention, then a feed-forward part" (dense or experts), or
+**one mixer alone** (``mixer_pattern``):
 
 - ``attention_kind="mha", ffn_kind="relu"`` — the SASRec block (pre-LN,
   learned positions, tied head); its math lives in ``seqrec._block``.
@@ -22,6 +25,21 @@ Three blocks share ``models/seqrec.py``'s trainer and server:
   kinds' projections have unlike shapes. ``heads_full``, ``heads_window``
   and ``kv_heads`` count the heads held here, as ``experts_held`` counts
   the experts.
+- the same two kinds with ``mixer_pattern`` set (the ``nemotron_h`` family):
+  layer ``i`` is ``h + mixer(norm(h))`` with the one mixer
+  ``mixer_pattern[i]`` names: ``"mamba"`` (a Mamba-2
+  state-space mixer, :func:`mamba`: one wide input projection, a causal
+  depthwise convolution, the selective recurrence over ``ssm_heads`` heads
+  computed by chunks, :func:`ssd_scan`, a gated group RMSNorm and an output
+  projection), ``"moe"`` (the expert feed-forward alone, :func:`moe`, here
+  with ``expert_act="relu2"`` experts of two matrices behind either router;
+  ``expert_matmul="gmm"`` hands any moe block's routed rows to the Pallas
+  grouped matmul on a TPU, :func:`grouped_matmul`)
+  or ``"attn"`` (:func:`gqa` with ``heads_full`` query heads, no position
+  encoding where ``attn_rope`` is false and no gate where ``attn_gate`` is).
+  Its stacks are ``mamba/*``, ``moe/*`` and ``attn/*``. A ``mamba`` layer's
+  recurrent state is not passed from shard to shard: a mesh with a ``seq``
+  axis is refused.
 
 :func:`describe_params` is the one place a block's parameter shapes are
 written: ``init_params``, ``param_specs`` and the placement check of
@@ -30,7 +48,9 @@ written: ``init_params``, ``param_specs`` and the placement check of
 Compute policy of the new block (``compute_dtype``, bfloat16 as published):
 master weights, Adam and the residual stream are float32; matmul operands
 are cast to ``compute_dtype`` and accumulate in float32; norms, softmax,
-the router's scores and every reduction are float32.
+the router's scores and every reduction are float32. In a ``mamba`` layer
+the step sizes, ``A``, the cumulative sums, every ``exp``, the carried state
+and the gated norm are float32 too.
 """
 
 from __future__ import annotations
@@ -40,7 +60,10 @@ from typing import Dict, NamedTuple, Tuple
 
 BLOCK_KINDS = {("mha", "relu"), ("mla", "moe"), ("gqa", "moe")}
 LAYER_KINDS = ("full", "window")
+MIXER_KINDS = ("mamba", "moe", "attn")
 ROUTER_KINDS = ("sigmoid_bias", "softmax")
+EXPERT_ACTS = ("swiglu", "relu2")
+EXPERT_MATMULS = ("ragged_dot", "gmm")
 
 #: How the mla/moe block's parameters are drawn (a norm's gain is 1): every
 #: matrix and the head; the embedding rows; the router's selection bias.
@@ -54,6 +77,13 @@ ATTN_BLOCK, TOKEN_CHUNK = 512, 2048
 #: Share of all (token, expert) pairs one pass of the grouped matmuls takes;
 #: further passes run only while held pairs are left.
 MOE_PASS_SHARE = 0.25
+#: Row, contraction and column tile of the Pallas grouped matmul
+#: (``expert_matmul="gmm"``), each clamped to the size it cuts: of the
+#: product, of its rows' gradient and of its weights' gradient. The least
+#: of fifteen an operation timed on a v5e at 2,688 x 1,856 and back, 4,000
+#: and 8,000 rows in 8 groups (PERF.md section 6, PR 35).
+GMM_TILES = {"out": (128, 2688, 512), "d_rows": (256, 2688, 512),
+             "d_weights": (512, 896, 1024)}
 
 
 #: parameter groups the trainer reports gradient norms for, in this order
@@ -68,7 +98,16 @@ GQA_GROUPS = ("embedding", "head", "attn_window", "attn_full", "gate",
               "norms")
 
 
+#: a block of single mixers: a mamba layer's two projections apart from what
+#: its recurrence reads (the convolution, ``A_log``, ``D``, ``dt_bias`` and
+#: the gated norm's gain), the attention layers' four projections together
+MIXER_GROUPS = ("embedding", "head", "ssm_proj", "ssm_scan", "attn", "router",
+                "routed_experts", "shared_expert", "norms")
+
+
 def groups_of(cfg) -> Tuple[str, ...]:
+    if cfg.mixer_pattern:
+        return MIXER_GROUPS
     return GQA_GROUPS if cfg.attention_kind == "gqa" else GROUPS
 
 
@@ -79,9 +118,17 @@ def group_of(path: str, cfg=None) -> str:
     dense layers' MLP; and attention with every norm under ``mla``. The
     gqa/moe block: the four projections under the attention of their
     layer's kind (a dense layer's kind is the pattern's), the gate's map,
-    and the norms' gains on their own."""
+    and the norms' gains on their own. A block of single mixers: see
+    ``MIXER_GROUPS``."""
     group, _, name = path.rpartition("/")
-    if cfg is not None and cfg.attention_kind == "gqa":
+    if cfg is not None and cfg.mixer_pattern:
+        if name.endswith("norm") or name == "lnf_g":
+            return "norms"
+        if group == "mamba":
+            return "ssm_proj" if name.endswith("_proj") else "ssm_scan"
+        if group == "attn":
+            return "attn"
+    elif cfg is not None and cfg.attention_kind == "gqa":
         if name.endswith("norm") or name == "lnf_g":
             return "norms"
         if name == "g_proj":
@@ -121,7 +168,10 @@ class Leaf(NamedTuple):
     ``"zeros"``, ``("split", i, scale)`` (the SASRec block's historical
     draw: key ``i`` of ``split(PRNGKey(seed), 8)``) or ``("named", std)``
     (normal of that std under ``fold_in(PRNGKey(seed), crc32(path))``, the
-    rule a plain reference can follow by name)."""
+    rule a plain reference can follow by name), or under the same key
+    ``("uniform", lo, hi)``, ``("log_uniform", lo, hi)`` (the log of a
+    uniform draw) and ``("dt_bias", lo, hi, floor)`` (the inverse softplus
+    of a log-uniform step size in ``[lo, hi]``, floored)."""
 
     shape: Tuple[int, ...]
     init: object
@@ -154,17 +204,28 @@ def heads_of(cfg, kind: str) -> int:
     return cfg.heads_window if kind == "window" else cfg.heads_full
 
 
-def check_block(cfg) -> None:
+def check_block(cfg, n_seq: int = 1) -> None:
+    """Refuses a block that cannot be built; ``n_seq`` is the size of the
+    mesh's ``seq`` axis the block is to run under."""
     if (cfg.attention_kind, cfg.ffn_kind) not in BLOCK_KINDS:
         raise ValueError(
             f"unsupported block: attention_kind={cfg.attention_kind!r} with "
             f"ffn_kind={cfg.ffn_kind!r}; have {sorted(BLOCK_KINDS)}"
         )
     if not is_moe(cfg):
+        if cfg.mixer_pattern:
+            raise ValueError("mixer_pattern needs ffn_kind='moe': the mha/relu "
+                             "block's layers are attention and FFN together")
         return
     if cfg.router_kind not in ROUTER_KINDS:
         raise ValueError(f"router_kind is one of {ROUTER_KINDS}")
-    if cfg.attention_kind == "gqa":
+    if cfg.expert_act not in EXPERT_ACTS:
+        raise ValueError(f"expert_act is one of {EXPERT_ACTS}")
+    if cfg.expert_matmul not in EXPERT_MATMULS:
+        raise ValueError(f"expert_matmul is one of {EXPERT_MATMULS}")
+    if cfg.mixer_pattern:
+        _check_mixers(cfg, n_seq)
+    elif cfg.attention_kind == "gqa":
         _check_gqa(cfg)
     if not 0 <= cfg.dense_layers < cfg.n_layers:
         raise ValueError("dense_layers must leave at least one expert layer")
@@ -182,6 +243,42 @@ def check_block(cfg) -> None:
         raise ValueError("qk_rope_dim must be even")
 
 
+def _check_mixers(cfg, n_seq: int) -> None:
+    pattern = cfg.mixer_pattern
+    if any(k not in MIXER_KINDS for k in pattern):
+        raise ValueError(f"mixer_pattern holds kinds of {MIXER_KINDS}")
+    if cfg.attention_kind != "gqa":
+        raise ValueError("a block of single mixers has gqa attention layers: "
+                         "attention_kind='gqa'")
+    if cfg.n_layers != len(pattern):
+        raise ValueError(f"mixer_pattern names every layer: {len(pattern)} "
+                         f"kinds for n_layers {cfg.n_layers}")
+    if "moe" not in pattern:
+        raise ValueError("mixer_pattern needs a 'moe' layer: the step's trace "
+                         "is built on the expert layers' counters")
+    if cfg.dense_layers or cfg.mtp_depth:
+        raise ValueError("a block of single mixers has no dense layers and "
+                         "no MTP module: a layer is one mixer alone")
+    if "attn" in pattern:
+        _check_heads(cfg, ("full",))
+    if "mamba" in pattern:
+        if n_seq > 1:
+            raise ValueError(
+                f"a mamba layer cannot run under a seq axis of {n_seq}: its "
+                "recurrent state (and the convolution's last inputs) would "
+                "have to pass from one shard of the sequence to the next, "
+                "which is not built, and a state that silently restarts at "
+                "a shard's edge is another model")
+        if cfg.ssm_heads % cfg.ssm_groups or min(
+                cfg.ssm_heads, cfg.ssm_groups, cfg.ssm_head_dim,
+                cfg.ssm_state, cfg.ssm_conv, cfg.ssm_chunk) < 1:
+            raise ValueError(
+                f"ssm_heads {cfg.ssm_heads} must be a multiple of "
+                f"ssm_groups {cfg.ssm_groups}, every ssm size at least 1")
+        if not 0 < cfg.ssm_dt_min <= cfg.ssm_dt_max:
+            raise ValueError("0 < ssm_dt_min <= ssm_dt_max")
+
+
 def _check_gqa(cfg) -> None:
     pattern = cfg.layer_pattern
     if not pattern or any(k not in LAYER_KINDS for k in pattern):
@@ -191,20 +288,25 @@ def _check_gqa(cfg) -> None:
             "the expert layers must be whole periods of layer_pattern")
     if len({layer_kind(cfg, i) for i in range(cfg.dense_layers)}) > 1:
         raise ValueError("the dense layers must be of one kind: they stack")
-    for kind in set(pattern):
-        if heads_of(cfg, kind) % cfg.kv_heads or heads_of(cfg, kind) < 1:
-            raise ValueError(
-                f"the {kind} layers' query heads must be a multiple of "
-                f"kv_heads {cfg.kv_heads}")
-    rotary = cfg.rotary_dim or cfg.head_dim
-    if cfg.head_dim % 2 or rotary % 2 or rotary > cfg.head_dim:
-        raise ValueError("head_dim and rotary_dim are even, rotary_dim at "
-                         "most head_dim")
+    _check_heads(cfg, set(pattern))
     if "window" in pattern and cfg.window < 1:
         raise ValueError("window layers need window >= 1")
     if cfg.router_kind != "softmax" or cfg.mtp_depth:
         raise ValueError("the gqa/moe block has a softmax router and no "
                          "MTP module")
+
+
+def _check_heads(cfg, kinds) -> None:
+    for kind in kinds:
+        if heads_of(cfg, kind) % cfg.kv_heads or heads_of(cfg, kind) < 1:
+            raise ValueError(
+                f"the {kind} layers' query heads must be a multiple of "
+                f"kv_heads {cfg.kv_heads}")
+    rotary = cfg.rotary_dim or cfg.head_dim
+    if cfg.attn_rope and (cfg.head_dim % 2 or rotary % 2
+                          or rotary > cfg.head_dim):
+        raise ValueError("head_dim and rotary_dim are even, rotary_dim at "
+                         "most head_dim")
 
 
 def _mla_leaves(L: int, cfg) -> Dict[str, Leaf]:
@@ -227,7 +329,7 @@ def _mla_leaves(L: int, cfg) -> Dict[str, Leaf]:
 def _gqa_leaves(L: int, cfg, kind: str) -> Dict[str, Leaf]:
     D, H, std = cfg.d_model, heads_of(cfg, kind), ("named", INIT_STD)
     d, Hkv = cfg.head_dim, cfg.kv_heads
-    return {
+    out = {
         "attn_norm": Leaf((L, D), "ones"),
         "q_proj": Leaf((L, D, H * d), std),
         "k_proj": Leaf((L, D, Hkv * d), std),
@@ -236,13 +338,16 @@ def _gqa_leaves(L: int, cfg, kind: str) -> Dict[str, Leaf]:
         "o_proj": Leaf((L, H * d, D), std),
         "ffn_norm": Leaf((L, D), "ones"),
     }
+    if not cfg.attn_gate:
+        del out["g_proj"]
+    return out
 
 
 def _moe_leaves(L: int, cfg) -> Dict[str, Leaf]:
     """An expert layer's feed-forward: router, held experts, shared expert."""
     D, Fe, std = cfg.d_model, cfg.expert_ffn, ("named", INIT_STD)
     Eh, Fs = cfg.experts_held, cfg.expert_ffn * cfg.shared_experts
-    return {
+    out = {
         "router_w": Leaf((L, D, cfg.n_experts), std),
         "e_gate": Leaf((L, Eh, D, Fe), std),
         "e_up": Leaf((L, Eh, D, Fe), std),
@@ -251,6 +356,59 @@ def _moe_leaves(L: int, cfg) -> Dict[str, Leaf]:
         "s_up": Leaf((L, D, Fs), std),
         "s_down": Leaf((L, Fs, D), std),
     }
+    if cfg.expert_act == "relu2":  # two matrices an expert: no gate
+        del out["e_gate"], out["s_gate"]
+    return out
+
+
+def ssm_widths(cfg) -> Tuple[int, int]:
+    """``(inner, convolved)`` widths of a mamba layer: the heads' channels
+    ``z`` and ``x`` have, and ``x | B | C`` together."""
+    inner = cfg.ssm_heads * cfg.ssm_head_dim
+    return inner, inner + 2 * cfg.ssm_groups * cfg.ssm_state
+
+
+def _mamba_leaves(L: int, cfg) -> Dict[str, Leaf]:
+    """A mamba layer: ``in_proj``'s columns are ``z | x B C | dt``;
+    ``conv_w[j]`` multiplies the input ``ssm_conv - 1 - j`` steps back."""
+    D, H, std = cfg.d_model, cfg.ssm_heads, ("named", INIT_STD)
+    inner, conv = ssm_widths(cfg)
+    bound = cfg.ssm_conv ** -0.5
+    return {
+        "norm": Leaf((L, D), "ones"),
+        "in_proj": Leaf((L, D, inner + conv + H), std),
+        "conv_w": Leaf((L, cfg.ssm_conv, conv), ("uniform", -bound, bound)),
+        "conv_b": Leaf((L, conv), ("uniform", -bound, bound)),
+        "a_log": Leaf((L, H), ("log_uniform", 1.0, 16.0)),
+        "d_skip": Leaf((L, H), "ones"),
+        "dt_bias": Leaf((L, H), ("dt_bias", cfg.ssm_dt_min, cfg.ssm_dt_max,
+                                 cfg.ssm_dt_floor)),
+        "gate_g": Leaf((L, inner), "ones"),
+        "out_proj": Leaf((L, inner, D), std),
+    }
+
+
+def _describe_mixers(vocab: int, cfg) -> Dict[str, Leaf]:
+    D, std = cfg.d_model, ("named", INIT_STD)
+    out = {"emb": Leaf((vocab, D), ("named", EMBED_INIT_STD)),
+           "head": Leaf((vocab, D), std),
+           "lnf_g": Leaf((D,), "ones")}
+    for kind in MIXER_KINDS:
+        L = cfg.mixer_pattern.count(kind)
+        if not L:
+            continue
+        if kind == "mamba":
+            leaves = _mamba_leaves(L, cfg)
+        elif kind == "attn":
+            leaves = _gqa_leaves(L, cfg, "full")
+            del leaves["ffn_norm"]
+        else:
+            leaves = {"ffn_norm": Leaf((L, D), "ones"), **_moe_leaves(L, cfg)}
+            if cfg.router_kind == "sigmoid_bias":
+                leaves["router_b"] = Leaf((L, cfg.n_experts),
+                                          ("named", BIAS_INIT_STD))
+        out.update({f"{kind}/{k}": v for k, v in leaves.items()})
+    return out
 
 
 def _describe_gqa(vocab: int, cfg) -> Dict[str, Leaf]:
@@ -284,6 +442,8 @@ def describe_params(vocab: int, cfg) -> Dict[str, Leaf]:
     """``{"group/name": Leaf}`` of every parameter of the configured
     block, layer-stacked (leading dim = layers of that group)."""
     D, F, L = cfg.d_model, cfg.ffn, cfg.n_layers
+    if cfg.mixer_pattern:
+        return _describe_mixers(vocab, cfg)
     if cfg.attention_kind == "gqa":
         return _describe_gqa(vocab, cfg)
     if not is_moe(cfg):
@@ -360,11 +520,36 @@ def init_from(desc: Dict[str, Leaf], seed: int) -> dict:
         elif leaf.init[0] == "split":
             _, i, scale = leaf.init
             flat[path] = jax.random.normal(split[i], leaf.shape) * scale
-        else:
+        elif leaf.init[0] == "named":
             flat[path] = jax.random.normal(
                 name_key(seed, path), leaf.shape, jnp.float32
             ) * jnp.float32(leaf.init[1])
+        else:
+            flat[path] = _drawn(name_key(seed, path), leaf)
     return unflatten(flat)
+
+
+def _drawn(key, leaf: Leaf):
+    """A mamba layer's draws (``Leaf``): uniform, the log of a uniform, and
+    ``dt_bias``, the inverse softplus of a log-uniform step size."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+
+    kind, lo, hi = leaf.init[:3]
+    if kind == "dt_bias":
+        u = jax.random.uniform(key, leaf.shape, jnp.float32)
+        dt = jnp.maximum(
+            jnp.exp(u * jnp.float32(math.log(hi) - math.log(lo))
+                    + jnp.float32(math.log(lo))), jnp.float32(leaf.init[3]))
+        return dt + jnp.log(-jnp.expm1(-dt))
+    draw = jax.random.uniform(key, leaf.shape, jnp.float32, lo, hi)
+    if kind == "log_uniform":
+        return jnp.log(draw)
+    if kind != "uniform":
+        raise ValueError(f"unknown init {leaf.init!r}")
+    return draw
 
 
 # ------------------------------------------------------------------ layers
@@ -466,6 +651,14 @@ def swiglu(x, w_gate, w_up, w_down, cd, chunk: int = 0):
     ).reshape(n, -1)
 
 
+def relu2_mlp(x, w_up, w_down, cd):
+    """``W_down relu(W_up x)^2`` of ``x [N, D]``: two matrices, no gate."""
+    import jax
+    import jax.numpy as jnp
+
+    return mm(jnp.square(jax.nn.relu(mm(x, w_up, cd))), w_down, cd)
+
+
 def mla(blk, h, cfg, s_axis):
     """Multi-head latent attention on the local ``[B, T_loc, D]`` slice."""
     import jax
@@ -511,7 +704,9 @@ def gqa(blk, h, cfg, s_axis, kind: str):
     full layer. The ``heads_of(kind) / kv_heads`` query heads of a KV head
     share its keys and values inside one score tile; a window layer's tiles
     outside the window are skipped. Every query head's output is scaled by
-    its own gate, ``sigmoid(x W_g)``, before ``W_o``."""
+    its own gate, ``sigmoid(x W_g)``, before ``W_o`` (``attn_gate``), and
+    ``q`` and ``k`` are rotated by position (``attn_rope``): without either
+    the layer is plain grouped-query attention with no position encoding."""
     import jax
     import jax.numpy as jnp
 
@@ -536,13 +731,18 @@ def gqa(blk, h, cfg, s_axis, kind: str):
                 factor=cfg.yarn_attention_factor)
     with jax.named_scope("seq.gqa/proj"):
         x = rms_norm(h, blk["attn_norm"], eps)
-        q = rope(mm(x, blk["q_proj"], cd).reshape(B, T, H, d), pos, **turn)
-        k = rope(mm(x, blk["k_proj"], cd).reshape(B, T, Hkv, d), pos, **turn)
+        q = mm(x, blk["q_proj"], cd).reshape(B, T, H, d)
+        if cfg.attn_rope:
+            q = rope(q, pos, **turn)
+        k = mm(x, blk["k_proj"], cd).reshape(B, T, Hkv, d)
+        if cfg.attn_rope:
+            k = rope(k, pos, **turn)
         v = mm(x, blk["v_proj"], cd).reshape(B, T, Hkv, d)
-    with jax.named_scope("seq.gqa/gate"):
-        gate = jax.nn.sigmoid(jnp.dot(
-            x, blk["g_proj"].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))  # [B, T, H] float32
+    if cfg.attn_gate:
+        with jax.named_scope("seq.gqa/gate"):
+            gate = jax.nn.sigmoid(jnp.dot(
+                x, blk["g_proj"].astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST))  # [B, T, H] float32
     with jax.named_scope(f"seq.gqa/attn/{kind}"):
         attn, tiles = ring_attention(
             q.astype(cd), k.astype(cd), v.astype(cd), axis=s_axis,
@@ -551,8 +751,9 @@ def gqa(blk, h, cfg, s_axis, kind: str):
         )
     # the window layers' tiles are what the counter is for
     tiles = tiles.astype(jnp.float32) * (kind == "window")
-    with jax.named_scope("seq.gqa/gate"):
-        attn = attn.astype(jnp.float32) * gate[..., None]
+    if cfg.attn_gate:
+        with jax.named_scope("seq.gqa/gate"):
+            attn = attn.astype(jnp.float32) * gate[..., None]
     with jax.named_scope("seq.gqa/proj"):
         return mm(attn.reshape(B, T, H * d), blk["o_proj"], cd), tiles
 
@@ -606,13 +807,81 @@ def pass_plan(sizes, width: int, n_pass: int):
             - jnp.clip(ends - sizes[None, :] - lo, 0, width))
 
 
+def experts_impl(platform: str, cfg) -> str:
+    """Which grouped matmul the routed experts run, from what is visible at
+    trace time: ``gmm`` (:func:`grouped_matmul`, the Pallas kernel) on a TPU
+    where ``expert_matmul`` asks for it, ``ragged_dot`` (XLA's) everywhere
+    else, and the kernel's oracle."""
+    return ("gmm" if platform == "tpu" and cfg.expert_matmul == "gmm"
+            else "ragged_dot")
+
+
+def _gmm_tiles(which: str, k: int, n: int):
+    tm, tk, tn = GMM_TILES[which]
+    return tm, min(tk, k), min(tn, n)
+
+
+def grouped_matmul(a, w, sizes, interpret: bool = False):
+    """``a [M, K]`` times ``w [G, K, N]`` by groups of sorted rows (``sizes
+    [G]``, ``int32``) -> ``[M, N]`` float32, as ``jax.lax.ragged_dot``, on
+    the Pallas TPU kernel JAX ships (megablox ``gmm`` / ``tgmm``). Its grid
+    ends at the last row tile a group touches, so the time follows the rows
+    given and not ``M``; rows past the last group hold whatever the buffer
+    held, in the result and in ``a``'s gradient. Operands stay in ``a``'s
+    dtype and accumulate in float32, backward too: the cotangent is cast to
+    that dtype before its two matmuls (what XLA's default precision does
+    to a float32 operand on a TPU)."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    # the package's ``gmm`` is its own differentiable wrapper; the module
+    # of that name holds the two kernels
+    backend = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+    @jax.custom_vjp
+    def run(a, w, sizes):
+        return backend.gmm(a, w, sizes, jnp.float32,
+                           _gmm_tiles("out", a.shape[1], w.shape[2]),
+                           interpret=interpret)
+
+    def fwd(a, w, sizes):
+        return run(a, w, sizes), (a, w, sizes)
+
+    def bwd(kept, ct):
+        a, w, sizes = kept
+        ct = ct.astype(a.dtype)
+        k, n = a.shape[1], w.shape[2]
+        da = backend.gmm(ct, w, sizes, jnp.float32,
+                         _gmm_tiles("d_rows", n, k), transpose_rhs=True,
+                         interpret=interpret)
+        dw = backend.tgmm(a.swapaxes(0, 1), ct, sizes, jnp.float32,
+                          _gmm_tiles("d_weights", k, n),
+                          num_actual_groups=w.shape[0], interpret=interpret)
+        return da.astype(a.dtype), dw.astype(w.dtype), None
+
+    run.defvjp(fwd, bwd)
+    m = a.shape[0]
+    # the kernels take whole row tiles (the three row tiles are powers of 2)
+    over = -m % max(tiles[0] for tiles in GMM_TILES.values())
+    with jax.named_scope("gmm"):
+        if over:
+            a = jnp.pad(a, ((0, over), (0, 0)))
+        return run(a, w, sizes)[:m]
+
+
 def routed_experts(blk, x, idx, gate, cfg, first, held: int):
-    """The held experts' part of the layer's output, dropless.
+    """The held experts' part of the layer's output, dropless
+    (``expert_act``: ``W_down(silu(W_gate x) * W_up x)``, or the two-matrix
+    ``W_down relu(W_up x)^2``).
 
     ``x [N, D]``; ``first`` (may be traced) and ``held`` say which experts'
     weights ``blk["e_*"]`` are. The (token, expert) pairs are sorted by
     held expert (pairs of absent experts last) and the three matmuls run
-    grouped (``jax.lax.ragged_dot``) over the sorted pairs, ``MOE_PASS_SHARE``
+    grouped (``jax.lax.ragged_dot``, or :func:`grouped_matmul` where
+    :func:`experts_impl` says) over the sorted pairs, ``MOE_PASS_SHARE``
     of all pairs to a pass. A pass runs only while held pairs are left, so
     the work follows the load and no pair is dropped whatever the imbalance.
     Returns ``(y [N, D] float32, pairs, dropped)``: the pairs routed to held
@@ -621,6 +890,7 @@ def routed_experts(blk, x, idx, gate, cfg, first, held: int):
     import jax.numpy as jnp
 
     cd = _dtype(cfg)
+    impl = experts_impl(jax.default_backend(), cfg)
     N, D = x.shape
     k = cfg.experts_per_token
     M = N * k
@@ -639,7 +909,8 @@ def routed_experts(blk, x, idx, gate, cfg, first, held: int):
         gate_sorted = jnp.pad(gate.reshape(-1)[order], (0, pad))
         token_sorted = jnp.pad(order // k, (0, pad))
     xc = x.astype(cd)
-    w_gate, w_up, w_down = (blk[n].astype(cd)
+    gated = cfg.expert_act == "swiglu"
+    w_gate, w_up, w_down = (blk[n].astype(cd) if n in blk else None
                             for n in ("e_gate", "e_up", "e_down"))
 
     @jax.checkpoint
@@ -655,12 +926,17 @@ def routed_experts(blk, x, idx, gate, cfg, first, held: int):
             # its transpose's. Selecting on both sides keeps them out of
             # the sum and out of every gradient.
             a = jnp.where(rows, a, jnp.zeros((), a.dtype))
+            if impl == "gmm":
+                return jnp.where(rows, grouped_matmul(a, w, sizes_here), 0.0)
             return jnp.where(rows, jax.lax.ragged_dot(
                 a, w, sizes_here, preferred_element_type=jnp.float32), 0.0)
 
         with jax.named_scope("seq.moe/experts"):
             xs = xc[tok]
-            hidden = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
+            if gated:
+                hidden = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
+            else:
+                hidden = jnp.square(jax.nn.relu(grouped(xs, w_up)))
             ys = grouped(hidden.astype(cd), w_down)
         with jax.named_scope("seq.moe/route"):
             return ys * g[:, None]
@@ -698,7 +974,7 @@ def moe(blk, x, cfg, m_axis):
     flat = x.reshape(B * T, D)
     with jax.named_scope("seq.moe/route"):
         idx, gate, load = route(flat, blk["router_w"], blk.get("router_b"), cfg)
-    held = blk["e_gate"].shape[0]
+    held = blk["e_up"].shape[0]
     first = cfg.experts_first
     if m_axis is not None:
         first = first + jax.lax.axis_index(m_axis) * held
@@ -708,11 +984,171 @@ def moe(blk, x, cfg, m_axis):
         pairs = jax.lax.psum(pairs, m_axis)
         dropped = jax.lax.psum(dropped, m_axis)
     with jax.named_scope("seq.ffn"):
-        y = y + swiglu(flat, blk["s_gate"], blk["s_up"], blk["s_down"],
-                       _dtype(cfg))
+        if cfg.expert_act == "swiglu":
+            y = y + swiglu(flat, blk["s_gate"], blk["s_up"], blk["s_down"],
+                           _dtype(cfg))
+        else:
+            y = y + relu2_mlp(flat, blk["s_up"], blk["s_down"], _dtype(cfg))
     counters = {"load": load, "pairs": pairs.astype(jnp.float32),
                 "dropped": dropped.astype(jnp.float32)}
     return y.reshape(B, T, D), counters
+
+
+def carried_states(own, decay):
+    """The state that enters each chunk. ``own [C, ...]`` is what each
+    chunk's own events leave behind at its end and ``decay [C, ...]`` (two
+    dims fewer) what the chunk's steps leave of a state that enters it:
+    ``S_in[0] = 0``, ``S_in[c + 1] = decay[c] S_in[c] + own[c]``, float32,
+    one chunk after another."""
+    import jax
+    import jax.numpy as jnp
+
+    def step(state, xs):
+        own_c, decay_c = xs
+        return decay_c[..., None, None] * state + own_c, state
+
+    _, entering = jax.lax.scan(step, jnp.zeros_like(own[0]), (own, decay))
+    return entering
+
+
+def ssd_scan(x, dt, a, b, c, chunk: int, cd):
+    """Mamba-2's selective recurrence by chunks (the SSD form).
+
+    ``x [B, T, H, P]``, step sizes ``dt [B, T, H]`` (positive), ``a [H]``
+    (negative), ``b, c [B, T, G, N]``, all float32; head ``h`` reads group
+    ``h // (H / G)``. Computes ``S_t = exp(dt_t a) S_{t-1} + dt_t x_t (x)
+    b_t`` (``S_{-1} = 0``), ``y_t = S_t c_t`` without holding a state a
+    step: inside a chunk of ``chunk`` steps (clamped to a divisor of ``T``)
+    ``Y = (L * C B^T)(dt X)`` with ``L_ts = exp(sum_{s<r<=t} dt_r a)``; each
+    chunk's own state; the states carried from chunk to chunk
+    (:func:`carried_states`); ``C`` against the state that entered. The
+    chunks are batched, the groups mapped one after another under
+    ``jax.checkpoint``: the ``[chunk, chunk]`` weights of one group's heads
+    stand at a time, forward and in the recomputing backward pass. Matmul
+    operands are cast to ``cd`` and accumulate in float32; the cumulative
+    sums, every ``exp`` and the carried state are float32.
+
+    Returns ``(y [B, T, H, P] float32, chunks, absmax)``: the chunks a
+    group's carrying loop ran times the rows, and the largest magnitude of
+    a carried state."""
+    import jax
+    import jax.numpy as jnp
+
+    from pio_tpu.parallel.ring import pick_block
+
+    B, T, H, P = x.shape
+    G, N = b.shape[2:]
+    R, Q = H // G, pick_block(T, chunk)
+    C = T // Q
+    f32 = jnp.float32
+    seen = jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :]
+
+    @jax.checkpoint
+    def group(args):
+        xg, dtg, ag, bg, cg = args  # [B,C,Q,R,P] [B,C,Q,R] [R] [B,C,Q,N] x2
+        # log of what the steps up to and with t leave of a state
+        cum = jnp.cumsum(dtg * ag, axis=2)  # [B, C, Q, R]
+        dtx32 = dtg[..., None] * xg
+        dtx = dtx32.astype(cd)
+        bg, cg = bg.astype(cd), cg.astype(cd)
+        # inside a chunk: Y = (L * C B^T) (dt X)
+        cb = jnp.einsum("bcqn,bcsn->bcqs", cg, bg, preferred_element_type=f32)
+        by_head = cum.transpose(0, 1, 3, 2)  # [B, C, R, Q]
+        span = by_head[..., :, None] - by_head[..., None, :]
+        weights = jnp.exp(jnp.where(seen, span, -jnp.inf)) * cb[:, :, None]
+        y = jnp.einsum("bcrqs,bcsrp->bcqrp", weights.astype(cd), dtx,
+                       preferred_element_type=f32)
+        # each chunk's own state at its end, and the states carried forward
+        to_end = jnp.exp(cum[:, :, -1:] - cum)
+        own = jnp.einsum("bcsrp,bcsn->bcrpn",
+                         (to_end[..., None] * dtx32).astype(cd), bg,
+                         preferred_element_type=f32)
+        entering = jnp.swapaxes(carried_states(
+            jnp.swapaxes(own, 0, 1),
+            jnp.swapaxes(jnp.exp(cum[:, :, -1]), 0, 1)), 0, 1)  # [B,C,R,P,N]
+        y = y + jnp.exp(cum)[..., None] * jnp.einsum(
+            "bcqn,bcrpn->bcqrp", cg, entering.astype(cd),
+            preferred_element_type=f32)
+        chunks = jnp.float32(B * entering.shape[1])  # the loop's length
+        return y, chunks, jnp.abs(jax.lax.stop_gradient(entering)).max()
+
+    y, chunks, absmax = jax.lax.map(group, (
+        jnp.moveaxis(x.reshape(B, C, Q, G, R, P), 3, 0),
+        jnp.moveaxis(dt.reshape(B, C, Q, G, R), 3, 0), a.reshape(G, R),
+        jnp.moveaxis(b.reshape(B, C, Q, G, N), 3, 0),
+        jnp.moveaxis(c.reshape(B, C, Q, G, N), 3, 0)))
+    return (jnp.moveaxis(y, 0, 3).reshape(B, T, H, P), chunks[0],
+            absmax.max())
+
+
+def mamba(blk, h, cfg):
+    """The Mamba-2 mixer of the normed ``h [B, T, D]`` -> ``(its output
+    before the residual, counters)``: ``[z | xBC | dt] = x W_in``; ``xBC <-
+    silu(conv(xBC) + b)``, a causal depthwise convolution over the last
+    ``ssm_conv`` steps (zeros before the first); ``xBC`` split into ``x [H,
+    P]``, ``B, C [G, N]``; ``dt = softplus(dt + dt_bias)``, ``A =
+    -exp(A_log)``; the recurrence (:func:`ssd_scan`) plus ``D x``; the gate
+    first, ``u = y silu(z)``, then RMSNorm over each of the ``G`` groups of
+    channels times a gain; ``u W_out``. The sequence is whole here
+    (``check_block`` refuses a ``seq`` axis). The convolution and the gated
+    norm are recomputed in the backward pass from their inputs, as the
+    recurrence is: beside 16 B a parameter a layer's float32 ``[T, 4096]``
+    intermediates do not all fit."""
+    import jax
+    import jax.numpy as jnp
+
+    cd, eps = _dtype(cfg), cfg.norm_eps
+    B, T, _ = h.shape
+    H, P, G, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
+    inner, conv = ssm_widths(cfg)
+    K = cfg.ssm_conv
+
+    @jax.checkpoint
+    def convolved(xbc, w, bias):
+        padded = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
+        return jax.nn.silu(bias + sum(
+            w[j] * padded[:, j:j + T] for j in range(K)))
+
+    @jax.checkpoint
+    def gated_norm(y, z, gain):
+        u = (y * jax.nn.silu(z)).reshape(B, T, G, inner // G)
+        u = u * jax.lax.rsqrt((u * u).mean(axis=-1, keepdims=True) + eps)
+        return u.reshape(B, T, inner) * gain
+
+    with jax.named_scope("seq.ssm/proj"):
+        zxd = mm(rms_norm(h, blk["norm"], eps), blk["in_proj"], cd)
+        z, xbc, dt = (zxd[..., :inner], zxd[..., inner:inner + conv],
+                      zxd[..., inner + conv:])
+    with jax.named_scope("seq.ssm/conv"):
+        xbc = convolved(xbc, blk["conv_w"], blk["conv_b"])
+    with jax.named_scope("seq.ssm/ssd"):
+        x = xbc[..., :inner].reshape(B, T, H, P)
+        b = xbc[..., inner:inner + G * N].reshape(B, T, G, N)
+        c = xbc[..., inner + G * N:].reshape(B, T, G, N)
+        dt = jax.nn.softplus(dt + blk["dt_bias"])
+        a = -jnp.exp(blk["a_log"])
+        y, chunks, absmax = ssd_scan(x, dt, a, b, c, cfg.ssm_chunk, cd)
+        y = (y + blk["d_skip"][:, None] * x).reshape(B, T, inner)
+    with jax.named_scope("seq.ssm/norm"):
+        u = gated_norm(y, z, blk["gate_g"])
+    with jax.named_scope("seq.ssm/proj"):
+        out = mm(u, blk["out_proj"], cd)
+    return out, {"ssm_chunks": chunks, "ssm_state_absmax": absmax}
+
+
+def mixer_layer(blk, h, cfg, m_axis, s_axis, kind):
+    """``(h + mixer(norm(h)), the mixer's counters)`` of a layer that is one
+    mixer alone (``blk`` has no layer dim): a ``mamba`` layer counts its
+    chunks and its largest carried state, a ``moe`` layer what
+    :func:`moe` counts, an ``attn`` layer nothing."""
+    if kind == "mamba":
+        out, counters = mamba(blk, h, cfg)
+    elif kind == "attn":
+        out, counters = gqa(blk, h, cfg, s_axis, "full")[0], {}
+    else:
+        out, counters = moe(
+            blk, rms_norm(h, blk["ffn_norm"], cfg.norm_eps), cfg, m_axis)
+    return h + out, counters
 
 
 def dense_layer(blk, h, cfg, m_axis, s_axis, kind):

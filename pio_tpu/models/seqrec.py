@@ -38,10 +38,12 @@ from pio_tpu.models.seq_layers import (
     dense_layer,
     describe_params,
     expert_layer,
+    experts_impl,
     group_norms,
     init_from,
     is_moe,
     layer_kind,
+    mixer_layer,
     mm,
     period_kinds,
     rms_norm,
@@ -143,10 +145,43 @@ class SeqRecConfig:
     yarn_beta_fast: float = 32.0
     yarn_beta_slow: float = 1.0
     yarn_attention_factor: float = 1.0
+    #: the gqa layers rotate ``q`` and ``k`` by position / scale every query
+    #: head's output by its own sigmoid gate; false = no position encoding /
+    #: no gate (and no ``g_proj``)
+    attn_rope: bool = True
+    attn_gate: bool = True
+    #: the experts and the shared expert: "swiglu" (three matrices,
+    #: ``W_down(silu(W_gate x) * W_up x)``) or "relu2" (two,
+    #: ``W_down relu(W_up x)^2``)
+    expert_act: str = "swiglu"
+    #: the routed experts' grouped matmul: "ragged_dot" (XLA's) or "gmm"
+    #: (the Pallas TPU kernel, whose time follows the rows routed here;
+    #: off a TPU it is "ragged_dot" all the same)
+    expert_matmul: str = "ragged_dot"
+    # -- a layer that is one mixer alone, ``h + mixer(norm(h))``. Layer ``i``
+    # -- is ``mixer_pattern[i]`` (``n_layers`` of them): "mamba" (a Mamba-2 mixer of
+    # -- ``ssm_heads`` heads of ``ssm_head_dim`` channels over ``ssm_groups``
+    # -- groups of state ``ssm_state``, a causal depthwise convolution of
+    # -- ``ssm_conv`` taps, the recurrence by chunks of ``ssm_chunk``), "moe"
+    # -- (the expert feed-forward) or "attn" (gqa with ``heads_full`` query
+    # -- heads). Empty = the layers are attention and a feed-forward part.
+    # -- ``ssm_dt_*`` draw a mamba layer's initial step sizes (log-uniform
+    # -- in [min, max], floored).
+    mixer_pattern: Tuple[str, ...] = ()
+    ssm_heads: int = 64
+    ssm_head_dim: int = 64
+    ssm_groups: int = 8
+    ssm_state: int = 128
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    ssm_dt_min: float = 1e-3
+    ssm_dt_max: float = 0.1
+    ssm_dt_floor: float = 1e-4
 
     def __post_init__(self):
         # engine.json gives a list; the config keys the kept programs
         object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
+        object.__setattr__(self, "mixer_pattern", tuple(self.mixer_pattern))
 
 
 @dataclasses.dataclass
@@ -164,7 +199,8 @@ class SeqRecModel:
     #: ``load_max_over_mean`` over all experts, ``bias_max`` (a router with
     #: a selection bias), ``window_tiles``/``causal_tiles`` (the gqa block:
     #: score tiles its window layers visited, and what causal layers of
-    #: their length visit)
+    #: their length visit), ``ssm_chunks``/``ssm_state_absmax`` (mamba
+    #: layers: the chunks their carrying loops ran, the largest carried state)
     trace: Optional[dict] = None
     _serve_cache: Optional[tuple] = dataclasses.field(
         default=None, init=False, repr=False, compare=False
@@ -362,7 +398,8 @@ def _latent_trunk(params, seqs, cfg, m_axis, s_axis):
     layers are scanned a period at a time; the period is data
     (``period_kinds``): each of its layers takes the next slice of its
     kind's stack (``window/*`` and ``full/*`` have unlike shapes; the
-    mla/moe block's period is one layer of ``blocks``)."""
+    mla/moe block's period is one layer of ``blocks``). Layers that are one
+    mixer alone (``mixer_pattern``) go through :func:`_mixer_layers`."""
     import jax
     import jax.numpy as jnp
 
@@ -377,6 +414,8 @@ def _latent_trunk(params, seqs, cfg, m_axis, s_axis):
         return jax.checkpoint(lambda blk, h: fn(
             jax.lax.optimization_barrier(blk), h, cfg, m_axis, s_axis, kind))
 
+    if cfg.mixer_pattern:
+        return _mixer_layers(params, h, cfg, layer)
     dense = {}
     if "dense" in params:
         h, dense = jax.lax.scan(
@@ -414,6 +453,32 @@ def _latent_trunk(params, seqs, cfg, m_axis, s_axis):
     if "tiles" in counters:  # one sum over every layer, the dense ones too
         counters["tiles"] = sum(
             c["tiles"].sum(axis=0) for c in (dense, counters) if "tiles" in c)
+    return h, counters
+
+
+def _mixer_layers(params, h, cfg, layer):
+    """The layers of a block of single mixers -> ``(h, counters)``: layer
+    after layer as ``mixer_pattern`` names them, each taking the next slice
+    of its kind's stack (``mamba/*``, ``moe/*``, ``attn/*``). Unlike kinds
+    of layer share no counters, so they are stacked by kind: the ``moe``
+    layers' as the expert layers' of the other blocks, the ``mamba`` layers'
+    summed (``ssm_chunks``) and maximised (``ssm_state_absmax``)."""
+    import jax
+    import jax.numpy as jnp
+
+    taken = dict.fromkeys(cfg.mixer_pattern, 0)
+    by_kind = {kind: [] for kind in taken}
+    for kind in cfg.mixer_pattern:
+        blk = jax.tree.map(lambda a: a[taken[kind]], params[kind])
+        taken[kind] += 1
+        h, c = layer(mixer_layer, kind)(blk, h)
+        by_kind[kind].append(c)
+    counters = jax.tree.map(lambda *a: jnp.stack(a), *by_kind["moe"])
+    if "mamba" in by_kind:
+        counters["ssm_chunks"] = sum(
+            c["ssm_chunks"] for c in by_kind["mamba"])
+        counters["ssm_state_absmax"] = jnp.stack(
+            [c["ssm_state_absmax"] for c in by_kind["mamba"]]).max()
     return h, counters
 
 
@@ -579,7 +644,12 @@ def _programs(cfg: SeqRecConfig, mesh, vocab: int, B: int,
         if latent:
             sums, counters = _latent_loss_sums(
                 params, batch, cfg, m_axis, s_axis)
-            return _latent_loss(*psum((sums, counters)), cfg)
+            absmax = counters.pop("ssm_state_absmax", None)
+            sums, counters = psum((sums, counters))
+            if absmax is not None:  # a maximum over the rows, not a sum
+                counters["ssm_state_absmax"] = absmax if mesh is None else (
+                    jax.lax.all_gather(absmax, ("data", "seq")).max())
+            return _latent_loss(sums, counters, cfg)
         seqs, targets, mask = batch
         h = _trunk(params, seqs, cfg, m_axis, s_axis, p_axis)
         ce, denom = psum(_vocab_parallel_ce(
@@ -692,11 +762,17 @@ def train_seqrec(
             phases serialize). On a TPU the steps of a ``stats`` call are
             also traced and reduced to the program's ``seq.*`` scopes
             (``device_scope_s``, ``device_unscoped_s``, ``device_busy_s``,
-            ``device_program_s``: pio_tpu/obs/profile.py), ``xla`` holds
+            ``device_program_s``, and ``device_renamed_s``: the part of
+            the unscoped seconds in operations XLA renamed, as the grouped
+            expert matmuls' ``ragged-dot-none``, whose ``seq.moe/experts``
+            scope the TPU compiler drops: pio_tpu/obs/profile.py), ``xla`` holds
             the compile counts with ``in_call``, and ``counters`` the
             moe blocks' routed pairs, load ratio and dropped pairs, the
-            largest selection bias (a router that has one) and the gqa
-            block's ``window_tiles`` and ``causal_tiles``.
+            largest selection bias (a router that has one), the gqa
+            block's ``window_tiles`` and ``causal_tiles`` and the mamba
+            layers' ``ssm_chunks`` and ``ssm_state_absmax``; a moe block
+            also says which grouped matmul its routed experts ran
+            (``experts_impl``: ``seq_layers.experts_impl``).
 
     Raises:
         DeviceBudgetExceeded: the params can't fit (single-chip or even
@@ -721,7 +797,7 @@ def train_seqrec(
     n_pipe = mesh_axis_size(mesh, "pipe")
     p_axis = "pipe" if (mesh is not None and n_pipe > 1) else None
 
-    check_block(cfg)
+    check_block(cfg, n_seq)
     if cfg.stream not in ("auto", "on", "off"):
         raise ValueError(
             f"stream must be auto/on/off, got {cfg.stream!r}"
@@ -869,6 +945,8 @@ def train_seqrec(
         n_stream = min(n_batches, n_stream)
     if stats is not None:
         stats["n_stream"] = n_stream
+        if latent:
+            stats["experts_impl"] = experts_impl(jax.default_backend(), cfg)
 
     # the programs are built once for these sizes and kept: a second call
     # traces and compiles nothing
@@ -1048,9 +1126,12 @@ def train_seqrec(
             }
             if "bias_max" in trace:
                 stats["counters"]["bias_max"] = float(trace["bias_max"].max())
-            for name in ("window_tiles", "causal_tiles"):
+            for name in ("window_tiles", "causal_tiles", "ssm_chunks"):
                 if name in trace:
                     stats["counters"][name] = float(trace[name].sum())
+            if "ssm_state_absmax" in trace:
+                stats["counters"]["ssm_state_absmax"] = float(
+                    trace["ssm_state_absmax"].max())
     return SeqRecModel(params=host, n_items=n_items, config=cfg, trace=trace)
 
 
@@ -1066,9 +1147,10 @@ def _after_step(params, aux, cfg):
     if "tiles" in aux:
         aux["window_tiles"], aux["causal_tiles"] = aux.pop("tiles")
     if cfg.router_kind == "sigmoid_bias":
-        n_main = params["blocks"]["router_b"].shape[0]
+        main = "moe" if cfg.mixer_pattern else "blocks"
+        n_main = params[main]["router_b"].shape[0]
         params = dict(params)
-        groups = [("blocks", load[:n_main])]
+        groups = [(main, load[:n_main])]
         if cfg.mtp_depth:
             groups.append(("mtp", load[n_main:]))
         biases = []

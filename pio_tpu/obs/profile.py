@@ -348,7 +348,11 @@ def reduce_scopes(trace_dir: str, prefix: str = "als.") -> dict:
     one's end), ``busy_s`` (union of the operations' intervals),
     ``scope_s`` (``{scope path: self seconds}``, see :func:`scope_path`),
     ``unscoped_s`` (operations outside every scope: loop counters, the
-    copies of loop carries, programs of other code) and ``program_s``
+    copies of loop carries, programs of other code), ``renamed_s`` (the
+    part of ``unscoped_s`` in operations whose name is none of JAX's: XLA
+    rewrote them and gave them a name of its own with no path, so the
+    scope they were written under is lost; ``{that name: seconds}``, as
+    ``ragged-dot-none``, the TPU's grouped-matmul kernel) and ``program_s``
     (``{jit name: seconds}`` from the ``XLA Modules`` line). ``scope_s``
     and ``unscoped_s`` sum to the operations' total self time, which is
     ``busy_s`` unless operations overlap. Operations are the ``XLA Ops``
@@ -361,6 +365,7 @@ def reduce_scopes(trace_dir: str, prefix: str = "als.") -> dict:
     n = len(planes)
     lo, hi, busy, unscoped = float("inf"), float("-inf"), 0.0, 0.0
     scope_s: Dict[str, float] = {}
+    renamed_s: Dict[str, float] = {}
     program_s: Dict[str, float] = {}
     for names, plain, ops, modules in planes:
         end = None
@@ -374,9 +379,13 @@ def reduce_scopes(trace_dir: str, prefix: str = "als.") -> dict:
         for _key, start, dur in ops or modules:
             lo, hi = min(lo, start), max(hi, start + dur)
         for key, sec in _self_seconds(ops).items():
-            path = scope_path(names.get(key, ""), prefix)
+            name = names.get(key, "")
+            path = scope_path(name, prefix)
             if path is None:
                 unscoped += sec / n
+                own = name.rstrip(":")
+                if own and "/" not in own:
+                    renamed_s[own] = renamed_s.get(own, 0.0) + sec / n
             else:
                 scope_s[path] = scope_s.get(path, 0.0) + sec / n
         for key, _start, dur in modules:
@@ -387,6 +396,7 @@ def reduce_scopes(trace_dir: str, prefix: str = "als.") -> dict:
         "busy_s": busy / n,
         "scope_s": scope_s,
         "unscoped_s": unscoped,
+        "renamed_s": renamed_s,
         "program_s": program_s,
     }
 
@@ -397,12 +407,15 @@ def device_stats(seen: Optional[dict]) -> dict:
     nothing was captured."""
     if seen is None:
         return {}
-    return {
+    stats = {
         "device_scope_s": dict(seen["scope_s"]),
         "device_unscoped_s": seen["unscoped_s"],
         "device_busy_s": seen["busy_s"],
         "device_program_s": dict(seen["program_s"]),
     }
+    if seen.get("renamed_s"):
+        stats["device_renamed_s"] = dict(seen["renamed_s"])
+    return stats
 
 
 class ScopeCapture:

@@ -192,7 +192,11 @@ class SeqRecParams(SeqRecConfig, Params):
     there, by data: ``attention_kind``, ``ffn_kind``, ``dense_layers``,
     the expert and MTP counts; for ``"gqa"`` the ``layer_pattern`` of full
     and window layers, the heads held by kind, each kind's RoPE and the
-    ``router_kind``) plus the mesh splits."""
+    ``router_kind``; for layers that are one mixer alone the
+    ``mixer_pattern`` of ``"mamba"``, ``"moe"`` and ``"attn"`` layers, the
+    ``ssm_*`` sizes of a Mamba-2 layer, ``expert_act``, ``attn_rope`` and
+    ``attn_gate``; for any moe block ``expert_matmul``, the routed experts'
+    grouped matmul) plus the mesh splits."""
 
     steps: int = 300
     #: mesh splits; remaining devices ride the data axis

@@ -147,6 +147,7 @@ def test_reduce_scopes_on_the_recorded_v5e_trace():
     assert 0 < got["busy_s"] <= got["window_s"]
     assert got["busy_s"] == pytest.approx(by_hand["busy_s"], rel=1e-9)
     assert got["unscoped_s"] == pytest.approx(by_hand["unscoped_s"], rel=1e-9)
+    assert sum(got["renamed_s"].values()) <= got["unscoped_s"]
     assert 100 * got["unscoped_s"] / got["busy_s"] == pytest.approx(
         by_hand["unscoped_pct"], rel=1e-6)
     for path, sec in by_hand["scope_s"].items():
@@ -160,6 +161,31 @@ def test_a_loop_is_not_counted_again_for_its_body():
     events = [("while", 0.0, 10.0), ("body_a", 1.0, 3.0), ("body_b", 5.0, 4.0)]
     assert profile._self_seconds(events) == {
         "while": 3.0, "body_a": 3.0, "body_b": 4.0}
+
+
+def test_an_operation_xla_renamed_is_unscoped_and_named(monkeypatch):
+    """The TPU compiler rewrites ``jax.lax.ragged_dot`` into a custom call
+    named ``ragged-dot-none``: no path, so no scope. Its seconds stay
+    unscoped, and ``renamed_s`` says whose they are; an operation with no
+    name at all (a copy XLA adds) or with a path outside every scope is
+    unscoped only."""
+    names = {1: "jit(step)/while/body/closed_call/seq.moe/experts/gather:",
+             2: "ragged-dot-none:", 3: "", 4: "jit(step)/while/body/add:",
+             5: "ragged-dot-none:"}
+    ops = [(1, 0.0, 1.0), (2, 1.0, 2.0), (3, 3.0, 0.5), (4, 3.5, 0.25),
+           (5, 3.75, 0.25)]
+    monkeypatch.setattr(profile, "find_xplane", lambda d: d)
+    monkeypatch.setattr(profile, "_device_planes",
+                        lambda path: iter([(names, {}, ops, [])]))
+    got = profile.reduce_scopes("anywhere", prefix="seq.")
+    assert got["scope_s"] == {"seq.moe/experts": 1.0}
+    assert got["unscoped_s"] == pytest.approx(3.0)
+    assert got["renamed_s"] == {"ragged-dot-none": pytest.approx(2.25)}
+    assert got["busy_s"] == pytest.approx(4.0)
+    stats = profile.device_stats(got)
+    assert stats["device_renamed_s"] == got["renamed_s"]
+    del got["renamed_s"]  # a capture of before: the key is left out
+    assert "device_renamed_s" not in profile.device_stats(got)
 
 
 def test_reduce_scopes_needs_a_trace_with_a_device(tmp_path):

@@ -243,3 +243,28 @@ def test_mesh_trainer_sums_in_the_kernel_on_four_v5e(topo, monkeypatch):
              if "custom-call(" in ln and "als_accum_fused" in ln]
     assert len(calls) == 2, len(calls)
     assert "f32[4096,64,64]" not in text
+
+
+@pytest.mark.parametrize("k,n", [(2688, 1856), (1856, 2688)])
+def test_grouped_expert_matmul_compiles_for_v5e(one_chip, k, n):
+    """The Nemotron cell's two expert matrices (a pass of 24,576 rows over 8
+    held experts; 1,856 is no multiple of a 128-lane tile) through
+    ``seq_layers.grouped_matmul`` with the tiles as committed: the product
+    and both gradients are Pallas calls that fit VMEM."""
+    import jax
+    import jax.numpy as jnp
+
+    from pio_tpu.models.seq_layers import grouped_matmul
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def both(a, w, sizes, ct):
+        y, back = jax.vjp(lambda a, w: grouped_matmul(a, w, sizes), a, w)
+        return y, back(ct)
+
+    text = jax.jit(both).lower(
+        sd((24576, k), jnp.bfloat16), sd((8, k, n), jnp.bfloat16),
+        sd((8,), jnp.int32), sd((24576, n), jnp.float32)).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 3, len(calls)
