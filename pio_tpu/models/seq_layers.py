@@ -58,6 +58,8 @@ from __future__ import annotations
 import zlib
 from typing import Dict, NamedTuple, Tuple
 
+from pio_tpu.utils.numutil import round_up
+
 BLOCK_KINDS = {("mha", "relu"), ("mla", "moe"), ("gqa", "moe")}
 LAYER_KINDS = ("full", "window")
 MIXER_KINDS = ("mamba", "moe", "attn")
@@ -74,9 +76,24 @@ INIT_STD, EMBED_INIT_STD, BIAS_INIT_STD = 0.02, 1.0, 0.02
 #: Edge of the attention tiles, and tokens to a chunk of the cross-entropy and
 #: of the dense SwiGLU (each clamped to a divisor of what it cuts).
 ATTN_BLOCK, TOKEN_CHUNK = 512, 2048
-#: Share of all (token, expert) pairs one pass of the grouped matmuls takes;
-#: further passes run only while held pairs are left.
-MOE_PASS_SHARE = 0.25
+#: Rows the first pass of the grouped matmuls stages, over the pairs a
+#: balanced router sends the held experts (:func:`pass_widths`). Everything
+#: around the matmuls (gathers, selects, the combine) costs by the row staged,
+#: so the pass is sized to the pairs held; twice their mean leaves room for
+#: the uneven loads one pass should still take, and a hot held expert costs
+#: further passes, never pairs.
+MOE_PASS_OVER_HELD = 2
+#: Passes a layer unrolls at most. A pass is three grouped matmuls, their
+#: recomputation and their backward in the compiled step whether it runs or
+#: not, so the count is held to one more than the four that a quarter of all
+#: pairs to a pass gave.
+MOE_MAX_PASSES = 5
+#: Share of all pairs the grouped matmuls are given at once at most (but by
+#: the first pass and one like it), and that the unrolled passes cover
+#: together; the last pass takes the rest a chunk of this share at a time. A
+#: pass's float32 rows live in HBM together: with half the pairs to one the
+#: 16k cells' steps do not fit a v5e.
+MOE_CHUNK_SHARE = 0.25
 #: Row, contraction and column tile of the Pallas grouped matmul
 #: (``expert_matmul="gmm"``), each clamped to the size it cuts: of the
 #: product, of its rows' gradient and of its weights' gradient. The least
@@ -796,15 +813,40 @@ def route(x, router_w, router_b, cfg):
     return idx, gate, load
 
 
-def pass_plan(sizes, width: int, n_pass: int):
-    """``[n_pass, held]``: how many rows of each expert's group lie in pass
-    ``p``'s slice ``[p * width, (p + 1) * width)`` of the sorted pairs."""
+def pass_widths(n_pairs: int, held: int, n_experts: int) -> tuple:
+    """The passes of the routed experts, each the equal widths of its
+    chunks, from what a layer sees at trace time: ``n_pairs`` (token,
+    expert) pairs sorted held experts first, ``held`` of ``n_experts``
+    experts here. The first pass takes ``MOE_PASS_OVER_HELD`` times the pairs
+    a balanced router holds here (whole sublanes of 8; every pair where a
+    chip holds every expert). The passes behind it run only while held pairs
+    are left, and each takes as many rows as all before it (``w0, w0, 2 w0,
+    ..``) while that keeps them within ``MOE_CHUNK_SHARE`` of the pairs (a
+    second of the first's width whatever that is); the last takes what is
+    left, in equal chunks of at most that share."""
+    chunk = max(8, round_up(int(n_pairs * MOE_CHUNK_SHARE), 8))
+    first = min(n_pairs, max(8, round_up(
+        -(-MOE_PASS_OVER_HELD * n_pairs * held // n_experts), 8)))
+    passes, covered = [(first,)], first
+    while (covered < n_pairs and covered <= max(chunk // 2, first)
+           and len(passes) < MOE_MAX_PASSES - 1):
+        passes.append((min(covered, round_up(n_pairs - covered, 8)),))
+        covered += passes[-1][0]
+    if covered < n_pairs:
+        n = -(-(n_pairs - covered) // chunk)
+        passes.append((round_up(-(-(n_pairs - covered) // n), 8),) * n)
+    return tuple(passes)
+
+
+def pass_plan(sizes, offsets):
+    """``[chunks, held]``: how many rows of each expert's group lie in chunk
+    ``c``'s slice ``[offsets[c], offsets[c + 1])`` of the sorted pairs."""
     import jax.numpy as jnp
 
     ends = jnp.cumsum(sizes)[None, :]
-    lo = (jnp.arange(n_pass) * width)[:, None]
-    return (jnp.clip(ends - lo, 0, width)
-            - jnp.clip(ends - sizes[None, :] - lo, 0, width))
+    lo = jnp.asarray(offsets[:-1], sizes.dtype)[:, None]
+    hi = jnp.asarray(offsets[1:], sizes.dtype)[:, None]
+    return jnp.clip(ends, lo, hi) - jnp.clip(ends - sizes[None, :], lo, hi)
 
 
 def experts_impl(platform: str, cfg) -> str:
@@ -881,11 +923,16 @@ def routed_experts(blk, x, idx, gate, cfg, first, held: int):
     weights ``blk["e_*"]`` are. The (token, expert) pairs are sorted by
     held expert (pairs of absent experts last) and the three matmuls run
     grouped (``jax.lax.ragged_dot``, or :func:`grouped_matmul` where
-    :func:`experts_impl` says) over the sorted pairs, ``MOE_PASS_SHARE``
-    of all pairs to a pass. A pass runs only while held pairs are left, so
-    the work follows the load and no pair is dropped whatever the imbalance.
-    Returns ``(y [N, D] float32, pairs, dropped)``: the pairs routed to held
-    experts, and those of them that no grouped matmul that ran was given."""
+    :func:`experts_impl` says) over the sorted pairs, a pass at a time
+    (:func:`pass_widths`: the first sized to the pairs held, the passes
+    together covering every pair, a wide one chunk after chunk). A pass runs
+    only while held pairs are left, so the work follows the load and no pair
+    is dropped whatever the imbalance. Returns ``(y [N, D] float32,
+    counters)``, the counters ``int32``: ``pairs`` routed to held experts,
+    ``dropped`` (those of them that no grouped matmul that ran was given),
+    the ``passes`` that ran and the rows they ``staged``."""
+    import itertools
+
     import jax
     import jax.numpy as jnp
 
@@ -894,8 +941,8 @@ def routed_experts(blk, x, idx, gate, cfg, first, held: int):
     N, D = x.shape
     k = cfg.experts_per_token
     M = N * k
-    C = min(M, max(8, -(-int(M * MOE_PASS_SHARE) // 8) * 8))
-    n_pass = -(-M // C)
+    passes = pass_widths(M, held, cfg.n_experts)
+    offsets = (0, *itertools.accumulate(itertools.chain(*passes)))
     with jax.named_scope("seq.moe/route"):
         local = idx.reshape(-1) - first
         is_held = (local >= 0) & (local < held)
@@ -904,8 +951,8 @@ def routed_experts(blk, x, idx, gate, cfg, first, held: int):
         sizes = (key[:, None] == jnp.arange(held)[None, :]).sum(
             axis=0).astype(jnp.int32)
         pairs = is_held.sum().astype(jnp.int32)
-        plan = pass_plan(sizes, C, n_pass)
-        pad = n_pass * C - M
+        plan = pass_plan(sizes, offsets)
+        pad = offsets[-1] - M
         gate_sorted = jnp.pad(gate.reshape(-1)[order], (0, pad))
         token_sorted = jnp.pad(order // k, (0, pad))
     xc = x.astype(cd)
@@ -941,26 +988,39 @@ def routed_experts(blk, x, idx, gate, cfg, first, held: int):
         with jax.named_scope("seq.moe/route"):
             return ys * g[:, None]
 
-    # the passes are unrolled, not scanned: a scan would stack what each
-    # pass's cond keeps for the backward pass, the experts' weights among it
-    state = (jnp.zeros((N, D), jnp.float32), jnp.int32(0))
-    for p in range(n_pass):
-        lo = p * C
-        tok = token_sorted[lo:lo + C]
-        g = gate_sorted[lo:lo + C]
-        valid = lo + jnp.arange(C) < pairs
+    def chunk(y, staged):
+        tok, g, sizes_here, valid = staged
+        rows = weighted(tok, g, sizes_here, valid)
+        with jax.named_scope("seq.moe/route"):
+            return y.at[tok].add(rows), None
 
-        def run(state, tok=tok, g=g, sizes_here=plan[p], valid=valid):
-            y, given = state
-            rows = weighted(tok, g, sizes_here, valid)
-            with jax.named_scope("seq.moe/route"):
-                # counted where it is consumed: the rows this pass's grouped
-                # matmuls were told to compute
-                return y.at[tok].add(rows), given + sizes_here.sum()
+    # the passes are unrolled, not scanned: a scan would stack what each
+    # pass's cond keeps for the backward pass, the experts' weights among it.
+    # The last pass scans its chunks inside its own cond, where the weights
+    # are the scan's constants and only the chunks' small arguments stack.
+    state = (jnp.zeros((N, D), jnp.float32), jnp.zeros(3, jnp.int32))
+    done = 0
+    for widths in passes:
+        n, lo, hi = len(widths), offsets[done], offsets[done + len(widths)]
+        staged = (token_sorted[lo:hi].reshape(n, -1),
+                  gate_sorted[lo:hi].reshape(n, -1), plan[done:done + n],
+                  (jnp.arange(lo, hi) < pairs).reshape(n, -1))
+        done += n
+
+        def run(state, staged=staged, n=n, rows=hi - lo):
+            y, counted = state
+            if n == 1:
+                y, _ = chunk(y, jax.tree.map(lambda a: a[0], staged))
+            else:
+                y, _ = jax.lax.scan(chunk, y, staged)
+            # counted where it is consumed: the rows this pass's grouped
+            # matmuls were told to compute, the pass, the rows it staged
+            return y, counted + jnp.stack([staged[2].sum(), 1, rows])
 
         state = jax.lax.cond(lo < pairs, run, lambda state: state, state)
-    y, given = state
-    return y, pairs, pairs - given
+    y, (given, ran, staged) = state
+    return y, {"pairs": pairs, "dropped": pairs - given, "passes": ran,
+               "staged": staged}
 
 
 def moe(blk, x, cfg, m_axis):
@@ -978,19 +1038,17 @@ def moe(blk, x, cfg, m_axis):
     first = cfg.experts_first
     if m_axis is not None:
         first = first + jax.lax.axis_index(m_axis) * held
-    y, pairs, dropped = routed_experts(blk, flat, idx, gate, cfg, first, held)
+    y, counters = routed_experts(blk, flat, idx, gate, cfg, first, held)
     if m_axis is not None:
-        y = jax.lax.psum(y, m_axis)
-        pairs = jax.lax.psum(pairs, m_axis)
-        dropped = jax.lax.psum(dropped, m_axis)
+        y, counters = jax.lax.psum((y, counters), m_axis)
     with jax.named_scope("seq.ffn"):
         if cfg.expert_act == "swiglu":
             y = y + swiglu(flat, blk["s_gate"], blk["s_up"], blk["s_down"],
                            _dtype(cfg))
         else:
             y = y + relu2_mlp(flat, blk["s_up"], blk["s_down"], _dtype(cfg))
-    counters = {"load": load, "pairs": pairs.astype(jnp.float32),
-                "dropped": dropped.astype(jnp.float32)}
+    counters = {"load": load, **jax.tree.map(
+        lambda a: a.astype(jnp.float32), counters)}
     return y.reshape(B, T, D), counters
 
 

@@ -196,6 +196,8 @@ class SeqRecModel:
     #: of ``seq_layers.groups_of``], and per expert layer (the MTP module's
     #: last) ``pairs`` (token, held expert) routed here, ``dropped`` (those
     #: of them the grouped matmuls were not given: 0 for a dropless layer),
+    #: ``passes`` (of the grouped matmuls that ran: more than one where the
+    #: held pairs passed the first's rows) and the rows they ``staged``,
     #: ``load_max_over_mean`` over all experts, ``bias_max`` (a router with
     #: a selection bias), ``window_tiles``/``causal_tiles`` (the gqa block:
     #: score tiles its window layers visited, and what causal layers of
@@ -768,6 +770,8 @@ def train_seqrec(
             scope the TPU compiler drops: pio_tpu/obs/profile.py), ``xla`` holds
             the compile counts with ``in_call``, and ``counters`` the
             moe blocks' routed pairs, load ratio and dropped pairs, the
+            passes of their grouped matmuls that ran and the rows those
+            staged (``moe_passes``, ``moe_staged_rows``), the
             largest selection bias (a router that has one), the gqa
             block's ``window_tiles`` and ``causal_tiles`` and the mamba
             layers' ``ssm_chunks`` and ``ssm_state_absmax``; a moe block
@@ -1121,6 +1125,8 @@ def train_seqrec(
             stats["counters"] = {
                 "pairs_held": float(trace["pairs"].sum()),
                 "dropped_pairs": float(trace["dropped"].sum()),
+                "moe_passes": float(trace["passes"].sum()),
+                "moe_staged_rows": float(trace["staged"].sum()),
                 "load_max_over_mean": float(
                     trace["load_max_over_mean"].max()),
             }

@@ -212,21 +212,26 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
     assert float(ref_pairs) == pairs
 
 
-@pytest.mark.parametrize("capacity", [0.25, 1.0])
-def test_dropless_under_a_planted_skew(capacity, monkeypatch):
-    """Every token to the same two held experts: more pairs than one pass
-    of the grouped matmuls takes, none dropped, the result the reference's."""
+@pytest.mark.parametrize("first, held, passes", [
+    (4, 4, 2),    # a quarter held: the first pass stages 64 of the 128 pairs
+    (5, 2, 3),    # an eighth held: 32, 32, then the rest in two chunks
+    (0, 16, 1),   # every expert held: one pass of all pairs
+])
+def test_dropless_under_a_planted_skew(first, held, passes):
+    """Every token to the same two held experts: more pairs than the first
+    pass of the grouped matmuls stages (twice a balanced router's), so
+    further passes run, none dropped, the result the reference's."""
     import jax.numpy as jnp
 
     cfg, blk, x = _layer(n_tokens=64)
-    cfg, part = _share(cfg, blk, 4, 4)
-    monkeypatch.setattr(seq_layers, "MOE_PASS_SHARE", capacity)
+    cfg, part = _share(cfg, blk, first, held)
     bias = jnp.zeros(16).at[jnp.array([5, 6])].set(10.0)
     part["router_b"] = bias
     y, c = seq_layers.moe(part, x, cfg, None)
     assert float(c["pairs"]) == 2 * 64 and float(c["dropped"]) == 0
     assert float(c["load"][5]) == float(c["load"][6]) == 64
-    m = dict(M, experts_first=4, n_routed_experts=4)
+    assert float(c["passes"]) == passes and float(c["staged"]) == 2 * 64
+    m = dict(M, experts_first=first, n_routed_experts=held)
     want, _load, _pairs = R._moe(part, x[0], m, None, None)
     np.testing.assert_allclose(y[0], want, atol=1e-6)
 
@@ -300,6 +305,10 @@ def test_the_stats_call_reports_spans_and_counters():
     counters = stats["counters"]
     assert counters["dropped_pairs"] == 0 and counters["pairs_held"] > 0
     assert counters["load_max_over_mean"] >= 1 and counters["bias_max"] > 0
+    # two steps of two expert layers and the MTP module's: a pass each at
+    # least, and no pair outside the rows they staged
+    assert counters["moe_passes"] >= 2 * 3
+    assert counters["moe_staged_rows"] >= counters["pairs_held"]
     assert "device_scope_s" not in stats  # no chip, no device scopes
 
 

@@ -172,6 +172,11 @@ def test_the_counters_reach_the_trace_and_the_stats():
     assert counters["ssm_state_absmax"] == model.trace["ssm_state_absmax"].max() > 0
     assert counters["dropped_pairs"] == 0.0 and counters["bias_max"] > 0
     assert counters["pairs_held"] == model.trace["pairs"].sum()
+    # three steps of four expert layers: a pass each at least, and the held
+    # pairs lie in the rows the passes staged
+    assert counters["moe_passes"] == model.trace["passes"].sum() >= 3 * 4
+    assert (model.trace["staged"] >= model.trace["pairs"]).all()
+    assert counters["moe_staged_rows"] == model.trace["staged"].sum()
 
 
 def test_it_trains_and_serves_from_engine_json_params():

@@ -197,9 +197,10 @@ def test_the_sixteen_expert_shares_add_up_to_the_uncut_layer():
 
     pairs = 0.0
     for first in range(16):
-        y, n, dropped = share(first)
-        total, pairs = total + y, pairs + float(n)
-        assert float(dropped) == 0 and float(n) == float(load[first])
+        y, c = share(first)
+        total, pairs = total + y, pairs + float(c["pairs"])
+        assert float(c["dropped"]) == 0
+        assert float(c["pairs"]) == float(load[first])
     total = total[None]
     np.testing.assert_allclose(total[0], want, atol=2e-6)
     np.testing.assert_allclose(whole[0], want, atol=2e-6)
@@ -302,6 +303,38 @@ def test_an_expert_layer_is_the_same_through_either_grouped_matmul(monkeypatch):
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(g, w, rtol=1e-5,
                                    atol=1e-5 * float(np.abs(w).max()))
+
+
+@pytest.mark.parametrize("impl", ["ragged_dot", "gmm"])
+def test_a_planted_skew_costs_a_second_pass_not_a_pair(impl, monkeypatch):
+    """Every token to the same three held experts of a quarter held: twice
+    the rows the first pass stages, so a second runs; no pair dropped, the
+    result the reference's, through XLA's grouped matmul and the kernel's
+    oracle path (interpret mode)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    blk = one_layer({"ffn_norm": seq_layers.Leaf((1, 32), "ones"),
+                     **seq_layers._moe_leaves(1, CFG)})
+    blk["router_b"] = jnp.zeros(16).at[jnp.array([4, 5, 6])].set(10.0)
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, T, 32))
+    if impl == "gmm":
+        monkeypatch.setattr(seq_layers, "experts_impl", lambda platform, c: impl)
+        monkeypatch.setattr(seq_layers, "grouped_matmul", functools.partial(
+            seq_layers.grouped_matmul, interpret=True))
+        monkeypatch.setattr(seq_layers, "GMM_TILES", dict.fromkeys(
+            seq_layers.GMM_TILES, (128, 128, 128)))
+    xn = seq_layers.rms_norm(x, blk["ffn_norm"], CFG.norm_eps)
+    y, c = seq_layers.moe(blk, xn, CFG, None)
+    n_pairs = 2 * T * CFG.experts_per_token
+    assert seq_layers.pass_widths(n_pairs, 4, 16) == ((n_pairs // 2,),) * 2
+    assert float(c["pairs"]) == n_pairs and float(c["dropped"]) == 0
+    assert float(c["passes"]) == 2 and float(c["staged"]) == n_pairs
+    with jax.default_matmul_precision("highest"):
+        want = jnp.stack([R._moe(blk, row, M, None, None)[0] for row in x])
+    np.testing.assert_allclose(y, want, atol=2e-6)
 
 
 # ------------------------------------------------------- what check_block refuses

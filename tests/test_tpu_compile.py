@@ -245,10 +245,12 @@ def test_mesh_trainer_sums_in_the_kernel_on_four_v5e(topo, monkeypatch):
     assert "f32[4096,64,64]" not in text
 
 
+@pytest.mark.parametrize("rows", [12288, 24576])
 @pytest.mark.parametrize("k,n", [(2688, 1856), (1856, 2688)])
-def test_grouped_expert_matmul_compiles_for_v5e(one_chip, k, n):
-    """The Nemotron cell's two expert matrices (a pass of 24,576 rows over 8
-    held experts; 1,856 is no multiple of a 128-lane tile) through
+def test_grouped_expert_matmul_compiles_for_v5e(one_chip, k, n, rows):
+    """The Nemotron cell's two expert matrices (the first passes' 12,288 rows
+    and the last's chunks of 24,576, ``seq_layers.pass_widths``, over 8 held
+    experts; 1,856 is no multiple of a 128-lane tile) through
     ``seq_layers.grouped_matmul`` with the tiles as committed: the product
     and both gradients are Pallas calls that fit VMEM."""
     import jax
@@ -264,7 +266,7 @@ def test_grouped_expert_matmul_compiles_for_v5e(one_chip, k, n):
         return y, back(ct)
 
     text = jax.jit(both).lower(
-        sd((24576, k), jnp.bfloat16), sd((8, k, n), jnp.bfloat16),
-        sd((8,), jnp.int32), sd((24576, n), jnp.float32)).compile().as_text()
+        sd((rows, k), jnp.bfloat16), sd((8, k, n), jnp.bfloat16),
+        sd((8,), jnp.int32), sd((rows, n), jnp.float32)).compile().as_text()
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     assert len(calls) == 3, len(calls)
