@@ -30,4 +30,12 @@ sharded JAX programs over a ``jax.sharding.Mesh``; XLA collectives over
 ICI/DCN replace Spark shuffles and tree-aggregations.
 """
 
+import time as _time
+
+#: when this package was first imported, on ``pio_tpu.obs.monotonic_s``'s
+#: clock: the process timeline's ``pio_tpu_imported`` mark
+#: (``pio_tpu/obs/tracing.py``). Read before anything else is imported, so
+#: that the package's own modules stand after the mark.
+IMPORTED_AT = _time.perf_counter()
+
 __version__ = "0.1.0"

@@ -43,6 +43,7 @@ recompile only on shape changes.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import logging
@@ -1273,34 +1274,35 @@ def _run_streamed(config: "ALSConfig", rank: int, U_pad: int, I_pad: int,
     """
     import jax
 
-    edge_start = np.zeros(U_pad + 1, np.int64)
-    np.cumsum(counts_u, out=edge_start[1:])
-    spans = _edge_spans(i_sorted.shape[0], n_stream)
+    with active_span("als.build"):
+        edge_start = np.zeros(U_pad + 1, np.int64)
+        np.cumsum(counts_u, out=edge_start[1:])
+        spans = _edge_spans(i_sorted.shape[0], n_stream)
 
-    local_slices, n_blocks, chunk_spec = [], [], []
-    for e0, e1 in spans:
-        lc = np.diff(np.clip(edge_start, e0, e1))
-        u0 = int(np.searchsorted(edge_start, e0, side="right")) - 1
-        pad_c = int(np.searchsorted(edge_start, e1 - 1, side="right")) - 1
-        local_slices.append(
-            np.ascontiguousarray(lc[u0:pad_c + 1], np.int32)
+        local_slices, n_blocks, chunk_spec = [], [], []
+        for e0, e1 in spans:
+            lc = np.diff(np.clip(edge_start, e0, e1))
+            u0 = int(np.searchsorted(edge_start, e0, side="right")) - 1
+            pad_c = int(np.searchsorted(edge_start, e1 - 1, side="right")) - 1
+            local_slices.append(
+                np.ascontiguousarray(lc[u0:pad_c + 1], np.int32)
+            )
+            n_blocks.append(int((-(-lc // w_user)).sum()))
+            chunk_spec.append([0, pad_c, u0])  # S_c filled below
+        chunk_stream = min(
+            config.blocks_per_chunk,
+            _round_up(max(1, -(-sum(n_blocks) // len(spans))), 8),
         )
-        n_blocks.append(int((-(-lc // w_user)).sum()))
-        chunk_spec.append([0, pad_c, u0])  # S_c filled below
-    chunk_stream = min(
-        config.blocks_per_chunk,
-        _round_up(max(1, -(-sum(n_blocks) // len(spans))), 8),
-    )
-    for spec, nb in zip(chunk_spec, n_blocks):
-        spec[0] = _round_up(max(nb, 1), chunk_stream)
+        for spec, nb in zip(chunk_spec, n_blocks):
+            spec[0] = _round_up(max(nb, 1), chunk_stream)
 
-    init, accums, finalize = _build_stream_trainer(
-        config.iterations, float(config.reg), bool(config.implicit),
-        float(config.alpha), _resolve_matmul_dtype(str(config.matmul_dtype)), str(config.solver),
-        rank, U_pad, I_pad, w_user, w_item, S_item,
-        chunk_stream, chunk_item,
-        tuple(tuple(s) for s in chunk_spec),
-    )
+        init, accums, finalize = _build_stream_trainer(
+            config.iterations, float(config.reg), bool(config.implicit),
+            float(config.alpha), _resolve_matmul_dtype(str(config.matmul_dtype)), str(config.solver),
+            rank, U_pad, I_pad, w_user, w_item, S_item,
+            chunk_stream, chunk_item,
+            tuple(tuple(s) for s in chunk_spec),
+        )
 
     # the shared streamed-feed executor (parallel/stream.py) runs the
     # slice → queued-put → chained-dispatch loop; ALS retains the edge
@@ -1424,37 +1426,36 @@ def _run_mesh_compact(config, mesh, axis, n_shards, user_idx, item_idx,
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    t0 = monotonic_s()
-    counts_u, chunk_user, S_u = _counts_layout(
-        user_idx, w_user, U_pad, n_shards, config.blocks_per_chunk)
-    counts_i, chunk_item, S_i = _counts_layout(
-        item_idx, w_item, I_pad, n_shards, config.blocks_per_chunk)
-    if S_u * w_user >= 2 ** 31 or S_i * w_item >= 2 ** 31:
-        raise ValueError(
-            "edge set too large for int32 block addressing; raise "
-            "block width or shard the edge set first"
+    with active_span("als.sort") as sort:
+        counts_u, chunk_user, S_u = _counts_layout(
+            user_idx, w_user, U_pad, n_shards, config.blocks_per_chunk)
+        counts_i, chunk_item, S_i = _counts_layout(
+            item_idx, w_item, I_pad, n_shards, config.blocks_per_chunk)
+        if S_u * w_user >= 2 ** 31 or S_i * w_item >= 2 ** 31:
+            raise ValueError(
+                "edge set too large for int32 block addressing; raise "
+                "block width or shard the edge set first"
+            )
+        counts_u = np.ascontiguousarray(counts_u, np.int64)
+        i_sorted, r_sorted = _sort_edges_by_user(
+            user_idx, item_idx, rating, n_edges, U_pad, counts_u
         )
-    counts_u = np.ascontiguousarray(counts_u, np.int64)
-    i_sorted, r_sorted = _sort_edges_by_user(
-        user_idx, item_idx, rating, n_edges, U_pad, counts_u
-    )
     # chunked shipment (the single-device stream discipline applied to
     # the sharded puts): slice both arrays into ≤8 spans so the
     # per-device transfers of span k+1 pipeline behind span k instead of
     # one monolithic put per array serializing the whole h2d. The
     # trainer splices the trimmed spans back together before packing.
-    spans = _edge_spans(n_edges, _n_stream_chunks(
-        _EDGE_BYTES * n_edges, "PIO_TPU_ALS_STREAM_MB"))
-
+    with active_span("als.build"):
+        spans = _edge_spans(n_edges, _n_stream_chunks(
+            _EDGE_BYTES * n_edges, "PIO_TPU_ALS_STREAM_MB"))
+        run = trainer(
+            chunk_user, chunk_item, (S_u, w_user, S_i, w_item),
+            mesh_span_lens=tuple(e1 - e0 for e0, e1 in spans),
+        )
     if stats is not None:
-        stats["pack_s"] = monotonic_s() - t0
+        stats["pack_s"] = sort.seconds
         stats["wire_bytes"] = _EDGE_BYTES * n_edges + 4 * (U_pad + I_pad)
         stats["n_stream"] = len(spans)
-
-    run = trainer(
-        chunk_user, chunk_item, (S_u, w_user, S_i, w_item),
-        mesh_span_lens=tuple(e1 - e0 for e0, e1 in spans),
-    )
     shard1 = NamedSharding(mesh, P(axis))
     repl = NamedSharding(mesh, P())
 
@@ -1462,30 +1463,31 @@ def _run_mesh_compact(config, mesh, axis, n_shards, user_idx, item_idx,
         p = (-len(a)) % n_shards
         return np.concatenate([a, np.zeros(p, a.dtype)]) if p else a
 
-    t0 = monotonic_s()
-    counts_dev = (
-        jax.device_put(counts_u.astype(np.int32), repl),
-        jax.device_put(np.ascontiguousarray(counts_i, np.int32), repl),
-    )
-    # a span of both arrays goes out together so early spans of each are
-    # in flight at once; per-span timings land in stats on profiled runs
-    i_dev, r_dev, chunk_ts = [], [], []
-    for e0, e1 in spans:
-        tc = monotonic_s()
-        i_dev.append(jax.device_put(pad_to_shards(i_sorted[e0:e1]), shard1))
-        r_dev.append(jax.device_put(pad_to_shards(r_sorted[e0:e1]), shard1))
+    with active_span("als.put") as put:
+        counts_dev = (
+            jax.device_put(counts_u.astype(np.int32), repl),
+            jax.device_put(np.ascontiguousarray(counts_i, np.int32), repl),
+        )
+        # a span of both arrays goes out together so early spans of each
+        # are in flight at once; per-span timings land in stats on
+        # profiled runs
+        i_dev, r_dev, chunk_ts = [], [], []
+        for e0, e1 in spans:
+            tc = monotonic_s()
+            i_dev.append(
+                jax.device_put(pad_to_shards(i_sorted[e0:e1]), shard1))
+            r_dev.append(
+                jax.device_put(pad_to_shards(r_sorted[e0:e1]), shard1))
+            if stats is not None:
+                jax.block_until_ready((i_dev[-1], r_dev[-1]))
+                chunk_ts.append(round(monotonic_s() - tc, 3))
+        args = (*counts_dev, tuple(i_dev), tuple(r_dev))
         if stats is not None:
-            jax.block_until_ready((i_dev[-1], r_dev[-1]))
-            chunk_ts.append(round(monotonic_s() - tc, 3))
-    args = (*counts_dev, tuple(i_dev), tuple(r_dev))
+            jax.block_until_ready(args)
     if stats is not None:
-        jax.block_until_ready(args)
-        stats["h2d_s"] = monotonic_s() - t0
+        stats["h2d_s"] = put.seconds
         stats["h2d_chunk_s"] = chunk_ts
-        P_f, Q_f = _profiled_run(run, (*args, seed), stats, capture)
-    else:
-        P_f, Q_f = run(*args, seed)
-    return P_f, Q_f
+    return _run_whole(run, (*args, seed), stats, capture)
 
 
 def _train_mesh_host_packed(ctx: ComputeContext, user_idx, item_idx, rating,
@@ -1539,16 +1541,19 @@ def _train_mesh_host_packed(ctx: ComputeContext, user_idx, item_idx, rating,
                       item_factors=np.asarray(Q_f)[:n_items])
 
 
-def _profiled_run(run, args, stats: dict, capture: ScopeCapture):
-    """The device phase of a ``stats`` call: dispatch, block, time it as
-    ``device_s``, all inside the scope capture."""
+def _run_whole(run, args, stats: Optional[dict], capture: ScopeCapture):
+    """Dispatch the one program of the routes that do not stream, in the
+    leaf span ``als.run``. A ``stats`` call also waits for it there, inside
+    the scope capture, and the span is its ``device_s``."""
     import jax
 
-    with capture:
-        t0 = monotonic_s()
-        out = run(*args)
-        jax.block_until_ready(out)
-        stats["device_s"] = monotonic_s() - t0
+    with capture if stats is not None else contextlib.nullcontext():
+        with active_span("als.run") as ran:
+            out = run(*args)
+            if stats is not None:
+                jax.block_until_ready(out)
+    if stats is not None:
+        stats["device_s"] = ran.seconds
     return out
 
 
@@ -1629,9 +1634,24 @@ def train_als(
     same four over this call: 0 compiles and 0 loads once warm.
 
     Host work is marked by leaf spans (:func:`pio_tpu.obs.active_span`):
-    ``als.sort``, the feed's ``stream.*`` and ``als.readback``; they tile
-    and never nest, and no span encloses the call.
+    ``als.sort``, ``als.build`` (the chunks' layout and the trainer's
+    programs, looked up or made), the feed's ``stream.*`` (or, on the
+    routes that do not stream, ``als.put`` in a ``stats`` call and
+    ``als.run``) and ``als.readback``; they tile the call and never nest,
+    and no span encloses it. The call itself is numbered on the process
+    timeline (:meth:`pio_tpu.obs.tracing.ProcessTimeline.train_call`),
+    whose record a ``stats`` call reports as ``stats["process"]``.
     """
+    from pio_tpu.obs.tracing import PROCESS
+
+    with PROCESS.train_call(stats):
+        return _train_als(ctx, user_idx, item_idx, rating, n_users, n_items,
+                          config, stats)
+
+
+def _train_als(ctx: ComputeContext, user_idx, item_idx, rating, n_users: int,
+               n_items: int, config: ALSConfig,
+               stats: Optional[dict]) -> ALSFactors:
     import jax
     import jax.numpy as jnp
 
@@ -1718,8 +1738,7 @@ def train_als(
         # (_build_trainer's ``run_packed``). Above a size threshold the
         # shipment is STREAMED in chunks overlapped with the chunk packs +
         # iteration-1 accumulation (_build_stream_trainer).
-        t0 = monotonic_s()
-        with active_span("als.sort"):
+        with active_span("als.sort") as sort:
             counts_u, chunk_user, S_u = _counts_layout(
                 user_idx, w_user, U_pad, 1, config.blocks_per_chunk)
             counts_i, chunk_item, S_i = _counts_layout(
@@ -1736,7 +1755,7 @@ def train_als(
             )
         edge_bytes = _EDGE_BYTES * n_edges
         if stats is not None:
-            stats["pack_s"] = monotonic_s() - t0
+            stats["pack_s"] = sort.seconds
             stats["wire_bytes"] = (
                 edge_bytes + 4 * (U_pad + I_pad)  # + the two count arrays
             )
@@ -1762,21 +1781,20 @@ def train_als(
                 stats, capture,
             )
         else:
-            run = _trainer(
-                chunk_user, chunk_item, (S_u, w_user, S_i, w_item))
-            args = (
-                counts_u.astype(np.int32),
-                np.ascontiguousarray(counts_i, np.int32),
-                i_sorted, r_sorted,
-            )
+            with active_span("als.build"):
+                run = _trainer(
+                    chunk_user, chunk_item, (S_u, w_user, S_i, w_item))
+                args = (
+                    counts_u.astype(np.int32),
+                    np.ascontiguousarray(counts_i, np.int32),
+                    i_sorted, r_sorted,
+                )
             if stats is not None:
-                t0 = monotonic_s()
-                args = tuple(jax.device_put(a) for a in args)
-                jax.block_until_ready(args)
-                stats["h2d_s"] = monotonic_s() - t0
-                P_f, Q_f = _profiled_run(run, (*args, seed), stats, capture)
-            else:
-                P_f, Q_f = run(*args, seed)
+                with active_span("als.put") as put:
+                    args = tuple(jax.device_put(a) for a in args)
+                    jax.block_until_ready(args)
+                stats["h2d_s"] = put.seconds
+            P_f, Q_f = _run_whole(run, (*args, seed), stats, capture)
 
     with active_span("als.readback"):
         P_f, Q_f = jax.device_get((P_f, Q_f))

@@ -26,6 +26,7 @@ collectives (psum↔broadcast, ppermute↔reverse ppermute, gather↔scatter).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import NamedTuple, Optional, Tuple
@@ -786,6 +787,25 @@ def train_seqrec(
             parameters alone (4 B each): not Adam's two moments, the
             gradients or the activations, which the compiler places.
     """
+    from pio_tpu.obs.tracing import PROCESS
+
+    with PROCESS.train_call(stats):
+        return _train_seqrec(mesh, sequences, n_items, config, checkpoint,
+                             checkpoint_every, stats)
+
+
+def _train_seqrec(mesh, sequences, n_items, config, checkpoint,
+                  checkpoint_every, stats) -> SeqRecModel:
+    """The call itself, inside :meth:`ProcessTimeline.train_call`. Its host
+    work stands in leaf spans that tile it (:func:`pio_tpu.obs.active_span`):
+    ``seq.pack``, ``seq.build`` (the placement accounting and the steppers,
+    looked up or made), ``seq.init`` (the parameters and the epoch placed;
+    again for Adam's state), ``seq.steps`` (the chunks' dispatch: on a
+    first call JAX's trace, lowering and compile or cache load of the
+    stepper; in a ``stats`` call also the wait for the device and the
+    scope capture) and ``seq.readback`` (the wait for the device, but for
+    a ``stats`` call, and the transfer). ``stats``' phase seconds are
+    those spans'."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -840,8 +860,7 @@ def train_seqrec(
             f"({n_seq}); use ring attention or adjust n_heads"
         )
 
-    t_pack = monotonic_s()
-    with active_span("seq.pack"):
+    with active_span("seq.pack") as packed:
         seqs = np.asarray(sequences, np.int32)
         n, t = seqs.shape
         t_pad = _round_up(min(t, cfg.max_len), n_seq)
@@ -885,97 +904,96 @@ def train_seqrec(
             epoch += [targets2, (mask & (targets2 > 0)).astype(np.float32)]
         epoch = tuple(epoch)
     if stats is not None:
-        stats["pack_s"] = monotonic_s() - t_pack
+        stats["pack_s"] = packed.seconds
 
-    vocab = _round_up(n_items + 1, n_model)  # +1 for the pad row
-    specs = param_specs(cfg)
+    with active_span("seq.build"):
+        vocab = _round_up(n_items + 1, n_model)  # +1 for the pad row
+        specs = param_specs(cfg)
 
-    # placement accounting BEFORE anything lands on device (the
-    # two_tower discipline): sharded params must fit the per-chip
-    # budget, and the staged epoch must fit NEXT TO them or the feed
-    # streams row spans instead. What is counted is the parameters, 4 B
-    # each, as they shard; Adam's moments (8 B more) are not.
-    from pio_tpu.parallel.partition import (
-        DeviceBudgetExceeded,
-        assert_device_budget,
-        device_budget_bytes,
-        per_device_nbytes,
-    )
-
-    z = np.zeros((), np.float32)
-    skeleton = unflatten({
-        path: np.broadcast_to(z, leaf.shape)
-        for path, leaf in describe_params(vocab, cfg).items()
-    })
-    params_nbytes = sum(
-        leaf.nbytes for leaf in jax.tree_util.tree_leaves(skeleton)
-    )
-    if mesh is None:
-        assert_device_budget(
-            params_nbytes, 1,
-            "seqrec params alone, no optimizer state (single-chip placement)"
+        # placement accounting BEFORE anything lands on device (the
+        # two_tower discipline): sharded params must fit the per-chip
+        # budget, and the staged epoch must fit NEXT TO them or the feed
+        # streams row spans instead. What is counted is the parameters, 4 B
+        # each, as they shard; Adam's moments (8 B more) are not.
+        from pio_tpu.parallel.partition import (
+            DeviceBudgetExceeded,
+            assert_device_budget,
+            device_budget_bytes,
+            per_device_nbytes,
         )
-        params_pd = params_nbytes
-    else:
-        params_pd = per_device_nbytes(mesh, skeleton, specs)
-        assert_device_budget(
-            params_pd, 1, "seqrec sharded params alone, no optimizer state")
-    # seqs + targets (int32) + masks (float32), sharded over data × seq
-    row_bytes = 4 * len(epoch)
-    staged_pd = -(-row_bytes * seqs.shape[0] * t_pad // (n_data * n_seq))
-    budget = device_budget_bytes()
-    over = budget > 0 and params_pd + staged_pd > budget
-    streamed = cfg.batch_size > 0 and (
-        cfg.stream == "on" or (cfg.stream == "auto" and over)
-    )
-    if over and cfg.batch_size <= 0 and cfg.stream != "off":
-        raise DeviceBudgetExceeded(
-            f"seqrec staged epoch ({staged_pd} B/device) does not fit "
-            f"beside the params ({params_pd} B/device) under "
-            f"PIO_TPU_DEVICE_BUDGET_BYTES={budget}; set batch_size > 0 "
-            f"so the feed can stream row spans"
-        )
-    n_stream = 0
-    if streamed:
-        from pio_tpu.parallel.stream import n_stream_chunks
 
-        n_stream = max(
-            2,
-            n_stream_chunks(row_bytes * seqs.shape[0] * t_pad,
-                            "PIO_TPU_TRAIN_STREAM_MB", cap=256),
+        z = np.zeros((), np.float32)
+        skeleton = unflatten({
+            path: np.broadcast_to(z, leaf.shape)
+            for path, leaf in describe_params(vocab, cfg).items()
+        })
+        params_nbytes = sum(
+            leaf.nbytes for leaf in jax.tree_util.tree_leaves(skeleton)
         )
-        if budget > params_pd:
-            n_stream = max(n_stream, -(-staged_pd // (budget - params_pd)))
-        n_stream = min(n_batches, n_stream)
-    if stats is not None:
-        stats["n_stream"] = n_stream
-        if latent:
-            stats["experts_impl"] = experts_impl(jax.default_backend(), cfg)
+        if mesh is None:
+            assert_device_budget(
+                params_nbytes, 1,
+                "seqrec params alone, no optimizer state (single-chip placement)"
+            )
+            params_pd = params_nbytes
+        else:
+            params_pd = per_device_nbytes(mesh, skeleton, specs)
+            assert_device_budget(
+                params_pd, 1, "seqrec sharded params alone, no optimizer state")
+        # seqs + targets (int32) + masks (float32), sharded over data × seq
+        row_bytes = 4 * len(epoch)
+        staged_pd = -(-row_bytes * seqs.shape[0] * t_pad // (n_data * n_seq))
+        budget = device_budget_bytes()
+        over = budget > 0 and params_pd + staged_pd > budget
+        streamed = cfg.batch_size > 0 and (
+            cfg.stream == "on" or (cfg.stream == "auto" and over)
+        )
+        if over and cfg.batch_size <= 0 and cfg.stream != "off":
+            raise DeviceBudgetExceeded(
+                f"seqrec staged epoch ({staged_pd} B/device) does not fit "
+                f"beside the params ({params_pd} B/device) under "
+                f"PIO_TPU_DEVICE_BUDGET_BYTES={budget}; set batch_size > 0 "
+                f"so the feed can stream row spans"
+            )
+        n_stream = 0
+        if streamed:
+            from pio_tpu.parallel.stream import n_stream_chunks
 
-    # the programs are built once for these sizes and kept: a second call
-    # traces and compiles nothing
-    prog = _programs(dataclasses.replace(cfg, seed=0, steps=0), mesh, vocab,
-                     B, n_batches)
-    t0 = monotonic_s()
-    dsh = None
-    with active_span("seq.init"):
-        # with a mesh each device materializes only its shard — the
-        # vocab-sharded table never exists unsharded on any chip
-        params = prog.init(jnp.int32(cfg.seed))
-        if mesh is not None:
-            dsh = NamedSharding(mesh, P("data", "seq"))
+            n_stream = max(
+                2,
+                n_stream_chunks(row_bytes * seqs.shape[0] * t_pad,
+                                "PIO_TPU_TRAIN_STREAM_MB", cap=256),
+            )
+            if budget > params_pd:
+                n_stream = max(n_stream, -(-staged_pd // (budget - params_pd)))
+            n_stream = min(n_batches, n_stream)
+        if stats is not None:
+            stats["n_stream"] = n_stream
+            if latent:
+                stats["experts_impl"] = experts_impl(jax.default_backend(), cfg)
+
+        # the programs are built once for these sizes and kept: a second call
+        # traces and compiles nothing
+        prog = _programs(dataclasses.replace(cfg, seed=0, steps=0), mesh, vocab,
+                         B, n_batches)
+    dsh = None if mesh is None else NamedSharding(mesh, P("data", "seq"))
 
     def _put_epoch(*arrays):
         if mesh is None:
             return tuple(jnp.asarray(a) for a in arrays)
         return tuple(jax.device_put(jnp.asarray(a), dsh) for a in arrays)
 
-    epoch_d = None
-    if not streamed:
-        epoch_d = _put_epoch(*epoch)
+    with active_span("seq.init") as placed:
+        # with a mesh each device materializes only its shard — the
+        # vocab-sharded table never exists unsharded on any chip
+        params = prog.init(jnp.int32(cfg.seed))
+        epoch_d = None
+        if not streamed:
+            epoch_d = _put_epoch(*epoch)
+        if stats is not None:
+            jax.block_until_ready((params, epoch_d))
     if stats is not None:
-        jax.block_until_ready((params, epoch_d))
-        stats["place_s"] = monotonic_s() - t0
+        stats["place_s"] = placed.seconds
 
     trainwatch.begin_algo(
         "seqrec", total_steps=cfg.steps, n_batches=n_batches,
@@ -1065,57 +1083,54 @@ def train_seqrec(
             _note_chunk(n, losses, aux, keep=1)
             return state
 
-    from pio_tpu.workflow.checkpoint import (
-        run_chunked_steps,
-        state_fingerprint,
-    )
+    with active_span("seq.init"):  # Adam's state, and what a snapshot keys on
+        from pio_tpu.workflow.checkpoint import (
+            run_chunked_steps,
+            state_fingerprint,
+        )
 
-    # steps excluded: resume with a different total must still match.
-    # stream normalized: streamed and staged feeds walk the SAME batch
-    # schedule, so their snapshots are interchangeable
-    fingerprint = state_fingerprint(
-        "seqrec", dataclasses.replace(cfg, steps=0, stream="auto"),
-        n_items, seqs.shape, int(seqs.sum()),
-    )
-    state = (jnp.int32(0), params, prog.opt_init(params))
+        # steps excluded: resume with a different total must still match.
+        # stream normalized: streamed and staged feeds walk the SAME batch
+        # schedule, so their snapshots are interchangeable
+        fingerprint = state_fingerprint(
+            "seqrec", dataclasses.replace(cfg, steps=0, stream="auto"),
+            n_items, seqs.shape, int(seqs.sum()),
+        )
+        state = (jnp.int32(0), params, prog.opt_init(params))
     xla_before = devicewatch.xla_totals() if stats is not None else None
-    capture = ScopeCapture("seq.")  # entered by a ``stats`` call only
-    t0 = monotonic_s()
-    if stats is not None:
-        jax.block_until_ready(state)
-        with capture:
+    # a ``stats`` call waits for the device on both sides of its steps and
+    # traces them; a plain call's span ends with the last chunk's dispatch
+    capture = ScopeCapture("seq.") if stats is not None else None
+    with active_span("seq.steps") as stepped:
+        if stats is not None:
+            jax.block_until_ready(state)
+        with capture or contextlib.nullcontext():
             state = run_chunked_steps(
                 state, cfg.steps, chunk_fn,
                 checkpoint=checkpoint, checkpoint_every=checkpoint_every,
                 fingerprint=fingerprint,
             )
-            jax.block_until_ready(state)
-        stats["steps_s"] = monotonic_s() - t0
-    else:
-        state = run_chunked_steps(
-            state, cfg.steps, chunk_fn,
-            checkpoint=checkpoint, checkpoint_every=checkpoint_every,
-            fingerprint=fingerprint,
-        )
-    _drain()  # flush the telemetry tail (no-op without a recorder)
-    fitted = state[1]
-
-    # ONE fused pull (device_get returns host numpy): per-leaf
-    # np.asarray paid a host link round trip per parameter tensor
-    t0 = monotonic_s()
-    with active_span("seq.readback"):
-        host, aux = jax.device_get((fitted, _aux))
-    for table in ("emb", "head"):
-        if table in host:
-            host[table] = host[table][: n_items + 1]
-    trace = None
-    if aux:
-        trace = {
-            k: np.concatenate([np.asarray(a[k]) for a in aux])
-            for k in aux[0] if k != "load"
-        }
+            if stats is not None:
+                jax.block_until_ready(state)
     if stats is not None:
-        stats["readback_s"] = monotonic_s() - t0
+        stats["steps_s"] = stepped.seconds
+
+    with active_span("seq.readback") as read:
+        _drain()  # flush the telemetry tail (no-op without a recorder)
+        # ONE fused pull (device_get returns host numpy): per-leaf
+        # np.asarray paid a host link round trip per parameter tensor
+        host, aux = jax.device_get((state[1], _aux))
+        for table in ("emb", "head"):
+            if table in host:
+                host[table] = host[table][: n_items + 1]
+        trace = None
+        if aux:
+            trace = {
+                k: np.concatenate([np.asarray(a[k]) for a in aux])
+                for k in aux[0] if k != "load"
+            }
+    if stats is not None:
+        stats["readback_s"] = read.seconds
         stats.update(device_stats(capture.result))
         xla = devicewatch.xla_totals()
         if xla is not None and xla_before is not None:

@@ -26,13 +26,15 @@ third telemetry plane, mirroring the fleet (ISSUE 11) and training
   trace+compile entry. A shape the global jit cache already holds —
   e.g. a hot-swap re-warm over an unchanged bucket ladder — is NOT
   recounted, matching what XLA actually does.)
-- **Real compiles** — :func:`watch_xla_compiles` listens to JAX's own
-  monitoring events, so what the backend compiled and what it loaded
-  from the persistent compilation cache are counted apart, process
-  wide, whichever site dispatched (:func:`xla_totals`;
-  ``pio_tpu_xla_backend_compile*`` / ``pio_tpu_xla_cache_load*``). The
-  site counters above infer a compile from a shape key new to the
-  site; these count the compiler's own calls.
+- **The real compile path** — :func:`watch_xla_compiles` listens to
+  JAX's own monitoring events, so what it traced, what it lowered, what
+  the backend compiled and what it loaded from the persistent
+  compilation cache are counted apart, process wide, whichever site
+  dispatched (:func:`xla_totals`; ``pio_tpu_xla_trace*`` /
+  ``pio_tpu_xla_lower*`` / ``pio_tpu_xla_backend_compile*`` /
+  ``pio_tpu_xla_cache_load*``), and by program
+  (:func:`xla_by_program`). The site counters above infer a compile
+  from a shape key new to the site; these count JAX's own calls.
 - **Endpoints** — ``payload()`` renders ``GET /device.json`` on the
   query server and the trainer status sidecar; the fleet aggregator
   federates it into ``/fleet.json`` as a per-member ``devices`` block
@@ -605,26 +607,59 @@ def watching(watch: DeviceWatch, sample: bool = True):
 
 
 # ---------------------------------------------------------------------------
-# real compiles — JAX's own monitoring events, process totals
+# JAX's compile path: its own monitoring events, process totals and by program
 # ---------------------------------------------------------------------------
 
-#: jax 0.9.0 ``jax/_src/dispatch.py`` BACKEND_COMPILE_EVENT: one duration
-#: event per ``compile_or_get_cached`` call, a persistent-cache hit included
+#: jax 0.9.0 ``jax/_src/dispatch.py``: ``log_elapsed_time`` brackets each
+#: step of the compile path and reports it twice, as a scalar (the start
+#: time) when the step begins and as a duration when it ends, both with
+#: ``fun_name``. Tracing nests (a jitted function traced inside another
+#: is an event inside the outer's), and whatever a trace runs eagerly
+#: compiles inside it: the begin events are what lets each step be charged
+#: its own seconds and not its children's.
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+#: one duration event per ``compile_or_get_cached`` call, a
+#: persistent-cache hit included
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 #: jax 0.9.0 ``jax/_src/compiler.py``: fired inside that call, before it
 #: ends, only when the executable came from the persistent cache
 _CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
-_XLA_LOCK = threading.Lock()
-_XLA_TOTALS = {"compiles": 0, "compile_s": 0.0,
-               "cache_loads": 0, "cache_load_s": 0.0}
-_XLA_WATCHING = False
-_XLA_THREAD = threading.local()  # .loaded: a retrieval event is pending
+#: by-program rows kept; the smallest folds into :data:`_OTHER_PROGRAMS`
+MAX_PROGRAMS = 64
+_OTHER_PROGRAMS = "(other)"
 
-#: loaded from the cache? -> (totals' count key, seconds key, the two
+_XLA_LOCK = threading.Lock()
+_XLA_TOTALS = {"traces": 0, "trace_s": 0.0, "lowers": 0, "lower_s": 0.0,
+               "compiles": 0, "compile_s": 0.0,
+               "cache_loads": 0, "cache_load_s": 0.0}
+_XLA_BY_PROGRAM: Dict[str, dict] = {}
+_XLA_WATCHING = False
+#: ``.loaded``: a retrieval event is pending; ``.open``: the seconds of the
+#: ended steps inside each step still open on this thread, outermost first
+_XLA_THREAD = threading.local()
+
+
+#: step of the compile path -> (totals' count key, seconds key, the two
 #: process-global counter families beside the inferred per-site ones)
 _XLA_KINDS = {
-    False: ("compiles", "compile_s", REGISTRY.counter(
+    "trace": ("traces", "trace_s", REGISTRY.counter(
+        "pio_tpu_xla_traces_total",
+        "Jitted functions JAX traced to a jaxpr (its jaxpr-trace events; "
+        "a function traced inside another counts too)",
+    ), REGISTRY.counter(
+        "pio_tpu_xla_trace_seconds_total",
+        "Wall seconds of those traces, each less the traces inside it",
+    )),
+    "lower": ("lowers", "lower_s", REGISTRY.counter(
+        "pio_tpu_xla_lowers_total",
+        "Jaxprs JAX lowered to an MLIR module (its jaxpr-to-MLIR events)",
+    ), REGISTRY.counter(
+        "pio_tpu_xla_lower_seconds_total",
+        "Wall seconds of those lowerings",
+    )),
+    "compile": ("compiles", "compile_s", REGISTRY.counter(
         "pio_tpu_xla_backend_compiles_total",
         "Programs the XLA backend compiled (JAX's backend-compile events "
         "that were not persistent-cache loads)",
@@ -632,7 +667,7 @@ _XLA_KINDS = {
         "pio_tpu_xla_backend_compile_seconds_total",
         "Wall seconds of those backend compiles",
     )),
-    True: ("cache_loads", "cache_load_s", REGISTRY.counter(
+    "cache_load": ("cache_loads", "cache_load_s", REGISTRY.counter(
         "pio_tpu_xla_cache_loads_total",
         "Executables loaded from JAX's persistent compilation cache",
     ), REGISTRY.counter(
@@ -640,31 +675,88 @@ _XLA_KINDS = {
         "Wall seconds of those persistent-cache loads",
     )),
 }
+_XLA_EVENT_KIND = {_TRACE_EVENT: "trace", _LOWER_EVENT: "lower",
+                   _BACKEND_COMPILE_EVENT: "compile"}
 
 
-def _on_xla_duration(event: str, duration: float, **_kw) -> None:
+def _program_name(fun_name: Optional[str]) -> str:
+    """One name a program through the three steps: a trace reports the
+    function's ``__name__`` (``chunk_staged``), lowering and compiling the
+    module's (``jit(chunk_staged)``); both read ``jit_chunk_staged``, the
+    name the program has in a device trace."""
+    if not fun_name:
+        return "(unnamed)"
+    head, paren, rest = fun_name.partition("(")
+    if paren and rest.endswith(")"):
+        return f"{head}_{rest[:-1]}"
+    return f"jit_{fun_name}"
+
+
+def _charge_program(name: str, s_key: str, seconds: float,
+                    traced: bool) -> None:
+    """Under ``_XLA_LOCK``. A table that is full folds its cheapest row
+    into ``(other)`` first, so the costly programs keep their names."""
+    row = _XLA_BY_PROGRAM.get(name)
+    if row is None:
+        if len(_XLA_BY_PROGRAM) >= MAX_PROGRAMS:
+            cheapest = min(
+                (n for n in _XLA_BY_PROGRAM if n != _OTHER_PROGRAMS),
+                key=lambda n: sum(
+                    v for k, v in _XLA_BY_PROGRAM[n].items() if k != "n"))
+            folded = _XLA_BY_PROGRAM.pop(cheapest)
+            other = _XLA_BY_PROGRAM.setdefault(
+                _OTHER_PROGRAMS, dict.fromkeys(folded, 0))
+            for k, v in folded.items():
+                other[k] += v
+        row = _XLA_BY_PROGRAM.setdefault(name, {
+            "trace_s": 0.0, "lower_s": 0.0, "compile_s": 0.0,
+            "cache_load_s": 0.0, "n": 0})
+    row[s_key] += seconds
+    row["n"] += traced
+
+
+def _on_xla_begin(event: str, _start_time: float, **_kw) -> None:
+    if event in _XLA_EVENT_KIND:
+        if not hasattr(_XLA_THREAD, "open"):
+            _XLA_THREAD.open = []
+        _XLA_THREAD.open.append(0.0)
+
+
+def _on_xla_duration(event: str, duration: float, fun_name=None,
+                     **_kw) -> None:
     if event == _CACHE_RETRIEVAL_EVENT:
         _XLA_THREAD.loaded = True
         return
-    if event != _BACKEND_COMPILE_EVENT:
+    kind = _XLA_EVENT_KIND.get(event)
+    if kind is None:
         return
-    n_key, s_key, count, seconds = _XLA_KINDS[
-        getattr(_XLA_THREAD, "loaded", False)]
-    _XLA_THREAD.loaded = False
+    if kind == "compile" and getattr(_XLA_THREAD, "loaded", False):
+        kind = "cache_load"
+        _XLA_THREAD.loaded = False
     duration = max(0.0, float(duration))
+    # a step that began before anything listened has no entry: all its own
+    still_open = getattr(_XLA_THREAD, "open", None)
+    inside = still_open.pop() if still_open else 0.0
+    if still_open:
+        still_open[-1] += duration
+    own = max(0.0, duration - inside)
+    n_key, s_key, count, seconds = _XLA_KINDS[kind]
     with _XLA_LOCK:
         _XLA_TOTALS[n_key] += 1
-        _XLA_TOTALS[s_key] += duration
+        _XLA_TOTALS[s_key] += own
+        _charge_program(_program_name(fun_name), s_key, own,
+                        traced=kind == "trace")
     count.inc()
-    seconds.inc(duration)
+    seconds.inc(own)
 
 
 def watch_xla_compiles() -> None:
-    """Start counting real compiles and cache loads, once per process.
-    Called where a :class:`~pio_tpu.parallel.context.ComputeContext` is
+    """Start counting what JAX traces, lowers, compiles and loads from its
+    cache, once per process. Called where a
+    :class:`~pio_tpu.parallel.context.ComputeContext` is
     built, which every entry point does before its first program; JAX is
-    imported by then. Touches no dispatch path: JAX calls the listener
-    from inside its own compile call."""
+    imported by then. Touches no dispatch path: JAX calls the listeners
+    from inside its own trace, lowering and compile calls."""
     global _XLA_WATCHING
     with _XLA_LOCK:
         if _XLA_WATCHING:
@@ -672,14 +764,31 @@ def watch_xla_compiles() -> None:
         _XLA_WATCHING = True
     from jax import monitoring
 
+    monitoring.register_scalar_listener(_on_xla_begin)
     monitoring.register_event_duration_secs_listener(_on_xla_duration)
 
 
 def xla_totals() -> Optional[dict]:
-    """``{compiles, compile_s, cache_loads, cache_load_s}`` of this
-    process so far, or ``None`` when nothing is listening."""
+    """``{traces, trace_s, lowers, lower_s, compiles, compile_s,
+    cache_loads, cache_load_s}`` of this process so far, or ``None`` when
+    nothing is listening. A step's seconds are its own: a trace less the
+    traces (and whatever else of these) inside it, so the four sum to the
+    wall time the compile path took."""
     with _XLA_LOCK:
         return dict(_XLA_TOTALS) if _XLA_WATCHING else None
+
+
+def xla_by_program() -> Optional[dict]:
+    """The same seconds by program, ``{name: {trace_s, lower_s, compile_s,
+    cache_load_s, n}}`` (``jit_chunk_staged``, ``jit_accum``; a jitted
+    function traced inside a program, as a Pallas kernel's wrapper, has a
+    row of its own; ``n``: times traced), at most :data:`MAX_PROGRAMS`
+    names and ``(other)``; each column sums to its total. ``None`` when
+    nothing is listening."""
+    with _XLA_LOCK:
+        if not _XLA_WATCHING:
+            return None
+        return {name: dict(row) for name, row in _XLA_BY_PROGRAM.items()}
 
 
 # ---------------------------------------------------------------------------

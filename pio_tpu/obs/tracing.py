@@ -38,6 +38,20 @@ signature. :func:`active_span` is its context-manager form, which also
 writes the span into a running JAX profiler trace (the trainer's leaf
 host spans: ``als.sort``, ``stream.put``, ``als.readback``…).
 
+The process timeline
+--------------------
+
+:data:`PROCESS` (a :class:`ProcessTimeline`) is the one record of this
+process from the OS's start of it: five marks (``process_start``,
+``pio_tpu_imported``, ``context_built``, ``first_call_enter``,
+``first_call_exit``), the trainers' numbered calls
+(:meth:`ProcessTimeline.train_call`) and every :func:`active_span`, with
+the call it fell in, whether or not a trace or a profiler session is
+open. In memory and bounded; :meth:`ProcessTimeline.record` is its
+JSON-plain form, which every ``stats`` call of a trainer reports as
+``stats["process"]``. The first call's part is frozen when that call
+ends: what set-up cost stays readable however long the process runs.
+
 Naming: span/stage names are dot-scoped ``stage`` or ``stage.substage``
 (lowercase ``[a-z0-9_]`` atoms). Top-level stages tile the request
 (their durations sum to the end-to-end time); dotted substages attribute
@@ -53,7 +67,10 @@ milliseconds. ``/traces.json?slow=1`` serves it.
 
 from __future__ import annotations
 
+import collections
 import contextvars
+import copy
+import os
 import re
 import sys
 import threading
@@ -61,6 +78,8 @@ import time
 from contextlib import contextmanager
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+import pio_tpu
+from pio_tpu.obs import devicewatch
 from pio_tpu.obs.metrics import (
     DEFAULT_LATENCY_BUCKETS,
     MetricsRegistry,
@@ -117,10 +136,27 @@ def add_active_span(stage: str, dur_s: float,
         handle.add_span(stage, dur_s, rel_start_s)
 
 
+class Span:
+    """What :func:`active_span` yields: the span's bounds on
+    ``monotonic_s``, ``end`` set when the block is left."""
+
+    __slots__ = ("name", "start", "end")
+
+    def __init__(self, name: str, start: float):
+        self.name = name
+        self.start = start
+        self.end: Optional[float] = None
+
+    @property
+    def seconds(self) -> float:
+        return self.end - self.start
+
+
 @contextmanager
 def active_span(stage: str):
     """A leaf span around the body, on two clocks at once: recorded on
-    the active trace (if any) like :func:`add_active_span`, and entered
+    the active trace (if any) like :func:`add_active_span` and on the
+    process timeline (:data:`PROCESS`, always), and entered
     as a ``jax.profiler.TraceAnnotation`` so that a profiler trace shows
     it on the Python thread's line beside the device events — the
     profiler's clock is not ``monotonic_s``, so only a span the
@@ -129,17 +165,171 @@ def active_span(stage: str):
     JAX is never imported from here: a process that has not loaded it
     has no profiler to write to. Keep these spans leaves that tile
     (a trace reducer gives a device gap to the span overlapping it most,
-    so an enclosing span would take every gap)."""
+    so an enclosing span would take every gap). Yields the :class:`Span`:
+    a caller that reports the interval reads it there, and keeps no
+    clock of its own."""
     jax = sys.modules.get("jax")
-    t0 = monotonic_s()
+    span = Span(stage, monotonic_s())
     try:
         if jax is None:
-            yield
+            yield span
         else:
             with jax.profiler.TraceAnnotation(stage):
-                yield
+                yield span
     finally:
-        add_active_span(stage, monotonic_s() - t0)
+        span.end = monotonic_s()
+        add_active_span(stage, span.seconds)
+        PROCESS.add_span(span)
+
+
+def _process_age_s() -> Optional[float]:
+    """Seconds since the OS started this process: the start time of
+    ``/proc/self/stat`` (field 22, clock ticks after boot) against the
+    boot clock. ``None`` where there is no ``/proc`` or no such clock."""
+    try:
+        with open("/proc/self/stat", "rb") as f:
+            # the command's name (field 2) may hold spaces and brackets
+            after_comm = f.read().rsplit(b")", 1)[1].split()
+        started = int(after_comm[19]) / os.sysconf("SC_CLK_TCK")
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - started
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return age if age >= 0.0 else None
+
+
+#: the train call open on this thread/task (0: none); spans carry it
+_CALL: contextvars.ContextVar[int] = contextvars.ContextVar(
+    "pio_tpu_train_call", default=0)
+
+
+class ProcessTimeline:
+    """Marks, numbered train calls and leaf spans of one process, as
+    seconds since ``origin`` (a reading of ``monotonic_s``). Bounded:
+    the first call keeps up to ``max_spans`` spans and is never
+    overwritten, later spans share a ring of as many, and of the calls
+    the first :data:`FIRST_CALLS` and the newest are kept."""
+
+    FIRST_CALLS = 4
+
+    def __init__(self, origin: float, origin_kind: str,
+                 max_spans: int = 2048):
+        self.origin = origin
+        #: ``proc_stat`` (the OS's start of the process) or
+        #: ``first_import`` (of ``pio_tpu``, where ``/proc`` is missing)
+        self.origin_kind = origin_kind
+        self._lock = threading.Lock()
+        self._marks: Dict[str, float] = {"process_start": 0.0}
+        self._n_calls = 0
+        self._calls: List[Tuple[int, float, float]] = []
+        self._max_spans = max_spans
+        self._first_spans: List[Tuple[str, float, float, int]] = []
+        self._spans: collections.deque = collections.deque(maxlen=max_spans)
+        self._dropped = 0  # of the first call's, past ``max_spans``
+        self._first_call: Optional[dict] = None
+
+    def _since_origin(self, t: Optional[float] = None) -> float:
+        return (monotonic_s() if t is None else t) - self.origin
+
+    def mark(self, name: str, t: Optional[float] = None) -> None:
+        """Set ``name`` to ``t`` (a reading of ``monotonic_s``; now by
+        default) the first time it happens; later calls change nothing."""
+        with self._lock:
+            if name not in self._marks:
+                self._marks[name] = self._since_origin(t)
+
+    def add_span(self, span: Span) -> None:
+        call = _CALL.get()
+        row = (span.name, self._since_origin(span.start),
+               self._since_origin(span.end), call)
+        with self._lock:
+            if call != 1:
+                self._spans.append(row)
+            elif len(self._first_spans) < self._max_spans:
+                self._first_spans.append(row)
+            else:
+                self._dropped += 1
+
+    def spans(self, call: int) -> List[Tuple[str, float, float, int]]:
+        """The kept spans of train call ``call``, in the order they ended."""
+        with self._lock:
+            kept = self._first_spans if call == 1 else self._spans
+            return [row for row in kept if row[3] == call]
+
+    @contextmanager
+    def train_call(self, stats: Optional[dict] = None):
+        """Around one whole ``train_als`` / ``train_seqrec``: numbers the
+        call, sets ``first_call_enter`` / ``first_call_exit`` and freezes
+        the first call's spans and compile-path totals at its exit, and
+        hands a ``stats`` dict the record as ``stats["process"]``, this
+        call included. Yields the call's number."""
+        with self._lock:
+            self._n_calls += 1
+            call = self._n_calls
+        entered = monotonic_s()
+        xla_before = None
+        if call == 1:
+            self.mark("first_call_enter", entered)
+            xla_before = devicewatch.xla_totals()
+        token = _CALL.set(call)
+        try:
+            yield call
+        finally:
+            _CALL.reset(token)
+            left = monotonic_s()
+            with self._lock:
+                if len(self._calls) > self.FIRST_CALLS:
+                    self._calls.pop()  # the newest makes way
+                self._calls.append((call, self._since_origin(entered),
+                                    self._since_origin(left)))
+            if call == 1:
+                self.mark("first_call_exit", left)
+                xla = devicewatch.xla_totals()
+                with self._lock:
+                    self._first_call = {
+                        "spans": [list(row) for row in self._first_spans],
+                        "spans_dropped": self._dropped,
+                        "xla": None if xla is None or xla_before is None
+                        else {k: xla[k] - xla_before[k] for k in xla},
+                    }
+            if stats is not None:
+                stats["process"] = self.record()
+
+    def record(self) -> dict:
+        """JSON-plain: ``origin``, ``marks`` (seconds since it), ``calls``
+        (``[call, start, end]`` of the first four and the newest),
+        ``first_call`` (``spans`` as ``[name, start, end, call]``, those
+        past the bound counted in ``spans_dropped``, and ``xla``: the
+        compile-path totals' change over the call; ``None`` until the
+        call has ended, and the same ever after), ``later_spans`` (the
+        other listed calls' spans still in the ring: what the first
+        call's spans are held against) and ``xla_by_program``
+        (:func:`pio_tpu.obs.devicewatch.xla_by_program`, the process so
+        far)."""
+        with self._lock:
+            listed = {c[0] for c in self._calls}
+            return {
+                "origin": self.origin_kind,
+                "marks": dict(self._marks),
+                "calls": [list(c) for c in self._calls],
+                "first_call": copy.deepcopy(self._first_call),
+                "later_spans": [list(row) for row in self._spans
+                                if row[3] in listed],
+                "xla_by_program": devicewatch.xla_by_program(),
+            }
+
+
+def _process_timeline() -> ProcessTimeline:
+    age = _process_age_s()
+    if age is None:
+        timeline = ProcessTimeline(pio_tpu.IMPORTED_AT, "first_import")
+    else:
+        timeline = ProcessTimeline(monotonic_s() - age, "proc_stat")
+    timeline.mark("pio_tpu_imported", pio_tpu.IMPORTED_AT)
+    return timeline
+
+
+#: this process's timeline; the trainers and ``ComputeContext`` write it
+PROCESS = _process_timeline()
 
 
 class Trace:

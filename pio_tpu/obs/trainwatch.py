@@ -461,7 +461,8 @@ def run_record(*, run_id: str, engine_id: str, status: str,
                shard_manifest: Optional[str] = None,
                timestamp: Optional[str] = None,
                error: Optional[str] = None,
-               device_scopes: Optional[dict] = None) -> dict:
+               device_scopes: Optional[dict] = None,
+               xla: Optional[dict] = None) -> dict:
     """One runs.jsonl row. Flat where it matters: the step summary's
     numeric fields are lifted to the top level so :func:`delta_rows`
     can diff two rows directly. ``device_scopes`` is a profiled run's
@@ -471,7 +472,10 @@ def run_record(*, run_id: str, engine_id: str, status: str,
     ``solve_impl`` (``{"user", "item"}``: ``resident_cg`` / ``xla_cg`` /
     ``cholesky`` / ``lu``), ``gather_impl`` (``{"user", "item"}``:
     ``packed`` / ``plain``) and ``accum_impl`` (``{"user", "item"}``:
-    ``fused`` / ``xla``) are lifted beside them."""
+    ``fused`` / ``xla``) are lifted beside them. ``xla`` is the process's
+    :func:`pio_tpu.obs.devicewatch.xla_totals` at the run's end: what JAX's
+    compile path took of the run, lifted as ``xla_trace_s``,
+    ``xla_lower_s``, ``xla_compile_s`` and ``xla_cache_load_s``."""
     if timestamp is None:
         import datetime as _dt
 
@@ -506,6 +510,9 @@ def run_record(*, run_id: str, engine_id: str, status: str,
         rec["device_busy_s"] = round(float(busy), 6)
         if window > 0:
             rec["device_idle_pct"] = round(100.0 * (1.0 - busy / window), 3)
+    if xla:
+        for key in ("trace_s", "lower_s", "compile_s", "cache_load_s"):
+            rec[f"xla_{key}"] = round(float(xla[key]), 3)
     if error:
         rec["error"] = error[-500:]
     return rec
@@ -553,13 +560,14 @@ def read_runs(engine_id: Optional[str] = None,
 def run_delta_table(prev: dict, cur: dict,
                     threshold: float = DEFAULT_RUN_THRESHOLD) -> Tuple[list, list]:
     """``(table_lines, regressed_fields)`` for two run-ledger rows —
-    the static :data:`RUN_FIELDS` plus every ``phase_*`` duration and
-    every profiled run's ``scope_*`` / ``device_*`` number both rows
-    carry (direction "down": a slower phase or scope is a regression)."""
+    the static :data:`RUN_FIELDS` plus every ``phase_*`` duration, the
+    compile path's ``xla_*`` seconds and every profiled run's ``scope_*`` /
+    ``device_*`` number both rows carry (direction "down": a slower phase,
+    compile or scope is a regression)."""
     fields = list(RUN_FIELDS)
     lower_is_better = sorted(
         k for k in cur
-        if k.startswith(("phase_", "scope_", "device_busy_s",
+        if k.startswith(("phase_", "xla_", "scope_", "device_busy_s",
                          "device_idle_pct")) and k in prev
     )
     fields.extend((k, "down") for k in lower_is_better)

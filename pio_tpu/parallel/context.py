@@ -67,10 +67,13 @@ class ComputeContext:
 
     def __post_init__(self):
         # every entry point builds a context before its first program:
-        # the one place that sees all of a process's real compiles
-        from pio_tpu.obs import devicewatch
+        # the one place that sees all of a process's compile path, and
+        # the process timeline's ``context_built`` (JAX is imported and
+        # the devices have been asked for by now)
+        from pio_tpu.obs import devicewatch, tracing
 
         devicewatch.watch_xla_compiles()
+        tracing.PROCESS.mark("context_built")
 
     @staticmethod
     def create(seed: int = 0, axis_names: Tuple[str, ...] = ("data",)):

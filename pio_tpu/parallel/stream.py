@@ -41,10 +41,13 @@ just bench.
 
 Failpoints: ``stream.encode`` / ``stream.put`` / ``stream.dispatch``
 fire per chunk per phase (fault-injection surface for the feed loop).
-The same three names, and ``stream.finalize``, are leaf host spans
+The same three names, ``stream.init`` (the initial carry) and
+``stream.finalize``, are leaf host spans
 (:func:`pio_tpu.obs.active_span`): they tile the loop, land on the
-active trace and, in a JAX profiler trace, on the Python thread's line,
-where they name the device's idle gaps.
+active trace, on the process timeline and, in a JAX profiler trace, on
+the Python thread's line, where they name the device's idle gaps. The
+once-per-run extra shipment is ``stream.put_extra``; a ``stats`` run's
+two waits for the device stand there and in ``stream.finalize``.
 """
 
 from __future__ import annotations
@@ -216,9 +219,25 @@ def stream_feed(
             devicewatch.stream_carry(-chunk_bytes.pop(i, 0))
         return out
 
-    def _finalize(carry, devs):
+    def _init():
+        with active_span("stream.init"):
+            return init_carry()
+
+    def _put_extra(wait_for=None):
+        """The extra shipment; a ``stats`` run waits here for every put."""
+        if put_extra is None and wait_for is None:
+            return
+        with active_span("stream.put_extra"):
+            extra = put_extra() if put_extra is not None else None
+            if wait_for is not None:
+                jax.block_until_ready((wait_for, extra))
+
+    def _finalize(carry, devs, wait=False):
         with active_span("stream.finalize"):
-            return finalize(carry, devs)
+            result = finalize(carry, devs) if retain else carry
+            if wait:
+                jax.block_until_ready(result)
+            return result
 
     n = len(chunks)
     retain = finalize is not None
@@ -233,18 +252,16 @@ def stream_feed(
         )
         t0 = monotonic_s()
         devs = [_put(encoded[i], i) for i in range(n)]
-        extra = put_extra() if put_extra is not None else None
-        jax.block_until_ready((devs, extra))
+        _put_extra(wait_for=devs)
         stats["h2d_s"] = stats.get("h2d_s", 0.0) + (monotonic_s() - t0)
         with device_phase or contextlib.nullcontext():
             t0 = monotonic_s()
-            carry = init_carry()
+            carry = _init()
             for i in range(n):
                 carry = _dispatch(carry, devs[i], i)
                 if not retain:
                     devs[i] = None
-            result = _finalize(carry, tuple(devs)) if retain else carry
-            jax.block_until_ready(result)
+            result = _finalize(carry, tuple(devs), wait=True)
             stats["device_s"] = stats.get("device_s", 0.0) + (
                 monotonic_s() - t0
             )
@@ -260,7 +277,7 @@ def stream_feed(
     put_idx = 0
     extra_done = put_extra is None
     synced: list = []  # per-chunk carry leaf, for lookahead throttling
-    carry = init_carry()
+    carry = _init()
     probe = None
     start = 0
     rec = trainwatch.active_recorder()
@@ -292,7 +309,7 @@ def stream_feed(
             devs[put_idx] = _put(_encode(put_idx), put_idx)
             put_idx += 1
         if put_idx == n and not extra_done:
-            put_extra()
+            _put_extra()
             extra_done = True
         carry = _dispatch(carry, devs[i], i)
         if not retain:
@@ -308,7 +325,7 @@ def stream_feed(
                 jax.block_until_ready(synced[j])
                 synced[j] = None
     if not extra_done:
-        put_extra()
+        _put_extra()
     if probe is not None:
         jax.block_until_ready(jax.tree_util.tree_leaves(carry)[:1])
         bytes0, h2d_s0, device_s0, t_rest = probe

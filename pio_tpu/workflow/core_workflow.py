@@ -215,6 +215,7 @@ def run_train(
                 shard_manifest=shard_manifest,
                 error=error,
                 device_scopes=device_scopes,
+                xla=devicewatch.xla_totals(),
             )
             path = trainwatch.append_run(rec)
             log.info("run record appended to %s", path)

@@ -295,7 +295,12 @@ def test_stats_on_cpu_carry_compile_counts_and_no_device_number():
     stats = {}
     als.train_als(ctx, u, i, r, nu, ni, config, stats=stats)
     assert not [k for k in stats if k.startswith("device_") and k != "device_s"]
-    assert stats["xla"]["in_call"] == {
+    # device arrays where the warm call gave numpy miss jit's fast path, and
+    # JAX reports the look-up of the kept jaxpr as a trace: microseconds
+    in_call = stats["xla"]["in_call"]
+    assert in_call.pop("traces") <= 1 and in_call.pop("trace_s") < 0.01
+    assert in_call == {
+        "lowers": 0, "lower_s": 0.0,
         "compiles": 0, "compile_s": 0.0, "cache_loads": 0, "cache_load_s": 0.0}
     json.dumps(stats)  # JSON-plain
 
@@ -373,7 +378,8 @@ def test_device_json_shows_the_totals():
 
     ComputeContext.local()
     payload = devicewatch.DeviceWatch(stats_fn=lambda: []).payload()
-    assert set(payload["xla"]) == {"compiles", "compile_s", "cache_loads",
+    assert set(payload["xla"]) == {"traces", "trace_s", "lowers", "lower_s",
+                                   "compiles", "compile_s", "cache_loads",
                                    "cache_load_s"}
 
 
