@@ -858,6 +858,26 @@ def experts_impl(platform: str, cfg) -> str:
             else "ragged_dot")
 
 
+def attn_impls(platform: str, cfg, t_local: int) -> Dict[str, str]:
+    """What runs the attention tiles of each kind of attention layer the
+    block has (``mla``; the gqa block's ``full`` and ``window``), over rows
+    of ``t_local`` events: ``ring.attention_impl``'s answer at the shapes
+    :func:`mla` and :func:`gqa` hand it."""
+    from pio_tpu.parallel.ring import attention_impl, pick_block
+
+    blk = pick_block(t_local, ATTN_BLOCK)
+    if cfg.attention_kind != "gqa":
+        widths = {"mla": (cfg.qk_nope_dim + cfg.qk_rope_dim, cfg.v_head_dim)}
+    elif cfg.mixer_pattern:
+        widths = {"full": (cfg.head_dim, cfg.head_dim)}
+    else:
+        widths = {kind: (cfg.head_dim, cfg.head_dim)
+                  for kind in sorted(set(cfg.layer_pattern))}
+    return {kind: attention_impl(platform, _dtype(cfg), d_k, d_v, blk, blk,
+                                 True, t_local)
+            for kind, (d_k, d_v) in widths.items()}
+
+
 def _gmm_tiles(which: str, k: int, n: int):
     tm, tk, tn = GMM_TILES[which]
     return tm, min(tk, k), min(tn, n)

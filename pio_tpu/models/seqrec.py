@@ -35,6 +35,7 @@ import numpy as np
 
 from pio_tpu.models.seq_layers import (
     TOKEN_CHUNK,
+    attn_impls,
     check_block,
     dense_layer,
     describe_params,
@@ -777,7 +778,11 @@ def train_seqrec(
             block's ``window_tiles`` and ``causal_tiles`` and the mamba
             layers' ``ssm_chunks`` and ``ssm_state_absmax``; a moe block
             also says which grouped matmul its routed experts ran
-            (``experts_impl``: ``seq_layers.experts_impl``).
+            (``experts_impl``: ``seq_layers.experts_impl``) and what ran
+            the attention tiles of each kind of its attention layers
+            (``attn_impl``: ``{"mla"}`` or ``{"full", "window"}`` ->
+            ``pallas`` / ``xla``, ``ring.attention_impl``; in the run
+            record too).
 
     Raises:
         DeviceBudgetExceeded: the params can't fit (single-chip or even
@@ -1000,6 +1005,11 @@ def _train_seqrec(mesh, sequences, n_items, config, checkpoint,
         streamed=streamed, n_stream=n_stream,
         per_device_bytes=params_pd,
     )
+    if latent:
+        attn_impl = attn_impls(jax.default_backend(), cfg, t_pad // n_seq)
+        trainwatch.set_attn_impl(attn_impl)
+        if stats is not None:
+            stats["attn_impl"] = attn_impl
     # lagged loss drain (the two_tower discipline): per-step losses come
     # back as device arrays and are fetched one chunk behind the
     # dispatch frontier; no recorder → dropped undereferenced.

@@ -104,6 +104,7 @@ class StepRecorder:
         self.solve_impl: Optional[Dict[str, str]] = None
         self.gather_impl: Optional[Dict[str, str]] = None
         self.accum_impl: Optional[Dict[str, str]] = None
+        self.attn_impl: Optional[Dict[str, str]] = None
         self.params_per_device_bytes = 0
         self.h2d_bytes = 0
         self.overlap_ratio: Optional[float] = None
@@ -143,6 +144,7 @@ class StepRecorder:
             self.solve_impl = None
             self.gather_impl = None
             self.accum_impl = None
+            self.attn_impl = None
             self.params_per_device_bytes = int(per_device_bytes)
             self.last_loss = None
             self.losses.clear()
@@ -203,6 +205,13 @@ class StepRecorder:
         operand size: ``_accum_impl``)."""
         with self._lock:
             self.accum_impl = dict(impl)
+
+    def set_attn_impl(self, impl: Dict[str, str]) -> None:
+        """What runs the attention tiles of each kind of attention layer
+        (the sequence template decides from platform, dtype and shapes:
+        ``ring.attention_impl``)."""
+        with self._lock:
+            self.attn_impl = dict(impl)
 
     def set_overlap(self, ratio: float) -> None:
         with self._lock:
@@ -300,6 +309,7 @@ class StepRecorder:
                 "solve_impl": self.solve_impl,
                 "gather_impl": self.gather_impl,
                 "accum_impl": self.accum_impl,
+                "attn_impl": self.attn_impl,
             }
 
 
@@ -392,6 +402,12 @@ def set_accum_impl(impl: Dict[str, str]) -> None:
         rec.set_accum_impl(impl)
 
 
+def set_attn_impl(impl: Dict[str, str]) -> None:
+    rec = _ACTIVE
+    if rec is not None:
+        rec.set_attn_impl(impl)
+
+
 # ---------------------------------------------------------------------------
 # direction-aware deltas — the regression core of ``pio runs --diff``
 # ---------------------------------------------------------------------------
@@ -472,7 +488,9 @@ def run_record(*, run_id: str, engine_id: str, status: str,
     ``solve_impl`` (``{"user", "item"}``: ``resident_cg`` / ``xla_cg`` /
     ``cholesky`` / ``lu``), ``gather_impl`` (``{"user", "item"}``:
     ``packed`` / ``plain``) and ``accum_impl`` (``{"user", "item"}``:
-    ``fused`` / ``xla``) are lifted beside them. ``xla`` is the process's
+    ``fused`` / ``xla``) are lifted beside them, and so is a sequence run's
+    ``attn_impl`` (``{"mla"}`` or ``{"full", "window"}``: ``pallas`` /
+    ``xla``). ``xla`` is the process's
     :func:`pio_tpu.obs.devicewatch.xla_totals` at the run's end: what JAX's
     compile path took of the run, lifted as ``xla_trace_s``,
     ``xla_lower_s``, ``xla_compile_s`` and ``xla_cache_load_s``."""
@@ -500,7 +518,7 @@ def run_record(*, run_id: str, engine_id: str, status: str,
         rec["step_summary"] = dict(step_summary)
         for key in ("examples_per_sec", "final_loss", "loss_window_mean",
                     "overlap_ratio", "steps", "examples", "solve_impl",
-                    "gather_impl", "accum_impl"):
+                    "gather_impl", "accum_impl", "attn_impl"):
             if step_summary.get(key) is not None:
                 rec[key] = step_summary[key]
     if device_scopes:

@@ -57,6 +57,26 @@ def pick_block(t: int, block: int) -> int:
     return b
 
 
+def attention_impl(platform: str, dtype, d_k: int, d_v: int, bq: int, bk: int,
+                   causal: bool, key_rows: int = 0) -> str:
+    """What runs :func:`attention_partial`'s tiles, from what is visible at
+    trace time: ``pallas`` / ``xla``. ``pallas`` is the pair of kernels of
+    :mod:`pio_tpu.parallel.ring_kernel` (a tile's scores, softmax and
+    accumulators in VMEM): on a TPU, for causal attention with bfloat16
+    operands, head widths and blocks that are multiples of the 128 lanes,
+    and ``key_rows`` keys whose ``k`` and ``v`` of one head fit VMEM
+    (``ring_kernel.fits``). ``xla`` is the ``fori_loop`` of this module:
+    everywhere else (every CPU run, float32 operands, a head width of 64,
+    non-causal calls), and the kernels' oracle."""
+    from pio_tpu.parallel.ring_kernel import fits
+
+    dtype = jnp.dtype(dtype)
+    tiles = (causal and dtype == jnp.bfloat16
+             and all(x % 128 == 0 for x in (d_k, d_v, bq, bk))
+             and fits(key_rows, d_k, d_v, dtype.itemsize))
+    return "pallas" if platform == "tpu" and tiles else "xla"
+
+
 def needed_key_blocks(i, q_off, k_off, bq: int, bk: int, nk: int, causal: bool):
     """How many leading key blocks query block ``i`` can see: a key block
     whose first position lies past the query block's last is above the
@@ -152,7 +172,47 @@ def _partial_fwd(q, k, v, q_off, k_off, causal, scale, bq, bk, window=0,
     return o, lse, ran.sum(axis=0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _loop_bounds(q, k, q_off, k_off, causal, bq: int, bk: int, window: int,
+                 group: int):
+    """``(first [nq], n [nq], offs [2])`` int32: every query block's key
+    blocks ``first <= j < n`` and the two offsets, as the kernels' loops
+    take them (scalar prefetch)."""
+    nq, nk = q.shape[2] // (bq * group), k.shape[2] // bk
+    i = jnp.arange(nq)
+    flat = lambda a: jnp.broadcast_to(jnp.asarray(a, jnp.int32), (nq,))
+    return (flat(first_key_block(i, q_off, k_off, bq, bk, nk, window)),
+            flat(needed_key_blocks(i, q_off, k_off, bq, bk, nk, causal)),
+            jnp.stack([jnp.asarray(q_off, jnp.int32),
+                       jnp.asarray(k_off, jnp.int32)]))
+
+
+def _kernel_fwd(q, k, v, q_off, k_off, causal, scale, bq, bk, window, group,
+                interpret):
+    """:func:`_partial_fwd` on the kernels: the same ``(o, lse, tiles)``,
+    ``tiles`` from the very bounds handed to the kernel's loop."""
+    from pio_tpu.parallel import ring_kernel
+
+    first, n, offs = _loop_bounds(q, k, q_off, k_off, causal, bq, bk, window,
+                                  group)
+    o, lse = ring_kernel.forward(q, k, v, first, n, offs, scale, bq, bk,
+                                 window, group, interpret)
+    ran = jnp.stack([jnp.maximum(n - first, 0).sum(), n.sum()])
+    return o, lse, ran.astype(jnp.int32)
+
+
+def _forward(impl: str, *args):
+    if impl == "xla":
+        return _partial_fwd(*args)
+    return _kernel_fwd(*args, impl == "pallas_interpret")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
+def _attention(q, k, v, q_off, k_off, causal, scale, bq, bk, window, group,
+               impl):
+    return _forward(impl, q, k, v, q_off, k_off, causal, scale, bq, bk,
+                    window, group)
+
+
 def attention_partial(q, k, v, q_off, k_off, causal, scale, bq, bk, window=0,
                       group=1):
     """Exact attention of ``q`` over the keys given, in ``bq x bk`` tiles.
@@ -164,9 +224,10 @@ def attention_partial(q, k, v, q_off, k_off, causal, scale, bq, bk, window=0,
     so partial results over disjoint key sets merge exactly
     (:func:`merge_partials`), and ``tiles`` ``[2]`` int32 from the bounds
     the forward's tile loops ran between: the score tiles computed (a head
-    and row), and those a loop from key block 0 computes. Matmul operands stay in the dtype given;
-    scores, softmax and accumulators are float32. Memory is linear in the
-    row's length forward and backward: the backward recomputes each score
+    and row), and those a loop from key block 0 computes. Matmul operands
+    stay in the dtype given; scores, softmax and accumulators are float32.
+    Memory is linear in the row's length forward and backward: the backward
+    recomputes each score
     tile from ``lse`` and keeps none. Key blocks above the diagonal are
     skipped, not masked, and with a ``window`` (query ``t`` sees keys ``s``
     with ``0 <= t - s < window``; causal only) so are the key blocks that
@@ -177,16 +238,44 @@ def attention_partial(q, k, v, q_off, k_off, causal, scale, bq, bk, window=0,
     a tile's rows being the ``group`` heads' ``bq`` positions one after the
     other, so that one tile's matmuls serve the whole group and ``k``/``v``
     are read once a KV head; ``o`` and ``lse`` come back in the same order.
+
+    What runs the tiles is :func:`attention_impl`'s choice: XLA's loops
+    here, or on a TPU the two kernels of :mod:`pio_tpu.parallel.ring_kernel`.
+    The arithmetic is the same either way (operands in the dtype given to
+    every matmul, float32 scores, softmax and accumulators, ``p`` and ``ds``
+    cast to that dtype before their matmuls); the kernels add the float32
+    terms of a row in another order, and a key block a query tile sees whole
+    runs there without its mask.
     """
-    return _partial_fwd(q, k, v, q_off, k_off, causal, scale, bq, bk, window,
-                        group)
+    impl = attention_impl(jax.default_backend(), q.dtype, q.shape[-1],
+                          v.shape[-1], bq, bk, causal, k.shape[2])
+    return _attention(q, k, v, q_off, k_off, causal, scale, bq, bk, window,
+                      group, impl)
 
 
-def _attention_partial_fwd(q, k, v, q_off, k_off, causal, scale, bq, bk,
-                           window=0, group=1):
-    o, lse, tiles = _partial_fwd(q, k, v, q_off, k_off, causal, scale, bq, bk,
-                                 window, group)
+def _attention_fwd(q, k, v, q_off, k_off, causal, scale, bq, bk, window,
+                   group, impl):
+    o, lse, tiles = _forward(impl, q, k, v, q_off, k_off, causal, scale, bq,
+                             bk, window, group)
     return (o, lse, tiles), (q, k, v, q_off, k_off, o, lse)
+
+
+def _attention_bwd(causal, scale, bq, bk, window, group, impl, res, cts):
+    if impl == "xla":
+        return _attention_partial_bwd(causal, scale, bq, bk, window, group,
+                                      res, cts)
+    from pio_tpu.parallel import ring_kernel
+
+    q, k, v, q_off, k_off, o, lse = res
+    do, dlse, _ = cts
+    # d s_ij = p_ij (dp_ij - delta_i + dlse_i): one pass outside the kernel
+    g = dlse - (do * o).sum(axis=-1)
+    first, n, offs = _loop_bounds(q, k, q_off, k_off, causal, bq, bk, window,
+                                  group)
+    dq, dk, dv = ring_kernel.backward(
+        q, k, v, do.astype(q.dtype), lse, g, first, n, offs, scale, bq, bk,
+        window, group, impl == "pallas_interpret")
+    return dq, dk, dv, None, None
 
 
 def _attention_partial_bwd(causal, scale, bq, bk, window, group, res, cts):
@@ -260,7 +349,7 @@ def _attention_partial_bwd(causal, scale, bq, bk, window, group, res, cts):
             None, None)
 
 
-attention_partial.defvjp(_attention_partial_fwd, _attention_partial_bwd)
+_attention.defvjp(_attention_fwd, _attention_bwd)
 
 
 def merge_partials(o_a, lse_a, o_b, lse_b):

@@ -310,6 +310,25 @@ def test_the_stats_call_reports_spans_and_counters():
     assert counters["moe_passes"] >= 2 * 3
     assert counters["moe_staged_rows"] >= counters["pairs_held"]
     assert "device_scope_s" not in stats  # no chip, no device scopes
+    assert stats["attn_impl"] == {"mla": "xla"}  # the CPU keeps XLA's loops
+
+
+def test_the_attention_rule_takes_the_cells_heads_on_a_tpu():
+    """``stats["attn_impl"]`` is ``ring.attention_impl`` at the shapes ``mla``
+    hands it: the published head (192 + 64 wide keys, 256 wide values) in
+    bfloat16 rides the kernels on a TPU; this test's 12 wide head, float32
+    operands and every CPU run keep XLA's loops."""
+    from pio_tpu.models.seq_layers import attn_impls
+
+    wide = dataclasses.replace(CFG, qk_nope_dim=192, qk_rope_dim=64,
+                               v_head_dim=256, compute_dtype="bfloat16")
+    assert attn_impls("tpu", wide, 8192) == {"mla": "pallas"}
+    assert attn_impls("cpu", wide, 8192) == {"mla": "xla"}
+    assert attn_impls("tpu", CFG, 8192) == {"mla": "xla"}
+    assert attn_impls("tpu", dataclasses.replace(wide, compute_dtype="float32"),
+                      8192) == {"mla": "xla"}
+    # a row too short for a lane-wide block
+    assert attn_impls("tpu", wide, 64) == {"mla": "xla"}
 
 
 def test_the_scopes_the_metrics_read_are_in_the_compiled_step():

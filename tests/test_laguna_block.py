@@ -532,6 +532,19 @@ def test_the_window_counters_reach_the_trace_and_the_stats(monkeypatch):
     assert stats["counters"]["causal_tiles"] == 60.0
     assert "bias_max" not in stats["counters"]
     assert stats["counters"]["dropped_pairs"] == 0.0
+    assert stats["attn_impl"] == {"full": "xla", "window": "xla"}
+
+
+def test_the_attention_rule_answers_by_kind_of_layer():
+    """Both kinds of layer at the published head width, bfloat16, ride the
+    kernels on a TPU; this file's 8 wide head keeps XLA's loops."""
+    wide = dataclasses.replace(CFG, head_dim=128, compute_dtype="bfloat16")
+    assert seq_layers.attn_impls("tpu", wide, 16384) == {
+        "full": "pallas", "window": "pallas"}
+    assert seq_layers.attn_impls("cpu", wide, 16384) == {
+        "full": "xla", "window": "xla"}
+    assert seq_layers.attn_impls("tpu", CFG, 16384) == {
+        "full": "xla", "window": "xla"}
 
 
 def test_it_trains_and_serves_from_engine_json_params():

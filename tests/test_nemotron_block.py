@@ -177,6 +177,9 @@ def test_the_counters_reach_the_trace_and_the_stats():
     assert counters["moe_passes"] == model.trace["passes"].sum() >= 3 * 4
     assert (model.trace["staged"] >= model.trace["pairs"]).all()
     assert counters["moe_staged_rows"] == model.trace["staged"].sum()
+    assert stats["attn_impl"] == {"full": "xla"}
+    wide = dataclasses.replace(CFG, head_dim=128, compute_dtype="bfloat16")
+    assert seq_layers.attn_impls("tpu", wide, 16384) == {"full": "pallas"}
 
 
 def test_it_trains_and_serves_from_engine_json_params():

@@ -270,3 +270,52 @@ def test_grouped_expert_matmul_compiles_for_v5e(one_chip, k, n, rows):
         sd((8,), jnp.int32), sd((rows, n), jnp.float32)).compile().as_text()
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     assert len(calls) == 3, len(calls)
+
+
+@pytest.mark.parametrize("cell, b, h_kv, t, d_k, d_v, group, window", [
+    ("glm47flash", 2, 20, 8192, 256, 256, 1, 0),
+    ("laguna_full", 1, 4, 16384, 128, 128, 6, 0),
+    ("laguna_window", 1, 4, 16384, 128, 128, 9, 512),
+    ("nemotron", 1, 2, 16384, 128, 128, 16, 0),
+])
+def test_blocked_attention_compiles_for_v5e(one_chip, monkeypatch, cell, b,
+                                            h_kv, t, d_k, d_v, group, window):
+    """The three cells' attention (``ring.attention_partial`` at their
+    shapes and groups, 512-blocks), forward and backward, with the rule
+    asked as a TPU is: two Pallas calls that fit VMEM (``k``, ``v``, ``dk``,
+    ``dv`` of a KV head resident), and between them nothing of a score
+    tile's or a ``dk``/``dv`` carry's size: XLA's loops hold two float32
+    ``[B, H, T, D]`` carries and a float32 ``[B, H, rows, 512]`` tile there.
+    The cotangent comes as the step's does, rounded to bfloat16."""
+    import jax
+    import jax.numpy as jnp
+
+    from pio_tpu.parallel import ring
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bf, f32 = jnp.bfloat16, jnp.float32
+    rows = t * group
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def both(q, k, v, do, dlse):
+        def attend(q, k, v):
+            return ring.attention_partial(
+                q, k, v, jnp.int32(0), jnp.int32(0), True, d_k ** -0.5, 512,
+                512, window, group)[:2]
+
+        (o, lse), back = jax.vjp(attend, q, k, v)
+        return o, lse, back((do.astype(f32), dlse))
+
+    compiled = jax.jit(both).lower(
+        sd((b, h_kv, rows, d_k), bf), sd((b, h_kv, t, d_k), bf),
+        sd((b, h_kv, t, d_v), bf), sd((b, h_kv, rows, d_v), bf),
+        sd((b, h_kv, rows), f32)).compile()
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    assert len(calls) == 2, len(calls)
+    score_tile = b * h_kv * group * 512 * 512 * 4
+    carry = b * h_kv * t * min(d_k, d_v) * 4
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= min(score_tile, carry) // 4, (temp, score_tile, carry)
