@@ -34,12 +34,24 @@ moe blocks' "attention, then a feed-forward part" (dense or experts), or
   projection), ``"moe"`` (the expert feed-forward alone, :func:`moe`, here
   with ``expert_act="relu2"`` experts of two matrices behind either router;
   ``expert_matmul="gmm"`` hands any moe block's routed rows to the Pallas
-  grouped matmul on a TPU, :func:`grouped_matmul`)
-  or ``"attn"`` (:func:`gqa` with ``heads_full`` query heads, no position
-  encoding where ``attn_rope`` is false and no gate where ``attn_gate`` is).
-  Its stacks are ``mamba/*``, ``moe/*`` and ``attn/*``. A ``mamba`` layer's
+  grouped matmul on a TPU, :func:`grouped_matmul`),
+  ``"attn"`` (:func:`gqa` with ``heads_full`` query heads, no position
+  encoding where ``attn_rope`` is false and no gate where ``attn_gate`` is)
+  or ``"mlp"`` (the dense SwiGLU of width ``ffn``, :func:`swiglu`, as a
+  layer of its own with its own norm: the ``granitemoehybrid`` family's
+  layer, a mixer *and* an MLP, is two entries of the pattern).
+  Its stacks are ``mamba/*``, ``moe/*``, ``attn/*`` and ``mlp/*``. The
+  pattern may hold no ``moe`` layer at all: the step's trace then carries
+  the expert counters as empty columns. A ``mamba`` layer's
   recurrent state is not passed from shard to shard: a mesh with a ``seq``
   axis is refused.
+
+Four scalars of the model and one switch apply to every moe block, each only
+where set: ``embed_scale`` multiplies the looked-up rows, ``residual_scale``
+what a layer adds to the stream, ``attn_scale`` replaces the scores'
+``head width ** -0.5``, ``logit_scale`` multiplies the logits before the
+log-sum-exp, and ``tied_head`` reads the logits from the embedding table (no
+``head`` parameter: one gradient, the sum of both uses; drawn as a head is).
 
 :func:`describe_params` is the one place a block's parameter shapes are
 written: ``init_params``, ``param_specs`` and the placement check of
@@ -62,7 +74,7 @@ from pio_tpu.utils.numutil import round_up
 
 BLOCK_KINDS = {("mha", "relu"), ("mla", "moe"), ("gqa", "moe")}
 LAYER_KINDS = ("full", "window")
-MIXER_KINDS = ("mamba", "moe", "attn")
+MIXER_KINDS = ("mamba", "moe", "attn", "mlp")
 ROUTER_KINDS = ("sigmoid_bias", "softmax")
 EXPERT_ACTS = ("swiglu", "relu2")
 EXPERT_MATMULS = ("ragged_dot", "gmm")
@@ -76,6 +88,16 @@ INIT_STD, EMBED_INIT_STD, BIAS_INIT_STD = 0.02, 1.0, 0.02
 #: Edge of the attention tiles, and tokens to a chunk of the cross-entropy and
 #: of the dense SwiGLU (each clamped to a divisor of what it cuts).
 ATTN_BLOCK, TOKEN_CHUNK = 512, 2048
+#: Heads of a Mamba-2 group to a turn of the chunked scan's map
+#: (:func:`ssd_scan`; clamped to a divisor of the group's heads, so a group of
+#: 8 heads is one turn). A turn's float32 ``[chunk, chunk]`` weights stand a
+#: head: at chunks of 256 a group of 64 heads whole would hold 0.5 GB three
+#: times over. Of 8, 16 and 32 on a v5e at 64 heads in one group, chunks of
+#: 256, nine layers of 8,192 events, 16 read the scan's fewest seconds (1.023,
+#: 0.972 and 1.028 s a call) on one process a setting, one seed, not repeated;
+#: a whole call moved by half a percent, inside the calls' own scatter, so the
+#: choice is not resolved at the call's level (PERF.md section 6, PR 39).
+SSM_HEAD_BLOCK = 16
 #: Rows the first pass of the grouped matmuls stages, over the pairs a
 #: balanced router sends the held experts (:func:`pass_widths`). Everything
 #: around the matmuls (gathers, selects, the combine) costs by the row staged,
@@ -123,7 +145,21 @@ MIXER_GROUPS = ("embedding", "head", "ssm_proj", "ssm_scan", "attn", "router",
 
 
 def groups_of(cfg) -> Tuple[str, ...]:
-    if cfg.mixer_pattern:
+    """The groups a block's gradient norms are reported for, in order. A
+    block of single mixers with a dense ``mlp`` mixer reports the groups it
+    has parameters in, ``dense_mlp`` among them (no ``head`` under a tied
+    table, no router or experts without a ``moe`` layer); one without keeps
+    ``MIXER_GROUPS`` whole, whatever its pattern: the comparison of a
+    trained call reads the columns by position."""
+    pattern = cfg.mixer_pattern
+    if "mlp" in pattern:
+        has = {"head": not cfg.tied_head, "ssm_proj": "mamba" in pattern,
+               "ssm_scan": "mamba" in pattern, "attn": "attn" in pattern,
+               "router": "moe" in pattern, "routed_experts": "moe" in pattern,
+               "shared_expert": "moe" in pattern}
+        return tuple(g for g in (*MIXER_GROUPS[:-1], "dense_mlp", "norms")
+                     if has.get(g, True))
+    if pattern:
         return MIXER_GROUPS
     return GQA_GROUPS if cfg.attention_kind == "gqa" else GROUPS
 
@@ -136,7 +172,7 @@ def group_of(path: str, cfg=None) -> str:
     gqa/moe block: the four projections under the attention of their
     layer's kind (a dense layer's kind is the pattern's), the gate's map,
     and the norms' gains on their own. A block of single mixers: see
-    ``MIXER_GROUPS``."""
+    ``MIXER_GROUPS``; an ``mlp`` mixer's three matrices are ``dense_mlp``."""
     group, _, name = path.rpartition("/")
     if cfg is not None and cfg.mixer_pattern:
         if name.endswith("norm") or name == "lnf_g":
@@ -201,6 +237,12 @@ def is_moe(cfg) -> bool:
     return cfg.ffn_kind == "moe"
 
 
+def has_experts(cfg) -> bool:
+    """Whether a moe block has an expert layer: all but a block of single
+    mixers whose pattern names no ``moe``."""
+    return not cfg.mixer_pattern or "moe" in cfg.mixer_pattern
+
+
 def layer_kind(cfg, layer: int) -> str:
     """The gqa/moe block's kind of layer ``layer``: the pattern repeats."""
     return cfg.layer_pattern[layer % len(cfg.layer_pattern)]
@@ -256,6 +298,11 @@ def check_block(cfg, n_seq: int = 1) -> None:
         )
     if cfg.mtp_depth not in (0, 1):
         raise ValueError("mtp_depth is 0 or 1")
+    if cfg.attn_scale < 0 or min(cfg.embed_scale, cfg.residual_scale,
+                                 cfg.logit_scale) <= 0:
+        raise ValueError("embed_scale, residual_scale and logit_scale are "
+                         "positive, attn_scale positive or 0 (head width "
+                         "** -0.5)")
     if cfg.attention_kind == "mla" and cfg.qk_rope_dim % 2:
         raise ValueError("qk_rope_dim must be even")
 
@@ -270,9 +317,6 @@ def _check_mixers(cfg, n_seq: int) -> None:
     if cfg.n_layers != len(pattern):
         raise ValueError(f"mixer_pattern names every layer: {len(pattern)} "
                          f"kinds for n_layers {cfg.n_layers}")
-    if "moe" not in pattern:
-        raise ValueError("mixer_pattern needs a 'moe' layer: the step's trace "
-                         "is built on the expert layers' counters")
     if cfg.dense_layers or cfg.mtp_depth:
         raise ValueError("a block of single mixers has no dense layers and "
                          "no MTP module: a layer is one mixer alone")
@@ -405,11 +449,21 @@ def _mamba_leaves(L: int, cfg) -> Dict[str, Leaf]:
     }
 
 
-def _describe_mixers(vocab: int, cfg) -> Dict[str, Leaf]:
+def _table_leaves(vocab: int, cfg) -> Dict[str, Leaf]:
+    """A moe block's embedding table, head and final norm's gain. A tied
+    table (``tied_head``) stands for both and is drawn as a head is: the
+    logits read it."""
     D, std = cfg.d_model, ("named", INIT_STD)
-    out = {"emb": Leaf((vocab, D), ("named", EMBED_INIT_STD)),
-           "head": Leaf((vocab, D), std),
-           "lnf_g": Leaf((D,), "ones")}
+    if cfg.tied_head:
+        return {"emb": Leaf((vocab, D), std), "lnf_g": Leaf((D,), "ones")}
+    return {"emb": Leaf((vocab, D), ("named", EMBED_INIT_STD)),
+            "head": Leaf((vocab, D), std),
+            "lnf_g": Leaf((D,), "ones")}
+
+
+def _describe_mixers(vocab: int, cfg) -> Dict[str, Leaf]:
+    D, F, std = cfg.d_model, cfg.ffn, ("named", INIT_STD)
+    out = _table_leaves(vocab, cfg)
     for kind in MIXER_KINDS:
         L = cfg.mixer_pattern.count(kind)
         if not L:
@@ -419,6 +473,11 @@ def _describe_mixers(vocab: int, cfg) -> Dict[str, Leaf]:
         elif kind == "attn":
             leaves = _gqa_leaves(L, cfg, "full")
             del leaves["ffn_norm"]
+        elif kind == "mlp":
+            leaves = {"norm": Leaf((L, D), "ones"),
+                      "w_gate": Leaf((L, D, F), std),
+                      "w_up": Leaf((L, D, F), std),
+                      "w_down": Leaf((L, F, D), std)}
         else:
             leaves = {"ffn_norm": Leaf((L, D), "ones"), **_moe_leaves(L, cfg)}
             if cfg.router_kind == "sigmoid_bias":
@@ -431,9 +490,7 @@ def _describe_mixers(vocab: int, cfg) -> Dict[str, Leaf]:
 def _describe_gqa(vocab: int, cfg) -> Dict[str, Leaf]:
     D, F, std = cfg.d_model, cfg.ffn, ("named", INIT_STD)
     Ld = cfg.dense_layers
-    out = {"emb": Leaf((vocab, D), ("named", EMBED_INIT_STD)),
-           "head": Leaf((vocab, D), std),
-           "lnf_g": Leaf((D,), "ones")}
+    out = _table_leaves(vocab, cfg)
     if Ld:
         dense = {**_gqa_leaves(Ld, cfg, layer_kind(cfg, 0)),
                  "w_gate": Leaf((Ld, D, F), std), "w_up": Leaf((Ld, D, F), std),
@@ -485,9 +542,7 @@ def describe_params(vocab: int, cfg) -> Dict[str, Leaf]:
         }
     std = ("named", INIT_STD)
     Ld = cfg.dense_layers
-    out = {"emb": Leaf((vocab, D), ("named", EMBED_INIT_STD)),
-           "head": Leaf((vocab, D), std),
-           "lnf_g": Leaf((D,), "ones")}
+    out = _table_leaves(vocab, cfg)
     if Ld:
         dense = {**_mla_leaves(Ld, cfg),
                  "w_gate": Leaf((Ld, D, F), std), "w_up": Leaf((Ld, D, F), std),
@@ -707,7 +762,8 @@ def mla(blk, h, cfg, s_axis):
     with jax.named_scope("seq.mla/attn"):
         attn = ring_attention(
             q.astype(cd), k.astype(cd), v.astype(cd), axis=s_axis,
-            causal=True, block=ATTN_BLOCK, scale=(dn + dr) ** -0.5,
+            causal=True, block=ATTN_BLOCK,
+            scale=cfg.attn_scale or (dn + dr) ** -0.5,
         )
     with jax.named_scope("seq.mla/proj"):
         return mm(attn.reshape(B, T, H * dv), blk["o_proj"], cd)
@@ -723,7 +779,9 @@ def gqa(blk, h, cfg, s_axis, kind: str):
     outside the window are skipped. Every query head's output is scaled by
     its own gate, ``sigmoid(x W_g)``, before ``W_o`` (``attn_gate``), and
     ``q`` and ``k`` are rotated by position (``attn_rope``): without either
-    the layer is plain grouped-query attention with no position encoding."""
+    the layer is plain grouped-query attention with no position encoding.
+    The scores are scaled by ``attn_scale`` where set, else by ``head_dim **
+    -0.5``."""
     import jax
     import jax.numpy as jnp
 
@@ -763,8 +821,8 @@ def gqa(blk, h, cfg, s_axis, kind: str):
     with jax.named_scope(f"seq.gqa/attn/{kind}"):
         attn, tiles = ring_attention(
             q.astype(cd), k.astype(cd), v.astype(cd), axis=s_axis,
-            causal=True, block=ATTN_BLOCK, scale=d ** -0.5, window=window,
-            with_tiles=True,
+            causal=True, block=ATTN_BLOCK, scale=cfg.attn_scale or d ** -0.5,
+            window=window, with_tiles=True,
         )
     # the window layers' tiles are what the counter is for
     tiles = tiles.astype(jnp.float32) * (kind == "window")
@@ -853,7 +911,10 @@ def experts_impl(platform: str, cfg) -> str:
     """Which grouped matmul the routed experts run, from what is visible at
     trace time: ``gmm`` (:func:`grouped_matmul`, the Pallas kernel) on a TPU
     where ``expert_matmul`` asks for it, ``ragged_dot`` (XLA's) everywhere
-    else, and the kernel's oracle."""
+    else, and the kernel's oracle; ``none`` for a block of single mixers
+    without a ``moe`` layer."""
+    if not has_experts(cfg):
+        return "none"
     return ("gmm" if platform == "tpu" and cfg.expert_matmul == "gmm"
             else "ragged_dot")
 
@@ -1089,7 +1150,7 @@ def carried_states(own, decay):
     return entering
 
 
-def ssd_scan(x, dt, a, b, c, chunk: int, cd):
+def ssd_scan(x, dt, a, b, c, chunk: int, cd, head_block: int = 0):
     """Mamba-2's selective recurrence by chunks (the SSD form).
 
     ``x [B, T, H, P]``, step sizes ``dt [B, T, H]`` (positive), ``a [H]``
@@ -1100,15 +1161,19 @@ def ssd_scan(x, dt, a, b, c, chunk: int, cd):
     ``Y = (L * C B^T)(dt X)`` with ``L_ts = exp(sum_{s<r<=t} dt_r a)``; each
     chunk's own state; the states carried from chunk to chunk
     (:func:`carried_states`); ``C`` against the state that entered. The
-    chunks are batched, the groups mapped one after another under
-    ``jax.checkpoint``: the ``[chunk, chunk]`` weights of one group's heads
-    stand at a time, forward and in the recomputing backward pass. Matmul
-    operands are cast to ``cd`` and accumulate in float32; the cumulative
-    sums, every ``exp`` and the carried state are float32.
+    chunks are batched, the heads mapped a block after another under
+    ``jax.checkpoint``: the ``[chunk, chunk]`` weights of one block's heads
+    stand at a time, forward and in the recomputing backward pass. A block
+    is ``head_block`` heads of one group (clamped to a divisor of the
+    group's ``H / G``; 0 = the group): where it is the group, ``C B^T`` is
+    computed in the group's turn; where a group takes several turns, once a
+    group before the map, and each turn reads its group's. Matmul operands
+    are cast to ``cd`` and accumulate in float32; the cumulative sums, every
+    ``exp`` and the carried state are float32.
 
-    Returns ``(y [B, T, H, P] float32, chunks, absmax)``: the chunks a
-    group's carrying loop ran times the rows, and the largest magnitude of
-    a carried state."""
+    Returns ``(y [B, T, H, P] float32, chunks, absmax, turns)``: the chunks
+    a block's carrying loop ran times the rows, the largest magnitude of a
+    carried state, and the turns of the map, read from its length."""
     import jax
     import jax.numpy as jnp
 
@@ -1116,21 +1181,25 @@ def ssd_scan(x, dt, a, b, c, chunk: int, cd):
 
     B, T, H, P = x.shape
     G, N = b.shape[2:]
-    R, Q = H // G, pick_block(T, chunk)
+    Q = pick_block(T, chunk)
     C = T // Q
+    R = pick_block(H // G, head_block) if head_block else H // G
+    per_group = H // G // R  # turns a group takes
     f32 = jnp.float32
     seen = jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :]
 
-    @jax.checkpoint
-    def group(args):
-        xg, dtg, ag, bg, cg = args  # [B,C,Q,R,P] [B,C,Q,R] [R] [B,C,Q,N] x2
+    def scores(cg, bg):
+        return jnp.einsum("...qn,...sn->...qs", cg, bg,
+                          preferred_element_type=f32)
+
+    def heads(xg, dtg, ag, bg, cg, cb):
+        """One block: ``xg [B,C,Q,R,P]``, ``dtg [B,C,Q,R]``, ``ag [R]``, its
+        group's ``bg, cg [B,C,Q,N]`` (in ``cd``) and ``cb = C B^T``."""
         # log of what the steps up to and with t leave of a state
         cum = jnp.cumsum(dtg * ag, axis=2)  # [B, C, Q, R]
         dtx32 = dtg[..., None] * xg
         dtx = dtx32.astype(cd)
-        bg, cg = bg.astype(cd), cg.astype(cd)
         # inside a chunk: Y = (L * C B^T) (dt X)
-        cb = jnp.einsum("bcqn,bcsn->bcqs", cg, bg, preferred_element_type=f32)
         by_head = cum.transpose(0, 1, 3, 2)  # [B, C, R, Q]
         span = by_head[..., :, None] - by_head[..., None, :]
         weights = jnp.exp(jnp.where(seen, span, -jnp.inf)) * cb[:, :, None]
@@ -1150,13 +1219,35 @@ def ssd_scan(x, dt, a, b, c, chunk: int, cd):
         chunks = jnp.float32(B * entering.shape[1])  # the loop's length
         return y, chunks, jnp.abs(jax.lax.stop_gradient(entering)).max()
 
-    y, chunks, absmax = jax.lax.map(group, (
-        jnp.moveaxis(x.reshape(B, C, Q, G, R, P), 3, 0),
-        jnp.moveaxis(dt.reshape(B, C, Q, G, R), 3, 0), a.reshape(G, R),
-        jnp.moveaxis(b.reshape(B, C, Q, G, N), 3, 0),
-        jnp.moveaxis(c.reshape(B, C, Q, G, N), 3, 0)))
+    def by_block(v, *rest):  # [B, T, H, ..] -> [blocks, B, C, Q, R, ..]
+        return jnp.moveaxis(v.reshape(B, C, Q, H // R, R, *rest), 3, 0)
+
+    def by_group(v):  # [B, T, G, N] -> [G, B, C, Q, N]
+        return jnp.moveaxis(v.reshape(B, C, Q, G, N), 3, 0)
+
+    if per_group == 1:
+        @jax.checkpoint
+        def turn(args):
+            xg, dtg, ag, bg, cg = args
+            bg, cg = bg.astype(cd), cg.astype(cd)
+            return heads(xg, dtg, ag, bg, cg, scores(cg, bg))
+
+        mapped = (by_block(x, P), by_block(dt), a.reshape(G, R), by_group(b),
+                  by_group(c))
+    else:
+        b_all, c_all = by_group(b).astype(cd), by_group(c).astype(cd)
+        cb_all = scores(c_all, b_all)  # [G, B, C, Q, Q], once a group
+
+        @jax.checkpoint
+        def turn(args):
+            xg, dtg, ag, g = args
+            return heads(xg, dtg, ag, b_all[g], c_all[g], cb_all[g])
+
+        mapped = (by_block(x, P), by_block(dt), a.reshape(H // R, R),
+                  jnp.arange(H // R) // per_group)
+    y, chunks, absmax = jax.lax.map(turn, mapped)
     return (jnp.moveaxis(y, 0, 3).reshape(B, T, H, P), chunks[0],
-            absmax.max())
+            absmax.max(), jnp.float32(y.shape[0]))
 
 
 def mamba(blk, h, cfg):
@@ -1205,20 +1296,44 @@ def mamba(blk, h, cfg):
         c = xbc[..., inner + G * N:].reshape(B, T, G, N)
         dt = jax.nn.softplus(dt + blk["dt_bias"])
         a = -jnp.exp(blk["a_log"])
-        y, chunks, absmax = ssd_scan(x, dt, a, b, c, cfg.ssm_chunk, cd)
+        y, chunks, absmax, turns = ssd_scan(
+            x, dt, a, b, c, cfg.ssm_chunk, cd, SSM_HEAD_BLOCK)
         y = (y + blk["d_skip"][:, None] * x).reshape(B, T, inner)
     with jax.named_scope("seq.ssm/norm"):
         u = gated_norm(y, z, blk["gate_g"])
     with jax.named_scope("seq.ssm/proj"):
         out = mm(u, blk["out_proj"], cd)
-    return out, {"ssm_chunks": chunks, "ssm_state_absmax": absmax}
+    return out, {"ssm_chunks": chunks, "ssm_state_absmax": absmax,
+                 "ssm_head_blocks": turns}
+
+
+def residual(h, out, cfg):
+    """``h + residual_scale * out``: what a layer adds to the stream."""
+    if cfg.residual_scale != 1.0:
+        out = out * cfg.residual_scale
+    return h + out
+
+
+def dense_mlp(blk, h, cfg, norm: str):
+    """``h`` plus the dense SwiGLU of ``rms_norm(h, blk[norm])``,
+    ``TOKEN_CHUNK`` tokens at a time."""
+    import jax
+
+    with jax.named_scope("seq.ffn"):
+        B, T, D = h.shape
+        x = rms_norm(h, blk[norm], cfg.norm_eps).reshape(B * T, D)
+        return residual(h, swiglu(
+            x, blk["w_gate"], blk["w_up"], blk["w_down"], _dtype(cfg),
+            TOKEN_CHUNK).reshape(B, T, D), cfg)
 
 
 def mixer_layer(blk, h, cfg, m_axis, s_axis, kind):
     """``(h + mixer(norm(h)), the mixer's counters)`` of a layer that is one
     mixer alone (``blk`` has no layer dim): a ``mamba`` layer counts its
-    chunks and its largest carried state, a ``moe`` layer what
-    :func:`moe` counts, an ``attn`` layer nothing."""
+    chunks, its map's turns and its largest carried state, a ``moe`` layer
+    what :func:`moe` counts, an ``attn`` or ``mlp`` layer nothing."""
+    if kind == "mlp":
+        return dense_mlp(blk, h, cfg, "norm"), {}
     if kind == "mamba":
         out, counters = mamba(blk, h, cfg)
     elif kind == "attn":
@@ -1226,29 +1341,22 @@ def mixer_layer(blk, h, cfg, m_axis, s_axis, kind):
     else:
         out, counters = moe(
             blk, rms_norm(h, blk["ffn_norm"], cfg.norm_eps), cfg, m_axis)
-    return h + out, counters
+    return residual(h, out, cfg), counters
 
 
 def dense_layer(blk, h, cfg, m_axis, s_axis, kind):
     """``(h, the attention's counters)`` of one dense layer."""
-    import jax
-
     out, counters = attend(blk, h, cfg, s_axis, kind)
-    h = h + out
-    with jax.named_scope("seq.ffn"):
-        B, T, D = h.shape
-        x = rms_norm(h, blk["ffn_norm"], cfg.norm_eps).reshape(B * T, D)
-        return h + swiglu(x, blk["w_gate"], blk["w_up"], blk["w_down"],
-                          _dtype(cfg), TOKEN_CHUNK).reshape(B, T, D), counters
+    return dense_mlp(blk, residual(h, out, cfg), cfg, "ffn_norm"), counters
 
 
 def expert_layer(blk, h, cfg, m_axis, s_axis, kind):
     """``(h, counters)`` of one expert layer (``blk`` has no layer dim)."""
     out, attn_counters = attend(blk, h, cfg, s_axis, kind)
-    h = h + out
+    h = residual(h, out, cfg)
     y, counters = moe(blk, rms_norm(h, blk["ffn_norm"], cfg.norm_eps), cfg,
                       m_axis)
-    return h + y, {**counters, **attn_counters}
+    return residual(h, y, cfg), {**counters, **attn_counters}
 
 
 def update_router_bias(router_b, load, rate: float):

@@ -42,6 +42,7 @@ from pio_tpu.models.seq_layers import (
     expert_layer,
     experts_impl,
     group_norms,
+    has_experts,
     init_from,
     is_moe,
     layer_kind,
@@ -165,8 +166,10 @@ class SeqRecConfig:
     # -- ``ssm_heads`` heads of ``ssm_head_dim`` channels over ``ssm_groups``
     # -- groups of state ``ssm_state``, a causal depthwise convolution of
     # -- ``ssm_conv`` taps, the recurrence by chunks of ``ssm_chunk``), "moe"
-    # -- (the expert feed-forward) or "attn" (gqa with ``heads_full`` query
-    # -- heads). Empty = the layers are attention and a feed-forward part.
+    # -- (the expert feed-forward), "attn" (gqa with ``heads_full`` query
+    # -- heads) or "mlp" (the dense SwiGLU of width ``ffn`` with a norm of its
+    # -- own); it need hold no "moe". Empty = the layers are attention and a
+    # -- feed-forward part.
     # -- ``ssm_dt_*`` draw a mamba layer's initial step sizes (log-uniform
     # -- in [min, max], floored).
     mixer_pattern: Tuple[str, ...] = ()
@@ -179,6 +182,19 @@ class SeqRecConfig:
     ssm_dt_min: float = 1e-3
     ssm_dt_max: float = 0.1
     ssm_dt_floor: float = 1e-4
+    # -- four scalars of the model and its table, for any moe block, each
+    # -- applied only where set: ``embed_scale`` multiplies the looked-up
+    # -- rows, ``residual_scale`` what every layer (every mixer) adds to the
+    # -- stream, ``attn_scale`` replaces the scores' head width ** -0.5 (0 =
+    # -- that), ``logit_scale`` multiplies the logits before the log-sum-exp
+    # -- (and the served scores); ``tied_head`` reads the logits from the
+    # -- embedding table: no ``head``, one gradient (the sum of both uses)
+    # -- and one Adam state, the table drawn as a head is.
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    attn_scale: float = 0.0
+    logit_scale: float = 1.0
+    tied_head: bool = False
 
     def __post_init__(self):
         # engine.json gives a list; the config keys the kept programs
@@ -203,8 +219,10 @@ class SeqRecModel:
     #: ``load_max_over_mean`` over all experts, ``bias_max`` (a router with
     #: a selection bias), ``window_tiles``/``causal_tiles`` (the gqa block:
     #: score tiles its window layers visited, and what causal layers of
-    #: their length visit), ``ssm_chunks``/``ssm_state_absmax`` (mamba
-    #: layers: the chunks their carrying loops ran, the largest carried state)
+    #: their length visit), ``ssm_chunks``/``ssm_head_blocks``/
+    #: ``ssm_state_absmax`` (mamba layers: the chunks their carrying loops
+    #: ran, the turns of their scans' maps, the largest carried state). A
+    #: block without an expert layer holds the expert columns ``[steps, 0]``
     trace: Optional[dict] = None
     _serve_cache: Optional[tuple] = dataclasses.field(
         default=None, init=False, repr=False, compare=False
@@ -240,8 +258,10 @@ class SeqRecModel:
                     last = rms_norm(
                         jnp.take_along_axis(h, at, axis=1)[:, 0],
                         params["lnf_g"], cfg.norm_eps)
-                    return mm(last, params["head"].T,
-                              jnp.dtype(cfg.compute_dtype))
+                    scores = mm(last, _head_table(params, cfg).T,
+                                jnp.dtype(cfg.compute_dtype))
+                    return (scores if cfg.logit_scale == 1.0
+                            else scores * cfg.logit_scale)
                 h = _trunk(params, seqs, cfg, None, None, None)
                 last = jnp.take_along_axis(h, at, axis=1)[:, 0]
                 return jnp.dot(
@@ -395,6 +415,17 @@ def _trunk(params, seqs, cfg, m_axis, s_axis, p_axis):
 _MTP_OWN = ("eh_proj", "h_norm", "e_norm", "lnf_g")
 
 
+def _embedded(params, ids, cfg, m_axis):
+    """The rows of the (vocab-parallel) table, times ``embed_scale``."""
+    rows = vocab_parallel_lookup(params["emb"], ids, m_axis)
+    return rows if cfg.embed_scale == 1.0 else rows * cfg.embed_scale
+
+
+def _head_table(params, cfg):
+    """The table the logits read: the embedding's under ``tied_head``."""
+    return params["emb"] if cfg.tied_head else params["head"]
+
+
 def _latent_trunk(params, seqs, cfg, m_axis, s_axis):
     """Embed + the dense layers + the expert layers of a moe block, one
     ``jax.checkpoint`` a layer -> ``(h [mb, T_loc, D] float32 before the
@@ -407,7 +438,7 @@ def _latent_trunk(params, seqs, cfg, m_axis, s_axis):
     import jax
     import jax.numpy as jnp
 
-    h = vocab_parallel_lookup(params["emb"], seqs, m_axis)
+    h = _embedded(params, seqs, cfg, m_axis)
 
     # the barrier keeps a layer's float32 -> compute-dtype weight casts
     # inside the layer: hoisted out of the scan they stand for every layer
@@ -463,10 +494,12 @@ def _latent_trunk(params, seqs, cfg, m_axis, s_axis):
 def _mixer_layers(params, h, cfg, layer):
     """The layers of a block of single mixers -> ``(h, counters)``: layer
     after layer as ``mixer_pattern`` names them, each taking the next slice
-    of its kind's stack (``mamba/*``, ``moe/*``, ``attn/*``). Unlike kinds
-    of layer share no counters, so they are stacked by kind: the ``moe``
-    layers' as the expert layers' of the other blocks, the ``mamba`` layers'
-    summed (``ssm_chunks``) and maximised (``ssm_state_absmax``)."""
+    of its kind's stack (``mamba/*``, ``moe/*``, ``attn/*``, ``mlp/*``).
+    Unlike kinds of layer share no counters, so they are stacked by kind:
+    the ``moe`` layers' as the expert layers' of the other blocks (empty
+    columns where the pattern has none), the ``mamba`` layers' summed
+    (``ssm_chunks``, ``ssm_head_blocks``) and maximised
+    (``ssm_state_absmax``)."""
     import jax
     import jax.numpy as jnp
 
@@ -477,10 +510,15 @@ def _mixer_layers(params, h, cfg, layer):
         taken[kind] += 1
         h, c = layer(mixer_layer, kind)(blk, h)
         by_kind[kind].append(c)
-    counters = jax.tree.map(lambda *a: jnp.stack(a), *by_kind["moe"])
+    if "moe" in by_kind:
+        counters = jax.tree.map(lambda *a: jnp.stack(a), *by_kind["moe"])
+    else:  # what ``seq_layers.moe`` counts, over no layer
+        counters = {name: jnp.zeros((0,), jnp.float32) for name in (
+            "pairs", "dropped", "passes", "staged")}
+        counters["load"] = jnp.zeros((0, cfg.n_experts), jnp.float32)
     if "mamba" in by_kind:
-        counters["ssm_chunks"] = sum(
-            c["ssm_chunks"] for c in by_kind["mamba"])
+        for name in ("ssm_chunks", "ssm_head_blocks"):
+            counters[name] = sum(c[name] for c in by_kind["mamba"])
         counters["ssm_state_absmax"] = jnp.stack(
             [c["ssm_state_absmax"] for c in by_kind["mamba"]]).max()
     return h, counters
@@ -494,7 +532,7 @@ def _mtp_hidden(params, h, next_ids, cfg, m_axis, s_axis):
     import jax.numpy as jnp
 
     mtp = params["mtp"]
-    e = vocab_parallel_lookup(params["emb"], next_ids, m_axis)
+    e = _embedded(params, next_ids, cfg, m_axis)
     both = jnp.concatenate(
         [rms_norm(h, mtp["h_norm"], cfg.norm_eps),
          rms_norm(e, mtp["e_norm"], cfg.norm_eps)], axis=-1)
@@ -506,10 +544,11 @@ def _mtp_hidden(params, h, next_ids, cfg, m_axis, s_axis):
 
 
 def _chunked_ce(h, norm_g, head, targets, mask, cfg, m_axis):
-    """Final RMSNorm, the untied head and the cross-entropy, ``TOKEN_CHUNK``
-    tokens at a time under ``jax.checkpoint``: ``[B, T, V]`` in float32
-    never stands whole, forward or backward. Returns ``(sum_ce, sum_mask)``
-    like :func:`_vocab_parallel_ce`."""
+    """Final RMSNorm, the head (the model's own, or its embedding table
+    where tied: :func:`_head_table`), ``logit_scale`` and the cross-entropy,
+    ``TOKEN_CHUNK`` tokens at a time under ``jax.checkpoint``: ``[B, T, V]``
+    in float32 never stands whole, forward or backward. Returns ``(sum_ce,
+    sum_mask)`` like :func:`_vocab_parallel_ce`."""
     import jax
     import jax.numpy as jnp
 
@@ -524,7 +563,8 @@ def _chunked_ce(h, norm_g, head, targets, mask, cfg, m_axis):
         @jax.checkpoint
         def one(hc, tc, mc):
             x = rms_norm(hc, norm_g, cfg.norm_eps).astype(cd)
-            return _vocab_parallel_ce(x[None], head, tc[None], mc[None], m_axis)
+            return _vocab_parallel_ce(x[None], head, tc[None], mc[None],
+                                      m_axis, cfg.logit_scale)
 
         def body(acc, xs):
             ce, den = one(*xs)
@@ -548,14 +588,15 @@ def _latent_loss_sums(params, batch, cfg, m_axis, s_axis):
     seqs, targets, mask, targets2, mask2 = batch
     h, counters = _latent_trunk(params, seqs, cfg, m_axis, s_axis)
     ce, den = _chunked_ce(
-        h, params["lnf_g"], params["head"], targets, mask, cfg, m_axis)
+        h, params["lnf_g"], _head_table(params, cfg), targets, mask, cfg,
+        m_axis)
     sums = {"ce": ce, "den": den}
     if cfg.mtp_depth:
         with jax.named_scope("seq.mtp"):
             h2, c2 = _mtp_hidden(params, h, targets, cfg, m_axis, s_axis)
             sums["ce2"], sums["den2"] = _chunked_ce(
-                h2, params["mtp"]["lnf_g"], params["head"], targets2, mask2,
-                cfg, m_axis)
+                h2, params["mtp"]["lnf_g"], _head_table(params, cfg), targets2,
+                mask2, cfg, m_axis)
         counters = jax.tree.map(
             lambda a, b: jnp.concatenate([a, b[None]]), counters, c2)
     return sums, counters
@@ -574,8 +615,9 @@ def _latent_loss(sums, counters, cfg):
     return loss, aux
 
 
-def _vocab_parallel_ce(h, emb, targets, mask, m_axis):
-    """CE over the vocab-sharded logits; [mb, T_loc] masked mean parts.
+def _vocab_parallel_ce(h, emb, targets, mask, m_axis, scale: float = 1.0):
+    """CE over the vocab-sharded logits (times ``scale`` where it is not 1);
+    [mb, T_loc] masked mean parts.
 
     Returns (sum_ce, sum_mask) — caller psums over data/seq axes.
     """
@@ -585,6 +627,8 @@ def _vocab_parallel_ce(h, emb, targets, mask, m_axis):
     logits = jnp.einsum(
         "btd,vd->btv", h, emb, preferred_element_type=jnp.float32
     )  # local vocab shard
+    if scale != 1.0:
+        logits = logits * scale
     if m_axis is None:
         z = jax.nn.logsumexp(logits, axis=-1)
         tgt = jnp.take_along_axis(
@@ -776,9 +820,12 @@ def train_seqrec(
             staged (``moe_passes``, ``moe_staged_rows``), the
             largest selection bias (a router that has one), the gqa
             block's ``window_tiles`` and ``causal_tiles`` and the mamba
-            layers' ``ssm_chunks`` and ``ssm_state_absmax``; a moe block
+            layers' ``ssm_chunks``, ``ssm_head_blocks`` (the turns of
+            their scans' maps) and ``ssm_state_absmax`` (the same counters
+            stand in ``/train.json``); a moe block
             also says which grouped matmul its routed experts ran
-            (``experts_impl``: ``seq_layers.experts_impl``) and what ran
+            (``experts_impl``: ``seq_layers.experts_impl``; ``none`` for a
+            block of single mixers without an expert layer) and what ran
             the attention tiles of each kind of its attention layers
             (``attn_impl``: ``{"mla"}`` or ``{"full", "window"}`` ->
             ``pallas`` / ``xla``, ``ring.attention_impl``; in the run
@@ -848,7 +895,7 @@ def _train_seqrec(mesh, sequences, n_items, config, checkpoint,
             )
         if cfg.attention != "ring":
             raise ValueError("the moe blocks ride ring attention")
-        if cfg.experts_held % n_model:
+        if has_experts(cfg) and cfg.experts_held % n_model:
             raise ValueError("experts_held must divide by the model axis")
     else:
         if cfg.n_heads % n_model:
@@ -1139,6 +1186,8 @@ def _train_seqrec(mesh, sequences, n_items, config, checkpoint,
                 k: np.concatenate([np.asarray(a[k]) for a in aux])
                 for k in aux[0] if k != "load"
             }
+            counters = _counters(trace)
+            trainwatch.set_counters(counters)  # /train.json
     if stats is not None:
         stats["readback_s"] = read.seconds
         stats.update(device_stats(capture.result))
@@ -1147,38 +1196,44 @@ def _train_seqrec(mesh, sequences, n_items, config, checkpoint,
             stats["xla"] = dict(
                 xla, in_call={k: xla[k] - xla_before[k] for k in xla})
         if trace is not None:
-            stats["counters"] = {
-                "pairs_held": float(trace["pairs"].sum()),
-                "dropped_pairs": float(trace["dropped"].sum()),
-                "moe_passes": float(trace["passes"].sum()),
-                "moe_staged_rows": float(trace["staged"].sum()),
-                "load_max_over_mean": float(
-                    trace["load_max_over_mean"].max()),
-            }
-            if "bias_max" in trace:
-                stats["counters"]["bias_max"] = float(trace["bias_max"].max())
-            for name in ("window_tiles", "causal_tiles", "ssm_chunks"):
-                if name in trace:
-                    stats["counters"][name] = float(trace[name].sum())
-            if "ssm_state_absmax" in trace:
-                stats["counters"]["ssm_state_absmax"] = float(
-                    trace["ssm_state_absmax"].max())
+            stats["counters"] = counters
     return SeqRecModel(params=host, n_items=n_items, config=cfg, trace=trace)
+
+
+def _counters(trace: dict) -> dict:
+    """A call's counters from its per-step trace: sums over steps and
+    layers, and the largest of what is a maximum (left out where no layer
+    reported one: a block without an expert layer has no load ratio)."""
+    out = {
+        "pairs_held": float(trace["pairs"].sum()),
+        "dropped_pairs": float(trace["dropped"].sum()),
+        "moe_passes": float(trace["passes"].sum()),
+        "moe_staged_rows": float(trace["staged"].sum()),
+    }
+    for name in ("window_tiles", "causal_tiles", "ssm_chunks",
+                 "ssm_head_blocks"):
+        if name in trace:
+            out[name] = float(trace[name].sum())
+    for name in ("load_max_over_mean", "bias_max", "ssm_state_absmax"):
+        if name in trace and trace[name].size:
+            out[name] = float(trace[name].max())
+    return out
 
 
 def _after_step(params, aux, cfg):
     """What follows the optimizer in a step of a moe block: the per-expert
     loads reduce to the step's counters, a router with a selection bias
     moves every expert layer's bias towards the mean load, and the gqa
-    block's tile counters take their names."""
+    block's tile counters take their names. A block without an expert
+    layer has no bias to move and reduces no load: its columns are empty."""
     import jax.numpy as jnp
 
     load = aux["load"]  # [expert layers (+ the MTP module's), n_experts]
     aux = dict(aux)
     if "tiles" in aux:
         aux["window_tiles"], aux["causal_tiles"] = aux.pop("tiles")
-    if cfg.router_kind == "sigmoid_bias":
-        main = "moe" if cfg.mixer_pattern else "blocks"
+    main = "moe" if cfg.mixer_pattern else "blocks"
+    if cfg.router_kind == "sigmoid_bias" and main in params:
         n_main = params[main]["router_b"].shape[0]
         params = dict(params)
         groups = [(main, load[:n_main])]

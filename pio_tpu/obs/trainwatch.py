@@ -105,6 +105,7 @@ class StepRecorder:
         self.gather_impl: Optional[Dict[str, str]] = None
         self.accum_impl: Optional[Dict[str, str]] = None
         self.attn_impl: Optional[Dict[str, str]] = None
+        self.counters: Optional[Dict[str, float]] = None
         self.params_per_device_bytes = 0
         self.h2d_bytes = 0
         self.overlap_ratio: Optional[float] = None
@@ -145,6 +146,7 @@ class StepRecorder:
             self.gather_impl = None
             self.accum_impl = None
             self.attn_impl = None
+            self.counters = None
             self.params_per_device_bytes = int(per_device_bytes)
             self.last_loss = None
             self.losses.clear()
@@ -213,6 +215,15 @@ class StepRecorder:
         with self._lock:
             self.attn_impl = dict(impl)
 
+    def set_counters(self, counters: Dict[str, float]) -> None:
+        """What the algorithm's program counted over its steps (the
+        sequence template: routed and dropped pairs, the expert passes, the
+        attention tiles, a Mamba-2 layer's ``ssm_chunks``,
+        ``ssm_head_blocks`` and ``ssm_state_absmax``), known once the
+        trained state is read back."""
+        with self._lock:
+            self.counters = dict(counters)
+
     def set_overlap(self, ratio: float) -> None:
         with self._lock:
             self.overlap_ratio = float(ratio)
@@ -276,6 +287,7 @@ class StepRecorder:
                     "overlapRatio": self.overlap_ratio,
                 },
                 "paramsPerDeviceBytes": self.params_per_device_bytes,
+                "counters": self.counters,
                 "phases": dict(self.phases),
             }
 
@@ -406,6 +418,12 @@ def set_attn_impl(impl: Dict[str, str]) -> None:
     rec = _ACTIVE
     if rec is not None:
         rec.set_attn_impl(impl)
+
+
+def set_counters(counters: Dict[str, float]) -> None:
+    rec = _ACTIVE
+    if rec is not None:
+        rec.set_counters(counters)
 
 
 # ---------------------------------------------------------------------------

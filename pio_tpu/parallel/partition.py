@@ -233,8 +233,9 @@ def _seqrec_rules():
          r"router_w|router_b|s_gate|s_up|s_down)$", P()),
         # the gqa/moe block's stacks by kind of layer: as above, experts by
         # expert and the rest whole (its heads are not divided over model);
-        # the stacks of layers that are one mixer alone likewise
-        (r"(dense|mtp|window|full|mamba|moe|attn)/", P()),
+        # the stacks of layers that are one mixer alone likewise. A tied
+        # table is ``emb`` alone: the same row shard serves lookup and logits
+        (r"(dense|mtp|window|full|mamba|moe|attn|mlp)/", P()),
         (r"blocks/", P("pipe", None)),
         (r"(emb|head)$", P("model", None)),
         (r"(pos|lnf_g|lnf_b)$", P()),

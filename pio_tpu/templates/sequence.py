@@ -193,10 +193,15 @@ class SeqRecParams(SeqRecConfig, Params):
     the expert and MTP counts; for ``"gqa"`` the ``layer_pattern`` of full
     and window layers, the heads held by kind, each kind's RoPE and the
     ``router_kind``; for layers that are one mixer alone the
-    ``mixer_pattern`` of ``"mamba"``, ``"moe"`` and ``"attn"`` layers, the
+    ``mixer_pattern`` of ``"mamba"``, ``"moe"``, ``"attn"`` and ``"mlp"``
+    layers (``"mlp"``: the dense SwiGLU of width ``ffn`` as a layer of its
+    own; the pattern may hold no ``"moe"`` at all), the
     ``ssm_*`` sizes of a Mamba-2 layer, ``expert_act``, ``attn_rope`` and
     ``attn_gate``; for any moe block ``expert_matmul``, the routed experts'
-    grouped matmul) plus the mesh splits."""
+    grouped matmul, the model's four scalars ``embed_scale``,
+    ``residual_scale``, ``attn_scale`` and ``logit_scale`` (each applied
+    only where set) and ``tied_head``, which reads the logits from the
+    embedding table) plus the mesh splits."""
 
     steps: int = 300
     #: mesh splits; remaining devices ride the data axis

@@ -111,7 +111,7 @@ def test_the_chunked_scan_equals_the_recurrence(t, chunk, runs):
     def ours(*a):
         return seq_layers.ssd_scan(*a, chunk, jnp.float32)[0]
 
-    y, chunks, absmax = seq_layers.ssd_scan(*args, chunk, jnp.float32)
+    y, chunks, absmax, _ = seq_layers.ssd_scan(*args, chunk, jnp.float32)
     np.testing.assert_allclose(y, _step_by_step(*args), atol=2e-5)
     assert float(chunks) == 2 * (t // runs)  # rows x chunks: the loop's length
     assert (float(absmax) > 0) == (t > runs)  # one chunk carries nothing
@@ -132,7 +132,7 @@ def test_a_state_that_is_not_carried_is_seen(monkeypatch):
     sound = seq_layers.ssd_scan(*args, 8, jnp.float32)[0]
     monkeypatch.setattr(seq_layers, "carried_states",
                         lambda own, decay: jnp.zeros_like(own))
-    broken, _, absmax = seq_layers.ssd_scan(*args, 8, jnp.float32)
+    broken, _, absmax, _ = seq_layers.ssd_scan(*args, 8, jnp.float32)
     assert float(absmax) == 0.0
     assert float(jnp.abs(broken - sound).max()) > 1e-2
     with pytest.raises(AssertionError):
@@ -341,7 +341,7 @@ def test_a_planted_skew_costs_a_second_pass_not_a_pair(impl, monkeypatch):
 @pytest.mark.parametrize("change,match", [
     (dict(mixer_pattern=("mamba", "ffn")), "mixer_pattern holds kinds"),
     (dict(n_layers=10), "names every layer"),
-    (dict(mixer_pattern=("mamba", "attn", "mamba"), n_layers=3), "needs a 'moe'"),
+    (dict(residual_scale=0.0), "residual_scale and logit_scale are positive"),
     (dict(dense_layers=1), "no dense layers"),
     (dict(attention_kind="mla"), "attention_kind='gqa'"),
     (dict(heads_full=7), "multiple of"),

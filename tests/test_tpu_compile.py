@@ -319,3 +319,82 @@ def test_blocked_attention_compiles_for_v5e(one_chip, monkeypatch, cell, b,
     carry = b * h_kv * t * min(d_k, d_v) * 4
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp <= min(score_tile, carry) // 4, (temp, score_tile, carry)
+
+
+def _sequence_step(topo, config_name, max_len=0):
+    """``chunk_staged`` of a sequence cell (its configuration's file read as
+    the benchmark's driver reads it; ``max_len`` another row length), lowered
+    for one described chip with the rules asked as a TPU is."""
+    import dataclasses
+    import json
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import run
+
+    from pio_tpu.controller.params import params_from_dict
+    from pio_tpu.models import seqrec
+    from pio_tpu.parallel.mesh import MeshSpec, build_mesh
+    from pio_tpu.templates.sequence import SeqRecParams
+
+    with open(os.path.join(bench, "configs", config_name + ".json")) as f:
+        config = json.load(f)
+    driver = run.load_module("drivers", "train_seq_cfg")
+    p = params_from_dict(SeqRecParams, driver.algorithm_params(
+        config, driver.reference_module(config).model(config), 1))
+    cfg = seqrec.SeqRecConfig(**{f.name: getattr(p, f.name) for f in
+                                 dataclasses.fields(seqrec.SeqRecConfig)})
+    cfg = dataclasses.replace(cfg, max_len=max_len or cfg.max_len)
+    mesh = build_mesh(MeshSpec(data=-1), devices=topo.devices[:1])
+    rows, vocab = int(config["data"]["n_histories"]), int(config["data"]["n_items"]) + 1
+    prog = seqrec._programs(dataclasses.replace(cfg, seed=0, steps=0), mesh,
+                            vocab, cfg.batch_size, rows // cfg.batch_size)
+    params = jax.eval_shape(prog.init, jnp.int32(0))
+    whole = NamedSharding(mesh, P())
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=whole),
+        (jax.ShapeDtypeStruct((), jnp.int32), params,
+         jax.eval_shape(prog.opt_init, params)))
+    epoch = tuple(jax.ShapeDtypeStruct(
+        (rows, cfg.max_len), dtype, sharding=NamedSharding(mesh, P("data", "seq")))
+        for dtype in (jnp.int32, jnp.int32, jnp.float32, jnp.int32, jnp.float32))
+    return prog.chunk_staged.lower(state, epoch, cfg.steps), params
+
+
+@pytest.mark.parametrize("config_name,max_len,parameters,temp_bytes", [
+    # the accepted cell of single mixers, whose step runs in 15.04 of the
+    # chip's 15.75 GiB (PERF.md section 4): the offline temporaries overstate
+    # what the chip reserves, so a step is held to its own number
+    ("nemotron3nano-ep16", 0, 666_963_456, 10_052_246_016),
+    # no experts: 772 M parameters leave less room, rows of 8,192 fit it
+    ("granite4hmicro-vp8", 0, 772_160_448, 6_396_983_808),
+    # for the record: one row of 16,384 does not (a Mamba-2 mixer's float32
+    # [T, 4096] and [T, 8512] intermediates): the TPU compiler refuses it
+    ("granite4hmicro-vp8", 16384, 772_160_448, None),
+])
+def test_a_sequence_cells_step_fits_a_v5e(topo, monkeypatch, config_name,
+                                          max_len, parameters, temp_bytes):
+    """The whole training step of the two cells of single mixers, compiled
+    for a described v5e: the parameters' count, and the temporaries beside 12
+    B a parameter of arguments (weights and Adam's moments, donated)."""
+    import jax
+    import numpy as np
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lowered, params = _sequence_step(topo, config_name, max_len)
+    assert sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params)) == parameters
+    if temp_bytes is None:
+        with pytest.raises(Exception, match="(?i)memory|exhausted"):
+            lowered.compile()
+        return
+    mem = lowered.compile().memory_analysis()
+    assert mem.temp_size_in_bytes <= 1.02 * temp_bytes
+    assert mem.argument_size_in_bytes == pytest.approx(12 * parameters, rel=2e-3)
+    assert mem.alias_size_in_bytes >= 12 * parameters  # the state is donated
