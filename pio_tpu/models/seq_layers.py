@@ -30,7 +30,8 @@ moe blocks' "attention, then a feed-forward part" (dense or experts), or
   ``mixer_pattern[i]`` names: ``"mamba"`` (a Mamba-2
   state-space mixer, :func:`mamba`: one wide input projection, a causal
   depthwise convolution, the selective recurrence over ``ssm_heads`` heads
-  computed by chunks, :func:`ssd_scan`, a gated group RMSNorm and an output
+  computed by chunks, :func:`ssd_scan` or on a TPU its two Pallas kernels
+  (:func:`ssd_impl`), a gated group RMSNorm and an output
   projection), ``"moe"`` (the expert feed-forward alone, :func:`moe`, here
   with ``expert_act="relu2"`` experts of two matrices behind either router;
   ``expert_matmul="gmm"`` hands any moe block's routed rows to the Pallas
@@ -88,8 +89,9 @@ INIT_STD, EMBED_INIT_STD, BIAS_INIT_STD = 0.02, 1.0, 0.02
 #: Edge of the attention tiles, and tokens to a chunk of the cross-entropy and
 #: of the dense SwiGLU (each clamped to a divisor of what it cuts).
 ATTN_BLOCK, TOKEN_CHUNK = 512, 2048
-#: Heads of a Mamba-2 group to a turn of the chunked scan's map
-#: (:func:`ssd_scan`; clamped to a divisor of the group's heads, so a group of
+#: Heads of a Mamba-2 group to a turn of the XLA chunked scan's map
+#: (:func:`ssd_scan`, which the TPU's kernels replace where :func:`ssd_impl`
+#: admits them; clamped to a divisor of the group's heads, so a group of
 #: 8 heads is one turn). A turn's float32 ``[chunk, chunk]`` weights stand a
 #: head: at chunks of 256 a group of 64 heads whole would hold 0.5 GB three
 #: times over. Of 8, 16 and 32 on a v5e at 64 heads in one group, chunks of
@@ -1250,6 +1252,43 @@ def ssd_scan(x, dt, a, b, c, chunk: int, cd, head_block: int = 0):
             absmax.max(), jnp.float32(y.shape[0]))
 
 
+def ssd_impl(platform: str, cd, chunk: int, p: int, n: int,
+             heads_per_group: int) -> str:
+    """What runs :func:`ssd_scan`'s chunks, from what is visible at trace
+    time: ``pallas`` / ``xla``. ``pallas`` is the pair of kernels of
+    :mod:`pio_tpu.models.ssd_kernel` (a head block's state in VMEM across
+    its chunks, the decay tiles never in HBM): on a TPU, with bfloat16
+    operands, a chunk and a state width that are multiples of the 128 lanes,
+    a group's ``x`` columns a multiple of the state width (the kernels read
+    ``B`` and ``C`` where they lie beside ``x``), and a head block whose
+    ``x`` columns fill whole lane tiles and whose tiles fit VMEM
+    (``ssd_kernel.head_block``, ``ssd_kernel.fits``). ``xla`` is
+    :func:`ssd_scan`: everywhere else (every CPU run, float32 operands,
+    chunks of 64), and the kernels' oracle."""
+    import jax.numpy as jnp
+
+    from pio_tpu.models import ssd_kernel
+
+    r = ssd_kernel.head_block(heads_per_group, p)
+    tiles = (jnp.dtype(cd) == jnp.bfloat16 and chunk % 128 == 0
+             and n % 128 == 0 and heads_per_group * p % n == 0
+             and ssd_kernel.fits(chunk, p, n, r))
+    return "pallas" if platform == "tpu" and tiles else "xla"
+
+
+def ssm_impl(platform: str, cfg, t_local: int) -> str:
+    """What runs the chunks of the block's Mamba-2 mixers over rows of
+    ``t_local`` events: :func:`ssd_impl`'s answer at the shapes
+    :func:`mamba` hands it; ``none`` for a block without a mamba layer."""
+    from pio_tpu.parallel.ring import pick_block
+
+    if "mamba" not in cfg.mixer_pattern:
+        return "none"
+    return ssd_impl(platform, _dtype(cfg), pick_block(t_local, cfg.ssm_chunk),
+                    cfg.ssm_head_dim, cfg.ssm_state,
+                    cfg.ssm_heads // cfg.ssm_groups)
+
+
 def mamba(blk, h, cfg):
     """The Mamba-2 mixer of the normed ``h [B, T, D]`` -> ``(its output
     before the residual, counters)``: ``[z | xBC | dt] = x W_in``; ``xBC <-
@@ -1258,13 +1297,19 @@ def mamba(blk, h, cfg):
     P]``, ``B, C [G, N]``; ``dt = softplus(dt + dt_bias)``, ``A =
     -exp(A_log)``; the recurrence (:func:`ssd_scan`) plus ``D x``; the gate
     first, ``u = y silu(z)``, then RMSNorm over each of the ``G`` groups of
-    channels times a gain; ``u W_out``. The sequence is whole here
+    channels times a gain; ``u W_out``. The recurrence runs on
+    :mod:`pio_tpu.models.ssd_kernel` where :func:`ssd_impl` says ``pallas``
+    (``pallas_interpret``, the same kernels interpreted, in tests), else on
+    :func:`ssd_scan`; ``ssm_head_blocks`` is then the kernels' blocks of
+    heads. The sequence is whole here
     (``check_block`` refuses a ``seq`` axis). The convolution and the gated
     norm are recomputed in the backward pass from their inputs, as the
     recurrence is: beside 16 B a parameter a layer's float32 ``[T, 4096]``
     intermediates do not all fit."""
     import jax
     import jax.numpy as jnp
+
+    from pio_tpu.parallel.ring import pick_block
 
     cd, eps = _dtype(cfg), cfg.norm_eps
     B, T, _ = h.shape
@@ -1291,14 +1336,23 @@ def mamba(blk, h, cfg):
     with jax.named_scope("seq.ssm/conv"):
         xbc = convolved(xbc, blk["conv_w"], blk["conv_b"])
     with jax.named_scope("seq.ssm/ssd"):
-        x = xbc[..., :inner].reshape(B, T, H, P)
-        b = xbc[..., inner:inner + G * N].reshape(B, T, G, N)
-        c = xbc[..., inner + G * N:].reshape(B, T, G, N)
         dt = jax.nn.softplus(dt + blk["dt_bias"])
         a = -jnp.exp(blk["a_log"])
-        y, chunks, absmax, turns = ssd_scan(
-            x, dt, a, b, c, cfg.ssm_chunk, cd, SSM_HEAD_BLOCK)
-        y = (y + blk["d_skip"][:, None] * x).reshape(B, T, inner)
+        Q = pick_block(T, cfg.ssm_chunk)
+        impl = ssd_impl(jax.default_backend(), cd, Q, P, N, H // G)
+        if impl == "xla":
+            x = xbc[..., :inner].reshape(B, T, H, P)
+            b = xbc[..., inner:inner + G * N].reshape(B, T, G, N)
+            c = xbc[..., inner + G * N:].reshape(B, T, G, N)
+            y, chunks, absmax, turns = ssd_scan(
+                x, dt, a, b, c, Q, cd, SSM_HEAD_BLOCK)
+            y = (y + blk["d_skip"][:, None] * x).reshape(B, T, inner)
+        else:
+            from pio_tpu.models import ssd_kernel
+
+            y, chunks, absmax, turns = ssd_kernel.scan(
+                xbc, dt, a, blk["d_skip"], (H, P, G, N), Q, cd,
+                impl == "pallas_interpret")
     with jax.named_scope("seq.ssm/norm"):
         u = gated_norm(y, z, blk["gate_g"])
     with jax.named_scope("seq.ssm/proj"):

@@ -50,6 +50,7 @@ from pio_tpu.models.seq_layers import (
     mm,
     period_kinds,
     rms_norm,
+    ssm_impl,
     unflatten,
     update_router_bias,
 )
@@ -828,8 +829,10 @@ def train_seqrec(
             block of single mixers without an expert layer) and what ran
             the attention tiles of each kind of its attention layers
             (``attn_impl``: ``{"mla"}`` or ``{"full", "window"}`` ->
-            ``pallas`` / ``xla``, ``ring.attention_impl``; in the run
-            record too).
+            ``pallas`` / ``xla``, ``ring.attention_impl``) and the chunks of
+            its Mamba-2 mixers (``ssm_impl``: ``pallas`` / ``xla``,
+            ``seq_layers.ssd_impl``; ``none`` without a mamba layer); both
+            in ``/train.json`` and the run record too.
 
     Raises:
         DeviceBudgetExceeded: the params can't fit (single-chip or even
@@ -1055,8 +1058,11 @@ def _train_seqrec(mesh, sequences, n_items, config, checkpoint,
     if latent:
         attn_impl = attn_impls(jax.default_backend(), cfg, t_pad // n_seq)
         trainwatch.set_attn_impl(attn_impl)
+        ssm = ssm_impl(jax.default_backend(), cfg, t_pad // n_seq)
+        trainwatch.set_ssm_impl(ssm)
         if stats is not None:
             stats["attn_impl"] = attn_impl
+            stats["ssm_impl"] = ssm
     # lagged loss drain (the two_tower discipline): per-step losses come
     # back as device arrays and are fetched one chunk behind the
     # dispatch frontier; no recorder → dropped undereferenced.

@@ -105,6 +105,7 @@ class StepRecorder:
         self.gather_impl: Optional[Dict[str, str]] = None
         self.accum_impl: Optional[Dict[str, str]] = None
         self.attn_impl: Optional[Dict[str, str]] = None
+        self.ssm_impl: Optional[str] = None
         self.counters: Optional[Dict[str, float]] = None
         self.params_per_device_bytes = 0
         self.h2d_bytes = 0
@@ -146,6 +147,7 @@ class StepRecorder:
             self.gather_impl = None
             self.accum_impl = None
             self.attn_impl = None
+            self.ssm_impl = None
             self.counters = None
             self.params_per_device_bytes = int(per_device_bytes)
             self.last_loss = None
@@ -214,6 +216,12 @@ class StepRecorder:
         ``ring.attention_impl``)."""
         with self._lock:
             self.attn_impl = dict(impl)
+
+    def set_ssm_impl(self, impl: str) -> None:
+        """What runs the chunks of the Mamba-2 mixers (``pallas`` / ``xla``;
+        ``none`` without one: ``seq_layers.ssd_impl``)."""
+        with self._lock:
+            self.ssm_impl = impl
 
     def set_counters(self, counters: Dict[str, float]) -> None:
         """What the algorithm's program counted over its steps (the
@@ -288,6 +296,7 @@ class StepRecorder:
                 },
                 "paramsPerDeviceBytes": self.params_per_device_bytes,
                 "counters": self.counters,
+                "ssmImpl": self.ssm_impl,
                 "phases": dict(self.phases),
             }
 
@@ -322,6 +331,7 @@ class StepRecorder:
                 "gather_impl": self.gather_impl,
                 "accum_impl": self.accum_impl,
                 "attn_impl": self.attn_impl,
+                "ssm_impl": self.ssm_impl,
             }
 
 
@@ -420,6 +430,12 @@ def set_attn_impl(impl: Dict[str, str]) -> None:
         rec.set_attn_impl(impl)
 
 
+def set_ssm_impl(impl: str) -> None:
+    rec = _ACTIVE
+    if rec is not None:
+        rec.set_ssm_impl(impl)
+
+
 def set_counters(counters: Dict[str, float]) -> None:
     rec = _ACTIVE
     if rec is not None:
@@ -508,7 +524,8 @@ def run_record(*, run_id: str, engine_id: str, status: str,
     ``packed`` / ``plain``) and ``accum_impl`` (``{"user", "item"}``:
     ``fused`` / ``xla``) are lifted beside them, and so is a sequence run's
     ``attn_impl`` (``{"mla"}`` or ``{"full", "window"}``: ``pallas`` /
-    ``xla``). ``xla`` is the process's
+    ``xla``) and ``ssm_impl`` (``pallas`` / ``xla`` / ``none``). ``xla`` is
+    the process's
     :func:`pio_tpu.obs.devicewatch.xla_totals` at the run's end: what JAX's
     compile path took of the run, lifted as ``xla_trace_s``,
     ``xla_lower_s``, ``xla_compile_s`` and ``xla_cache_load_s``."""
@@ -536,7 +553,7 @@ def run_record(*, run_id: str, engine_id: str, status: str,
         rec["step_summary"] = dict(step_summary)
         for key in ("examples_per_sec", "final_loss", "loss_window_mean",
                     "overlap_ratio", "steps", "examples", "solve_impl",
-                    "gather_impl", "accum_impl", "attn_impl"):
+                    "gather_impl", "accum_impl", "attn_impl", "ssm_impl"):
             if step_summary.get(key) is not None:
                 rec[key] = step_summary[key]
     if device_scopes:

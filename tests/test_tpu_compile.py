@@ -321,6 +321,49 @@ def test_blocked_attention_compiles_for_v5e(one_chip, monkeypatch, cell, b,
     assert temp <= min(score_tile, carry) // 4, (temp, score_tile, carry)
 
 
+@pytest.mark.parametrize("cell, t, g, q, temp_bytes", [
+    ("nemotron", 16384, 8, 128, 344_451_584),
+    ("granite", 8192, 1, 256, 138_930_688),
+])
+def test_ssd_kernels_compile_for_v5e(one_chip, cell, t, g, q, temp_bytes):
+    """The two mamba cells' chunked scan (one row, 64 heads of 64 channels, a
+    state of 128; eight groups with chunks of 128, one group with chunks of
+    256) on ``ssd_kernel``, forward and backward from the convolution's
+    output: two Pallas calls that fit VMEM, and no ``[Q, Q]`` float32 decay
+    weights in HBM, which XLA's scan writes for every chunk. The temporaries are the states that entered each chunk (float32
+    ``[T / Q, H P, N]``) and the blocks' ``dB``, ``dC`` before their sum."""
+    import math
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from pio_tpu.models import ssd_kernel
+
+    b, h, p, n = 1, 64, 64, 128
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def both(xbc, dt, a, d, dy):
+        y, back = jax.vjp(lambda *args: ssd_kernel.scan(
+            *args, (h, p, g, n), q, jnp.bfloat16)[0], xbc, dt, a, d)
+        return y, back(dy)
+
+    compiled = jax.jit(both).lower(
+        sd((b, t, h * p + 2 * g * n)), sd((b, t, h)), sd((h,)), sd((h,)),
+        sd((b, t, h * p))).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 2, len(calls)
+    # a head's [Q, Q] weights over the row's chunks: XLA's scan writes R of
+    # them a turn; per-position scalars in another layout are fewer
+    weights = [dims for dims in re.findall(rf"f32\[([\d,]*),{q},{q}\]", text)
+               if math.prod(map(int, dims.split(","))) * q * q >= t * q]
+    assert not weights, weights
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1.02 * temp_bytes
+
+
 def _sequence_step(topo, config_name, max_len=0):
     """``chunk_staged`` of a sequence cell (its configuration's file read as
     the benchmark's driver reads it; ``max_len`` another row length), lowered
@@ -372,9 +415,11 @@ def _sequence_step(topo, config_name, max_len=0):
     # the accepted cell of single mixers, whose step runs in 15.04 of the
     # chip's 15.75 GiB (PERF.md section 4): the offline temporaries overstate
     # what the chip reserves, so a step is held to its own number
-    ("nemotron3nano-ep16", 0, 666_963_456, 10_052_246_016),
+    # (10,052,246,016 B until the chunked scan became two Pallas kernels)
+    ("nemotron3nano-ep16", 0, 666_963_456, 9_545_038_336),
     # no experts: 772 M parameters leave less room, rows of 8,192 fit it
-    ("granite4hmicro-vp8", 0, 772_160_448, 6_396_983_808),
+    # (6,396,983,808 B on XLA's scan)
+    ("granite4hmicro-vp8", 0, 772_160_448, 6_147_741_184),
     # for the record: one row of 16,384 does not (a Mamba-2 mixer's float32
     # [T, 4096] and [T, 8512] intermediates): the TPU compiler refuses it
     ("granite4hmicro-vp8", 16384, 772_160_448, None),
