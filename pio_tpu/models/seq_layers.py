@@ -21,10 +21,14 @@ moe blocks' "attention, then a feed-forward part" (dense or experts), or
   (a rotated slice of the head, YaRN's interpolated frequencies and factor
   on a full layer), a sigmoid gate a query head on the attention's output,
   and the same dense and expert layers behind a ``router_kind="softmax"``
-  router. Its stacks are ``dense/*``, ``window/*`` and ``full/*``: the
-  kinds' projections have unlike shapes. ``heads_full``, ``heads_window``
-  and ``kv_heads`` count the heads held here, as ``experts_held`` counts
-  the experts.
+  router; or ``"sparse"`` layers (DeepSeek Sparse Attention's form,
+  :func:`dsa`): a full layer's heads over the keys a lightning indexer
+  selects a query, the indexer trained by its own KL loss. Per-head q/k
+  RMSNorm where ``attn_qk_norm``; no shared expert where ``shared_experts``
+  is 0. Its stacks are ``dense/*``, ``window/*``, ``full/*`` and
+  ``sparse/*``: the kinds' projections have unlike shapes. ``heads_full``,
+  ``heads_window`` and ``kv_heads`` count the heads held here, as
+  ``experts_held`` counts the experts.
 - the same two kinds with ``mixer_pattern`` set (the ``nemotron_h`` family):
   layer ``i`` is ``h + mixer(norm(h))`` with the one mixer
   ``mixer_pattern[i]`` names: ``"mamba"`` (a Mamba-2
@@ -68,13 +72,14 @@ and the gated norm are float32 too.
 
 from __future__ import annotations
 
+import functools
 import zlib
 from typing import Dict, NamedTuple, Tuple
 
 from pio_tpu.utils.numutil import round_up
 
 BLOCK_KINDS = {("mha", "relu"), ("mla", "moe"), ("gqa", "moe")}
-LAYER_KINDS = ("full", "window")
+LAYER_KINDS = ("full", "window", "sparse")
 MIXER_KINDS = ("mamba", "moe", "attn", "mlp")
 ROUTER_KINDS = ("sigmoid_bias", "softmax")
 EXPERT_ACTS = ("swiglu", "relu2")
@@ -89,6 +94,14 @@ INIT_STD, EMBED_INIT_STD, BIAS_INIT_STD = 0.02, 1.0, 0.02
 #: Edge of the attention tiles, and tokens to a chunk of the cross-entropy and
 #: of the dense SwiGLU (each clamped to a divisor of what it cuts).
 ATTN_BLOCK, TOKEN_CHUNK = 512, 2048
+#: Queries of a block of the indexer's scores: its ``[rows, index heads,
+#: keys]`` float32 products stand at once (16 heads of 16,384 keys: 128 MB
+#: at 128 rows), a block after another under ``jax.checkpoint``.
+INDEX_BLOCK = 128
+#: The name a sparse layer's selection carries (``checkpoint_name``): the
+#: layer's recomputation in the backward pass keeps it instead of running
+#: the indexer's scores and the top-k again.
+SELECTION = "seq.dsa.selection"
 #: Heads of a Mamba-2 group to a turn of the XLA chunked scan's map
 #: (:func:`ssd_scan`, which the TPU's kernels replace where :func:`ssd_impl`
 #: admits them; clamped to a divisor of the group's heads, so a group of
@@ -139,6 +152,13 @@ GQA_GROUPS = ("embedding", "head", "attn_window", "attn_full", "gate",
               "norms")
 
 
+#: a block of sparse layers: attention's projections and q/k norms' gains
+#: together, the indexer (trained by its own loss alone) apart; the shared
+#: expert and the dense MLP only where the block has them
+DSA_GROUPS = ("embedding", "head", "attn", "indexer", "router",
+              "routed_experts", "shared_expert", "dense_mlp", "norms")
+
+
 #: a block of single mixers: a mamba layer's two projections apart from what
 #: its recurrence reads (the convolution, ``A_log``, ``D``, ``dt_bias`` and
 #: the gated norm's gain), the attention layers' four projections together
@@ -163,6 +183,10 @@ def groups_of(cfg) -> Tuple[str, ...]:
                      if has.get(g, True))
     if pattern:
         return MIXER_GROUPS
+    if is_sparse(cfg):
+        has = {"shared_expert": cfg.shared_experts > 0,
+               "dense_mlp": cfg.dense_layers > 0}
+        return tuple(g for g in DSA_GROUPS if has.get(g, True))
     return GQA_GROUPS if cfg.attention_kind == "gqa" else GROUPS
 
 
@@ -173,7 +197,9 @@ def group_of(path: str, cfg=None) -> str:
     dense layers' MLP; and attention with every norm under ``mla``. The
     gqa/moe block: the four projections under the attention of their
     layer's kind (a dense layer's kind is the pattern's), the gate's map,
-    and the norms' gains on their own. A block of single mixers: see
+    and the norms' gains on their own; a block of sparse layers: the
+    projections under ``attn``, the indexer's ``idx_*`` under ``indexer``.
+    A block of single mixers: see
     ``MIXER_GROUPS``; an ``mlp`` mixer's three matrices are ``dense_mlp``."""
     group, _, name = path.rpartition("/")
     if cfg is not None and cfg.mixer_pattern:
@@ -184,6 +210,10 @@ def group_of(path: str, cfg=None) -> str:
         if group == "attn":
             return "attn"
     elif cfg is not None and cfg.attention_kind == "gqa":
+        if is_sparse(cfg) and name.startswith("idx_"):
+            return "indexer"
+        if is_sparse(cfg) and name.endswith("_proj"):
+            return "attn"
         if name.endswith("norm") or name == "lnf_g":
             return "norms"
         if name == "g_proj":
@@ -245,6 +275,11 @@ def has_experts(cfg) -> bool:
     return not cfg.mixer_pattern or "moe" in cfg.mixer_pattern
 
 
+def is_sparse(cfg) -> bool:
+    """Whether the gqa block's layers are ``"sparse"`` (:func:`dsa`)."""
+    return cfg.attention_kind == "gqa" and "sparse" in cfg.layer_pattern
+
+
 def layer_kind(cfg, layer: int) -> str:
     """The gqa/moe block's kind of layer ``layer``: the pattern repeats."""
     return cfg.layer_pattern[layer % len(cfg.layer_pattern)]
@@ -262,6 +297,8 @@ def period_kinds(cfg) -> Tuple[str, ...]:
 
 
 def heads_of(cfg, kind: str) -> int:
+    """Query heads of a layer of ``kind``: a sparse layer's are a full
+    layer's."""
     return cfg.heads_window if kind == "window" else cfg.heads_full
 
 
@@ -287,7 +324,7 @@ def check_block(cfg, n_seq: int = 1) -> None:
     if cfg.mixer_pattern:
         _check_mixers(cfg, n_seq)
     elif cfg.attention_kind == "gqa":
-        _check_gqa(cfg)
+        _check_gqa(cfg, n_seq)
     if not 0 <= cfg.dense_layers < cfg.n_layers:
         raise ValueError("dense_layers must leave at least one expert layer")
     if not (0 <= cfg.experts_first
@@ -342,7 +379,7 @@ def _check_mixers(cfg, n_seq: int) -> None:
             raise ValueError("0 < ssm_dt_min <= ssm_dt_max")
 
 
-def _check_gqa(cfg) -> None:
+def _check_gqa(cfg, n_seq: int) -> None:
     pattern = cfg.layer_pattern
     if not pattern or any(k not in LAYER_KINDS for k in pattern):
         raise ValueError(f"layer_pattern holds kinds of {LAYER_KINDS}")
@@ -354,6 +391,21 @@ def _check_gqa(cfg) -> None:
     _check_heads(cfg, set(pattern))
     if "window" in pattern and cfg.window < 1:
         raise ValueError("window layers need window >= 1")
+    if "sparse" in pattern:
+        if set(pattern) != {"sparse"}:
+            raise ValueError("layer_pattern holds sparse layers alone: the "
+                             "indexer's loss and group are the block's")
+        if n_seq > 1:
+            raise ValueError(
+                f"a sparse layer cannot run under a seq axis of {n_seq}: "
+                "its indexer scores every earlier key of the row, and the "
+                "selection across shards of the sequence is not built")
+        if (min(cfg.index_heads, cfg.index_head_dim, cfg.index_topk) < 1
+                or cfg.index_head_dim % 4):
+            raise ValueError("index_heads, index_topk >= 1; index_head_dim "
+                             "a multiple of 4 (its first half is rotated)")
+        if cfg.yarn_factor > 1.0:
+            raise ValueError("a sparse layer's RoPE is plain (no YaRN)")
     if cfg.router_kind != "softmax" or cfg.mtp_depth:
         raise ValueError("the gqa/moe block has a softmax router and no "
                          "MTP module")
@@ -403,6 +455,18 @@ def _gqa_leaves(L: int, cfg, kind: str) -> Dict[str, Leaf]:
     }
     if not cfg.attn_gate:
         del out["g_proj"]
+    if cfg.attn_qk_norm:
+        out["q_norm"] = Leaf((L, d), "ones")
+        out["k_norm"] = Leaf((L, d), "ones")
+    if kind == "sparse":  # the lightning indexer
+        Hi, di = cfg.index_heads, cfg.index_head_dim
+        out.update({
+            "idx_q": Leaf((L, D, Hi * di), std),
+            "idx_k": Leaf((L, D, di), std),
+            "idx_k_norm_g": Leaf((L, di), "ones"),
+            "idx_k_norm_b": Leaf((L, di), "zeros"),
+            "idx_w": Leaf((L, D, Hi), std),
+        })
     return out
 
 
@@ -421,6 +485,9 @@ def _moe_leaves(L: int, cfg) -> Dict[str, Leaf]:
     }
     if cfg.expert_act == "relu2":  # two matrices an expert: no gate
         del out["e_gate"], out["s_gate"]
+    if not cfg.shared_experts:
+        for name in ("s_gate", "s_up", "s_down"):
+            out.pop(name, None)
     return out
 
 
@@ -780,8 +847,9 @@ def gqa(blk, h, cfg, s_axis, kind: str):
     share its keys and values inside one score tile; a window layer's tiles
     outside the window are skipped. Every query head's output is scaled by
     its own gate, ``sigmoid(x W_g)``, before ``W_o`` (``attn_gate``), and
-    ``q`` and ``k`` are rotated by position (``attn_rope``): without either
-    the layer is plain grouped-query attention with no position encoding.
+    ``q`` and ``k`` are rotated by position (``attn_rope``), after a
+    per-head RMSNorm where ``attn_qk_norm``: without those the layer is
+    plain grouped-query attention with no position encoding.
     The scores are scaled by ``attn_scale`` where set, else by ``head_dim **
     -0.5``."""
     import jax
@@ -809,9 +877,13 @@ def gqa(blk, h, cfg, s_axis, kind: str):
     with jax.named_scope("seq.gqa/proj"):
         x = rms_norm(h, blk["attn_norm"], eps)
         q = mm(x, blk["q_proj"], cd).reshape(B, T, H, d)
+        if cfg.attn_qk_norm:
+            q = rms_norm(q, blk["q_norm"], eps)
         if cfg.attn_rope:
             q = rope(q, pos, **turn)
         k = mm(x, blk["k_proj"], cd).reshape(B, T, Hkv, d)
+        if cfg.attn_qk_norm:
+            k = rms_norm(k, blk["k_norm"], eps)
         if cfg.attn_rope:
             k = rope(k, pos, **turn)
         v = mm(x, blk["v_proj"], cd).reshape(B, T, Hkv, d)
@@ -835,10 +907,359 @@ def gqa(blk, h, cfg, s_axis, kind: str):
         return mm(attn.reshape(B, T, H * d), blk["o_proj"], cd), tiles
 
 
+def layer_norm(x, g, b, eps):
+    """LayerNorm over the last dim, float32, a gain and a bias."""
+    import jax
+    import jax.numpy as jnp
+
+    x = x.astype(jnp.float32)
+    c = x - x.mean(axis=-1, keepdims=True)
+    return c * jax.lax.rsqrt((c * c).mean(axis=-1, keepdims=True) + eps) * g + b
+
+
+def index_scores(qi, ki, w, t0, cd):
+    """The lightning indexer's scores of a block of queries at positions
+    ``t0 + r``: ``qi [B, n, Hi, di]`` and ``ki [B, T, di]`` (rotated), ``w
+    [B, n, Hi]`` (its weights, ``Hi ** -0.5`` in them) -> ``I [B, n, T]``
+    float32, ``di ** -0.5 sum_j w_j relu(q_j . k)``, ``-inf`` at the keys
+    after the query. Operands in ``cd``, float32 accumulation."""
+    import jax
+    import jax.numpy as jnp
+
+    s = jnp.einsum("bqhd,bkd->bqhk", qi.astype(cd), ki.astype(cd),
+                   preferred_element_type=jnp.float32)
+    scores = (jax.nn.relu(s) * w[..., None]).sum(axis=2) * jnp.float32(
+        qi.shape[-1] ** -0.5)
+    n, T = scores.shape[1:]
+    seen = jnp.arange(T)[None, :] <= (t0 + jnp.arange(n))[:, None]
+    return jnp.where(seen[None], scores, -jnp.inf)
+
+
+def top_keys(scores, k: int):
+    """``(sel, ties)`` of index scores ``[B, n, T]`` (``-inf`` at keys not
+    seen): ``sel`` bool, each row's ``k`` largest seen scores, **ties to the
+    earlier key** (+0.0 and -0.0 are one score), every seen key of a row that
+    sees at most ``k``; ``ties [B, n]`` bool, the rows whose ``k``-th and
+    ``k + 1``-th largest scores are equal. The ``k``-th is found by bisection
+    (:func:`_bisect_kth`), not by ``jax.lax.top_k``, which the TPU compiler
+    makes a whole sort of each row: 0.025 s against 0.106 s for a layer's
+    16,384 rows of 16,384 keys on a v5e (PERF.md section 6)."""
+    import jax.numpy as jnp
+
+    scores = jnp.where(scores == 0, 0.0, scores)  # no -0.0
+    valid = scores > -jnp.inf
+    n_valid = valid.sum(axis=-1)
+    T = scores.shape[-1]
+    if k >= T:
+        return valid, jnp.zeros(n_valid.shape, bool)
+    thr, last = _bisect_kth(scores, valid, k)
+    pos = jnp.arange(T)
+    sel = valid & ((scores > thr[..., None]) | (
+        (scores == thr[..., None]) & (pos <= last[..., None])))
+    sel = jnp.where((n_valid <= k)[..., None], valid, sel)
+    ties = (n_valid > k) & (
+        (valid & (scores >= thr[..., None])).sum(axis=-1) > k)
+    return sel, ties
+
+
+def _bisect_kth(scores, valid, k: int):
+    """``(thr, last)`` of each row: its ``k``-th largest seen score, found a
+    bit at a time over the scores' order-preserving integer bits (the
+    largest ``theta`` with ``k`` keys at or above it), and the position of
+    the last key at that score the selection takes (the ``k - above``-th
+    such key, found a bit at a time over positions)."""
+    import jax
+    import jax.numpy as jnp
+
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    flip = jnp.int32(0x7FFFFFFF)
+    key = jnp.where(bits < 0, bits ^ flip, bits)  # int32 order = score order
+    top = jnp.uint32(0x80000000)
+    u = jnp.where(valid, jax.lax.bitcast_convert_type(key, jnp.uint32) ^ top,
+                  jnp.uint32(0))
+    rows = scores.shape[:-1]
+
+    def value_bit(b, theta):
+        cand = theta | (jnp.uint32(1) << (31 - b).astype(jnp.uint32))
+        return jnp.where((u >= cand[..., None]).sum(axis=-1) >= k, cand, theta)
+
+    theta = jax.lax.fori_loop(0, 32, value_bit, jnp.zeros(rows, jnp.uint32))
+    take = k - (u > theta[..., None]).sum(axis=-1)
+    at = u == theta[..., None]
+    T = scores.shape[-1]
+    pos = jnp.arange(T, dtype=jnp.int32)
+    n_bits = max(T - 1, 1).bit_length()
+
+    def position_bit(b, last):
+        cand = last | (jnp.int32(1) << (n_bits - 1 - b))
+        before = (at & (pos < cand[..., None])).sum(axis=-1)
+        return jnp.where((cand < T) & (before < take), cand, last)
+
+    last = jax.lax.fori_loop(0, n_bits, position_bit,
+                             jnp.zeros(rows, jnp.int32))
+    key = jax.lax.bitcast_convert_type(theta ^ top, jnp.int32)
+    thr = jax.lax.bitcast_convert_type(jnp.where(key < 0, key ^ flip, key),
+                                       jnp.float32)
+    return thr, last
+
+
+def select_keys(qi, ki, w, k: int, bq: int, bk: int, cd):
+    """The layer's selection, a block of ``bq`` queries after another (its
+    scores :data:`INDEX_BLOCK` rows at a time): ``((bits, order, count),
+    selected pairs, tie rows, position sum)`` — ``bits [B, T / bq * W, T]``
+    int32 as ``ring.attention_partial``'s ``select`` takes it, the active
+    key blocks of every query block and their count
+    (``ring.selected_blocks``: a key block is active where some query of the
+    block, in any row, selected a key of it); the position sum, float32, is
+    the selected keys' positions summed over the queries, the selection's
+    checksum (each query's sum exact in int32). No gradient."""
+    import jax
+    import jax.numpy as jnp
+
+    from pio_tpu.parallel.ring import (pack_selection, pick_block,
+                                       select_words, selected_blocks)
+
+    B, T = ki.shape[:2]
+    nq, nk, sub = T // bq, T // bk, pick_block(bq, INDEX_BLOCK)
+
+    def block(i):
+        def part(r):
+            t0 = i * bq + r * sub
+            with jax.named_scope("seq.dsa/index"):
+                scores = index_scores(
+                    jax.lax.dynamic_slice_in_dim(qi, t0, sub, axis=1), ki,
+                    jax.lax.dynamic_slice_in_dim(w, t0, sub, axis=1), t0, cd)
+            with jax.named_scope("seq.dsa/select"):
+                return top_keys(scores, k)
+
+        sel, ties = jax.lax.map(part, jnp.arange(bq // sub))
+        with jax.named_scope("seq.dsa/select"):
+            sel = jnp.moveaxis(sel, 0, 1).reshape(B, bq, T)
+            active = sel.reshape(B, bq, nk, bk).any(axis=(0, 1, 3))
+            pos_sum = jnp.where(sel, jnp.arange(T, dtype=jnp.int32), 0).sum(
+                axis=-1, dtype=jnp.int32)
+            return (pack_selection(sel), active, sel.sum(dtype=jnp.int32),
+                    ties.sum(dtype=jnp.int32),
+                    pos_sum.astype(jnp.float32).sum())
+
+    qi, ki, w = (jax.lax.stop_gradient(a) for a in (qi, ki, w))
+    bits, active, pairs, ties, pos_sum = jax.lax.map(block, jnp.arange(nq))
+    bits = jnp.moveaxis(bits, 0, 1).reshape(B, nq * select_words(bq), T)
+    with jax.named_scope("seq.dsa/select"):
+        order, count = selected_blocks(active)
+    return (bits, order, count), pairs.sum(), ties.sum(), pos_sum.sum()
+
+
+def _head_probs(q, k, lse, sel, order, count, bk: int, scale: float):
+    """The main attention's probabilities summed over the query heads and
+    divided by their number, over the selection: ``q [B, n, H, d]``, ``k [B,
+    T, Hkv, d]`` (what the tiles were given), ``lse [B, n, H]`` (theirs) ->
+    ``[B, n, T]`` float32, 0 off ``sel``; the key blocks ``order[:count]``
+    alone (the others hold no selected key)."""
+    import jax
+    import jax.numpy as jnp
+
+    B, n, H, d = q.shape
+    T, Hkv = k.shape[1], k.shape[2]
+    qg = q.reshape(B, n, Hkv, H // Hkv, d)
+    lg = lse.reshape(B, n, Hkv, H // Hkv, 1)
+
+    def key_block(idx, p):
+        j = order[idx]
+        kj = jax.lax.dynamic_slice_in_dim(k, j * bk, bk, axis=1)
+        s = jnp.einsum("bqhgd,bkhd->bqhgk", qg, kj,
+                       preferred_element_type=jnp.float32) * scale
+        pj = jnp.exp(s - lg).sum(axis=(2, 3)) / H
+        seen = jax.lax.dynamic_slice_in_dim(sel, j * bk, bk, axis=2)
+        return jax.lax.dynamic_update_slice_in_dim(
+            p, jnp.where(seen, pj, 0.0), j * bk, axis=2)
+
+    return jax.lax.fori_loop(0, count, key_block,
+                             jnp.zeros((B, n, T), jnp.float32))
+
+
+def _selection_rows(bits, order, count, r, bq: int, sub: int):
+    """Block ``r`` of ``sub`` queries: its rows of the selection ``[B, sub,
+    T]`` bool, and its query block's active key blocks and their count."""
+    import jax
+
+    from pio_tpu.parallel.ring import select_words, unpack_selection
+
+    i = r * sub // bq
+    w_rows = select_words(bq)
+    words = jax.lax.dynamic_slice_in_dim(bits, i * w_rows, w_rows, axis=1)
+    rows = unpack_selection(words, bq, r * sub - i * bq, sub)
+    return rows, order[i], count[i]
+
+
+def _kl_rows(scores, sel, p):
+    """``(sum over the rows of KL(p || softmax over sel of scores), its
+    gradient in the scores)``."""
+    import jax
+    import jax.numpy as jnp
+
+    z = jax.nn.logsumexp(jnp.where(sel, scores, -jnp.inf), axis=-1)
+    mass = p.sum(axis=-1)
+    plogp = jnp.where(p > 0, p * jnp.log(jnp.where(p > 0, p, 1.0)), 0.0)
+    kl = (plogp.sum(axis=-1) - jnp.where(sel, p * scores, 0.0).sum(axis=-1)
+          + z * mass).sum()
+    grad = jnp.where(sel, mass[..., None] * jnp.exp(scores - z[..., None]) - p,
+                     0.0)
+    return kl, grad
+
+
+@functools.lru_cache(maxsize=16)
+def _index_loss(bq: int, bk: int, sub: int, scale: float, cd):
+    """The indexer's loss of one layer, as :func:`index_loss` computes it,
+    with its own backward pass: forward and backward walk the queries
+    ``sub`` at a time, recompute each block's scores, selection and target
+    from the arguments and keep nothing else, and the gradient reaches the
+    indexer's ``qi``, ``ki`` and ``w`` alone."""
+    import jax
+    import jax.numpy as jnp
+
+    def part(args, r):
+        qi, ki, w, q, k, lse, bits, order, count = args
+        t0 = r * sub
+        rows = lambda a: jax.lax.dynamic_slice_in_dim(a, t0, sub, axis=1)
+        with jax.named_scope("seq.dsa/index"):
+            scores, back = jax.vjp(
+                lambda qi_r, ki, w_r: index_scores(qi_r, ki, w_r, t0, cd),
+                rows(qi), ki, rows(w))
+        with jax.named_scope("seq.dsa/kl"):
+            sel, order_i, count_i = _selection_rows(bits, order, count, r,
+                                                    bq, sub)
+            p = _head_probs(rows(q), k, rows(lse), sel, order_i, count_i, bk,
+                            scale)
+            kl, grad = _kl_rows(scores, sel, p)
+        return kl, grad, back
+
+    @jax.custom_vjp
+    def loss(*args):
+        n = args[0].shape[1] // sub
+        return jax.lax.map(lambda r: part(args, r)[0], jnp.arange(n)).sum()
+
+    def fwd(*args):
+        return loss(*args), args
+
+    def bwd(args, g):
+        qi, ki, w = args[:3]
+        n = qi.shape[1] // sub
+
+        def step(dki, r):
+            _, grad, back = part(args, r)
+            with jax.named_scope("seq.dsa/index"):
+                dqi_r, dki_r, dw_r = back(grad * g)
+            return dki + dki_r, (dqi_r, dw_r)
+
+        dki, (dqi, dw) = jax.lax.scan(
+            step, jnp.zeros(ki.shape, jnp.float32), jnp.arange(n))
+        merge = lambda a: jnp.moveaxis(a, 0, 1).reshape(
+            a.shape[1], n * sub, *a.shape[3:])
+        return (merge(dqi).astype(qi.dtype), dki.astype(ki.dtype),
+                merge(dw).astype(w.dtype)) + (None,) * 6
+
+    loss.defvjp(fwd, bwd)
+    return loss
+
+
+def index_loss(qi, ki, w, q, k, lse, select, bq: int, bk: int, scale: float,
+               cd):
+    """The indexer's loss of one layer, summed over its queries: ``sum_t
+    KL(p_t || softmax over S_t of I[t])``, ``p_t`` the main attention's
+    probabilities over the selection ``S_t`` summed over the query heads
+    over their number (``q [B, T, H, d]``, ``k [B, T, Hkv, d]``, ``lse [B,
+    T, H]``: what the tiles were given and gave back), ``I`` the index
+    scores of ``qi``, ``ki``, ``w``. Only those three take a gradient;
+    ``select`` is ``(bits, order, count)`` (:func:`select_keys`)."""
+    import jax
+
+    from pio_tpu.parallel.ring import pick_block
+
+    sub = pick_block(bq, INDEX_BLOCK)
+    q, k, lse = (jax.lax.stop_gradient(a) for a in (q, k, lse))
+    return _index_loss(bq, bk, sub, float(scale), cd)(qi, ki, w, q, k, lse,
+                                                      *select)
+
+
+def dsa(blk, h, cfg):
+    """A sparse layer on ``[B, T, D]`` (DeepSeek Sparse Attention's form)
+    -> ``(attention's output before the residual, counters)``. With ``x``
+    the normed input: ``q = RoPE(norm(x W_q))``, ``k = RoPE(norm(x W_k))``
+    (the per-head norm where ``attn_qk_norm``), ``v = x W_v``; the indexer
+    reads ``x`` detached: ``qi = RoPE_half(x W_qi)`` (``index_heads`` heads
+    of ``index_head_dim``, the first half of each rotated), ``ki =
+    RoPE_half(LayerNorm(x W_ki))`` (one head), ``w = x W_w / sqrt(
+    index_heads)``, ``I[t, s] = index_head_dim ** -0.5 sum_j w[t, j]
+    relu(qi[t, j] . ki[s])`` for ``s <= t``; ``S_t`` the ``index_topk``
+    keys of largest ``I[t]`` (:func:`top_keys`: ties to the earlier key),
+    one selection for every head; the heads attend over ``S_t`` alone
+    (``ring.attention_partial`` with the selection, key blocks no query of a
+    block selected skipped); ``o W_o``. Counters: ``dsa`` ``[selected
+    pairs, key blocks the attention's loops ran, those a causal loop runs,
+    rows tied at the ``k``-th score]``, ``index_kl`` (:func:`index_loss`,
+    summed over the layer's queries), ``l_select`` (the selected keys'
+    positions summed over the queries: :func:`select_keys`)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.ad_checkpoint import checkpoint_name
+
+    from pio_tpu.parallel.ring import (attention_partial, fold_groups,
+                                       pick_block, unfold_groups)
+
+    cd, eps = _dtype(cfg), cfg.norm_eps
+    B, T, _ = h.shape
+    H, Hkv, d = cfg.heads_full, cfg.kv_heads, cfg.head_dim
+    Hi, di = cfg.index_heads, cfg.index_head_dim
+    pos = jnp.arange(T)
+    turn = dict(theta=cfg.rope_theta, rotary_dim=cfg.rotary_dim)
+    with jax.named_scope("seq.gqa/proj"):
+        x = rms_norm(h, blk["attn_norm"], eps)
+        q = mm(x, blk["q_proj"], cd).reshape(B, T, H, d)
+        k = mm(x, blk["k_proj"], cd).reshape(B, T, Hkv, d)
+        if cfg.attn_qk_norm:
+            q = rms_norm(q, blk["q_norm"], eps)
+            k = rms_norm(k, blk["k_norm"], eps)
+        q, k = rope(q, pos, **turn).astype(cd), rope(k, pos, **turn).astype(cd)
+        v = mm(x, blk["v_proj"], cd).reshape(B, T, Hkv, d).astype(cd)
+    with jax.named_scope("seq.dsa/index"):
+        xi = jax.lax.stop_gradient(x)
+        qi = rope(mm(xi, blk["idx_q"], cd).reshape(B, T, Hi, di), pos,
+                  cfg.rope_theta, di // 2).astype(cd)
+        ki = layer_norm(mm(xi, blk["idx_k"], cd), blk["idx_k_norm_g"],
+                        blk["idx_k_norm_b"], eps)
+        ki = rope(ki[:, :, None], pos, cfg.rope_theta, di // 2)[:, :, 0]
+        ki = ki.astype(cd)
+        wi = mm(xi, blk["idx_w"], cd) * jnp.float32(Hi ** -0.5)
+    blk_size = pick_block(T, ATTN_BLOCK)
+    group = H // Hkv
+    select, pairs, ties, pos_sum = select_keys(qi, ki, wi, cfg.index_topk,
+                                               blk_size, blk_size, cd)
+    select = tuple(checkpoint_name(a, SELECTION) for a in select)
+    scale = cfg.attn_scale or d ** -0.5
+    with jax.named_scope("seq.gqa/attn/sparse"):
+        o, lse, tiles = attention_partial(
+            fold_groups(q, blk_size, group), k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3), jnp.int32(0), jnp.int32(0), True, scale,
+            blk_size, blk_size, 0, group, select)
+        o = unfold_groups(o, blk_size, group).astype(cd)
+        lse = unfold_groups(lse, blk_size, group)
+    kl = index_loss(qi, ki, wi, q, k, lse, select, blk_size, blk_size, scale,
+                    cd)
+    with jax.named_scope("seq.gqa/proj"):
+        out = mm(o.reshape(B, T, H * d), blk["o_proj"], cd)
+    counters = jnp.stack([pairs, tiles[0], tiles[1], ties]).astype(jnp.float32)
+    return out, {"dsa": counters, "index_kl": kl, "l_select": pos_sum}
+
+
 def attend(blk, h, cfg, s_axis, kind):
     """``(attention's output, its counters)`` of the configured attention
     in a layer of the stack ``kind``: the gqa/moe block counts ``tiles``,
-    the mla/moe block nothing."""
+    a sparse layer ``dsa`` (:func:`dsa`'s four counters), ``index_kl``
+    (its indexer's loss summed over its queries) and ``l_select`` (its
+    selection's checksum), the mla/moe block nothing."""
+    if kind == "sparse":
+        return dsa(blk, h, cfg)
     if cfg.attention_kind == "gqa":
         out, tiles = gqa(blk, h, cfg, s_axis, kind)
         return out, {"tiles": tiles}
@@ -923,7 +1344,8 @@ def experts_impl(platform: str, cfg) -> str:
 
 def attn_impls(platform: str, cfg, t_local: int) -> Dict[str, str]:
     """What runs the attention tiles of each kind of attention layer the
-    block has (``mla``; the gqa block's ``full`` and ``window``), over rows
+    block has (``mla``; the gqa block's ``full``, ``window`` and
+    ``sparse``), over rows
     of ``t_local`` events: ``ring.attention_impl``'s answer at the shapes
     :func:`mla` and :func:`gqa` hand it."""
     from pio_tpu.parallel.ring import attention_impl, pick_block
@@ -937,7 +1359,7 @@ def attn_impls(platform: str, cfg, t_local: int) -> Dict[str, str]:
         widths = {kind: (cfg.head_dim, cfg.head_dim)
                   for kind in sorted(set(cfg.layer_pattern))}
     return {kind: attention_impl(platform, _dtype(cfg), d_k, d_v, blk, blk,
-                                 True, t_local)
+                                 True, t_local, kind == "sparse")
             for kind, (d_k, d_v) in widths.items()}
 
 
@@ -1109,7 +1531,8 @@ def routed_experts(blk, x, idx, gate, cfg, first, held: int):
 def moe(blk, x, cfg, m_axis):
     """Expert feed-forward of the normalised ``x [B, T, D]``: the held
     routed experts' sum (closed over ``m_axis`` when experts shard there)
-    plus the shared expert. Returns ``(y, counters)``."""
+    plus the shared expert where there is one. Returns ``(y,
+    counters)``."""
     import jax
     import jax.numpy as jnp
 
@@ -1124,12 +1547,14 @@ def moe(blk, x, cfg, m_axis):
     y, counters = routed_experts(blk, flat, idx, gate, cfg, first, held)
     if m_axis is not None:
         y, counters = jax.lax.psum((y, counters), m_axis)
-    with jax.named_scope("seq.ffn"):
-        if cfg.expert_act == "swiglu":
-            y = y + swiglu(flat, blk["s_gate"], blk["s_up"], blk["s_down"],
-                           _dtype(cfg))
-        else:
-            y = y + relu2_mlp(flat, blk["s_up"], blk["s_down"], _dtype(cfg))
+    if "s_up" in blk:  # a shared expert
+        with jax.named_scope("seq.ffn"):
+            if cfg.expert_act == "swiglu":
+                y = y + swiglu(flat, blk["s_gate"], blk["s_up"],
+                               blk["s_down"], _dtype(cfg))
+            else:
+                y = y + relu2_mlp(flat, blk["s_up"], blk["s_down"],
+                                  _dtype(cfg))
     counters = {"load": load, **jax.tree.map(
         lambda a: a.astype(jnp.float32), counters)}
     return y.reshape(B, T, D), counters

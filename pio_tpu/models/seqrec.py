@@ -34,6 +34,7 @@ from typing import NamedTuple, Optional, Tuple
 import numpy as np
 
 from pio_tpu.models.seq_layers import (
+    SELECTION,
     TOKEN_CHUNK,
     attn_impls,
     check_block,
@@ -95,7 +96,7 @@ class SeqRecConfig:
     #: "mha" (learned positions, full heads of d_model / n_heads), "mla"
     #: (multi-head latent attention, RoPE on a shared rope key) or "gqa"
     #: (grouped queries over ``kv_heads``, layers of the kinds in
-    #: ``layer_pattern``, a sigmoid gate a query head)
+    #: ``layer_pattern``, a sigmoid gate a query head where ``attn_gate``)
     attention_kind: str = "mha"
     #: "relu" (one biased two-matmul FFN of width ``ffn``) or "moe"
     #: (``dense_layers`` SwiGLU layers of width ``ffn``, then expert layers)
@@ -131,11 +132,13 @@ class SeqRecConfig:
     # -- the gqa block. Layer ``i`` is of kind ``layer_pattern[i mod its
     # -- length]``: "full" (causal; ``heads_full`` query heads; RoPE of
     # -- ``rope_theta`` on the first ``rotary_dim`` dims of a head, 0 = all,
-    # -- with YaRN's frequencies and factor where ``yarn_factor`` > 1) or
+    # -- with YaRN's frequencies and factor where ``yarn_factor`` > 1),
     # -- "window" (the last ``window`` keys, the query's own included;
     # -- ``heads_window`` query heads; RoPE of ``window_rope_theta`` on the
-    # -- whole head). ``kv_heads`` and both head counts are the heads held
-    # -- here: with attention divided over chips by KV head, a chip's share.
+    # -- whole head) or "sparse" (a full layer's heads and RoPE over the keys
+    # -- its indexer selects, below). ``kv_heads`` and both head counts are
+    # -- the heads held here: with attention divided over chips by KV head, a
+    # -- chip's share.
     layer_pattern: Tuple[str, ...] = ("full",)
     head_dim: int = 128
     kv_heads: int = 8
@@ -154,6 +157,20 @@ class SeqRecConfig:
     #: no gate (and no ``g_proj``)
     attn_rope: bool = True
     attn_gate: bool = True
+    #: per-head RMSNorm (a gain of ``head_dim``, ``norm_eps``) on ``q`` and
+    #: ``k`` before RoPE, in every gqa layer
+    attn_qk_norm: bool = False
+    # -- a "sparse" layer of the gqa block (DeepSeek Sparse Attention's
+    # -- form): a lightning indexer of ``index_heads`` heads of
+    # -- ``index_head_dim`` over one shared key scores every earlier key;
+    # -- each query attends to the ``index_topk`` keys it scores highest
+    # -- (ties to the earlier key), the selection shared by every head; the
+    # -- indexer learns from its own KL loss alone (``INDEX_LOSS_WEIGHT``),
+    # -- against the main attention's head-summed distribution over the
+    # -- selection.
+    index_heads: int = 16
+    index_head_dim: int = 64
+    index_topk: int = 2048
     #: the experts and the shared expert: "swiglu" (three matrices,
     #: ``W_down(silu(W_gate x) * W_up x)``) or "relu2" (two,
     #: ``W_down relu(W_up x)^2``)
@@ -220,7 +237,12 @@ class SeqRecModel:
     #: ``load_max_over_mean`` over all experts, ``bias_max`` (a router with
     #: a selection bias), ``window_tiles``/``causal_tiles`` (the gqa block:
     #: score tiles its window layers visited, and what causal layers of
-    #: their length visit), ``ssm_chunks``/``ssm_head_blocks``/
+    #: their length visit), ``l_index``, ``l_select`` and ``DSA_COUNTERS``
+    #: (sparse layers: the indexers' loss, the selected keys' positions
+    #: summed over the layers and queries, a checksum of the selection that
+    #: the benchmark compares, the (query, key) pairs selected, the key blocks
+    #: the attention's loops ran and those causal loops run, the rows tied
+    #: at the ``index_topk``-th score), ``ssm_chunks``/``ssm_head_blocks``/
     #: ``ssm_state_absmax`` (mamba layers: the chunks their carrying loops
     #: ran, the turns of their scans' maps, the largest carried state). A
     #: block without an expert layer holds the expert columns ``[steps, 0]``
@@ -446,9 +468,14 @@ def _latent_trunk(params, seqs, cfg, m_axis, s_axis):
     # at once (1.1 GB of the v5e's 16 at the published widths)
     # (inside the checkpoint, so that the backward pass's recomputation is
     # held the same way)
+    # a sparse layer's selection (32 MB of bits a layer at 16k) is kept for
+    # the backward pass rather than found again: its top-k is no gradient's
     def layer(fn, kind):
+        policy = (jax.checkpoint_policies.save_only_these_names(SELECTION)
+                  if kind == "sparse" else None)
         return jax.checkpoint(lambda blk, h: fn(
-            jax.lax.optimization_barrier(blk), h, cfg, m_axis, s_axis, kind))
+            jax.lax.optimization_barrier(blk), h, cfg, m_axis, s_axis, kind),
+            policy=policy)
 
     if cfg.mixer_pattern:
         return _mixer_layers(params, h, cfg, layer)
@@ -486,9 +513,10 @@ def _latent_trunk(params, seqs, cfg, m_axis, s_axis):
         h, counters = jax.lax.scan(period, h, stacks)
         counters = jax.tree.map(  # [periods, layers a period, ..] -> [layers, ..]
             lambda a: a.reshape(-1, *a.shape[2:]), counters)
-    if "tiles" in counters:  # one sum over every layer, the dense ones too
-        counters["tiles"] = sum(
-            c["tiles"].sum(axis=0) for c in (dense, counters) if "tiles" in c)
+    for name in ("tiles", "dsa", "index_kl", "l_select"):
+        if name in counters:  # one sum over every layer, the dense ones too
+            counters[name] = sum(
+                c[name].sum(axis=0) for c in (dense, counters) if name in c)
     return h, counters
 
 
@@ -581,8 +609,10 @@ def _chunked_ce(h, norm_g, head, targets, mask, cfg, m_axis):
 
 def _latent_loss_sums(params, batch, cfg, m_axis, s_axis):
     """Local sums of one batch through the mla/moe model: ``{"ce", "den",
-    "ce2", "den2"}`` and the expert layers' counters (the MTP module's
-    layer last). Callers psum over data/seq and divide."""
+    "ce2", "den2"}`` (``"kl", "kl_den"``: the sparse layers' indexer loss
+    and the positions it is summed over) and the expert layers' counters
+    (the MTP module's layer last). Callers psum over data/seq and
+    divide."""
     import jax
     import jax.numpy as jnp
 
@@ -592,6 +622,9 @@ def _latent_loss_sums(params, batch, cfg, m_axis, s_axis):
         h, params["lnf_g"], _head_table(params, cfg), targets, mask, cfg,
         m_axis)
     sums = {"ce": ce, "den": den}
+    if "index_kl" in counters:  # the sparse layers' indexers, every position
+        sums["kl"] = counters.pop("index_kl")
+        sums["kl_den"] = jnp.float32(seqs.size)
     if cfg.mtp_depth:
         with jax.named_scope("seq.mtp"):
             h2, c2 = _mtp_hidden(params, h, targets, cfg, m_axis, s_axis)
@@ -613,6 +646,9 @@ def _latent_loss(sums, counters, cfg):
     if cfg.mtp_depth:
         aux["l_mtp"] = sums["ce2"] / jnp.maximum(sums["den2"], 1.0)
         loss = loss + cfg.mtp_weight * aux["l_mtp"]
+    if "kl" in sums:  # summed over the layers, averaged over the positions
+        aux["l_index"] = sums["kl"] / jnp.maximum(sums["kl_den"], 1.0)
+        loss = loss + INDEX_LOSS_WEIGHT * aux["l_index"]
     return loss, aux
 
 
@@ -820,7 +856,9 @@ def train_seqrec(
             passes of their grouped matmuls that ran and the rows those
             staged (``moe_passes``, ``moe_staged_rows``), the
             largest selection bias (a router that has one), the gqa
-            block's ``window_tiles`` and ``causal_tiles`` and the mamba
+            block's ``window_tiles`` and ``causal_tiles``, its sparse
+            layers' ``selected_pairs``, ``sparse_key_blocks``,
+            ``causal_key_blocks`` and ``topk_boundary_ties``, and the mamba
             layers' ``ssm_chunks``, ``ssm_head_blocks`` (the turns of
             their scans' maps) and ``ssm_state_absmax`` (the same counters
             stand in ``/train.json``); a moe block
@@ -828,7 +866,8 @@ def train_seqrec(
             (``experts_impl``: ``seq_layers.experts_impl``; ``none`` for a
             block of single mixers without an expert layer) and what ran
             the attention tiles of each kind of its attention layers
-            (``attn_impl``: ``{"mla"}`` or ``{"full", "window"}`` ->
+            (``attn_impl``: ``{"mla"}`` or ``{"full", "window",
+            "sparse"}`` ->
             ``pallas`` / ``xla``, ``ring.attention_impl``) and the chunks of
             its Mamba-2 mixers (``ssm_impl``: ``pallas`` / ``xla``,
             ``seq_layers.ssd_impl``; ``none`` without a mamba layer); both
@@ -1217,13 +1256,22 @@ def _counters(trace: dict) -> dict:
         "moe_staged_rows": float(trace["staged"].sum()),
     }
     for name in ("window_tiles", "causal_tiles", "ssm_chunks",
-                 "ssm_head_blocks"):
+                 "ssm_head_blocks") + DSA_COUNTERS:
         if name in trace:
             out[name] = float(trace[name].sum())
     for name in ("load_max_over_mean", "bias_max", "ssm_state_absmax"):
         if name in trace and trace[name].size:
             out[name] = float(trace[name].max())
     return out
+
+
+#: the indexer loss's weight in the step's loss (DeepSeek-V3.2-Exp's sparse
+#: stage: the two losses share no parameter, so it scales the indexer's
+#: gradient alone)
+INDEX_LOSS_WEIGHT = 1.0
+#: a sparse layer's counters (``seq_layers.dsa``), summed over its layers
+DSA_COUNTERS = ("selected_pairs", "sparse_key_blocks", "causal_key_blocks",
+                "topk_boundary_ties")
 
 
 def _after_step(params, aux, cfg):
@@ -1238,6 +1286,8 @@ def _after_step(params, aux, cfg):
     aux = dict(aux)
     if "tiles" in aux:
         aux["window_tiles"], aux["causal_tiles"] = aux.pop("tiles")
+    if "dsa" in aux:
+        aux.update(zip(DSA_COUNTERS, aux.pop("dsa")))
     main = "moe" if cfg.mixer_pattern else "blocks"
     if cfg.router_kind == "sigmoid_bias" and main in params:
         n_main = params[main]["router_b"].shape[0]

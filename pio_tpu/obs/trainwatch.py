@@ -226,7 +226,8 @@ class StepRecorder:
     def set_counters(self, counters: Dict[str, float]) -> None:
         """What the algorithm's program counted over its steps (the
         sequence template: routed and dropped pairs, the expert passes, the
-        attention tiles, a Mamba-2 layer's ``ssm_chunks``,
+        attention tiles, a sparse layer's selected pairs, key blocks and
+        tied rows, a Mamba-2 layer's ``ssm_chunks``,
         ``ssm_head_blocks`` and ``ssm_state_absmax``), known once the
         trained state is read back."""
         with self._lock:
@@ -523,7 +524,8 @@ def run_record(*, run_id: str, engine_id: str, status: str,
     ``cholesky`` / ``lu``), ``gather_impl`` (``{"user", "item"}``:
     ``packed`` / ``plain``) and ``accum_impl`` (``{"user", "item"}``:
     ``fused`` / ``xla``) are lifted beside them, and so is a sequence run's
-    ``attn_impl`` (``{"mla"}`` or ``{"full", "window"}``: ``pallas`` /
+    ``attn_impl`` (``{"mla"}`` or ``{"full", "window", "sparse"}``:
+    ``pallas`` /
     ``xla``) and ``ssm_impl`` (``pallas`` / ``xla`` / ``none``). ``xla`` is
     the process's
     :func:`pio_tpu.obs.devicewatch.xla_totals` at the run's end: what JAX's

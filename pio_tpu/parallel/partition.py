@@ -228,14 +228,15 @@ def _seqrec_rules():
         # by expert over model ([layer, expert, in, out]); attention, the
         # router, the shared expert, the dense layers and the MTP module's
         # own leaves are held whole
-        (r"(blocks|mtp|window|full|moe)/e_(gate|up|down)$", P(None, "model")),
+        (r"(blocks|mtp|window|full|sparse|moe)/e_(gate|up|down)$",
+         P(None, "model")),
         (r"blocks/(attn_norm|q_a|q_norm|q_b|kv_a|kv_norm|kv_b|o_proj|ffn_norm|"
          r"router_w|router_b|s_gate|s_up|s_down)$", P()),
         # the gqa/moe block's stacks by kind of layer: as above, experts by
         # expert and the rest whole (its heads are not divided over model);
         # the stacks of layers that are one mixer alone likewise. A tied
         # table is ``emb`` alone: the same row shard serves lookup and logits
-        (r"(dense|mtp|window|full|mamba|moe|attn|mlp)/", P()),
+        (r"(dense|mtp|window|full|sparse|mamba|moe|attn|mlp)/", P()),
         (r"blocks/", P("pipe", None)),
         (r"(emb|head)$", P("model", None)),
         (r"(pos|lnf_g|lnf_b)$", P()),

@@ -27,6 +27,10 @@ Design (blockwise / ring formulation):
 - Grouped queries: ``k``/``v`` may have fewer heads than ``q``; the query
   heads of a KV head are folded into the query rows of one tile
   (:func:`fold_groups`), so nothing is repeated.
+- A learned selection of keys (:func:`attention_partial`'s ``select``): each
+  query sees the keys a bit mask names (:func:`pack_selection`), and a query
+  block's loop runs over the key blocks in which some query of it selected a
+  key, in order; the others are skipped, not masked.
 
 Inside ``jit`` with a sharded mesh this function must be wrapped in
 ``shard_map`` over the ``seq`` axis (see :func:`ring_attention_sharded`);
@@ -47,6 +51,8 @@ _NEG_BIG = -1e30
 #: key/query block edge of the blocked update; the score tile is
 #: ``[B, H, block, block]`` float32 whatever the row's length
 DEFAULT_BLOCK = 512
+#: query rows one word of a selection mask holds (:func:`pack_selection`)
+SELECT_BITS = 32
 
 
 def pick_block(t: int, block: int) -> int:
@@ -58,23 +64,67 @@ def pick_block(t: int, block: int) -> int:
 
 
 def attention_impl(platform: str, dtype, d_k: int, d_v: int, bq: int, bk: int,
-                   causal: bool, key_rows: int = 0) -> str:
+                   causal: bool, key_rows: int = 0, selected: bool = False
+                   ) -> str:
     """What runs :func:`attention_partial`'s tiles, from what is visible at
     trace time: ``pallas`` / ``xla``. ``pallas`` is the pair of kernels of
     :mod:`pio_tpu.parallel.ring_kernel` (a tile's scores, softmax and
     accumulators in VMEM): on a TPU, for causal attention with bfloat16
     operands, head widths and blocks that are multiples of the 128 lanes,
     and ``key_rows`` keys whose ``k`` and ``v`` of one head fit VMEM
-    (``ring_kernel.fits``). ``xla`` is the ``fori_loop`` of this module:
-    everywhere else (every CPU run, float32 operands, a head width of 64,
-    non-causal calls), and the kernels' oracle."""
+    (``ring_kernel.fits``); under a ``selected`` mask also a query block
+    whose words of the mask are whole sublane tiles (:func:`select_words`
+    a power of two, at least 8). ``xla`` is the ``fori_loop`` of this
+    module: everywhere else (every CPU run, float32 operands, a head width
+    of 64, non-causal calls), and the kernels' oracle."""
     from pio_tpu.parallel.ring_kernel import fits
 
     dtype = jnp.dtype(dtype)
     tiles = (causal and dtype == jnp.bfloat16
              and all(x % 128 == 0 for x in (d_k, d_v, bq, bk))
              and fits(key_rows, d_k, d_v, dtype.itemsize))
+    if selected:
+        words = select_words(bq)
+        tiles = tiles and words >= 8 and words & (words - 1) == 0
     return "pallas" if platform == "tpu" and tiles else "xla"
+
+
+def select_words(bq: int) -> int:
+    """Rows of int32 words that hold the selection of a block of ``bq``
+    queries (:func:`pack_selection`)."""
+    return -(-bq // SELECT_BITS)
+
+
+def pack_selection(sel):
+    """A query block's selection ``sel [B, bq, Tk]`` (bool: query ``r`` of
+    the block sees key ``s`` where set) -> ``bits [B, W, Tk]`` int32. Row
+    ``r`` is bit ``r // W`` of word row ``r mod W`` (``W =
+    select_words(bq)``), so that a tile's rows unpack as whole copies of its
+    ``W`` word rows (:func:`unpack_selection`)."""
+    b, bq, tk = sel.shape
+    w = select_words(bq)
+    sel = jnp.pad(sel, ((0, 0), (0, SELECT_BITS * w - bq), (0, 0)))
+    planes = sel.reshape(b, SELECT_BITS, w, tk).astype(jnp.int32)
+    shifts = jnp.arange(SELECT_BITS, dtype=jnp.int32)[None, :, None, None]
+    return (planes << shifts).sum(axis=1)  # disjoint bits: a sum is an or
+
+
+def unpack_selection(bits, bq: int, first=0, rows: int = 0):
+    """The inverse of :func:`pack_selection`: one query block's ``bits [B,
+    W, n]`` -> its rows ``first .. first + rows`` (all ``bq`` by default),
+    ``[B, rows, n]`` bool."""
+    w = bits.shape[1]
+    r = first + jnp.arange(rows or bq, dtype=jnp.int32)
+    words = jnp.take(bits, r % w, axis=1)  # row r: bit r // W of word r mod W
+    return ((words >> (r // w)[None, :, None]) & 1) == 1
+
+
+def selected_blocks(active):
+    """``active [nq, nk]`` (whether some query of block ``i`` selected a key
+    of block ``j``) -> ``(order [nq, nk], count [nq])`` int32: block ``i``'s
+    loop runs over ``order[i, :count[i]]``, its active key blocks in order."""
+    order = jnp.argsort(~active, axis=1, stable=True).astype(jnp.int32)
+    return order, active.sum(axis=1).astype(jnp.int32)
 
 
 def needed_key_blocks(i, q_off, k_off, bq: int, bk: int, nk: int, causal: bool):
@@ -119,8 +169,19 @@ def _row_positions(bq: int, group: int):
     return pos if group == 1 else jnp.tile(pos, group)
 
 
+def _tile_selection(bits, i, j, bq: int, bk: int, group: int):
+    """The selection of tile ``(i, j)`` as :func:`_scores` masks: ``[B, 1,
+    group * bq, bk]`` bool, the ``group`` heads' rows alike."""
+    w = select_words(bq)
+    words = jax.lax.dynamic_slice_in_dim(
+        jax.lax.dynamic_slice_in_dim(bits, i * w, w, axis=1), j * bk, bk,
+        axis=2)
+    seen = unpack_selection(words, bq)
+    return jnp.tile(seen, (1, group, 1))[:, None]
+
+
 def _partial_fwd(q, k, v, q_off, k_off, causal, scale, bq, bk, window=0,
-                 group=1):
+                 group=1, select=None):
     """Blocked online softmax of ``[B, H, T, D]`` operands: ``(o, lse,
     tiles)``, ``o`` float32 and normalised over the keys given here."""
     b, h, rows_q, _ = q.shape
@@ -134,12 +195,17 @@ def _partial_fwd(q, k, v, q_off, k_off, causal, scale, bq, bk, window=0,
 
         def body(j, carry):
             o, m, l = carry
+            if select is not None:
+                j = select[1][i, j]  # the j-th active key block
             kj = jax.lax.dynamic_slice_in_dim(k, j * bk, bk, axis=2)
             vj = jax.lax.dynamic_slice_in_dim(v, j * bk, bk, axis=2)
             s, mask = _scores(
-                qi, kj, q_pos, k_off + j * bk + jnp.arange(bk), causal, scale,
-                window,
+                qi, kj, q_pos, k_off + j * bk + jnp.arange(bk),
+                causal and select is None, scale, window,
             )
+            if select is not None:
+                mask = _tile_selection(select[0], i, j, bq, bk, group)
+                s = jnp.where(mask, s, _NEG_BIG)
             m_new = jnp.maximum(m, s.max(axis=-1))
             p = jnp.exp(s - m_new[..., None])
             if causal:
@@ -159,10 +225,16 @@ def _partial_fwd(q, k, v, q_off, k_off, causal, scale, bq, bk, window=0,
         )
         n = needed_key_blocks(i, q_off, k_off, bq, bk, nk, causal)
         first = first_key_block(i, q_off, k_off, bq, bk, nk, window)
+        if select is not None:
+            # the selected blocks, and the causal loop's for the counter
+            first, n, causal_n = 0, select[2][i], n
         o, m, l = jax.lax.fori_loop(first, n, body, init)
         safe = jnp.maximum(l, 1e-30)
         # the loop's own bounds: the tiles it ran, and those from block 0
-        ran = jnp.stack([jnp.maximum(n - first, 0), n]).astype(jnp.int32)
+        if select is not None:
+            ran = jnp.stack([n, causal_n]).astype(jnp.int32)
+        else:
+            ran = jnp.stack([jnp.maximum(n - first, 0), n]).astype(jnp.int32)
         return (o / safe[..., None],
                 jnp.where(l > 0, m + jnp.log(safe), _NEG_BIG), ran)
 
@@ -187,34 +259,41 @@ def _loop_bounds(q, k, q_off, k_off, causal, bq: int, bk: int, window: int,
 
 
 def _kernel_fwd(q, k, v, q_off, k_off, causal, scale, bq, bk, window, group,
-                interpret):
+                select, interpret):
     """:func:`_partial_fwd` on the kernels: the same ``(o, lse, tiles)``,
     ``tiles`` from the very bounds handed to the kernel's loop."""
     from pio_tpu.parallel import ring_kernel
 
     first, n, offs = _loop_bounds(q, k, q_off, k_off, causal, bq, bk, window,
                                   group)
+    if select is not None:
+        bits, order, count = select
+        o, lse = ring_kernel.forward(q, k, v, order.reshape(-1), count, offs,
+                                     scale, bq, bk, window, group, interpret,
+                                     bits)
+        ran = jnp.stack([count.sum(), n.sum()])
+        return o, lse, ran.astype(jnp.int32)
     o, lse = ring_kernel.forward(q, k, v, first, n, offs, scale, bq, bk,
                                  window, group, interpret)
     ran = jnp.stack([jnp.maximum(n - first, 0).sum(), n.sum()])
     return o, lse, ran.astype(jnp.int32)
 
 
-def _forward(impl: str, *args):
+def _forward(impl: str, *args, select=None):
     if impl == "xla":
-        return _partial_fwd(*args)
-    return _kernel_fwd(*args, impl == "pallas_interpret")
+        return _partial_fwd(*args, select=select)
+    return _kernel_fwd(*args, select, impl == "pallas_interpret")
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _attention(q, k, v, q_off, k_off, causal, scale, bq, bk, window, group,
-               impl):
+               impl, select=None):
     return _forward(impl, q, k, v, q_off, k_off, causal, scale, bq, bk,
-                    window, group)
+                    window, group, select=select)
 
 
 def attention_partial(q, k, v, q_off, k_off, causal, scale, bq, bk, window=0,
-                      group=1):
+                      group=1, select=None):
     """Exact attention of ``q`` over the keys given, in ``bq x bk`` tiles.
 
     ``q`` ``[B, H, Tq, Dk]``, ``k`` ``[B, H, Tk, Dk]``, ``v`` ``[B, H, Tk,
@@ -239,6 +318,14 @@ def attention_partial(q, k, v, q_off, k_off, causal, scale, bq, bk, window=0,
     other, so that one tile's matmuls serve the whole group and ``k``/``v``
     are read once a KV head; ``o`` and ``lse`` come back in the same order.
 
+    ``select`` ``(bits, order, count)`` restricts query ``t`` to the keys
+    its own row of ``bits [B, nq * W, Tk]`` names (one query block's ``W``
+    word rows after another, :func:`pack_selection`; every head alike; a
+    selection lies within the causal keys): query block ``i``'s loop runs
+    over the key blocks ``order[i, :count[i]]`` (:func:`selected_blocks`)
+    and skips the others, forward and backward; ``tiles`` then counts those
+    and the causal loop's. The window and the causal bounds are not read.
+
     What runs the tiles is :func:`attention_impl`'s choice: XLA's loops
     here, or on a TPU the two kernels of :mod:`pio_tpu.parallel.ring_kernel`.
     The arithmetic is the same either way (operands in the dtype given to
@@ -248,16 +335,17 @@ def attention_partial(q, k, v, q_off, k_off, causal, scale, bq, bk, window=0,
     runs there without its mask.
     """
     impl = attention_impl(jax.default_backend(), q.dtype, q.shape[-1],
-                          v.shape[-1], bq, bk, causal, k.shape[2])
+                          v.shape[-1], bq, bk, causal, k.shape[2],
+                          select is not None)
     return _attention(q, k, v, q_off, k_off, causal, scale, bq, bk, window,
-                      group, impl)
+                      group, impl, select)
 
 
 def _attention_fwd(q, k, v, q_off, k_off, causal, scale, bq, bk, window,
-                   group, impl):
+                   group, impl, select=None):
     o, lse, tiles = _forward(impl, q, k, v, q_off, k_off, causal, scale, bq,
-                             bk, window, group)
-    return (o, lse, tiles), (q, k, v, q_off, k_off, o, lse)
+                             bk, window, group, select=select)
+    return (o, lse, tiles), (q, k, v, q_off, k_off, o, lse, select)
 
 
 def _attention_bwd(causal, scale, bq, bk, window, group, impl, res, cts):
@@ -266,20 +354,27 @@ def _attention_bwd(causal, scale, bq, bk, window, group, impl, res, cts):
                                       res, cts)
     from pio_tpu.parallel import ring_kernel
 
-    q, k, v, q_off, k_off, o, lse = res
+    q, k, v, q_off, k_off, o, lse, select = res
     do, dlse, _ = cts
     # d s_ij = p_ij (dp_ij - delta_i + dlse_i): one pass outside the kernel
     g = dlse - (do * o).sum(axis=-1)
     first, n, offs = _loop_bounds(q, k, q_off, k_off, causal, bq, bk, window,
                                   group)
+    if select is not None:
+        bits, order, count = select
+        dq, dk, dv = ring_kernel.backward(
+            q, k, v, do.astype(q.dtype), lse, g, order.reshape(-1), count,
+            offs, scale, bq, bk, window, group, impl == "pallas_interpret",
+            bits)
+        return dq, dk, dv, None, None, None
     dq, dk, dv = ring_kernel.backward(
         q, k, v, do.astype(q.dtype), lse, g, first, n, offs, scale, bq, bk,
         window, group, impl == "pallas_interpret")
-    return dq, dk, dv, None, None
+    return dq, dk, dv, None, None, None
 
 
 def _attention_partial_bwd(causal, scale, bq, bk, window, group, res, cts):
-    q, k, v, q_off, k_off, o, lse = res
+    q, k, v, q_off, k_off, o, lse, select = res
     do, dlse, _ = cts  # the tile count is an integer: it has no cotangent
     b, h, rows_q, dk_ = q.shape
     tk, dv_ = k.shape[2], v.shape[3]
@@ -299,12 +394,17 @@ def _attention_partial_bwd(causal, scale, bq, bk, window, group, res, cts):
 
         def body(j, carry):
             dqi, dk, dv = carry
+            if select is not None:
+                j = select[1][i, j]
             kj = jax.lax.dynamic_slice_in_dim(k, j * bk, bk, axis=2)
             vj = jax.lax.dynamic_slice_in_dim(v, j * bk, bk, axis=2)
             s, mask = _scores(
-                qi, kj, q_pos, k_off + j * bk + jnp.arange(bk), causal, scale,
-                window,
+                qi, kj, q_pos, k_off + j * bk + jnp.arange(bk),
+                causal and select is None, scale, window,
             )
+            if select is not None:
+                mask = _tile_selection(select[0], i, j, bq, bk, group)
+                s = jnp.where(mask, s, _NEG_BIG)
             p = jnp.exp(s - lsei[..., None])
             if causal:
                 p = jnp.where(mask, p, 0.0)
@@ -333,6 +433,8 @@ def _attention_partial_bwd(causal, scale, bq, bk, window, group, res, cts):
 
         n = needed_key_blocks(i, q_off, k_off, bq, bk, nk, causal)
         first = first_key_block(i, q_off, k_off, bq, bk, nk, window)
+        if select is not None:
+            first, n = 0, select[2][i]
         dqi, dk, dv = jax.lax.fori_loop(
             first, n, body, (jnp.zeros((b, h, rows, dk_), jnp.float32), dk, dv)
         )
@@ -346,7 +448,7 @@ def _attention_partial_bwd(causal, scale, bq, bk, window, group, res, cts):
     )
     dq = jnp.moveaxis(dq, 0, 2).reshape(b, h, rows_q, dk_)
     return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
-            None, None)
+            None, None, None)
 
 
 _attention.defvjp(_attention_fwd, _attention_bwd)

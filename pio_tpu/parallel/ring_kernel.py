@@ -18,6 +18,13 @@ in VMEM:
   ``dv`` of one (batch, KV head) are float32 VMEM scratch across all of its
   query tiles and written once, in ``k``'s dtype.
 
+Under a learned selection of keys (``bits``: ``ring.pack_selection``) the
+two scalar arrays are each query block's active key blocks and their count
+(``ring.selected_blocks``); a query sub-tile's words of the mask stand in
+VMEM beside it, and every tile is masked by the selection, unpacked from
+its ``W`` word rows: row ``r`` of the tile is bit ``r // W`` of word row
+``r mod W``.
+
 ``k`` and ``v`` of one (batch, KV head) stay whole in VMEM (:func:`fits`
 says whether they can). The arithmetic is the XLA form's: operands in the
 dtype given to both matmuls, float32 scores, softmax and accumulators,
@@ -76,6 +83,41 @@ def _visible(delta, bq: int, bk: int, window: int, heads: int,
     return jnp.tile(seen, (1, heads) if transposed else (heads, 1))
 
 
+def _chosen(bits_ref, j, bq: int, bk: int, heads: int, transposed: bool):
+    """Which scores of tile ``j`` the selection keeps: the block's ``W``
+    word rows unpacked, ``[bq, bk]`` (transposed ``[bk, bq]``) tiled over
+    the sub-tile's heads."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    from pio_tpu.parallel.ring import SELECT_BITS
+
+    w = bits_ref.shape[0]
+    words = bits_ref[:, pl.ds(pl.multiple_of(j * bk, bk), bk)]  # [W, bk]
+    rows = jnp.tile(words, (SELECT_BITS, 1))  # row r: word row r mod W
+    shift = jax.lax.shift_right_logical(
+        jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0),
+        jnp.int32(w.bit_length() - 1))  # r // W, W a power of two
+    bit = jax.lax.shift_right_logical(rows, shift) & 1
+    if transposed:
+        bit = bit.T
+    seen = bit != 0
+    return jnp.tile(seen, (1, heads) if transposed else (heads, 1))
+
+
+def _chosen_loop(order_ref, count, i, nk: int, tile):
+    """Run ``tile(j, None, True)`` for the active key blocks ``order[i * nk
+    + idx]``, ``idx < count``."""
+    import jax
+
+    def body(idx, carry):
+        tile(order_ref[i * nk + idx], None, True)
+        return carry
+
+    jax.lax.fori_loop(0, count, body, 0)
+
+
 def _tile_loop(first, n, base, bq: int, bk: int, window: int, tile):
     """Run ``tile(j, delta, masked)`` for the key blocks ``first <= j < n``
     (``delta``: the first query's position less the block's first key's);
@@ -100,13 +142,16 @@ def _tile_loop(first, n, base, bq: int, bk: int, window: int, tile):
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 
 
-def _fwd_kernel(first_ref, n_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
-                lse_ref, m_scr, l_scr, acc_scr, *, scale, bq, bk, heads,
-                subs, window):
+def _fwd_kernel(first_ref, n_ref, off_ref, q_ref, k_ref, v_ref, *refs, scale,
+                bq, bk, heads, subs, window, nk=0):
+    """``nk`` > 0: under a selection (``first_ref`` the active blocks,
+    ``n_ref`` their counts, the first of ``refs`` the mask's words)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
+    bits_ref = refs[0] if nk else None
+    o_ref, lse_ref, m_scr, l_scr, acc_scr = refs[1:] if nk else refs
     f32 = jnp.float32
     i = pl.program_id(2) // subs  # the query block of this sub-tile
     base = off_ref[0] - off_ref[1] + i * bq
@@ -121,7 +166,8 @@ def _fwd_kernel(first_ref, n_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
         s = jax.lax.dot_general(q_ref[...], kj, _NT,
                                 preferred_element_type=f32) * scale
         if masked:
-            seen = _visible(delta, bq, bk, window, heads, False)
+            seen = (_chosen(bits_ref, j, bq, bk, heads, False) if nk else
+                    _visible(delta, bq, bk, window, heads, False))
             s = jnp.where(seen, s, _NEG_BIG)
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -135,7 +181,10 @@ def _fwd_kernel(first_ref, n_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
             acc_scr[...] * jnp.tile(corr, (1, dv // _LANES))
             + jnp.dot(p.astype(vj.dtype), vj, preferred_element_type=f32))
 
-    _tile_loop(first_ref[i], n_ref[i], base, bq, bk, window, tile)
+    if nk:
+        _chosen_loop(first_ref, n_ref[i], i, nk, tile)
+    else:
+        _tile_loop(first_ref[i], n_ref[i], base, bq, bk, window, tile)
     l = l_scr[...]
     safe = jnp.maximum(l, 1e-30)
     o_ref[...] = acc_scr[...] / jnp.tile(safe, (1, dv // _LANES))
@@ -144,12 +193,14 @@ def _fwd_kernel(first_ref, n_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def _bwd_kernel(first_ref, n_ref, off_ref, q_ref, k_ref, v_ref, do_ref,
-                lse_ref, g_ref, dq_ref, dk_ref, dv_ref, dq_scr, dk_scr,
-                dv_scr, *, scale, bq, bk, heads, subs, window):
+                lse_ref, g_ref, *refs, scale, bq, bk, heads, subs, window,
+                nk=0):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
+    bits_ref = refs[0] if nk else None
+    dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr = refs[1:] if nk else refs
     f32 = jnp.float32
     t = pl.program_id(2)
     i = t // subs
@@ -170,7 +221,8 @@ def _bwd_kernel(first_ref, n_ref, off_ref, q_ref, k_ref, v_ref, do_ref,
         s = jax.lax.dot_general(kj, q, _NT,
                                 preferred_element_type=f32) * scale
         if masked:
-            seen = _visible(delta, bq, bk, window, heads, True)
+            seen = (_chosen(bits_ref, j, bq, bk, heads, True) if nk else
+                    _visible(delta, bq, bk, window, heads, True))
             s = jnp.where(seen, s, _NEG_BIG)
         p = jnp.exp(s - lse_ref[...])
         if masked:
@@ -183,7 +235,10 @@ def _bwd_kernel(first_ref, n_ref, off_ref, q_ref, k_ref, v_ref, do_ref,
         dq_scr[...] += jax.lax.dot_general(
             ds, kj, (((0,), (0,)), ((), ())), preferred_element_type=f32)
 
-    _tile_loop(first_ref[i], n_ref[i], base, bq, bk, window, tile)
+    if nk:
+        _chosen_loop(first_ref, n_ref[i], i, nk, tile)
+    else:
+        _tile_loop(first_ref[i], n_ref[i], base, bq, bk, window, tile)
     dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
 
     @pl.when(t == pl.num_programs(2) - 1)
@@ -192,10 +247,13 @@ def _bwd_kernel(first_ref, n_ref, off_ref, q_ref, k_ref, v_ref, do_ref,
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _specs(heads: int, bq: int, tk: int, d_k: int, d_v: int):
+def _specs(heads: int, bq: int, tk: int, d_k: int, d_v: int, subs: int = 1):
     """Block specs by operand: a query sub-tile's rows, one (batch, KV
-    head)'s whole keys, a sub-tile's lane row of statistics."""
+    head)'s whole keys, a sub-tile's lane row of statistics, the words of
+    the selection of the sub-tile's query block."""
     from jax.experimental import pallas as pl
+
+    from pio_tpu.parallel.ring import select_words
 
     rows = heads * bq
     sub = lambda b, h, t, *_: (b, h, t, 0)
@@ -206,6 +264,8 @@ def _specs(heads: int, bq: int, tk: int, d_k: int, d_v: int):
         k=pl.BlockSpec((None, None, tk, d_k), whole),
         v=pl.BlockSpec((None, None, tk, d_v), whole),
         row=pl.BlockSpec((None, None, 1, rows), lambda b, h, t, *_: (b, h, 0, t)),
+        bits=pl.BlockSpec((None, select_words(bq), tk),
+                          lambda b, h, t, *_: (b, t // subs, 0)),
     )
 
 
@@ -220,7 +280,7 @@ def _params():
 @functools.lru_cache(maxsize=32)
 def _fwd_call(b: int, h: int, rows_q: int, tk: int, d_k: int, d_v: int,
               dtype: str, scale: float, bq: int, bk: int, window: int,
-              group: int, interpret: bool):
+              group: int, interpret: bool, selected: bool = False):
     """The forward's ``pallas_call`` for one set of shapes, built once (a
     step calls it from several layers, forward and recomputed, and every
     new ``pallas_call`` is traced again: set-up time)."""
@@ -231,15 +291,16 @@ def _fwd_call(b: int, h: int, rows_q: int, tk: int, d_k: int, d_v: int,
 
     heads = sub_heads(group, bq, TILE_ROWS)
     rows, subs = heads * bq, group // heads
-    sp = _specs(heads, bq, tk, d_k, d_v)
+    sp = _specs(heads, bq, tk, d_k, d_v, subs)
     f32 = jnp.float32
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, bq=bq, bk=bk,
-                          heads=heads, subs=subs, window=window),
+                          heads=heads, subs=subs, window=window,
+                          nk=tk // bk if selected else 0),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b, h, rows_q // rows),
-            in_specs=[sp["q"], sp["k"], sp["v"]],
+            in_specs=[sp["q"], sp["k"], sp["v"]] + [sp["bits"]] * selected,
             out_specs=[sp["o"], sp["row"]],
             scratch_shapes=[pltpu.VMEM((rows, _LANES), f32),
                             pltpu.VMEM((rows, _LANES), f32),
@@ -253,7 +314,7 @@ def _fwd_call(b: int, h: int, rows_q: int, tk: int, d_k: int, d_v: int,
 @functools.lru_cache(maxsize=32)
 def _bwd_call(b: int, h: int, rows_q: int, tk: int, d_k: int, d_v: int,
               dtype: str, scale: float, bq: int, bk: int, window: int,
-              group: int, interpret: bool):
+              group: int, interpret: bool, selected: bool = False):
     """The backward's ``pallas_call`` for one set of shapes, built once."""
     import jax
     import jax.numpy as jnp
@@ -262,16 +323,17 @@ def _bwd_call(b: int, h: int, rows_q: int, tk: int, d_k: int, d_v: int,
 
     heads = sub_heads(group, bq, TILE_ROWS)
     rows, subs = heads * bq, group // heads
-    sp = _specs(heads, bq, tk, d_k, d_v)
+    sp = _specs(heads, bq, tk, d_k, d_v, subs)
     f32, dt = jnp.float32, jnp.dtype(dtype)
     return pl.pallas_call(
         functools.partial(_bwd_kernel, scale=scale, bq=bq, bk=bk,
-                          heads=heads, subs=subs, window=window),
+                          heads=heads, subs=subs, window=window,
+                          nk=tk // bk if selected else 0),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b, h, rows_q // rows),
             in_specs=[sp["q"], sp["k"], sp["v"], sp["o"], sp["row"],
-                      sp["row"]],
+                      sp["row"]] + [sp["bits"]] * selected,
             out_specs=[sp["q"], sp["k"], sp["v"]],
             scratch_shapes=[pltpu.VMEM((rows, d_k), f32),
                             pltpu.VMEM((tk, d_k), f32),
@@ -289,21 +351,27 @@ def _shape_key(q, k, v):
 
 
 def forward(q, k, v, first, n, offs, scale, bq, bk, window, group,
-            interpret=False):
+            interpret=False, bits=None):
     """``(o float32, lse)`` of ``[B, H, rows, D]`` operands; ``first`` and
     ``n`` ``[nq]`` int32 are the key-block bounds of each query block and
-    ``offs`` ``[2]`` the positions of the first query and key."""
+    ``offs`` ``[2]`` the positions of the first query and key. With the
+    selection's ``bits [B, nq * W, Tk]``, ``first`` is ``[nq * nk]``: each
+    query block's active key blocks, and ``n`` their counts."""
+    selected = bits is not None
     call = _fwd_call(*_shape_key(q, k, v), float(scale), bq, bk, window,
-                     group, interpret)
-    o, lse = call(first, n, offs, q, k, v)
+                     group, interpret, selected)
+    o, lse = call(first, n, offs, q, k, v, *([bits] * selected))
     return o, lse.reshape(lse.shape[0], lse.shape[1], -1)
 
 
 def backward(q, k, v, do, lse, g, first, n, offs, scale, bq, bk, window,
-             group, interpret=False):
+             group, interpret=False, bits=None):
     """``(dq, dk, dv)`` in the operands' dtypes; ``do`` in ``q``'s dtype,
-    ``lse`` and ``g`` ``[B, H, rows]`` float32."""
+    ``lse`` and ``g`` ``[B, H, rows]`` float32; ``bits``, ``first`` and
+    ``n`` as :func:`forward` takes them."""
+    selected = bits is not None
     call = _bwd_call(*_shape_key(q, k, v), float(scale), bq, bk, window,
-                     group, interpret)
+                     group, interpret, selected)
     row = lambda a: a.reshape(a.shape[0], a.shape[1], 1, -1)
-    return call(first, n, offs, q, k, v, do, row(lse), row(g))
+    return call(first, n, offs, q, k, v, do, row(lse), row(g),
+                *([bits] * selected))

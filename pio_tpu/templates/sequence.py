@@ -196,8 +196,10 @@ class SeqRecParams(SeqRecConfig, Params):
     ``mixer_pattern`` of ``"mamba"``, ``"moe"``, ``"attn"`` and ``"mlp"``
     layers (``"mlp"``: the dense SwiGLU of width ``ffn`` as a layer of its
     own; the pattern may hold no ``"moe"`` at all), the
-    ``ssm_*`` sizes of a Mamba-2 layer, ``expert_act``, ``attn_rope`` and
-    ``attn_gate``; for any moe block ``expert_matmul``, the routed experts'
+    ``ssm_*`` sizes of a Mamba-2 layer, ``expert_act``, ``attn_rope``,
+    ``attn_gate`` and ``attn_qk_norm``; for ``"sparse"`` layers the
+    indexer's ``index_heads``, ``index_head_dim`` and ``index_topk``; for
+    any moe block ``expert_matmul``, the routed experts'
     grouped matmul, the model's four scalars ``embed_scale``,
     ``residual_scale``, ``attn_scale`` and ``logit_scale`` (each applied
     only where set) and ``tied_head``, which reads the logits from the

@@ -443,3 +443,148 @@ def test_a_sequence_cells_step_fits_a_v5e(topo, monkeypatch, config_name,
     assert mem.temp_size_in_bytes <= 1.02 * temp_bytes
     assert mem.argument_size_in_bytes == pytest.approx(12 * parameters, rel=2e-3)
     assert mem.alias_size_in_bytes >= 12 * parameters  # the state is donated
+
+
+def test_selected_attention_compiles_for_v5e(one_chip, monkeypatch):
+    """The sparse-attention cell's attention (one row of 16,384 events, 4 KV
+    heads of 128 with 8 query heads each, 512-blocks) under a selection mask,
+    forward and backward, with the rule asked as a TPU is: two Pallas calls
+    that fit VMEM, a query block's words of the mask (16 rows of int32)
+    beside its rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from pio_tpu.parallel import ring
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    b, h_kv, t, d, group, blk = 1, 4, 16384, 128, 8, 512
+    rows, nq = t * group, t // blk
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def both(q, k, v, bits, order, count, do, dlse):
+        def attend(q, k, v):
+            return ring.attention_partial(
+                q, k, v, jnp.int32(0), jnp.int32(0), True, d ** -0.5, blk,
+                blk, 0, group, (bits, order, count))[:2]
+
+        (o, lse), back = jax.vjp(attend, q, k, v)
+        return o, lse, back((do.astype(f32), dlse))
+
+    compiled = jax.jit(both).lower(
+        sd((b, h_kv, rows, d), bf), sd((b, h_kv, t, d), bf),
+        sd((b, h_kv, t, d), bf), sd((b, nq * ring.select_words(blk), t), i32),
+        sd((nq, nq), i32), sd((nq,), i32), sd((b, h_kv, rows, d), bf),
+        sd((b, h_kv, rows), f32)).compile()
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    assert len(calls) == 2, len(calls)
+
+
+def test_the_sparse_attention_cells_step_fits_a_v5e(topo, monkeypatch):
+    """The whole training step of the sparse-attention cell (six layers of
+    the lightning indexer, the selection, the attention's kernels under it,
+    the indexer's loss and 16 held experts on the grouped matmul's kernel)
+    compiled for a described v5e: 659,190,016 parameters, and the
+    temporaries beside 12 B a parameter of arguments (12,035,358,720 B
+    offline with each layer's selection kept for the backward pass,
+    11,731,404,800 found again there; the compiler's own report, 14.52 GiB
+    in all, stands 0.56 GiB over the Laguna cell's step, which holds 14.29
+    GiB on the chip)."""
+    import jax
+    import numpy as np
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lowered, params = _sequence_step(topo, "keyevl2-30b-ep8")
+    parameters = 659_190_016
+    assert sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params)) == parameters
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= 1.02 * 12_035_358_720
+    assert mem.argument_size_in_bytes == pytest.approx(12 * parameters, rel=2e-3)
+    assert mem.alias_size_in_bytes >= 12 * parameters  # the state is donated
+    # the attention's two kernels and the grouped matmuls' run, every layer
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _accepted_step(topo, config_name):
+    """``chunk_staged`` of a sequence cell lowered for one described chip,
+    its params read as its own driver reads them (the mla/moe cell's
+    ``train_seq.py``, the others' ``train_seq_cfg.py``)."""
+    import dataclasses
+    import json
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import run
+
+    from pio_tpu.controller.params import params_from_dict
+    from pio_tpu.models import seqrec
+    from pio_tpu.parallel.mesh import MeshSpec, build_mesh
+    from pio_tpu.templates.sequence import SeqRecParams
+
+    with open(os.path.join(bench, "configs", config_name + ".json")) as f:
+        config = json.load(f)
+    if "harness" in config:
+        driver = run.load_module("drivers", "train_seq_cfg")
+        params = driver.algorithm_params(
+            config, driver.reference_module(config).model(config), 1)
+    else:
+        params = run.load_module("drivers", "train_seq").algorithm_params(
+            config, 1)
+    p = params_from_dict(SeqRecParams, params)
+    cfg = seqrec.SeqRecConfig(**{f.name: getattr(p, f.name) for f in
+                                 dataclasses.fields(seqrec.SeqRecConfig)})
+    mesh = build_mesh(MeshSpec(data=-1), devices=topo.devices[:1])
+    rows = int(config["data"]["n_histories"])
+    vocab = int(config["data"]["n_items"]) + 1
+    prog = seqrec._programs(dataclasses.replace(cfg, seed=0, steps=0), mesh,
+                            vocab, cfg.batch_size, rows // cfg.batch_size)
+    params = jax.eval_shape(prog.init, jnp.int32(0))
+    whole = NamedSharding(mesh, P())
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=whole),
+        (jax.ShapeDtypeStruct((), jnp.int32), params,
+         jax.eval_shape(prog.opt_init, params)))
+    epoch = tuple(jax.ShapeDtypeStruct(
+        (rows, cfg.max_len), dtype, sharding=NamedSharding(mesh, P("data", "seq")))
+        for dtype in (jnp.int32, jnp.int32, jnp.float32, jnp.int32, jnp.float32))
+    return prog.chunk_staged.lower(state, epoch, cfg.steps)
+
+
+@pytest.mark.parametrize("config_name,n_ops,digest", [
+    ("glm47flash-ep8", 8380, "9fb967fe21dfa794"),
+    ("laguna-s21-ep32", 12410, "be44f69f6aed8e1a"),
+    ("nemotron3nano-ep16", 12110, "6e897d2d7f514f6e"),
+    ("granite4hmicro-vp8", 8838, "ab7e7cd12abe8fff"),
+])
+def test_the_accepted_cells_steps_lower_to_the_same_operations(
+        topo, monkeypatch, config_name, n_ops, digest):
+    """The four accepted sequence cells' steps, lowered as a TPU lowers them,
+    hold the same StableHLO operations, kind by kind, as before the sparse
+    layer kind, the q/k norms and the selection's path through the attention
+    tiles were added (counted on the code without them): every new operation
+    applies only where set. The text itself differs by the Pallas kernels'
+    embedded source locations, so the histogram is compared."""
+    import collections
+    import hashlib
+    import json
+    import re
+
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _accepted_step(topo, config_name).as_text()
+    ops = collections.Counter(re.findall(r"= (?:\"?)([a-z_]+\.[a-z_0-9.]+)", text))
+    assert sum(ops.values()) == n_ops
+    assert hashlib.sha256(json.dumps(sorted(ops.items())).encode()).hexdigest()[
+        :16] == digest
