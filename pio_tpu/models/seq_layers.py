@@ -23,7 +23,8 @@ moe blocks' "attention, then a feed-forward part" (dense or experts), or
   and the same dense and expert layers behind a ``router_kind="softmax"``
   router; or ``"sparse"`` layers (DeepSeek Sparse Attention's form,
   :func:`dsa`): a full layer's heads over the keys a lightning indexer
-  selects a query, the indexer trained by its own KL loss. Per-head q/k
+  selects a query, the indexer trained by its own KL loss (its scores on a
+  TPU in two Pallas kernels, :func:`index_impl`). Per-head q/k
   RMSNorm where ``attn_qk_norm``; no shared expert where ``shared_experts``
   is 0. Its stacks are ``dense/*``, ``window/*``, ``full/*`` and
   ``sparse/*``: the kinds' projections have unlike shapes. ``heads_full``,
@@ -94,9 +95,10 @@ INIT_STD, EMBED_INIT_STD, BIAS_INIT_STD = 0.02, 1.0, 0.02
 #: Edge of the attention tiles, and tokens to a chunk of the cross-entropy and
 #: of the dense SwiGLU (each clamped to a divisor of what it cuts).
 ATTN_BLOCK, TOKEN_CHUNK = 512, 2048
-#: Queries of a block of the indexer's scores: its ``[rows, index heads,
-#: keys]`` float32 products stand at once (16 heads of 16,384 keys: 128 MB
-#: at 128 rows), a block after another under ``jax.checkpoint``.
+#: Queries of a block of the indexer's scores, a block after another: XLA's
+#: einsum holds the block's ``[rows, index heads, keys]`` float32 products at
+#: once (16 heads of 16,384 keys: 128 MB at 128 rows), the kernels of
+#: :mod:`pio_tpu.models.index_kernel` a chunk of keys of them in VMEM.
 INDEX_BLOCK = 128
 #: The name a sparse layer's selection carries (``checkpoint_name``): the
 #: layer's recomputation in the backward pass keeps it instead of running
@@ -917,15 +919,46 @@ def layer_norm(x, g, b, eps):
     return c * jax.lax.rsqrt((c * c).mean(axis=-1, keepdims=True) + eps) * g + b
 
 
+def index_impl(platform: str, cd, heads: int, di: int, n: int,
+               t: int) -> str:
+    """What computes :func:`index_scores` of a block of ``n`` queries of
+    ``heads`` heads of ``di`` against ``t`` keys, from what is visible at
+    trace time: ``pallas`` / ``xla``. ``pallas`` is the pair of kernels of
+    :mod:`pio_tpu.models.index_kernel` (a block's ``[rows, heads, keys]``
+    products in VMEM, the key chunks after the block never computed): on a
+    TPU, with bfloat16 operands, a key count that whole tiles divide and
+    tiles that fit VMEM (``index_kernel.admits``). ``xla`` is the einsum of
+    :func:`index_scores`: everywhere else (every CPU run, float32
+    operands, shapes the tiles do not fit), and the kernels' oracle."""
+    import jax.numpy as jnp
+
+    from pio_tpu.models import index_kernel
+
+    cd = jnp.dtype(cd)
+    tiles = cd == jnp.bfloat16 and index_kernel.admits(heads, di, n, t,
+                                                       cd.itemsize)
+    return "pallas" if platform == "tpu" and tiles else "xla"
+
+
 def index_scores(qi, ki, w, t0, cd):
     """The lightning indexer's scores of a block of queries at positions
     ``t0 + r``: ``qi [B, n, Hi, di]`` and ``ki [B, T, di]`` (rotated), ``w
     [B, n, Hi]`` (its weights, ``Hi ** -0.5`` in them) -> ``I [B, n, T]``
     float32, ``di ** -0.5 sum_j w_j relu(q_j . k)``, ``-inf`` at the keys
-    after the query. Operands in ``cd``, float32 accumulation."""
+    after the query. Operands in ``cd``, float32 accumulation. On the
+    kernels of :mod:`pio_tpu.models.index_kernel` where :func:`index_impl`
+    says ``pallas`` (``pallas_interpret``, the same kernels interpreted, in
+    tests), else on the einsum below, their oracle."""
     import jax
     import jax.numpy as jnp
 
+    n, Hi, di = qi.shape[1:]
+    impl = index_impl(jax.default_backend(), cd, Hi, di, n, ki.shape[1])
+    if impl != "xla":
+        from pio_tpu.models import index_kernel
+
+        return index_kernel.scores(qi.astype(cd), ki.astype(cd), w, t0,
+                                   interpret=impl == "pallas_interpret")
     s = jnp.einsum("bqhd,bkd->bqhk", qi.astype(cd), ki.astype(cd),
                    preferred_element_type=jnp.float32)
     scores = (jax.nn.relu(s) * w[..., None]).sum(axis=2) * jnp.float32(
@@ -1347,7 +1380,11 @@ def attn_impls(platform: str, cfg, t_local: int) -> Dict[str, str]:
     block has (``mla``; the gqa block's ``full``, ``window`` and
     ``sparse``), over rows
     of ``t_local`` events: ``ring.attention_impl``'s answer at the shapes
-    :func:`mla` and :func:`gqa` hand it."""
+    :func:`mla` and :func:`gqa` hand it. A block with sparse layers adds
+    ``index`` where their indexer's scores run on its kernels
+    (:func:`index_impl` at the shapes :func:`select_keys` hands it); where
+    XLA's einsum computes them the map names the tiles alone, which is
+    what the benchmark's CPU rehearsal of the sparse cell reads."""
     from pio_tpu.parallel.ring import attention_impl, pick_block
 
     blk = pick_block(t_local, ATTN_BLOCK)
@@ -1358,9 +1395,16 @@ def attn_impls(platform: str, cfg, t_local: int) -> Dict[str, str]:
     else:
         widths = {kind: (cfg.head_dim, cfg.head_dim)
                   for kind in sorted(set(cfg.layer_pattern))}
-    return {kind: attention_impl(platform, _dtype(cfg), d_k, d_v, blk, blk,
-                                 True, t_local, kind == "sparse")
-            for kind, (d_k, d_v) in widths.items()}
+    impls = {kind: attention_impl(platform, _dtype(cfg), d_k, d_v, blk, blk,
+                                  True, t_local, kind == "sparse")
+             for kind, (d_k, d_v) in widths.items()}
+    if "sparse" in impls:
+        index = index_impl(platform, _dtype(cfg), cfg.index_heads,
+                           cfg.index_head_dim, pick_block(blk, INDEX_BLOCK),
+                           t_local)
+        if index != "xla":
+            impls["index"] = index
+    return impls
 
 
 def _gmm_tiles(which: str, k: int, n: int):

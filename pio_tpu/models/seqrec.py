@@ -868,7 +868,9 @@ def train_seqrec(
             the attention tiles of each kind of its attention layers
             (``attn_impl``: ``{"mla"}`` or ``{"full", "window",
             "sparse"}`` ->
-            ``pallas`` / ``xla``, ``ring.attention_impl``) and the chunks of
+            ``pallas`` / ``xla``, ``ring.attention_impl``; a sparse block
+            adds ``index`` where its indexer's scores run on their kernels,
+            ``seq_layers.index_impl``) and the chunks of
             its Mamba-2 mixers (``ssm_impl``: ``pallas`` / ``xla``,
             ``seq_layers.ssd_impl``; ``none`` without a mamba layer); both
             in ``/train.json`` and the run record too.

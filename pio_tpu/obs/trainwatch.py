@@ -526,7 +526,8 @@ def run_record(*, run_id: str, engine_id: str, status: str,
     ``fused`` / ``xla``) are lifted beside them, and so is a sequence run's
     ``attn_impl`` (``{"mla"}`` or ``{"full", "window", "sparse"}``:
     ``pallas`` /
-    ``xla``) and ``ssm_impl`` (``pallas`` / ``xla`` / ``none``). ``xla`` is
+    ``xla``; ``index`` beside ``sparse`` where the indexer's kernels ran)
+    and ``ssm_impl`` (``pallas`` / ``xla`` / ``none``). ``xla`` is
     the process's
     :func:`pio_tpu.obs.devicewatch.xla_totals` at the run's end: what JAX's
     compile path took of the run, lifted as ``xla_trace_s``,

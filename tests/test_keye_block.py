@@ -484,13 +484,73 @@ def test_a_seq_axis_is_refused():
 
 def test_the_attention_rule_answers_pallas_for_the_cells_sparse_layers():
     _config, cfg = _cell_config()
-    assert seq_layers.attn_impls("tpu", cfg, 16384) == {"sparse": "pallas"}
+    assert seq_layers.attn_impls("tpu", cfg, 16384) == {"sparse": "pallas",
+                                                        "index": "pallas"}
     assert seq_layers.attn_impls("cpu", cfg, 16384) == {"sparse": "xla"}
     # a block of 128 queries holds 4 word rows of the mask: no whole tile
     assert ring.attention_impl("tpu", "bfloat16", 128, 128, 128, 128, True,
                                16384, True) == "xla"
     assert ring.attention_impl("tpu", "bfloat16", 128, 128, 512, 512, True,
                                16384, True) == "pallas"
+
+
+def test_the_index_rule_answers_pallas_for_the_cells_indexer_alone():
+    """``index_impl`` at the shapes ``select_keys`` hands it: a block of 128
+    queries of 16 heads of 64 against 16,384 keys, bfloat16, takes the
+    kernels on a TPU; the CPU, float32 operands and a key count no tile
+    divides keep the einsum, and so does the map of what ran, which names
+    the indexer only where its kernels run (no other block has an entry)."""
+    _config, cfg = _cell_config()
+    assert (cfg.index_heads, cfg.index_head_dim, cfg.compute_dtype) == (
+        16, 64, "bfloat16")
+    n = ring.pick_block(ring.pick_block(16384, seq_layers.ATTN_BLOCK),
+                        seq_layers.INDEX_BLOCK)
+    assert n == 128
+    rule = seq_layers.index_impl
+    assert rule("tpu", "bfloat16", 16, 64, n, 16384) == "pallas"
+    assert rule("cpu", "bfloat16", 16, 64, n, 16384) == "xla"
+    assert rule("tpu", "float32", 16, 64, n, 16384) == "xla"
+    assert rule("tpu", "bfloat16", 16, 64, n, 16384 + 128) == "xla"
+    f32 = dataclasses.replace(cfg, compute_dtype="float32")
+    assert seq_layers.attn_impls("tpu", f32, 16384) == {"sparse": "xla"}
+    assert seq_layers.attn_impls("tpu", cfg, 16384 + 128) == {"sparse": "xla"}
+    dense = dataclasses.replace(cfg, layer_pattern=("full",))
+    assert seq_layers.attn_impls("tpu", dense, 16384) == {"full": "pallas"}
+
+
+#: the layer at widths the indexer's kernels take: a block of 128 queries
+#: (the row) of 4 heads of 16 against 128 keys
+WIDE_INDEX = dataclasses.replace(CFG, max_len=128, index_head_dim=16)
+
+
+def test_the_indexers_kernels_give_the_xla_forms_losses_and_gradients(
+        monkeypatch):
+    """One batch through the step's loss with the indexer's scores on the
+    kernels (interpreted) and on the einsum: the same selection (its
+    checksum), both losses and every gradient, the indexer's own among
+    them, within float32's summation order."""
+    import jax
+
+    rows = np.random.default_rng(6).integers(1, V, (2, 128)).astype(np.int32)
+    params = seqrec.init_params(V, WIDE_INDEX)
+
+    def run(impl):
+        monkeypatch.setattr(seq_layers, "index_impl", lambda *a: impl)
+        (loss, aux), grads = jax.value_and_grad(
+            lambda p: program_loss(p, rows, WIDE_INDEX), has_aux=True)(params)
+        return float(loss), aux, flat(grads)
+
+    loss, aux, grads = run("pallas_interpret")
+    loss_x, aux_x, grads_x = run("xla")
+    assert float(aux["l_select"]) == float(aux_x["l_select"])
+    assert loss == pytest.approx(loss_x, rel=2e-6)
+    assert float(aux["l_index"]) == pytest.approx(float(aux_x["l_index"]),
+                                                  rel=2e-5)
+    assert any(np.abs(g).max() > 0 for k, g in grads.items() if "idx_" in k)
+    for name, g in grads.items():
+        scale = max(float(np.abs(grads_x[name]).max()), 1e-12)
+        np.testing.assert_allclose(g, grads_x[name], atol=2e-5 * scale,
+                                   err_msg=name)
 
 
 def test_the_counters_and_the_indexer_loss_reach_the_stats(monkeypatch):
@@ -508,6 +568,14 @@ def test_the_counters_and_the_indexer_loss_reach_the_stats(monkeypatch):
             model.trace[name].sum())
     assert stats["attn_impl"] == {"sparse": "xla"}
     assert model.trace["l_index"].shape == (1,)
+    # where the indexer's kernels run, the map names them
+    monkeypatch.setattr(seq_layers, "index_impl",
+                        lambda *a: "pallas_interpret")
+    stats = {}
+    rows = np.random.default_rng(2).integers(1, V, (2, 128)).astype(np.int32)
+    train_seqrec(None, rows, V - 1,
+                 dataclasses.replace(WIDE_INDEX, steps=1), stats=stats)
+    assert stats["attn_impl"] == {"sparse": "xla", "index": "pallas_interpret"}
 
 
 def test_the_selection_checksum_sums_the_selected_positions():

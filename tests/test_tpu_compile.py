@@ -483,6 +483,44 @@ def test_selected_attention_compiles_for_v5e(one_chip, monkeypatch):
     assert len(calls) == 2, len(calls)
 
 
+def test_the_indexers_kernels_compile_for_v5e(one_chip, monkeypatch):
+    """One 128-query block of the sparse-attention cell's indexer (16 heads
+    of 64 against one row of 16,384 keys) through ``index_scores`` with the
+    rule asked as a TPU is, forward and backward: two Pallas calls that fit
+    VMEM, and no ``[128, 16, 16,384]`` float32 product in HBM, which the
+    einsum writes for every block."""
+    import math
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from pio_tpu.models import seq_layers
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    b, n, hi, di, t = 1, 128, 16, 64, 16384
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def both(qi, ki, w, t0, g):
+        scores, back = jax.vjp(
+            lambda *a: seq_layers.index_scores(*a, t0, bf), qi, ki, w)
+        return scores, back(g)
+
+    compiled = jax.jit(both).lower(
+        sd((b, n, hi, di), bf), sd((b, t, di), bf), sd((b, n, hi), f32),
+        sd((), i32), sd((b, n, t), f32)).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 2, len(calls)
+    products = [dims for dims in re.findall(r"f32\[([\d,]+)\]", text)
+                if math.prod(map(int, dims.split(","))) >= n * hi * t]
+    assert not products, products
+    assert compiled.memory_analysis().temp_size_in_bytes <= 4 * b * n * t
+
+
 def test_the_sparse_attention_cells_step_fits_a_v5e(topo, monkeypatch):
     """The whole training step of the sparse-attention cell (six layers of
     the lightning indexer, the selection, the attention's kernels under it,
