@@ -104,6 +104,11 @@ INDEX_BLOCK = 128
 #: layer's recomputation in the backward pass keeps it instead of running
 #: the indexer's scores and the top-k again.
 SELECTION = "seq.dsa.selection"
+#: The name a sparse layer's indexer gradient carries (``checkpoint_name``):
+#: its loss's gradient in the indexer's own parameters, taken in the forward
+#: pass (:func:`index_loss`) and kept, so that neither the backward pass nor
+#: the layer's recomputation walks the queries again.
+INDEX_GRAD = "seq.dsa.index_grad"
 #: Heads of a Mamba-2 group to a turn of the XLA chunked scan's map
 #: (:func:`ssd_scan`, which the TPU's kernels replace where :func:`ssd_impl`
 #: admits them; clamped to a divisor of the group's heads, so a group of
@@ -1141,18 +1146,53 @@ def _kl_rows(scores, sel, p):
     return kl, grad
 
 
-@functools.lru_cache(maxsize=16)
-def _index_loss(bq: int, bk: int, sub: int, scale: float, cd):
-    """The indexer's loss of one layer, as :func:`index_loss` computes it,
-    with its own backward pass: forward and backward walk the queries
-    ``sub`` at a time, recompute each block's scores, selection and target
-    from the arguments and keep nothing else, and the gradient reaches the
-    indexer's ``qi``, ``ki`` and ``w`` alone."""
-    import jax
+#: A sparse layer's leaves of the lightning indexer (:func:`indexer`)
+INDEXER = ("idx_q", "idx_k", "idx_k_norm_g", "idx_k_norm_b", "idx_w")
+
+
+def indexer(ip, xi, cfg):
+    """The lightning indexer's ``(qi, ki, w)`` (as :func:`index_scores` takes
+    them) of a sparse layer's normed input ``xi [B, T, D]``, from its own
+    parameters ``ip`` (the layer's ``idx_*`` leaves): ``qi = RoPE_half(xi
+    W_qi)``, ``ki = RoPE_half(LayerNorm(xi W_ki))``, ``w = xi W_w / sqrt(
+    index_heads)``."""
     import jax.numpy as jnp
 
-    def part(args, r):
-        qi, ki, w, q, k, lse, bits, order, count = args
+    cd, eps = _dtype(cfg), cfg.norm_eps
+    B, T, _ = xi.shape
+    Hi, di = cfg.index_heads, cfg.index_head_dim
+    pos = jnp.arange(T)
+    qi = rope(mm(xi, ip["idx_q"], cd).reshape(B, T, Hi, di), pos,
+              cfg.rope_theta, di // 2).astype(cd)
+    ki = layer_norm(mm(xi, ip["idx_k"], cd), ip["idx_k_norm_g"],
+                    ip["idx_k_norm_b"], eps)
+    ki = rope(ki[:, :, None], pos, cfg.rope_theta, di // 2)[:, :, 0]
+    return qi, ki.astype(cd), mm(xi, ip["idx_w"], cd) * jnp.float32(Hi ** -0.5)
+
+
+@functools.lru_cache(maxsize=16)
+def _index_loss(bq: int, bk: int, sub: int, scale: float, cfg):
+    """The indexer's loss of one layer, as :func:`index_loss` computes it,
+    with its own backward pass. The queries are walked ``sub`` at a time,
+    once: undifferentiated for the loss alone; differentiated (``fwd``) each
+    block's gradient in its scores (:func:`_kl_rows`) is carried back through
+    the scores at once, and the walk's gradient through the indexer's
+    projections after it. That gradient in the indexer's parameters, for a
+    cotangent of 1, is the one residual (named :data:`INDEX_GRAD`); the
+    backward pass scales it by the loss's cotangent, since nothing before it
+    depends on that scalar, and walks nothing. The gradient reaches the
+    indexer's parameters alone."""
+    import jax
+    import jax.numpy as jnp
+    from jax.ad_checkpoint import checkpoint_name
+
+    cd = _dtype(cfg)
+
+    def project(ip, xi):
+        with jax.named_scope("seq.dsa/index"):
+            return indexer(ip, xi, cfg)
+
+    def part(qi, ki, w, q, k, lse, bits, order, count, r):
         t0 = r * sub
         rows = lambda a: jax.lax.dynamic_slice_in_dim(a, t0, sub, axis=1)
         with jax.named_scope("seq.dsa/index"):
@@ -1168,51 +1208,56 @@ def _index_loss(bq: int, bk: int, sub: int, scale: float, cd):
         return kl, grad, back
 
     @jax.custom_vjp
-    def loss(*args):
-        n = args[0].shape[1] // sub
-        return jax.lax.map(lambda r: part(args, r)[0], jnp.arange(n)).sum()
+    def loss(ip, xi, *rest):
+        qi, ki, w = project(ip, xi)
+        return jax.lax.map(lambda r: part(qi, ki, w, *rest, r)[0],
+                           jnp.arange(qi.shape[1] // sub)).sum()
 
-    def fwd(*args):
-        return loss(*args), args
-
-    def bwd(args, g):
-        qi, ki, w = args[:3]
+    def fwd(ip, xi, *rest):
+        (qi, ki, w), back_proj = jax.vjp(lambda ip: project(ip, xi), ip)
         n = qi.shape[1] // sub
 
         def step(dki, r):
-            _, grad, back = part(args, r)
+            kl, grad, back = part(qi, ki, w, *rest, r)
             with jax.named_scope("seq.dsa/index"):
-                dqi_r, dki_r, dw_r = back(grad * g)
-            return dki + dki_r, (dqi_r, dw_r)
+                dqi_r, dki_r, dw_r = back(grad)
+            return dki + dki_r, (kl, dqi_r, dw_r)
 
-        dki, (dqi, dw) = jax.lax.scan(
+        dki, (kl, dqi, dw) = jax.lax.scan(
             step, jnp.zeros(ki.shape, jnp.float32), jnp.arange(n))
         merge = lambda a: jnp.moveaxis(a, 0, 1).reshape(
             a.shape[1], n * sub, *a.shape[3:])
-        return (merge(dqi).astype(qi.dtype), dki.astype(ki.dtype),
-                merge(dw).astype(w.dtype)) + (None,) * 6
+        with jax.named_scope("seq.dsa/index"):
+            (dip,) = back_proj((merge(dqi).astype(qi.dtype),
+                                dki.astype(ki.dtype), merge(dw).astype(w.dtype)))
+        return kl.sum(), checkpoint_name(dip, INDEX_GRAD)
+
+    def bwd(dip, g):
+        return (jax.tree.map(lambda a: a * g, dip),) + (None,) * 7
 
     loss.defvjp(fwd, bwd)
     return loss
 
 
-def index_loss(qi, ki, w, q, k, lse, select, bq: int, bk: int, scale: float,
-               cd):
+def index_loss(ip, xi, q, k, lse, select, bq: int, bk: int, scale: float,
+               cfg):
     """The indexer's loss of one layer, summed over its queries: ``sum_t
     KL(p_t || softmax over S_t of I[t])``, ``p_t`` the main attention's
     probabilities over the selection ``S_t`` summed over the query heads
     over their number (``q [B, T, H, d]``, ``k [B, T, Hkv, d]``, ``lse [B,
     T, H]``: what the tiles were given and gave back), ``I`` the index
-    scores of ``qi``, ``ki``, ``w``. Only those three take a gradient;
-    ``select`` is ``(bits, order, count)`` (:func:`select_keys`)."""
+    scores of :func:`indexer` of ``ip`` (the layer's ``idx_*`` leaves) and
+    ``xi``. Only ``ip`` takes a gradient, and it is taken in the forward
+    pass (:func:`_index_loss`); ``select`` is ``(bits, order, count)``
+    (:func:`select_keys`)."""
     import jax
 
     from pio_tpu.parallel.ring import pick_block
 
     sub = pick_block(bq, INDEX_BLOCK)
-    q, k, lse = (jax.lax.stop_gradient(a) for a in (q, k, lse))
-    return _index_loss(bq, bk, sub, float(scale), cd)(qi, ki, w, q, k, lse,
-                                                      *select)
+    xi, q, k, lse = (jax.lax.stop_gradient(a) for a in (xi, q, k, lse))
+    return _index_loss(bq, bk, sub, float(scale), cfg)(ip, xi, q, k, lse,
+                                                       *select)
 
 
 def dsa(blk, h, cfg):
@@ -1220,10 +1265,10 @@ def dsa(blk, h, cfg):
     -> ``(attention's output before the residual, counters)``. With ``x``
     the normed input: ``q = RoPE(norm(x W_q))``, ``k = RoPE(norm(x W_k))``
     (the per-head norm where ``attn_qk_norm``), ``v = x W_v``; the indexer
-    reads ``x`` detached: ``qi = RoPE_half(x W_qi)`` (``index_heads`` heads
-    of ``index_head_dim``, the first half of each rotated), ``ki =
-    RoPE_half(LayerNorm(x W_ki))`` (one head), ``w = x W_w / sqrt(
-    index_heads)``, ``I[t, s] = index_head_dim ** -0.5 sum_j w[t, j]
+    reads ``x`` detached (:func:`indexer`): ``qi = RoPE_half(x W_qi)``
+    (``index_heads`` heads of ``index_head_dim``, the first half of each
+    rotated), ``ki = RoPE_half(LayerNorm(x W_ki))`` (one head), ``w = x W_w /
+    sqrt(index_heads)``, ``I[t, s] = index_head_dim ** -0.5 sum_j w[t, j]
     relu(qi[t, j] . ki[s])`` for ``s <= t``; ``S_t`` the ``index_topk``
     keys of largest ``I[t]`` (:func:`top_keys`: ties to the earlier key),
     one selection for every head; the heads attend over ``S_t`` alone
@@ -1231,8 +1276,10 @@ def dsa(blk, h, cfg):
     block selected skipped); ``o W_o``. Counters: ``dsa`` ``[selected
     pairs, key blocks the attention's loops ran, those a causal loop runs,
     rows tied at the ``k``-th score]``, ``index_kl`` (:func:`index_loss`,
-    summed over the layer's queries), ``l_select`` (the selected keys'
-    positions summed over the queries: :func:`select_keys`)."""
+    summed over the layer's queries; differentiated, its gradient in the
+    indexer's parameters is taken here, named :data:`INDEX_GRAD`),
+    ``l_select`` (the selected keys' positions summed over the queries:
+    :func:`select_keys`)."""
     import jax
     import jax.numpy as jnp
     from jax.ad_checkpoint import checkpoint_name
@@ -1243,7 +1290,6 @@ def dsa(blk, h, cfg):
     cd, eps = _dtype(cfg), cfg.norm_eps
     B, T, _ = h.shape
     H, Hkv, d = cfg.heads_full, cfg.kv_heads, cfg.head_dim
-    Hi, di = cfg.index_heads, cfg.index_head_dim
     pos = jnp.arange(T)
     turn = dict(theta=cfg.rope_theta, rotary_dim=cfg.rotary_dim)
     with jax.named_scope("seq.gqa/proj"):
@@ -1257,13 +1303,8 @@ def dsa(blk, h, cfg):
         v = mm(x, blk["v_proj"], cd).reshape(B, T, Hkv, d).astype(cd)
     with jax.named_scope("seq.dsa/index"):
         xi = jax.lax.stop_gradient(x)
-        qi = rope(mm(xi, blk["idx_q"], cd).reshape(B, T, Hi, di), pos,
-                  cfg.rope_theta, di // 2).astype(cd)
-        ki = layer_norm(mm(xi, blk["idx_k"], cd), blk["idx_k_norm_g"],
-                        blk["idx_k_norm_b"], eps)
-        ki = rope(ki[:, :, None], pos, cfg.rope_theta, di // 2)[:, :, 0]
-        ki = ki.astype(cd)
-        wi = mm(xi, blk["idx_w"], cd) * jnp.float32(Hi ** -0.5)
+        ip = {name: blk[name] for name in INDEXER}
+        qi, ki, wi = indexer(ip, xi, cfg)
     blk_size = pick_block(T, ATTN_BLOCK)
     group = H // Hkv
     select, pairs, ties, pos_sum = select_keys(qi, ki, wi, cfg.index_topk,
@@ -1277,8 +1318,7 @@ def dsa(blk, h, cfg):
             blk_size, blk_size, 0, group, select)
         o = unfold_groups(o, blk_size, group).astype(cd)
         lse = unfold_groups(lse, blk_size, group)
-    kl = index_loss(qi, ki, wi, q, k, lse, select, blk_size, blk_size, scale,
-                    cd)
+    kl = index_loss(ip, xi, q, k, lse, select, blk_size, blk_size, scale, cfg)
     with jax.named_scope("seq.gqa/proj"):
         out = mm(o.reshape(B, T, H * d), blk["o_proj"], cd)
     counters = jnp.stack([pairs, tiles[0], tiles[1], ties]).astype(jnp.float32)

@@ -34,6 +34,7 @@ from typing import NamedTuple, Optional, Tuple
 import numpy as np
 
 from pio_tpu.models.seq_layers import (
+    INDEX_GRAD,
     SELECTION,
     TOKEN_CHUNK,
     attn_impls,
@@ -469,10 +470,13 @@ def _latent_trunk(params, seqs, cfg, m_axis, s_axis):
     # (inside the checkpoint, so that the backward pass's recomputation is
     # held the same way)
     # a sparse layer's selection (32 MB of bits a layer at 16k) is kept for
-    # the backward pass rather than found again: its top-k is no gradient's
+    # the backward pass rather than found again: its top-k is no gradient's;
+    # so is its indexer's gradient (9 MB at Keye's widths), taken in the
+    # forward pass: the recomputation then runs neither the indexer nor its
+    # loss
     def layer(fn, kind):
-        policy = (jax.checkpoint_policies.save_only_these_names(SELECTION)
-                  if kind == "sparse" else None)
+        policy = (jax.checkpoint_policies.save_only_these_names(
+            SELECTION, INDEX_GRAD) if kind == "sparse" else None)
         return jax.checkpoint(lambda blk, h: fn(
             jax.lax.optimization_barrier(blk), h, cfg, m_axis, s_axis, kind),
             policy=policy)

@@ -313,6 +313,140 @@ def test_the_indexer_learns_from_its_own_loss_alone(monkeypatch):
             np.testing.assert_array_equal(five[path], alone[path], err_msg=path)
 
 
+def _plain_index_loss(ip, xi, q, k, lse, sel, cfg, scale):
+    """The indexer's loss as one dense expression, for plain autodiff: the
+    einsum form of the scores over every key, float32, no custom VJP."""
+    import jax
+    import jax.numpy as jnp
+
+    qi, ki, w = seq_layers.indexer(ip, xi, cfg)
+    B, n, H, d = q.shape
+    s = jnp.einsum("bqhd,bkd->bqhk", qi, ki)
+    scores = (jax.nn.relu(s) * w[..., None]).sum(axis=2) * ki.shape[-1] ** -0.5
+    kg = jnp.repeat(k, H // k.shape[2], axis=2)
+    sa = jnp.einsum("bqhd,bkhd->bqhk", q, kg) * scale
+    p = jnp.where(sel, jnp.exp(sa - lse[..., None]).sum(axis=2) / H, 0.0)
+    z = jax.nn.logsumexp(jnp.where(sel, scores, -jnp.inf), axis=-1)
+    plogp = jnp.where(p > 0, p * jnp.log(jnp.where(p > 0, p, 1.0)), 0.0)
+    return (plogp.sum(-1) - jnp.where(sel, p * scores, 0.0).sum(-1)
+            + z * p.sum(-1)).sum()
+
+
+@pytest.fixture(scope="module")
+def one_walk():
+    """``at(cotangent)`` -> a layer's indexer loss and its gradient in the
+    indexer's parameters at that cotangent, the program's (walked once) and
+    plain autodiff's, and the selected pairs: two query blocks of 32, each
+    walked 16 queries at a time; one compiled program for every
+    cotangent."""
+    import jax
+    import jax.numpy as jnp
+
+    cfg, blk_size = CFG, 32
+    blk, h = _layer(cfg, seed=8)
+    B, H, Hkv, d = 2, cfg.heads_full, cfg.kv_heads, cfg.head_dim
+    q = jax.random.normal(jax.random.PRNGKey(1), (B, T, H, d))
+    k = jax.random.normal(jax.random.PRNGKey(2), (B, T, Hkv, d))
+    scale = d ** -0.5
+
+    @jax.jit
+    def at(cotangent):
+        xi = seq_layers.rms_norm(h, blk["attn_norm"], cfg.norm_eps)
+        ip = {name: blk[name] for name in seq_layers.INDEXER}
+        select = seq_layers.select_keys(*seq_layers.indexer(ip, xi, cfg),
+                                        TOPK, blk_size, blk_size,
+                                        jnp.float32)[0]
+        words = ring.select_words(blk_size)
+        sel = jnp.concatenate([ring.unpack_selection(
+            select[0][:, i * words:(i + 1) * words], blk_size)
+            for i in range(T // blk_size)], axis=1)
+        sa = jnp.einsum("bqhd,bkhd->bqhk", q, jnp.repeat(k, H // Hkv, axis=2))
+        lse = jax.nn.logsumexp(
+            jnp.where(sel[:, :, None], sa * scale, -jnp.inf), axis=-1)
+        got, back = jax.vjp(lambda ip: seq_layers.index_loss(
+            ip, xi, q, k, lse, select, blk_size, blk_size, scale, cfg), ip)
+        want, back_plain = jax.vjp(lambda ip: _plain_index_loss(
+            ip, xi, q, k, lse, sel, cfg, scale), ip)
+        return (got, want, back(cotangent)[0], back_plain(cotangent)[0],
+                sel.sum())
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(seq_layers, "INDEX_BLOCK", 16)
+        at(jnp.float32(1.0))  # traced with the walk's blocks of 16
+    return at
+
+
+@pytest.mark.parametrize("cotangent", [1.0, 2.0 ** -14, 0.3])
+def test_the_one_walk_loss_gives_plain_autodiffs_value_and_gradient(
+        one_walk, cotangent):
+    """The indexer's loss of a layer, walked once (its gradient in the
+    indexer's parameters taken in the forward pass and scaled in the
+    backward), against plain autodiff of the same KL: the same value and
+    the same gradient of every indexer parameter, whatever the loss's
+    cotangent (2 ** -14 is the cell's), within float32's summation order."""
+    import jax.numpy as jnp
+
+    got, want, grads, plain, pairs = one_walk(jnp.float32(cotangent))
+    assert 0 < int(pairs) < 2 * T * (T + 1) // 2  # a real selection
+    assert float(got) == pytest.approx(float(want), rel=2e-6)
+    assert float(got) > 0
+    for name in seq_layers.INDEXER:
+        bound = float(np.abs(plain[name]).max())
+        assert bound > 0, name
+        np.testing.assert_allclose(grads[name], plain[name], rtol=1e-4,
+                                   atol=1e-5 * bound, err_msg=name)
+
+
+def _kernels_and_targets(fn, *args):
+    """``Counter`` of the Pallas calls (by kernel name) and of the indexer
+    target's key-block loops (``while`` under ``seq.dsa/kl``) in the jaxpr
+    of ``fn(*args)``, every nested jaxpr walked."""
+    import collections
+
+    import jax
+
+    found = collections.Counter()
+
+    def walk(jaxpr, scope):
+        for eqn in jaxpr.eqns:
+            here = f"{scope}/{eqn.source_info.name_stack}"
+            if eqn.primitive.name == "pallas_call":
+                found[eqn.params["name"]] += 1
+            if eqn.primitive.name == "while" and "seq.dsa/kl" in here:
+                found["target"] += 1
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (tuple, list)) else [value]:
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        walk(inner, here)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, "")
+    return found
+
+
+def test_a_layers_gradient_walks_the_indexers_queries_once(monkeypatch):
+    """The step's loss through the sparse layers, the indexer on its kernels
+    (interpreted): differentiated, a layer runs the index scores' forward
+    kernel twice (the selection and the loss's one walk), its backward
+    kernel once and the target once, the layer's recomputation running
+    neither (what its checkpoint keeps: the selection and the indexer's
+    gradient); undifferentiated, the same forward work and no backward
+    kernel. The layers are scanned: the jaxpr holds one layer."""
+    import jax
+
+    monkeypatch.setattr(seq_layers, "index_impl",
+                        lambda *a: "pallas_interpret")
+    rows = np.random.default_rng(3).integers(1, V, (2, 128)).astype(np.int32)
+    params = seqrec.init_params(V, WIDE_INDEX)
+    loss = lambda p: program_loss(p, rows, WIDE_INDEX)
+    trained = _kernels_and_targets(
+        jax.value_and_grad(lambda p: loss(p)[0]), params)
+    assert trained == {"index_scores_fwd": 2, "index_scores_bwd": 1,
+                       "target": 1}
+    assert _kernels_and_targets(loss, params) == {"index_scores_fwd": 2,
+                                                  "target": 1}
+
+
 def test_selected_pairs_count_the_selection(trained):
     """``selected_pairs`` is summed from the selection itself: rows x layers
     x ``sum_t min(t + 1, k)`` a step; the attention's key-block counters are

@@ -530,7 +530,13 @@ def test_the_sparse_attention_cells_step_fits_a_v5e(topo, monkeypatch):
     offline with each layer's selection kept for the backward pass,
     11,731,404,800 found again there; the compiler's own report, 14.52 GiB
     in all, stands 0.56 GiB over the Laguna cell's step, which holds 14.29
-    GiB on the chip)."""
+    GiB on the chip). The indexer's loss walks the queries once, in the
+    forward pass, and the layer's recomputation keeps its gradient: a layer
+    holds two calls of the index scores' forward kernel (the selection and
+    that walk; three while the backward pass walked again) and one of its
+    backward kernel."""
+    import re
+
     import jax
     import numpy as np
 
@@ -544,7 +550,12 @@ def test_the_sparse_attention_cells_step_fits_a_v5e(topo, monkeypatch):
     assert mem.argument_size_in_bytes == pytest.approx(12 * parameters, rel=2e-3)
     assert mem.alias_size_in_bytes >= 12 * parameters  # the state is donated
     # the attention's two kernels and the grouped matmuls' run, every layer
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    index_calls = sorted(re.findall(
+        r"^\s*(?:ROOT )?%\w*?(index_scores_(?:fwd|bwd))_?[.\d]* = "
+        r".*tpu_custom_call", text, re.MULTILINE))
+    assert index_calls == ["index_scores_bwd"] + ["index_scores_fwd"] * 2
 
 
 def _accepted_step(topo, config_name):
