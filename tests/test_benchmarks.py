@@ -3,6 +3,13 @@ the yardstick, in ``benchmarks/tests``. This module imports them and hands
 their test functions to pytest under ``test_<file>__<name>``, so every later
 PR runs them (ROADMAP A0).
 
+Tier-1 runs on several workers with ``--dist loadfile``, which would keep
+this one module on one worker. ``tests/conftest.py`` (``unit_of`` and the
+``pytest_xdist_make_scheduler`` hook) makes each source file here a unit of
+its own instead: the tests of one file stay on one worker, different files
+spread. A file that writes a path another file writes joins that file's
+unit (``SHARED_UNIT``).
+
 Their ``from conftest import BENCH, ROOT`` means ``benchmarks/tests/
 conftest.py``, which puts ``benchmarks/`` on ``sys.path``; ``tests/
 conftest.py`` already owns the module name here, so it is lent out for the

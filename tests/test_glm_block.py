@@ -128,8 +128,10 @@ def test_three_adam_steps_match_the_reference(trained, group):
     assert group_errors(flat(model.params), ref["final"], update)[group] < 1e-3
 
 
-@pytest.mark.parametrize("group", R.GROUPS)
-def test_the_gradients_match_the_reference(group):
+@pytest.fixture(scope="module")
+def gradients():
+    """The program's and the reference's gradients of one batch's loss at the
+    same initial weights, computed once for every group's case."""
     import jax
     import jax.numpy as jnp
 
@@ -148,6 +150,12 @@ def test_the_gradients_match_the_reference(group):
     theirs = jax.device_get(jax.grad(
         lambda p: R.batch_loss(p, jnp.asarray(rows), M)[0]
     )(R.init_params(M, CFG.seed)))
+    return ours, theirs
+
+
+@pytest.mark.parametrize("group", R.GROUPS)
+def test_the_gradients_match_the_reference(gradients, group):
+    ours, theirs = gradients
     assert group_errors(ours, theirs, theirs)[group] < 1e-4
 
 
